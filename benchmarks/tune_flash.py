@@ -16,6 +16,7 @@ import os
 import time
 import jax
 import jax.numpy as jnp
+from ray_tpu.accelerators.tpu import peak_bf16_flops
 from ray_tpu.models import transformer as tf
 from ray_tpu.parallel import MeshPlan, build_mesh, make_train_state, make_train_step
 from ray_tpu.parallel import mesh as mesh_lib
@@ -52,7 +53,8 @@ _ = float(m["loss"])  # materialize: forces the whole chain
 dt = (time.perf_counter() - t0) / N
 flops_tok = tf.flops_per_token(cfg, 2048)
 n_params = sum(int(x.size) for x in jax.tree.leaves(params))
-mfu = (flops_tok * BATCH * 2048 / dt) / (197e12 * jax.device_count())
+peak = peak_bf16_flops(jax.devices()[0].device_kind)  # unknown kind raises
+mfu = (flops_tok * BATCH * 2048 / dt) / (peak * jax.device_count())
 tps = BATCH * 2048 / dt
 print(f"RESULT {dt*1e3:.1f} ms/step  MFU {mfu:.2%}  {tps:.0f} tok/s  params {n_params/1e6:.0f}M", flush=True)
 """

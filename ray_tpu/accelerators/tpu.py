@@ -1,9 +1,11 @@
 """TPUAcceleratorManager.
 
 Reference: python/ray/_private/accelerators/tpu.py:71 —
-- chip detection via /dev/accel* and vfio (:98-117)
+- chip detection via /dev/accel* and vfio (:98-117); the ONE detector,
+  ``init()`` calls it
 - ``TPU_VISIBLE_CHIPS`` isolation (:155-195) with valid per-host chip
-  counts {1, 2, 4, 8} (:14 TPU_VALID_CHIP_OPTIONS)
+  counts {1, 2, 4, 8} (:14 TPU_VALID_CHIP_OPTIONS); ``visible_chips_env``
+  is the ONE place that turns chip indices into libtpu's variables
 - GCE/GKE metadata pod-type lookup (:198-228)
 - pod-slice resources: ``TPU-<pod_type>-head`` on worker 0 and a
   ``TPU-<pod_type>`` name resource on every pod host (:334-397) so
@@ -13,6 +15,7 @@ from __future__ import annotations
 
 import glob
 import os
+import sys
 from typing import Dict, List, Optional
 
 TPU_VALID_CHIP_OPTIONS = (1, 2, 4, 8)
@@ -22,18 +25,50 @@ TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance/attributes/"
 
 
+# Published peak dense bf16 FLOP/s of one chip, keyed by jax's
+# ``device_kind`` (Google Cloud documentation, "TPU v5e": 197 TFLOP/s).
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_bf16_flops(device_kind: str) -> float:
+    """A utilization needs the peak of the device that ran: a kind that
+    is not in the table is an error, never a default."""
+    if device_kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            f"no published peak for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAK_BF16_FLOPS)}"
+        )
+    return PEAK_BF16_FLOPS[device_kind]
+
+
+def jax_backend_initialized() -> bool:
+    """True once THIS process has opened a jax backend — on a TPU host,
+    once it owns its chips. Never imports jax and never opens the
+    backend itself (asking jax for its devices would)."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 class TPUAcceleratorManager:
     resource_name = "TPU"
 
     # -- detection ----------------------------------------------------------
     @staticmethod
-    def get_current_node_num_accelerators() -> int:
-        """Count local chips (reference: tpu.py:98-117)."""
-        n = len(glob.glob("/dev/accel*"))
-        if n == 0:
-            entries = glob.glob("/dev/vfio/*")
-            n = max(len([e for e in entries if not e.endswith("/vfio")]), 0)
-        return n
+    def detect_chips() -> tuple[int, str]:
+        """(chips this host can open, where they were seen): one
+        ``/dev/accel<N>`` per chip under the accel driver, else one
+        ``/dev/vfio/<group>`` per chip passed through VFIO (the v5e hosts
+        this repo runs on: ``/dev/vfio/2`` next to the ``/dev/vfio/vfio``
+        control node). PCI sysfs is not used: it lists every chip of the
+        physical host, including those this machine was not given."""
+        n = len(glob.glob("/dev/accel[0-9]*"))
+        if n:
+            return n, "/dev/accel*"
+        n = len(glob.glob("/dev/vfio/[0-9]*"))
+        return n, "/dev/vfio/*" if n else "no /dev/accel* or /dev/vfio/<group>"
 
     @staticmethod
     def get_current_node_accelerator_type() -> Optional[str]:
@@ -70,10 +105,29 @@ class TPUAcceleratorManager:
         )
 
     @staticmethod
-    def set_current_process_visible_accelerators(chip_ids: List[int]):
-        """TPU_VISIBLE_CHIPS must be set before the first jax import in the
-        process (libtpu reads it at initialization)."""
-        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in chip_ids)
+    def visible_chips_env(chip_ids: List[int], chips_on_host: int) -> Dict[str, str]:
+        """The variables that make exactly ``chip_ids`` this process's
+        TPU. libtpu reads them once, when the process first opens the
+        chip, so they must be in its environment before that (the
+        controller puts them in a TPU actor's runtime env, applied before
+        the actor's code is even unpickled).
+
+        Established on a four-chip v5e host, libtpu 0.0.34 (PR 21):
+        all chips — nothing, libtpu's default (and the host image's own
+        TPU_* variables) describe that; ONE chip — which one, and the
+        bounds of a one-chip, one-process slice (four such processes run
+        side by side). Other subsets carry the reference's bounds
+        unverified: two of four chips were refused with every pairing
+        and bounds tried, so a 2-chip actor on a 4-chip host is broken."""
+        n = len(chip_ids)
+        if n == chips_on_host:
+            return {}
+        return {
+            TPU_VISIBLE_CHIPS_ENV: ",".join(str(i) for i in chip_ids),
+            # four v5e chips sit as a 2x2, not the reference's 1x4 row
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "2,2,1" if n == 4 else f"1,{n},1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
 
     @staticmethod
     def get_current_process_visible_accelerator_ids() -> Optional[List[int]]:

@@ -114,7 +114,7 @@ def _spawn_monitor(cfg: dict, address: str, session_dir: str) -> int:
                 "--address", address, "--session-dir", session_dir,
                 "--config-json", json.dumps(mon_cfg),
             ],
-            env=child_env(needs_tpu=False),
+            env=child_env(),
             stdout=log, stderr=subprocess.STDOUT,
         )
     return mon.pid

@@ -178,7 +178,7 @@ class FakeGceTpuApi(GceTpuApi):
             resources[f"TPU-{accelerator_type}"] = 1
             if host_idx == 0:
                 resources[f"TPU-{accelerator_type}-head"] = 1
-            env = child_env(needs_tpu=False)
+            env = child_env()
             env["RAY_TPU_PROVIDER_INSTANCE_ID"] = f"{name}/host{host_idx}"
             log_path = os.path.join(
                 self.session_dir, "logs", f"gce-{name}-h{host_idx}.log"

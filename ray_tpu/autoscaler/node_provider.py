@@ -55,7 +55,7 @@ class FakeMultiNodeProvider(NodeProvider):
         log_path = os.path.join(self._session_dir, "logs", f"autoscaled-{provider_id}.log")
         os.makedirs(os.path.dirname(log_path), exist_ok=True)
         log = open(log_path, "ab")
-        env = child_env(needs_tpu=False)
+        env = child_env()
         # The agent reports this back at register_node, giving the
         # autoscaler the provider↔node identity it needs for per-node
         # idle scale-down (reference: v2 instance_manager cloud ids).

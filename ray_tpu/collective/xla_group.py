@@ -44,13 +44,11 @@ def in_graph_allreduce(x, mesh=None, axis_name: str = "ranks"):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.collective import diagnostics
-    from ray_tpu.utils import jax_compat
-
     if mesh is None:
         mesh, axis_name = mesh_for_group(axis_name=axis_name)
 
     @functools.partial(
-        jax_compat.shard_map, mesh=mesh, in_specs=P(axis_name), out_specs=P()
+        jax.shard_map, mesh=mesh, in_specs=P(axis_name), out_specs=P()
     )
     def _psum(shard):
         return lax.psum(shard.sum(axis=0), axis_name)

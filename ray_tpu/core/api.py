@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import atexit
 import json
+import logging
 import os
 import subprocess
 import sys
@@ -20,6 +21,8 @@ from ray_tpu.core.client import CoreWorker
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.remote_function import RemoteFunction
 from ray_tpu.utils import rpc
+
+logger = logging.getLogger("ray_tpu")
 
 _global_worker: Optional[CoreWorker] = None
 _controller_proc: Optional[subprocess.Popen] = None
@@ -40,18 +43,6 @@ def _attach_worker(core: CoreWorker):
     """Called by worker processes so the public API works inside tasks."""
     global _global_worker
     _global_worker = core
-
-
-def _detect_tpu_chips() -> int:
-    """Count local TPU chips (reference:
-    python/ray/_private/accelerators/tpu.py:98-117 — /dev/accel* and vfio)."""
-    import glob
-
-    n = len(glob.glob("/dev/accel*"))
-    if n == 0:
-        n = len(glob.glob("/dev/vfio/*")) - (1 if os.path.exists("/dev/vfio/vfio") else 0)
-        n = max(n, 0)
-    return n
 
 
 def init(
@@ -90,12 +81,25 @@ def init(
                 "env var and no address file)"
             )
 
+    # The driver's own jax (single-process loops) shares the workers'
+    # compile cache. The variable only reaches a jax imported later.
+    from ray_tpu.core.node_agent import place_compile_cache
+
+    cache_dir = place_compile_cache(os.environ)
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
     if address is None:
         head_resources = dict(resources or {})
         head_resources.setdefault("CPU", num_cpus if num_cpus is not None else os.cpu_count() or 1)
-        tpus = num_tpus if num_tpus is not None else _detect_tpu_chips()
-        if tpus:
-            head_resources.setdefault("TPU", tpus)
+        if num_tpus is None:
+            from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+            num_tpus, how = TPUAcceleratorManager.detect_chips()
+            logger.info("TPU chips on this host: %d (%s)", num_tpus, how)
+        if num_tpus:
+            head_resources.setdefault("TPU", num_tpus)
         cfg_overrides = dict(_system_config or {})
         if object_store_memory:
             cfg_overrides["object_store_memory"] = object_store_memory
@@ -148,7 +152,7 @@ def _start_controller(head_resources: dict, cfg_overrides: dict, owned: bool):
     os.makedirs(os.path.join(session_dir, "logs"), exist_ok=True)
     from ray_tpu.core.node_agent import child_env
 
-    env = child_env(needs_tpu=False)
+    env = child_env()
     log = open(os.path.join(session_dir, "logs", "controller.log"), "ab")
     cmd = [
         sys.executable,

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -17,6 +18,41 @@ from typing import Dict, List, Optional
 from ray_tpu.core import api
 from ray_tpu.core.client import CoreWorker
 from ray_tpu.utils import rpc
+
+
+_CLUSTER_PROC = re.compile(r"ray_tpu\.core\.(controller|worker_main|node_agent)")
+
+
+def cluster_processes() -> Dict[int, str]:
+    """{pid: command line} of every live controller, node agent and
+    worker on this host (from /proc; zombies have an empty cmdline)."""
+    found = {}
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                cmd = f.read().replace(b"\0", b" ").decode(errors="replace")
+        except OSError:
+            continue  # exited while we looked
+        if _CLUSTER_PROC.search(cmd):
+            found[int(pid)] = cmd.strip()
+    return found
+
+
+def wait_cluster_processes_gone(timeout_s: float = 60.0) -> None:
+    """Block until none is left. ``shutdown()`` waits for the controller
+    only; a worker that held a TPU still owns it until the process is
+    gone, and the next process to ask for the chip fails. Call this
+    between a cluster that used the chip and whatever opens it next."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        left = cluster_processes()
+        if not left:
+            return
+        if time.monotonic() > deadline:
+            raise TimeoutError(
+                f"cluster processes still alive after {timeout_s:.0f}s: {left}"
+            )
+        time.sleep(0.2)
 
 
 class NodeHandle:
@@ -58,7 +94,7 @@ class Cluster:
         from ray_tpu.core.node_agent import child_env
 
         before = self._list_node_ids()
-        env = child_env(needs_tpu=False)
+        env = child_env()
         if labels:
             env["RAY_TPU_NODE_LABELS"] = json.dumps(labels)
         log = open(os.path.join(self._session_dir, "logs", f"agent-{len(self._nodes)}.log"), "ab")

@@ -1765,9 +1765,12 @@ class Controller:
         self._schedule_pump()
 
     def _assign_tpu_chips(self, actor: ActorRecord, spec: TaskSpec, node: NodeRecord):
-        """Give a TPU actor concrete chip indices via TPU_VISIBLE_CHIPS
-        (reference: tpu.py:155-195; per-instance accounting,
-        resource_instance_set.cc). Applied in-worker before jax loads."""
+        """Give a TPU actor concrete chips (reference: tpu.py:155-195;
+        per-instance accounting, resource_instance_set.cc). This is the
+        ONLY place chips are chosen; the variables ride the actor's
+        runtime env, which the worker applies before it unpickles the
+        actor class — so before user code can import jax."""
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
         from ray_tpu.core.resources import from_fp
 
         n = int(from_fp(spec.resources.get("TPU")))
@@ -1784,17 +1787,20 @@ class Controller:
         chips, node.tpu_free = node.tpu_free[:n], node.tpu_free[n:]
         actor.tpu_chips = chips
         actor.tpu_node = node.node_id
+        on_host = int(from_fp(self.cluster.nodes[node.node_id].total.get("TPU")))
         renv = dict(spec.runtime_env or {})
         env_vars = dict(renv.get("env_vars") or {})
-        env_vars["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
-        renv["env_vars"] = env_vars
-        spec.runtime_env = renv
+        env_vars.update(TPUAcceleratorManager.visible_chips_env(chips, on_host))
+        if env_vars:
+            renv["env_vars"] = env_vars
+            spec.runtime_env = renv
 
     def _release_tpu_chips(self, actor: ActorRecord):
         if actor.tpu_chips and actor.tpu_node is not None:
             node = self.nodes.get(actor.tpu_node)
             if node is not None:
-                node.tpu_free.extend(actor.tpu_chips)
+                # lowest-first keeps multi-chip grants adjacent
+                node.tpu_free = sorted(node.tpu_free + actor.tpu_chips)
         actor.tpu_chips = []
         actor.tpu_node = None
 

@@ -10,10 +10,11 @@ chips can read — so DEVICE samples are taken by workers (shipped via
 (shipped inside its telemetry heartbeat) and by the controller for the
 head node.
 
-Sampling is deliberately jax-import-free: ``sample_devices`` reads
-devices only when jax is ALREADY imported in this process (a control
-plane process must never pay the TPU-runtime import, and must never
-grab chips it doesn't own).
+Sampling never takes a chip: ``sample_devices`` reads devices only when
+this process has ALREADY initialized a jax backend. Asking jax for its
+devices initializes the backend, and a TPU belongs to one process — a
+driver that merely did ``import jax`` must not open the chip its train
+worker or serve replica is about to ask for.
 """
 from __future__ import annotations
 
@@ -60,19 +61,17 @@ def build_node_sample(cpu_sampler, store) -> Dict:
 def sample_devices() -> List[Dict]:
     """Per-device memory stats of THIS process's accelerators.
 
-    Returns [] when jax is not imported here (never triggers the import)
-    or when the backend doesn't expose memory_stats (CPU). Rows:
+    Returns [] until user code in this process has initialized a jax
+    backend (never the first toucher — see the module docstring), and
+    when the backend doesn't expose memory_stats (CPU). Rows:
     {id, platform, kind, bytes_in_use, peak_bytes_in_use, bytes_limit}.
     """
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return []
-    try:
-        devices = jax.local_devices()
-    except Exception:  # noqa: BLE001 — backend not initialized / gone
+    from ray_tpu.accelerators.tpu import jax_backend_initialized
+
+    if not jax_backend_initialized():
         return []
     rows = []
-    for d in devices:
+    for d in sys.modules["jax"].local_devices():
         try:
             stats = d.memory_stats()
         except Exception:  # noqa: BLE001 — backend without memory_stats

@@ -8,6 +8,15 @@ libraries); on TPU the kernel must be native (SURVEY.md §2.9). Design:
   logsumexp, with a custom VJP running the flash *backward* as two Pallas
   kernels (dQ over q-blocks; dK/dV over k-blocks) — memory stays
   O(seq·d), no seq² materialization in either direction.
+  SEQUENCE CEILING: that O(seq·d) is VMEM, not HBM. Each program keeps
+  one head's whole K and V (forward, dQ) or whole Q and dO (dK/dV)
+  resident, so seq·head_dim·itemsize per array is bounded by
+  ``_resident_limit_bytes`` — 10,240 tokens at head_dim 128 in bf16,
+  5,120 in fp32 — and a longer sequence raises ValueError before
+  lowering. Longer sequences split over chips (``MeshPlan(sp=...)``)
+  until the kernels tile K/V. The backward additionally needs a q
+  length that is a multiple of 128 (forward-only callers — the serving
+  prefill buckets 8..72 — do not).
 - ``reference_attention``: straight jnp implementation used for CPU tests,
   as the non-TPU VJP path, and as the numerical oracle.
 
@@ -147,6 +156,31 @@ def _kv_index_map(q_heads: int, kv_heads: int):
     return imap
 
 
+def _resident_limit_bytes(head_dim: int) -> int:
+    """Largest seq·head_dim·itemsize one resident array may have, from
+    AOT compiles against a v5e topology (16 MiB scoped VMEM per core;
+    the resident arrays are double-buffered and share it with the q/o
+    blocks and the fp32 score temporaries). head_dim 128: bf16 12,288
+    and fp32 5,120 rows compile, 13,312 and 6,144 are refused; head_dim
+    256 bf16: 6,144 compiles, 7,168 is refused; head_dim 64: bf16
+    49,152 and fp32 24,576 compile, 65,536 and 32,768 are refused."""
+    return (6 << 20) if head_dim <= 64 else (5 << 19)
+
+
+def _check_resident_fits(what: str, rows: int, head_dim: int, dtype) -> None:
+    itemsize = jnp.dtype(dtype).itemsize
+    limit = _resident_limit_bytes(head_dim)
+    if rows * head_dim * itemsize > limit:
+        raise ValueError(
+            f"flash_attention: {what} of {rows} rows at head_dim {head_dim} "
+            f"({jnp.dtype(dtype).name}) exceeds the kernels' sequence ceiling "
+            f"of {limit // (head_dim * itemsize)} rows: each program keeps one "
+            f"head's whole K/V (forward, dQ) and Q/dO (dK/dV) resident in "
+            f"VMEM, at most {limit / 2**20:.1f} MiB per array on a v5e core. "
+            f"Split the sequence over chips (MeshPlan sp > 1) or shorten it."
+        )
+
+
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int, block_k: int, interpret: bool):
     batch, heads, q_len, head_dim = q.shape
     kv_heads = k.shape[1]
@@ -163,6 +197,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int, block_k: i
         kr = jnp.pad(kr, ((0, 0), (0, k_pad), (0, 0)))
         vr = jnp.pad(vr, ((0, 0), (0, k_pad), (0, 0)))
     k_len_padded = k_len + k_pad
+    if not interpret:
+        _check_resident_fits("K/V", k_len_padded, head_dim, k.dtype)
     kv_map = _kv_index_map(heads, kv_heads)
     grid = (batch * heads, pl.cdiv(q_len, bq))
     out, lse = pl.pallas_call(
@@ -372,6 +408,8 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
         kr = jnp.pad(kr, ((0, 0), (0, k_pad), (0, 0)))
         vr = jnp.pad(vr, ((0, 0), (0, k_pad), (0, 0)))
     k_len_p = k_len + k_pad
+    if not interpret:
+        _check_resident_fits("K/V", k_len_p, head_dim, k.dtype)
     kv_map = _kv_index_map(heads, kv_heads)
 
     # dQ: grid over q blocks, K/V resident (GQA: shared kv head indexed).
@@ -407,6 +445,18 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
         lser = jnp.pad(lser, ((0, 0), (0, 0), (0, q_pad)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, q_pad)))
     q_len_p = q_len + q_pad
+    if not interpret:
+        _check_resident_fits("Q/dO", q_len_p, head_dim, q.dtype)
+        if bq % 128:
+            # dK/dV slices lse/delta along the lane axis at a dynamic
+            # q-block offset; Mosaic needs that offset provably 128-aligned
+            # ("cannot statically prove that index in dimension 2 is a
+            # multiple of 128" otherwise).
+            raise ValueError(
+                f"flash_attention backward: q block {bq} (min of block_q "
+                f"{block_q} and q_len {q_len}) must be a multiple of 128 on "
+                f"TPU; pad the sequence to a multiple of 128"
+            )
 
     if group > 1:
         bkv = batch * kv_heads
@@ -493,18 +543,18 @@ def _default_blocks(q_len: int, k_len: int, head_dim: int, bwd: bool = False):
 
 
 def _use_pallas() -> bool:
+    """Pallas kernels when the default backend is a TPU; the jnp
+    reference on CPU (tier-1 tests run there). No other fallback: a TPU
+    backend that fails to come up raises here."""
     import os
 
     # AOT compiles against a TPU *topology* run with a CPU default
     # backend — the env override lets them force the TPU lowering
-    # (benchmarks/compile_7b.py --backend tpu).
+    # (benchmarks/compile_7b.py --backend tpu, tests/test_chip_bringup.py).
     force = os.environ.get("RAY_TPU_FORCE_PALLAS")
     if force is not None:
         return force == "1"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -542,7 +592,24 @@ def _bwd(causal, scale, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def make_flash_attn_fn(mesh, causal: bool = True):
+def unmanual_axes(mesh):
+    """(mesh to shard_map over, its axes not yet manual). Inside another
+    shard_map (the pp pipeline body) the AMBIENT abstract mesh replaces
+    the construction-time ``mesh``: it marks which axes are already
+    manual. Mosaic's lowering requires the union of manual axes to cover
+    EVERY mesh axis (tpu_custom_call.py), so a shard_map around a Pallas
+    kernel manualizes all of the returned axes; size-1 axes cost
+    nothing."""
+    from jax.sharding import AxisType
+
+    cur = jax.sharding.get_abstract_mesh()
+    use = cur if cur.shape else mesh
+    return use, {
+        n for n, t in zip(use.axis_names, use.axis_types) if t != AxisType.Manual
+    }
+
+
+def make_flash_attn_fn(mesh, causal: bool = True, scale: Optional[float] = None):
     """Flash attention for MULTI-DEVICE meshes: Mosaic (Pallas) kernels
     cannot be auto-partitioned by GSPMD, so the kernel must run inside a
     shard_map that makes the batch/head axes manual — each device runs
@@ -551,32 +618,16 @@ def make_flash_attn_fn(mesh, causal: bool = True):
     call on single-device meshes and when no known axes are present.
 
     Same construction-time-mesh/ambient-mesh convention as
-    ring.make_ring_attn_fn so it nests under the pp pipeline shard_map.
+    ring.make_ring_attn_fn so it nests under the pp pipeline shard_map;
+    ``mesh`` may be None for a caller that is always inside a shard_map
+    (the Ulysses per-shard body).
     """
 
     def attn(q, k, v):
-        from ray_tpu.utils import jax_compat
-
-        cur = jax_compat.get_abstract_mesh()
-        use = cur if (cur is not None and cur.shape) else mesh
-        if getattr(use, "size", 1) <= 1:
-            return flash_attention(q, k, v, causal, None)
-        # Mosaic's lowering requires the union of manual axes to cover
-        # EVERY mesh axis (tpu_custom_call.py) — manualize all axes not
-        # already manual in the ambient context (e.g. pp inside the
-        # pipeline body); size-1 axes cost nothing.
-        types = getattr(use, "axis_types", None)
-        if types is None:
-            manual = set(use.axis_names)
-        else:
-            from jax.sharding import AxisType
-
-            manual = {
-                n for n, t in zip(use.axis_names, types) if t != AxisType.Manual
-            }
-        if not manual:
-            # fully-manual context already: data is per-device local
-            return flash_attention(q, k, v, causal, None)
+        use, manual = unmanual_axes(mesh)
+        if use.size <= 1 or not manual:
+            # one device, or a fully-manual context: data is already local
+            return flash_attention(q, k, v, causal, scale)
         batch_axes = tuple(a for a in ("dp", "fsdp") if a in manual)
         head_axis = None
         if "tp" in manual:
@@ -596,8 +647,8 @@ def make_flash_attn_fn(mesh, causal: bool = True):
         from jax.sharding import PartitionSpec as P
 
         qspec = P(batch_axes or None, head_axis, None, None)
-        fn = jax_compat.shard_map(
-            lambda q, k, v: flash_attention(q, k, v, causal, None),
+        fn = jax.shard_map(
+            lambda q, k, v: flash_attention(q, k, v, causal, scale),
             mesh=use,
             in_specs=(qspec, qspec, qspec),
             out_specs=qspec,

@@ -76,16 +76,14 @@ class HopBridge:
         self._spec = spec
         self.sharding = NamedSharding(self.mesh, spec)
 
-        from ray_tpu.utils import jax_compat
-
         @functools.partial(
-            jax_compat.shard_map, mesh=self.mesh, in_specs=spec, out_specs=spec
+            jax.shard_map, mesh=self.mesh, in_specs=spec, out_specs=spec
         )
         def _fwd(x):
             return jax.lax.ppermute(x, "hop", [(0, 1)])
 
         @functools.partial(
-            jax_compat.shard_map, mesh=self.mesh, in_specs=spec, out_specs=spec
+            jax.shard_map, mesh=self.mesh, in_specs=spec, out_specs=spec
         )
         def _rev(x):
             return jax.lax.ppermute(x, "hop", [(1, 0)])

@@ -83,6 +83,21 @@ def make_stage_bwd(stage_fn: Callable) -> Callable:
     return bwd
 
 
+def stage_programs(cfg: "tf.TransformerConfig", attn_fn, mesh: Mesh):
+    """(fwd, bwd) bodies for the stage that lives on ``mesh``. A Pallas
+    kernel cannot be auto-partitioned by GSPMD, so on a multi-device stage
+    mesh the flash attention runs inside its own shard_map over that mesh
+    — the same rule train_step.build_loss_fn applies to the in-graph
+    plans (on a TPU the bare kernel is refused: "Mosaic kernels cannot be
+    automatically partitioned")."""
+    if attn_fn is None and mesh.size > 1:
+        from ray_tpu.ops.attention import make_flash_attn_fn
+
+        attn_fn = make_flash_attn_fn(mesh)
+    stage_fn = make_stage_fn(cfg, attn_fn)
+    return stage_fn, make_stage_bwd(stage_fn)
+
+
 def make_head_loss(cfg: "tf.TransformerConfig") -> Callable:
     def head_loss(head_params, h, targets, mask):
         logits = tf.unembed(head_params, h, cfg)
@@ -150,9 +165,6 @@ class MpmdPipeline:
         self._act_spec = P(("fsdp",) if stage_fsdp > 1 else None)
         self.stages: List[_Stage] = []
 
-        stage_fn = make_stage_fn(cfg, attn_fn)
-        self._stage_fn = stage_fn
-        bwd = make_stage_bwd(stage_fn)
         all_specs = mesh_lib.param_specs(cfg, self._stage_plan)
         self._layer_specs = all_specs["layers"]
         for s in range(num_stages):
@@ -160,6 +172,7 @@ class MpmdPipeline:
                 rep, stage_fsdp, stage_tp
             )
             mesh = Mesh(devs, ("rep", "fsdp", "tp"))
+            stage_fn, bwd = stage_programs(cfg, attn_fn, mesh)
             shard = NamedSharding(mesh, self._act_spec)
             lshard = jax.tree.map(
                 lambda sp: NamedSharding(mesh, sp), self._layer_specs,

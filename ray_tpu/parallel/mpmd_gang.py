@@ -37,12 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import transformer as tf
 from ray_tpu.parallel.hop_bridge import HopBridge
-from ray_tpu.parallel.mpmd import (
-    make_embed_bwd,
-    make_head_loss,
-    make_stage_bwd,
-    make_stage_fn,
-)
+from ray_tpu.parallel.mpmd import make_embed_bwd, make_head_loss, stage_programs
 
 
 @dataclass
@@ -88,8 +83,6 @@ class MpmdGangPipeline:
         all_specs = mesh_lib.param_specs(cfg, self._stage_plan)
         layer_specs = all_specs["layers"]
 
-        stage_fn = make_stage_fn(cfg, attn_fn)
-        bwd_fn = make_stage_bwd(stage_fn)
         self.stages: List[_GangStage] = []
         for s in range(num_stages):
             devs = devices[s * per : (s + 1) * per]
@@ -104,6 +97,7 @@ class MpmdGangPipeline:
             mesh = Mesh(
                 np.array(devs).reshape(rep, 1, stage_tp), ("rep", "fsdp", "tp")
             )
+            stage_fn, bwd_fn = stage_programs(cfg, attn_fn, mesh)
             shard = NamedSharding(mesh, P())
             lshard = jax.tree.map(
                 lambda sp: NamedSharding(mesh, sp), layer_specs,

@@ -98,9 +98,7 @@ def pipeline_apply(
     h_spec = P(None, None, seq_axis, None) if seq_axis else P()
     pos_spec = P(None, seq_axis) if seq_axis else P()
     manual = {axis_name} | ({seq_axis} if seq_axis else set())
-    from ray_tpu.utils import jax_compat
-
-    body = jax_compat.shard_map(
+    body = jax.shard_map(
         functools.partial(
             _pipeline_body,
             stage_fn=stage_fn,

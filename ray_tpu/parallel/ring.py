@@ -54,9 +54,7 @@ def ring_attention_local(q, k, v, axis_name: str, scale: Optional[float] = None)
     """Per-shard body — call inside shard_map with q,k,v local shards
     [b, h, s_local, d]."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    from ray_tpu.utils import jax_compat
-
-    sp = jax_compat.axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
 
@@ -107,11 +105,9 @@ def make_ring_attn_fn(mesh: Mesh, axis_name: str = "sp"):
     body = functools.partial(ring_attention_local, axis_name=axis_name)
 
     def attn(q, k, v):
-        from ray_tpu.utils import jax_compat
-
-        cur = jax_compat.get_abstract_mesh()
-        use = cur if (cur is not None and cur.shape) else mesh
-        fn = jax_compat.shard_map(
+        cur = jax.sharding.get_abstract_mesh()
+        use = cur if cur.shape else mesh
+        fn = jax.shard_map(
             body,
             mesh=use,
             in_specs=(spec, spec, spec),

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import make_flash_attn_fn, unmanual_axes
 
 
 def ulysses_attention_local(q, k, v, axis_name: str, scale: Optional[float] = None,
@@ -35,9 +35,7 @@ def ulysses_attention_local(q, k, v, axis_name: str, scale: Optional[float] = No
     """Per-shard body — call inside shard_map with q,k,v local shards
     ``[b, h, s_local, d]``. Requires ``h % sp == 0`` (heads per device
     after any tp split must still divide sp)."""
-    from ray_tpu.utils import jax_compat
-
-    sp = jax_compat.axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % sp != 0:
         raise ValueError(
@@ -51,7 +49,9 @@ def ulysses_attention_local(q, k, v, axis_name: str, scale: Optional[float] = No
     qkv = jnp.stack([q, k, v])  # [3, b, h, s/sp, d]
     qkv = jax.lax.all_to_all(qkv, axis_name, split_axis=2, concat_axis=3, tiled=True)
     qh, kh, vh = qkv  # each [b, h/sp, s, d]
-    out = flash_attention(qh, kh, vh, causal=causal, scale=scale)
+    # Under the pp pipeline only pp and sp are manual here; the Pallas
+    # kernel needs the rest manual too (a direct call when they all are).
+    out = make_flash_attn_fn(None, causal, scale)(qh, kh, vh)
     # [b, h/sp, s, d] -> [b, h, s/sp, d]
     return jax.lax.all_to_all(out, axis_name, split_axis=2, concat_axis=1, tiled=True)
 
@@ -65,17 +65,16 @@ def make_ulysses_attn_fn(mesh: Mesh, axis_name: str = "sp"):
     body = functools.partial(ulysses_attention_local, axis_name=axis_name)
 
     def attn(q, k, v):
-        # nestable under a pp shard_map — see ring.make_ring_attn_fn
-        from ray_tpu.utils import jax_compat
-
-        cur = jax_compat.get_abstract_mesh()
-        use = cur if (cur is not None and cur.shape) else mesh
-        fn = jax_compat.shard_map(
+        # Nestable under a pp shard_map like ring.make_ring_attn_fn, but
+        # the body runs the Pallas flash kernel, so EVERY axis not yet
+        # manual is manualized (ep/pp too), not only the four in ``spec``.
+        use, manual = unmanual_axes(mesh)
+        fn = jax.shard_map(
             body,
             mesh=use,
             in_specs=(spec, spec, spec),
             out_specs=spec,
-            axis_names={"dp", "fsdp", "tp", axis_name},
+            axis_names=manual,
             check_vma=False,
         )
         return fn(q, k, v)

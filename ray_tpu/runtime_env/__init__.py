@@ -101,6 +101,19 @@ def env_hash(env: Optional[dict]) -> str:
 # Built-in plugins (applied inside the worker process)
 # ---------------------------------------------------------------------------
 def _setup_env_vars(value: Dict[str, str]):
+    tpu_keys = sorted(k for k in value if k.startswith("TPU_"))
+    if tpu_keys:
+        from ray_tpu.accelerators.tpu import jax_backend_initialized
+
+        if jax_backend_initialized() and sys.modules["jax"].default_backend() == "tpu":
+            # libtpu read its chip set when the backend came up; setting
+            # it now would silently leave the actor on the wrong chips.
+            raise RuntimeEnvSetupError(
+                f"cannot apply {tpu_keys}: "
+                "this pooled worker already opened a jax backend (an earlier "
+                "task without TPU resources touched jax). Only tasks/actors "
+                "that hold TPU resources may initialize jax on a TPU host."
+            )
     os.environ.update(value)
 
 

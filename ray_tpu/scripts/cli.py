@@ -86,7 +86,7 @@ def cmd_start(args):
         json.dumps(res),
     ]
     os.makedirs(os.path.join(args.session_dir or "/tmp/ray_tpu/cli_node", "logs"), exist_ok=True)
-    proc = subprocess.Popen(cmd, env=child_env(needs_tpu=bool(args.num_tpus)))
+    proc = subprocess.Popen(cmd, env=child_env())
     print(f"node agent joining {args.address} (pid {proc.pid})")
     if args.block:
         proc.wait()

@@ -264,21 +264,25 @@ class ServeController:
 
     def _healthy(self, replica) -> bool:
         key = replica._actor_id
+        confirmed = key in self._confirmed
         in_grace = (
-            key not in self._confirmed
+            not confirmed
             and time.time() - self._birth.get(key, time.time()) < self.INIT_GRACE_S
         )
-        if in_grace:
+        if not confirmed:
             # Don't burn a 5s ping timeout on a replica still inside
-            # __init__ — ask the cluster's actor table instead. ALIVE but
-            # unconfirmed also stays in grace: the first requests may be
-            # holding every actor thread through a long jit warmup.
+            # __init__ — ask the cluster's actor table instead. It, not a
+            # clock, says when __init__ is over: a 7B engine builds and
+            # compiles for minutes, and a replica killed at INIT_GRACE_S
+            # mid-compile is replaced by one that meets the same end.
             state = self._replica_state(key)
             if state == "DEAD":
                 self._kill(replica)
                 return False
-            if state != "ALIVE":
-                return True  # PENDING / RESTARTING / UNKNOWN: keep waiting
+            if state in ("PENDING", "RESTARTING") or (state != "ALIVE" and in_grace):
+                return True
+        # ALIVE but unconfirmed stays in grace: the first requests may be
+        # holding every actor thread through a long jit warmup.
         try:
             ok = self._ray.get(replica.check_health.remote(), timeout=5) == "ok"
             if ok:

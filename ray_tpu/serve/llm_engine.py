@@ -52,6 +52,7 @@ logger = logging.getLogger("ray_tpu.serve.engine")
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from ray_tpu.models.paged import (
     TRASH_BLOCK,
@@ -297,6 +298,26 @@ class _ChunkState:
     plen: int
 
 
+def _leave_persistent_compile_cache() -> None:
+    """Take THIS process out of jax's persistent compile cache, for good.
+
+    The engine's programs carry non-default array layouts, and an
+    executable that comes back from the cache does not keep them: on a
+    v5e (jax 0.9.0, libtpu 0.0.34) the cached weight-init program, asked
+    for the decode program's layout, returned default-layout arrays, and
+    the second start of a 7B replica died in warm-up with "Layout passed
+    to jit does not match the layout on the respective arg". A cached
+    prefill that silently misread its weights would be worse, so no
+    engine program is read from or written to the cache. The flag is
+    latched per process at the first compile; reset_cache() re-arms it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if jax.config.jax_enable_compilation_cache:
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        logger.info("persistent compile cache off in this process (AUTO-layout programs)")
+
+
 class LLMEngine:
     """Continuous-batching engine for one model on one chip/mesh."""
 
@@ -366,6 +387,7 @@ class LLMEngine:
             prefill_chunk = min(prefill_chunk, p.max_seq_len)
         self.prefill_chunk = int(prefill_chunk or 0)
         self.prefix_cache = _PrefixCache() if enable_prefix_cache else None
+        _leave_persistent_compile_cache()
         self.cache = init_paged_cache(cfg, p)
         (self._decode, self._prefill, self._prefill_chunk_fn,
          self.params) = self._build_programs(params)
@@ -449,15 +471,16 @@ class LLMEngine:
     def _build_programs(self, params):
         """Build the decode window + prefill programs.
 
-        On TPU the decode program is AOT-compiled with AUTO input
-        layouts and ``params`` is device_put into the layout the program
-        chose: decode matvecs prefer a transposed tiling for the big
+        The decode program is AOT-compiled with AUTO input layouts and
+        ``params`` is device_put into the layout the program chose: on
+        TPU decode matvecs prefer a transposed tiling for the big
         projection stacks, and feeding default-layout params makes XLA
         insert per-call relayout copies (3 GB of HBM temps at 7B — an
         OOM on a 16 GB chip next to the weights). Prefill is then
         compiled to ACCEPT that same layout, so one params tree serves
-        both programs copy-free. Falls back to plain jit where custom
-        layouts are unsupported (CPU tests)."""
+        both programs copy-free. There is no plain-jit fallback: a
+        failure here is the failure to report (at 7B the fallback's own
+        program does not fit the chip and would bury it under an OOM)."""
         cfg, p, window = self.cfg, self.pcfg, self.window
         bs = p.block_size
 
@@ -482,56 +505,44 @@ class LLMEngine:
                 last_idx, temp, key,
             )
 
-        try:
-            from jax.experimental.layout import Format, Layout
-
-            sds = jax.ShapeDtypeStruct
-            b, W = p.max_batch, p.max_blocks_per_seq
-            if callable(params):
-                params_s = jax.eval_shape(params)
-            else:
-                params_s = jax.tree.map(lambda x: sds(x.shape, x.dtype), params)
-            cache_s = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.cache)
-            args_s = (
-                params_s,
-                sds((b,), np.int32),
-                cache_s,
-                sds((b, W), np.int32),
-                sds((b,), np.int32),
-                sds((b,), np.float32),
-                sds((2,), np.uint32),
-            )
-            auto = jax.tree.map(lambda _: Format(Layout.AUTO), params_s)
-            dec = jax.jit(
-                _decode, donate_argnums=(2,),
-                in_shardings=(auto, None, None, None, None, None, None),
-            )
-            compiled = dec.lower(*args_s).compile()
-            fmts = compiled.input_formats
-            afmts = fmts[0] if isinstance(fmts, tuple) and len(fmts) == 2 else fmts
-            params_fmt = afmts[0]
-            if callable(params):
-                # Materialize weights directly in the program's layout —
-                # no second copy ever exists on device.
-                params = jax.jit(params, out_shardings=params_fmt)()
-            else:
-                params = jax.device_put(params, params_fmt)
-            prefill = jax.jit(
-                _prefill, donate_argnums=(2,),
-                in_shardings=(params_fmt, None, None, None, None, None, None),
-            )
-            chunk = jax.jit(
-                _chunk, donate_argnums=(2,),
-                in_shardings=(params_fmt,) + (None,) * 8,
-            )
-            return compiled, prefill, chunk, params
-        except Exception:  # noqa: BLE001 — backend without layout support
-            decode = jax.jit(_decode, donate_argnums=(2,))
-            prefill = jax.jit(_prefill, donate_argnums=(2,))
-            chunk = jax.jit(_chunk, donate_argnums=(2,))
-            if callable(params):
-                params = params()
-            return decode, prefill, chunk, params
+        sds = jax.ShapeDtypeStruct
+        b, W = p.max_batch, p.max_blocks_per_seq
+        if callable(params):
+            params_s = jax.eval_shape(params)
+        else:
+            params_s = jax.tree.map(lambda x: sds(x.shape, x.dtype), params)
+        cache_s = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.cache)
+        args_s = (
+            params_s,
+            sds((b,), np.int32),
+            cache_s,
+            sds((b, W), np.int32),
+            sds((b,), np.int32),
+            sds((b,), np.float32),
+            sds((2,), np.uint32),
+        )
+        auto = jax.tree.map(lambda _: Format(Layout.AUTO), params_s)
+        dec = jax.jit(
+            _decode, donate_argnums=(2,),
+            in_shardings=(auto, None, None, None, None, None, None),
+        )
+        compiled = dec.lower(*args_s).compile()
+        (params_fmt, *_), _kwargs_fmt = compiled.input_formats
+        if callable(params):
+            # Materialize weights directly in the program's layout —
+            # no second copy ever exists on device.
+            params = jax.jit(params, out_shardings=params_fmt)()
+        else:
+            params = jax.device_put(params, params_fmt)
+        prefill = jax.jit(
+            _prefill, donate_argnums=(2,),
+            in_shardings=(params_fmt, None, None, None, None, None, None),
+        )
+        chunk = jax.jit(
+            _chunk, donate_argnums=(2,),
+            in_shardings=(params_fmt,) + (None,) * 8,
+        )
+        return compiled, prefill, chunk, params
 
     def _warmup(self) -> int:
         """Compile every program shape the serving path can hit: each
@@ -573,9 +584,8 @@ class LLMEngine:
                 np.int32(0), np.int32(0), np.float32(0.0), sub,
             )
             n += 1
-        # Decode window: a no-op compile on the AOT layout path (already
-        # built), but the fallback jit path compiles here instead of on
-        # the first live request.
+        # Decode window: already compiled (AOT) — this is its first
+        # execution, so a program that does not fit fails at build time.
         seq, _cur, _lens, self.cache = self._decode(
             self.params, jax.numpy.asarray(self.cur), self.cache,
             jax.numpy.asarray(self.tables), jax.numpy.asarray(self.lens),

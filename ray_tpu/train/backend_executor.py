@@ -1,8 +1,8 @@
 """BackendExecutor: PG + worker group + rank env + training drive loop.
 
 Reference: python/ray/train/_internal/backend_executor.py — PG creation
-:219, worker start :135, accelerator-visibility sharing :299
-(``_share_resource_ids`` — CUDA/TPU env vars), rank assignment :369,
+:219, worker start :135, rank assignment :369 (accelerator visibility,
+:299 there, is the controller's here: each TrainWorker is a TPU actor),
 ``start_training`` :451, health-check + ``_restart`` :759 (elastic retry).
 
 Fault tolerance (this repo's elastic extension of :759):
@@ -167,7 +167,10 @@ class BackendExecutor:
         if not self.pg.wait(timeout_seconds=60):
             raise TrainingFailedError(
                 f"placement group for {self.scaling.num_workers} workers "
-                f"({self.scaling.worker_resources()}) not placeable"
+                f"({self.scaling.worker_resources()}) not placeable within 60s; "
+                f"the cluster has {ray_tpu.cluster_resources()}. init() "
+                f"registers a TPU resource for the chips it detects "
+                f"(/dev/accel*, /dev/vfio/<group>) or is given (num_tpus=)."
             )
         self.worker_group = WorkerGroup(
             self.scaling.num_workers,
@@ -259,7 +262,6 @@ class BackendExecutor:
         t0 = time.monotonic()
         group_name = f"__train__{uuid.uuid4().hex[:8]}"
         self._group_name = group_name
-        tpu_per_worker = self.scaling.worker_resources().get("TPU", 0)
         refs = []
         for w in self.worker_group.workers:
             ctx = TrainContext(
@@ -271,7 +273,6 @@ class BackendExecutor:
                 storage_path=self.storage_path,
             )
             env = dict(self.scaling.worker_env or {})
-            env.update(self._visibility_env(w, tpu_per_worker))
             # Each rank gets its split index of every shard coordinator
             # (rank == split keeps shard assignment stable across ranks).
             shards = {
@@ -303,20 +304,6 @@ class BackendExecutor:
             recovery_metrics().resume_ms.observe(
                 resume_ms, {"run": self.experiment_name}
             )
-
-    def _visibility_env(self, w, tpu_per_worker) -> Dict[str, str]:
-        """Chip isolation for co-located workers (reference:
-        accelerators/tpu.py:155-195 TPU_VISIBLE_CHIPS + backend_executor.py
-        :299 _share_resource_ids)."""
-        if not tpu_per_worker:
-            return {}
-        n = int(tpu_per_worker)
-        start = w.local_rank * n
-        chips = ",".join(str(c) for c in range(start, start + n))
-        return {
-            "TPU_VISIBLE_CHIPS": chips,
-            "TPU_CHIPS_PER_PROCESS_BOUNDS": f"1,{n},1",
-        }
 
     def start_training(self, train_fn: Callable, config: Optional[dict]) -> List:
         assert self.worker_group is not None

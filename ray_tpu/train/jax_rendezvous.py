@@ -12,7 +12,6 @@ simulation used in tests).
 from __future__ import annotations
 
 import logging
-import os
 import socket
 import time
 from typing import Optional
@@ -65,16 +64,25 @@ def setup_jax_distributed(
             time.sleep(0.05)
     import jax
 
-    # The host image may pin a platform via sitecustomize before env vars
-    # are honored; re-assert the requested platform pre-initialize.
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        jax.config.update("jax_platforms", platforms)
     jax.distributed.initialize(
         coordinator_address=addr,
         num_processes=world_size,
         process_id=world_rank,
     )
+    # The coordinator handshake succeeding does not mean the accelerator
+    # runtime spans the gang. libtpu builds its slice from TPU_* topology
+    # variables, not from jax.distributed: four one-chip processes of one
+    # v5e host each come up as a complete 1-chip slice (measured, libtpu
+    # 0.0.34) and would train four unrelated models.
+    if jax.device_count() == jax.local_device_count() and world_size > 1:
+        raise RuntimeError(
+            f"use_jax_distributed: rank {world_rank}/{world_size} joined the "
+            f"coordinator at {addr} but its jax runtime holds only its own "
+            f"{jax.local_device_count()} device(s). Processes that each own "
+            "part of one TPU host's chips do not form one runtime here; give "
+            "one worker all chips of a host (resources_per_worker={'TPU': n}) "
+            "and use one worker per host."
+        )
     logger.info(
         "jax.distributed up: rank %d/%d via %s (%d global devices)",
         world_rank, world_size, addr, len(jax.devices()),

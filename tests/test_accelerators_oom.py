@@ -35,11 +35,14 @@ def test_tpu_chip_validation():
 
 
 def test_visible_chips_env(monkeypatch):
-    TPUAcceleratorManager.set_current_process_visible_accelerators([0, 2])
-    assert os.environ["TPU_VISIBLE_CHIPS"] == "0,2"
+    env = TPUAcceleratorManager.visible_chips_env([0, 2], chips_on_host=4)
+    assert env["TPU_VISIBLE_CHIPS"] == "0,2"
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", env["TPU_VISIBLE_CHIPS"])
     assert TPUAcceleratorManager.get_current_process_visible_accelerator_ids() == [0, 2]
     monkeypatch.delenv("TPU_VISIBLE_CHIPS")
     assert TPUAcceleratorManager.get_current_process_visible_accelerator_ids() is None
+    # every chip of the host: libtpu's own defaults, nothing to set
+    assert TPUAcceleratorManager.visible_chips_env([0, 1, 2, 3], 4) == {}
 
 
 def test_pod_resources(monkeypatch):

@@ -141,7 +141,7 @@ def test_controller_dies_mid_transfer_then_journal_recovery(tmp_path):
             "--session-dir", session, "--port", "0",
             "--resources", json.dumps({"CPU": 2}), "--config", "{}",
         ],
-        env=child_env(needs_tpu=False), stdout=log, stderr=subprocess.STDOUT,
+        env=child_env(), stdout=log, stderr=subprocess.STDOUT,
     )
     try:
         port_file = os.path.join(session, "controller_port")

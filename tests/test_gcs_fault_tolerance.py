@@ -97,7 +97,7 @@ def _start_controller(session_dir, port=0, resources=None, config=None):
             "--resources", json.dumps(resources or {"CPU": 4}),
             "--config", json.dumps(config or {}),
         ],
-        env=child_env(needs_tpu=False),
+        env=child_env(),
         stdout=log,
         stderr=subprocess.STDOUT,
     )
