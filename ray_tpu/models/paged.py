@@ -82,6 +82,7 @@ def init_paged_cache(cfg: TransformerConfig, pcfg: PagedConfig) -> PagedCache:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+@jax.named_scope("paged.attend")
 def _attend_paged(q, ck, cv, lens, cfg: TransformerConfig):
     """q: [b, H, HD] one token per slot; ck/cv: [b, m, KV, HD] gathered
     contiguous views; lens: [b] — position of the token just written
@@ -111,21 +112,26 @@ def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, len
     bs = ck.shape[1]
     h = rms_norm(x, lp["attn_norm"])
     q, k, v = project_qkv(h, lp, cfg, lens[:, None])
-    # Scatter the new K/V at (block, offset) per slot. Idle slots are
-    # pointed at the trash block by the host allocator.
-    phys = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]  # [b]
-    off = lens % bs
-    ck = ck.at[phys, off].set(k[:, 0])
-    cv = cv.at[phys, off].set(v[:, 0])
-    # Gather each slot's blocks into a contiguous [b, W*bs, KV, HD] view
-    # (post-scatter, so the just-written token attends to itself).
-    KV, HD = cfg.n_kv_heads, cfg.head_dim
-    W = tables.shape[1]
-    ck_g = ck[tables].reshape(b, W * bs, KV, HD)
-    cv_g = cv[tables].reshape(b, W * bs, KV, HD)
+    # The scopes below reach the profiler as the ``tf_op`` of each fused
+    # operation (a fusion's own name is made from its operations).
+    with jax.named_scope("paged.scatter"):
+        # Scatter the new K/V at (block, offset) per slot. Idle slots are
+        # pointed at the trash block by the host allocator.
+        phys = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]  # [b]
+        off = lens % bs
+        ck = ck.at[phys, off].set(k[:, 0])
+        cv = cv.at[phys, off].set(v[:, 0])
+    with jax.named_scope("paged.gather"):
+        # Gather each slot's blocks into a contiguous [b, W*bs, KV, HD] view
+        # (post-scatter, so the just-written token attends to itself).
+        KV, HD = cfg.n_kv_heads, cfg.head_dim
+        W = tables.shape[1]
+        ck_g = ck[tables].reshape(b, W * bs, KV, HD)
+        cv_g = cv[tables].reshape(b, W * bs, KV, HD)
     o = _attend_paged(q[:, 0], ck_g, cv_g, lens, cfg)
     x = x + (o @ lp["wo"].astype(o.dtype))[:, None, :]
-    x = mlp_block(x, lp, cfg)
+    with jax.named_scope("paged.mlp"):
+        x = mlp_block(x, lp, cfg)
     return x, ck, cv
 
 

@@ -222,6 +222,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int, block_k: i
             jax.ShapeDtypeStruct((batch * heads, 1, q_len), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     return (
         out.reshape(batch, heads, q_len, head_dim),
@@ -430,6 +431,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
         out_specs=pl.BlockSpec((1, bq, head_dim), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, q_len, head_dim), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, dor, lser, delta)
 
     # dK/dV: grid over (batch·kv_heads, k blocks[, q-head group]); each
@@ -494,6 +496,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
             jax.ShapeDtypeStruct((bkv, k_len_p, head_dim), out_dtype or v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lser, delta)
     if k_pad:
         dk = dk[:, :k_len]

@@ -63,9 +63,19 @@ from ray_tpu.models.paged import (
     prefill_chunk_and_sample,
 )
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.util import tracing
 
 _req_ids = itertools.count()
 _engine_ids = itertools.count()
+
+# The phases of one scheduler iteration (``tracing.phase("engine.<name>")``):
+# siblings that together cover ``LLMEngine.step`` with no span around them,
+# because a reader of the device trace lays an idle gap to the host span that
+# covers most of it, and an enclosing span always would. Each recorded step
+# carries ``<name>_ms``.
+_PHASES = ("harvest_wait", "emit", "admit", "prefill_wait", "dispatch")
+# Why a window in flight was not overlapped: ``stats["spec_blocked_<reason>"]``.
+_SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
 
 
 @dataclasses.dataclass
@@ -433,7 +443,10 @@ class LLMEngine:
                       "prefills": 0, "admitted": 0, "prompt_tokens": 0,
                       "finished": 0, "prefill_chunks": 0, "spec_windows": 0,
                       "h2d_ships": 0, "h2d_skips": 0, "prefix_hit_tokens": 0,
-                      "prefix_lookup_tokens": 0, "prefix_evictions": 0}
+                      "prefix_lookup_tokens": 0, "prefix_evictions": 0,
+                      **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED}}
+        # Milliseconds by phase of the iteration in progress (tracing.phase).
+        self._phase_ms: Dict[str, float] = {}
         if warmup_buckets:
             t0 = time.perf_counter()
             self.stats["warmup_compiles"] = self._warmup()
@@ -759,8 +772,6 @@ class LLMEngine:
             "e2e_ms": (now - req.submit_ts) * 1000.0,
         }
         self.recorder.record_request(rec)
-        from ray_tpu.util import tracing
-
         # Parent the engine-side request span under the serve-path trace
         # captured at add_request (cross-thread: explicit parenting).
         tracing.record_span(
@@ -982,13 +993,15 @@ class LLMEngine:
         if not self._pending_first:
             return
         pend, self._pending_first = self._pending_first, []
-        vals = jax.device_get([t for _, _, t in pend])  # one batched transfer
-        for (i, req, _), v in zip(pend, vals):
-            if self.slots[i] is not req:
-                continue  # preempted between prefill and flush
-            self.cur[i] = int(v)
-            self._dirty.add("cur")
-            self._emit(i, int(v))
+        with tracing.phase("engine.prefill_wait", self._phase_ms):
+            vals = jax.device_get([t for _, _, t in pend])  # one batched transfer
+        with tracing.phase("engine.emit", self._phase_ms):
+            for (i, req, _), v in zip(pend, vals):
+                if self.slots[i] is not req:
+                    continue  # preempted between prefill and flush
+                self.cur[i] = int(v)
+                self._dirty.add("cur")
+                self._emit(i, int(v))
 
     def _emit(self, i: int, tok: int):
         """Record + stream one generated token; retire the slot when done.
@@ -1080,35 +1093,40 @@ class LLMEngine:
         them. Slots freed/reused since dispatch fail the rid check and
         their lanes are discarded (overshoot)."""
         entries, seq = pending
-        nxt = np.asarray(seq)  # [window, b]
-        for i, rid, gen in entries:
-            req = self.slots[i]
-            if req is None or req.rid != rid or self._slot_gen[i] != gen:
-                continue  # finished / preempted / slot reused in flight
-            for k in range(self.window):
-                if self.slots[i] is not req:
-                    break  # finished mid-window; rest is overshoot
-                self.cur[i] = nxt[k, i]
-                self._emit(i, int(nxt[k, i]))
+        with tracing.phase("engine.harvest_wait", self._phase_ms):
+            nxt = np.asarray(seq)  # [window, b]: the host blocks on the device
+        with tracing.phase("engine.emit", self._phase_ms):
+            for i, rid, gen in entries:
+                req = self.slots[i]
+                if req is None or req.rid != rid or self._slot_gen[i] != gen:
+                    continue  # finished / preempted / slot reused in flight
+                for k in range(self.window):
+                    if self.slots[i] is not req:
+                        break  # finished mid-window; rest is overshoot
+                    self.cur[i] = nxt[k, i]
+                    self._emit(i, int(nxt[k, i]))
         return True
 
-    def _can_speculate(self) -> bool:
-        """Dispatch window N+1 before reading window N's tokens? Not when
-        a slot's cap-finish inside N is already certain (the speculated
-        window would be pure waste), and not when an admission could use
-        a free slot first (it should join N+1, not N+2). An eos-stopped
-        slot can still waste one window — capacity covers it (the
-        2*window-1 overlap margin)."""
+    def _can_speculate(self) -> Optional[str]:
+        """Dispatch window N+1 before reading window N's tokens? None to
+        go ahead, else the reason not to (one of ``_SPEC_BLOCKED``):
+        ``idle``, nothing decodable; ``admission``, a waiting request
+        could use a free slot first (it should join N+1, not N+2);
+        ``dirty_cur``, the host cur lags the in-flight window, so sync
+        first; ``finishing``, a slot's cap-finish inside N is already
+        certain (the speculated window would be pure waste). An
+        eos-stopped slot can still waste one window — capacity covers it
+        (the 2*window-1 overlap margin)."""
         entries = self._decode_entries()
         if not entries:
-            return False
+            return "idle"
         if self.waiting and any(s is None for s in self.slots):
-            return False
+            return "admission"
         if "cur" in self._dirty:
-            return False  # host cur lags the in-flight window — sync first
-        return all(
-            self.slots[i].remaining > self.window for i, _, _ in entries
-        )
+            return "dirty_cur"
+        if any(self.slots[i].remaining <= self.window for i, _, _ in entries):
+            return "finishing"
+        return None
 
     def step(self) -> bool:
         """One scheduler iteration: [speculate] → harvest → admit → page
@@ -1120,30 +1138,50 @@ class LLMEngine:
         all run while the device executes N+1 (the donated-cache chain
         serializes device-side writes, so a freed block re-used by a
         later prefill is always overwritten AFTER the stale window's
-        writes land)."""
+        writes land).
+
+        The iteration runs as the five sibling ``tracing.phase``s of
+        ``_PHASES`` (here and in ``_harvest_window``/``_flush_prefills``),
+        none inside another and none around them all; the step record
+        carries their milliseconds."""
         s0 = (self.stats["tokens"], self.stats["prefills"],
               self.stats["preemptions"], self.stats["admitted"],
               self.stats["prefill_chunks"], self.stats["prefix_hit_tokens"])
+        t_step = time.perf_counter()
+        ph = self._phase_ms = {}
         worked = False
+        overlapped = 0
         if self._inflight is not None:
             # Stash window N first: a speculated dispatch installs N+1 as
             # the new in-flight window, and N still owes its tokens.
             pending, self._inflight = self._inflight, None
-            if (
-                self.overlap
-                and self._can_speculate()
-                and self._dispatch_window(speculative=True)
-            ):
-                self.stats["spec_windows"] += 1
+            if self.overlap:
+                # Exactly one of spec_windows and the spec_blocked_* counts
+                # goes up for every window found in flight.
+                blocked = self._can_speculate()
+                if blocked is None:
+                    with tracing.phase("engine.dispatch", ph):
+                        if not self._dispatch_window(speculative=True):
+                            # _ensure_decode_blocks preempted: cur is dirty
+                            blocked = "dirty_cur"
+                if blocked is None:
+                    self.stats["spec_windows"] += 1
+                    overlapped = 1
+                else:
+                    self.stats["spec_blocked_" + blocked] += 1
             self._harvest_window(pending)
             worked = True
-        self._admit()
-        self._advance_chunked_prefills()
+        with tracing.phase("engine.admit", ph):
+            self._admit()
+            self._advance_chunked_prefills()
         self._flush_prefills()
-        if self._inflight is None and self._dispatch_window():
-            worked = True
-            if not self.overlap:
-                self._harvest()  # classic synchronous window
+        if self._inflight is None:
+            with tracing.phase("engine.dispatch", ph):
+                dispatched = self._dispatch_window()
+            if dispatched:
+                worked = True
+                if not self.overlap:
+                    self._harvest()  # classic synchronous window
         s1 = (self.stats["tokens"], self.stats["prefills"],
               self.stats["preemptions"], self.stats["admitted"],
               self.stats["prefill_chunks"], self.stats["prefix_hit_tokens"])
@@ -1167,6 +1205,11 @@ class LLMEngine:
                 "chunks": s1[4] - s0[4],
                 "prefix_hit_tokens": s1[5] - s0[5],
                 "cached_blocks": pc.resident_blocks if pc else 0,
+                # Host cost of this iteration, whole and by phase; the
+                # scheduler's own share is wall less the two waits.
+                "wall_ms": (time.perf_counter() - t_step) * 1e3,
+                **{f"{name}_ms": ph.get("engine." + name, 0.0) for name in _PHASES},
+                "overlapped": overlapped,
             })
             self._maybe_flush_metrics()
         return worked
@@ -1209,6 +1252,12 @@ class LLMEngine:
                 delta = s[key] - prev.get(key, 0)
                 if delta:
                     counter.inc(delta, t)
+            for why in _SPEC_BLOCKED:
+                key = "spec_blocked_" + why
+                delta = s[key] - prev.get(key, 0)
+                if delta:  # four fixed reasons, not an open set of series
+                    m.engine_overlap_blocked.inc(  # ray-tpu: lint-ignore[RTL004]
+                        delta, {**t, "reason": why})
             self._flushed_stats = s
             m.engine_active.set(self.active_count(), t)
             m.engine_waiting.set(len(self.waiting), t)
@@ -1268,6 +1317,9 @@ class LLMEngine:
                 # was still unread — host/device overlap occupancy.
                 "occupancy": self.stats["spec_windows"]
                 / max(1, self.stats["steps"]),
+                # Windows found in flight and not overlapped, by reason.
+                "blocked": {why: self.stats["spec_blocked_" + why]
+                            for why in _SPEC_BLOCKED},
                 "h2d_ships": self.stats["h2d_ships"],
                 "h2d_skips": self.stats["h2d_skips"],
             },

@@ -194,6 +194,12 @@ class _ServeMetrics:
             "(host/device overlap)",
             dr,
         )
+        self.engine_overlap_blocked = Counter(
+            "serve_engine_overlap_blocked_total",
+            "Decode windows found in flight and NOT overlapped, by reason "
+            "(idle, admission, dirty_cur, finishing)",
+            dr + ("reason",),
+        )
 
 
 def serve_metrics() -> _ServeMetrics:

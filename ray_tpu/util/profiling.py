@@ -889,7 +889,9 @@ _device_trace: Optional[dict] = None  # {"dir", "capture", "t0"}
 def device_trace_start(capture: str, base_dir: Optional[str] = None) -> dict:
     """Start a ``jax.profiler`` trace in THIS process (no restart —
     composes with the runtime_env plugin's capture dirs and the existing
-    list/fetch path). One trace at a time per process."""
+    list/fetch path). One trace at a time per process. The Python
+    tracer stays off and host events at level 2 stay on, so the capture
+    shows ``tracing.phase`` spans and does not slow the traced threads."""
     global _device_trace
     safe = "".join(c if c.isalnum() or c in "._-" else "_" for c in capture)[:64]
     try:
@@ -910,7 +912,13 @@ def device_trace_start(capture: str, base_dir: Optional[str] = None) -> dict:
         )
         os.makedirs(out_dir, exist_ok=True)
         try:
-            jax.profiler.start_trace(out_dir)
+            # Python tracer off: it slows the very thread it watches. Host
+            # level 2 keeps the program's own annotations (tracing.phase:
+            # the engine's ``engine.*`` spans) beside the device's rows.
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
         except Exception as e:  # noqa: BLE001 — backend may not support tracing
             return {"ok": False, "error": f"start_trace failed: {e}"}
         _device_trace = {"dir": out_dir, "capture": safe, "t0": time.time()}

@@ -274,6 +274,62 @@ def record_span(
     )
 
 
+# jax.profiler.TraceAnnotation once bound; False where JAX cannot be imported.
+_annotation: Any = None
+
+
+def _bind_annotation():
+    """Importing ``jax.profiler`` opens no backend."""
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    except ImportError:
+        _annotation = False
+    return _annotation
+
+
+class phase:
+    """One phase of a hot loop, on every clock this process keeps.
+
+    ``with phase("engine.admit", into):`` (a) is a
+    ``jax.profiler.TraceAnnotation``: whatever ``jax.profiler`` trace is
+    running in this process gets the span on its host plane, on the
+    device planes' own clock, so a device idle gap can be laid to the
+    phase the host was in; with or without a trace running it costs
+    about a microsecond; (b) adds the elapsed ``time.perf_counter()``
+    milliseconds to ``into[name]``; (c) with ``RAY_TPU_TRACE`` on, also
+    writes the span to the JSONL sink, where ``ray-tpu timeline`` finds
+    it. Make phases siblings: a reader that labels a gap with the span
+    covering most of it would name an enclosing span every time. Never
+    one per token."""
+
+    __slots__ = ("name", "into", "_ann", "_t0", "_wall0")
+
+    def __init__(self, name: str, into: Dict[str, float]):
+        self.name = name
+        self.into = into
+
+    def __enter__(self):
+        ann = _annotation if _annotation is not None else _bind_annotation()
+        self._ann = ann(self.name) if ann else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._wall0 = time.time() if _enabled else 0.0
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.into[self.name] = self.into.get(self.name, 0.0) + ms
+        if _enabled:
+            record_span(self.name, self._wall0, time.time())
+        return False
+
+
 def trace_span(name: Optional[str] = None):
     """Decorator form of ``start_span``."""
 

@@ -8,6 +8,7 @@ telemetry never opens a backend, and that ``chip_smoke.py`` refuses a CPU.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -113,6 +114,14 @@ def _abstract(tree, shardings):
     )
 
 
+def _kernel_names(compiled_text: str) -> list:
+    """The instruction names of a compiled program's Pallas kernels, less
+    their numbers: what the profiler's trace will call them."""
+    return [re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", line).group(1)
+            for line in compiled_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def test_flash_fwd_and_bwd_compile_for_v5e(v5e):
     from jax.sharding import SingleDeviceSharding
 
@@ -125,6 +134,39 @@ def test_flash_fwd_and_bwd_compile_for_v5e(v5e):
         argnums=(0, 1, 2),
     ))
     assert grad.lower(qkv, qkv, qkv).compile().as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_flash_kernels_are_named_in_the_lowering_and_the_compiled_program(v5e, remat):
+    """The trace calls a custom call after the innermost scope of its
+    ``op_name``: ``name=`` on each ``pallas_call`` puts the kernel's own
+    there, whatever ``checkpoint`` or ``shard_map`` is around it, so a
+    metric finds ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv`` by name
+    (by search: with no ``checkpoint`` between, ``jvp(flash_fwd)`` becomes
+    ``jvp_flash_fwd_``)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.attention import flash_attention
+
+    attn = lambda q, k, v: flash_attention(q, k, v, True, None)  # noqa: E731
+    if remat:
+        attn = jax.checkpoint(attn)
+    q = jax.ShapeDtypeStruct((1, 4, 128, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    kv = jax.ShapeDtypeStruct((1, 2, 128, 128), jnp.bfloat16,
+                              sharding=SingleDeviceSharding(v5e[0]))
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2),
+    )).lower(q, kv, kv)
+    text = lowered.as_text(debug_info=True)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"/{kernel}/pallas_call" in text or f'{kernel}"' in text, kernel
+    calls = _kernel_names(lowered.compile().as_text())
+    if remat:  # how the trainer runs them: the kernel's name comes first
+        assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    else:  # traced directly under the transforms, the scope is wrapped in theirs
+        assert sorted(calls) == ["jvp_flash_fwd_", "transpose_jvp_flash_bwd_dkv__",
+                                 "transpose_jvp_flash_bwd_dq__"]
 
 
 def test_flash_sequence_ceiling_is_a_value_error(v5e):
@@ -179,6 +221,8 @@ def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
     # ring attention is einsums; every other plan must hold the Pallas kernel
     assert ("tpu_custom_call" in text) == (plan_kw.get("sp_mode", "ring") != "ring"
                                            or plan.sp == 1)
+    # under remat and shard_map alike the trace will call them by their own names
+    assert set(_kernel_names(text)) <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
 
 
 def test_engine_programs_compile_for_v5e(v5e):

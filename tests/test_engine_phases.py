@@ -1,0 +1,260 @@
+"""The engine's scheduler thread on the profiler's clock.
+
+``tracing.phase`` is the one primitive; ``LLMEngine.step`` is five sibling
+phases (``engine.harvest_wait``, ``engine.emit``, ``engine.admit``,
+``engine.prefill_wait``, ``engine.dispatch``) with no span around them;
+every window found in flight is either overlapped (``spec_windows``) or
+counted under the reason it was not (``spec_blocked_*``); the decode
+program's layer carries ``paged.*`` scopes.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models.paged import PagedConfig
+from ray_tpu.models.transformer import TransformerConfig, init_params
+from ray_tpu.serve import llm_engine
+from ray_tpu.serve.llm_engine import LLMEngine
+from ray_tpu.util import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PHASE_FIELDS = [f"{name}_ms" for name in llm_engine._PHASES]
+BLOCKED = ["spec_blocked_" + why for why in llm_engine._SPEC_BLOCKED]
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, remat=False)
+    return cfg, init_params(jax.random.PRNGKey(7), cfg)
+
+
+def _engine(cfg, params, *, window=2, overlap=True, **paged):
+    pcfg = PagedConfig(**{**dict(block_size=8, num_blocks=33, max_batch=4,
+                                 max_blocks_per_seq=8), **paged})
+    return LLMEngine(params, cfg, pcfg, decode_window=window, overlap=overlap)
+
+
+# ---------------------------------------------------------------------------
+# tracing.phase
+# ---------------------------------------------------------------------------
+def test_phase_adds_milliseconds_and_writes_no_span_when_tracing_is_off(tmp_path):
+    assert not tracing.tracing_enabled()
+    into = {"engine.x": 1.0}
+    with tracing.phase("engine.x", into):
+        pass
+    with tracing.phase("engine.y", into):
+        pass
+    assert into["engine.x"] > 1.0 and 0.0 <= into["engine.y"] < 50.0
+    assert tracing.collect_spans(str(tmp_path)) == []
+
+
+def test_phase_span_reaches_the_jsonl_sink_under_ray_tpu_trace(tmp_path, monkeypatch):
+    monkeypatch.setenv(tracing.TRACE_ENV_VAR, "1")
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(tmp_path))
+    try:
+        assert tracing.maybe_enable_from_env()
+        into = {}
+        with tracing.phase("engine.admit", into):
+            pass
+    finally:
+        tracing.disable_tracing()
+    spans = [e for e in tracing.collect_spans(str(tmp_path)) if e.get("ph") == "X"]
+    assert [e["name"] for e in spans] == ["engine.admit"]
+    assert spans[0]["dur"] >= 0 and into["engine.admit"] >= 0.0
+
+
+def test_phase_without_jax_still_times(monkeypatch):
+    # ``import jax.profiler`` raises ImportError while these are None.
+    monkeypatch.setitem(sys.modules, "jax", None)
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    monkeypatch.setattr(tracing, "_annotation", None)
+    into = {}
+    with tracing.phase("engine.emit", into):
+        pass
+    assert tracing._annotation is False  # bound once, as absent
+    assert into["engine.emit"] >= 0.0
+
+
+def test_trace_annotation_lives_in_one_place():
+    hits = []
+    for root, _dirs, files in os.walk(os.path.join(REPO, "ray_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path, encoding="utf-8") as fh:
+                    if "TraceAnnotation" in fh.read():
+                        hits.append(os.path.relpath(path, REPO))
+    assert hits == ["ray_tpu/util/tracing.py"]
+
+
+# ---------------------------------------------------------------------------
+# Step records
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("overlap", [False, True])
+def test_every_recorded_step_carries_wall_and_phase_times(tiny_model, overlap):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
+    outs = eng.generate_batch(prompts, max_new_tokens=7)
+    assert all(len(o) == 7 for o in outs)
+    steps = list(eng.recorder.steps)
+    assert steps
+    for rec in steps:
+        assert rec["wall_ms"] >= 0.0 and rec["overlapped"] in (0, 1)
+        assert all(rec[f] >= 0.0 for f in PHASE_FIELDS), rec
+        # siblings: none is counted inside another
+        assert sum(rec[f] for f in PHASE_FIELDS) <= rec["wall_ms"] + 1.0, rec
+    assert any(r["dispatch_ms"] > 0 for r in steps)
+    assert any(r["harvest_wait_ms"] > 0 for r in steps)
+    assert any(r["prefill_wait_ms"] > 0 for r in steps)
+    assert sum(r["overlapped"] for r in steps) == eng.stats["spec_windows"]
+    if not overlap:
+        assert eng.stats["spec_windows"] == 0 and not any(eng.stats[k] for k in BLOCKED)
+
+
+# ---------------------------------------------------------------------------
+# Why a window was not overlapped
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("paged, lens", [
+    # roomy pool: admissions, cap-finishes, prefill flushes
+    ({}, [20, 5, 24, 9, 14, 24, 3, 11]),
+    # four long answers want 16 of 12 blocks: preemption inside a speculated dispatch
+    (dict(num_blocks=13, max_blocks_per_seq=4), [24, 24, 24, 24, 24, 24]),
+], ids=["roomy", "preempting"])
+def test_each_window_in_flight_is_overlapped_or_counted_once(tiny_model, paged, lens):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, **paged)
+    todo = [([i + 1, i + 2, i + 3, i + 4], n) for i, n in enumerate(lens)]
+    reqs = [eng.add_request(*todo.pop(0)) for _ in range(2)]
+    found_in_flight = 0
+    while eng.active_count() or eng.waiting:
+        if found_in_flight == 3:  # the rest arrive with a window in flight and slots free
+            reqs += [eng.add_request(*t) for t in todo]
+            todo = []
+        found_in_flight += eng._inflight is not None
+        eng.step()
+    assert [len(list(r.tokens(timeout=5))) for r in reqs] == lens
+    s = eng.stats
+    assert s["spec_windows"] + sum(s[k] for k in BLOCKED) == found_in_flight
+    assert s["spec_windows"] > 0 and s["spec_blocked_finishing"] > 0
+    assert s["spec_blocked_admission"] > 0
+    if paged:  # the speculated dispatch that preempted was aborted, and counted
+        assert s["preemptions"] > 0 and s["spec_blocked_dirty_cur"] > 0
+    snap = eng.report_state()
+    assert snap["overlap"]["blocked"] == {
+        why: s["spec_blocked_" + why] for why in llm_engine._SPEC_BLOCKED}
+
+
+def _force_idle(eng):
+    for i, req in enumerate(eng.slots):  # as if an eos stop had been harvested
+        if req is not None:
+            eng._free_slot(i)
+
+
+def _force_finishing(eng):
+    req = next(r for r in eng.slots if r is not None)
+    req.max_new_tokens = len(req.generated) + eng.window
+
+
+@pytest.mark.parametrize("reason, force", [
+    ("idle", _force_idle),
+    ("admission", lambda eng: eng.add_request([9, 8, 7], 4)),
+    ("dirty_cur", lambda eng: eng._dirty.add("cur")),
+    ("finishing", _force_finishing),
+])
+def test_a_blocked_window_is_counted_under_its_reason(tiny_model, reason, force):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params)
+    eng.add_request([5, 9, 2, 11], 40)
+    eng.step()  # admit, prefill, first window dispatched
+    assert eng._inflight is not None and eng._can_speculate() is None
+    force(eng)
+    assert eng._can_speculate() == reason
+    before = dict(eng.stats)
+    eng.step()
+    moved = {k for k in ["spec_windows"] + BLOCKED if eng.stats[k] != before[k]}
+    assert moved == {"spec_blocked_" + reason}
+    assert eng.stats["spec_blocked_" + reason] == before["spec_blocked_" + reason] + 1
+    assert eng.recorder.steps[-1]["overlapped"] == 0
+
+
+def test_blocked_reasons_reach_the_registry_counter(tiny_model):
+    from ray_tpu.serve.metrics import serve_metrics
+
+    cfg, params = tiny_model
+    eng = _engine(cfg, params)
+    eng.metrics_tags = {"deployment": "phases", "replica": "r0"}
+    eng.generate_batch([[1, 2, 3], [4, 5, 6]], max_new_tokens=5)
+    eng._maybe_flush_metrics(force=True)
+    counter = serve_metrics().engine_overlap_blocked
+    assert counter.name == "serve_engine_overlap_blocked_total"
+    mine = {dict(tags)["reason"]: value for _n, _t, _d, tags, value in counter._drain()
+            if dict(tags)["deployment"] == "phases"}
+    assert mine == {why: eng.stats["spec_blocked_" + why]
+                    for why in llm_engine._SPEC_BLOCKED if eng.stats["spec_blocked_" + why]}
+    assert mine["finishing"] > 0
+
+
+# ---------------------------------------------------------------------------
+# On the profiler's clock
+# ---------------------------------------------------------------------------
+_TRACED_ENGINE_DRIVER = """
+import glob, json, os, sys
+import jax, jax.numpy as jnp
+from jax.profiler import ProfileData
+from ray_tpu.models.paged import PagedConfig
+from ray_tpu.models.transformer import TransformerConfig, init_params
+from ray_tpu.serve.llm_engine import LLMEngine
+from ray_tpu.util import profiling
+
+cfg = TransformerConfig.tiny(dtype=jnp.float32, remat=False)
+eng = LLMEngine(init_params(jax.random.PRNGKey(7), cfg), cfg,
+                PagedConfig(block_size=8, num_blocks=33, max_batch=4, max_blocks_per_seq=8),
+                decode_window=2, overlap=True)
+eng.generate_batch([[1, 2, 3]], max_new_tokens=3)  # compile outside the trace
+started = profiling.device_trace_control("start", "phases", sys.argv[1])
+if not started["ok"]:
+    print(json.dumps({"skip": started.get("error", "?")}))
+    sys.exit(0)
+try:
+    eng.generate_batch([[i + 1, i + 2, i + 3] for i in range(6)], max_new_tokens=9)
+finally:
+    stopped = profiling.device_trace_control("stop")
+assert stopped["ok"], stopped
+[path] = glob.glob(os.path.join(stopped["dir"], "plugins", "profile", "*", "*.xplane.pb"))
+names = {ev.name for plane in ProfileData.from_file(path).planes
+         if plane.name.startswith("/host:") for line in plane.lines for ev in line.events}
+print(json.dumps({"engine": sorted(n for n in names if n.startswith("engine."))}))
+"""
+
+
+def test_traced_engine_puts_five_sibling_phases_on_the_host_plane(tmp_path):
+    # A fresh interpreter, as test_device_trace_control_rejects_double_start:
+    # stop_trace dumps every computation the process has ever run.
+    proc = subprocess.run(
+        [sys.executable, "-c", _TRACED_ENGINE_DRIVER, str(tmp_path)], cwd=REPO,
+        capture_output=True, text=True, timeout=240,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, f"{proc.stdout}\n{proc.stderr[-3000:]}"
+    verdict = json.loads(proc.stdout.strip().splitlines()[-1])
+    if "skip" in verdict:
+        pytest.skip(f"backend can't trace: {verdict['skip']}")
+    # the five, and nothing around them
+    assert verdict["engine"] == sorted("engine." + name for name in llm_engine._PHASES)
+
+
+# ---------------------------------------------------------------------------
+# Scopes of the decode program
+# ---------------------------------------------------------------------------
+def test_decode_program_carries_the_paged_scopes(tiny_model):
+    cfg, params = tiny_model
+    text = _engine(cfg, params)._decode.as_text()
+    for scope in ("paged.scatter", "paged.gather", "paged.attend", "paged.mlp"):
+        assert any("op_name=" in line and f"/{scope}/" in line
+                   for line in text.splitlines()), scope
