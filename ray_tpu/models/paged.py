@@ -8,7 +8,8 @@ re-designed for XLA's static-shape world:
 - **Physical cache**: one pool of fixed-size blocks per layer,
   ``[L, num_blocks, block_size, kv_heads, head_dim]``. Block 0 is a
   reserved trash block that idle decode slots harmlessly write to, so
-  the decode step never branches on slot liveness.
+  the decode step never branches on slot liveness. The layer scans
+  address it in place, as one flat pool (``_scan_layers``).
 - **Block tables**: each decode slot owns a row ``[max_blocks_per_seq]``
   of physical block ids. Tables/lengths are tiny int32 arrays passed
   into the jitted step each iteration — the host allocator (see
@@ -82,6 +83,24 @@ def init_paged_cache(cfg: TransformerConfig, pcfg: PagedConfig) -> PagedCache:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+def _scan_layers(layer, x, layers: Params, cache: PagedCache):
+    """Scan ``layer(x, ck, cv, lp, base) -> (x, ck, cv)`` over the layers,
+    carrying the stacked cache as one flat pool ``[L*num_blocks, bs, KV,
+    HD]`` (a bitcast, both ways). ``base`` is the layer's first block in
+    it: a layer addresses its block ``b`` at ``base + b`` and updates the
+    carry in place; no layer's pool is taken out of the stack or put back,
+    and only ``base`` knows how layers are laid out. → (x, cache')."""
+    shape = cache["k"].shape
+    L, nb = shape[:2]
+    flat = (L * nb,) + shape[2:]
+    bases = jnp.arange(L, dtype=jnp.int32) * nb
+    (x, ck, cv), _ = jax.lax.scan(
+        lambda carry, xs: (layer(*carry, *xs), None),
+        (x, cache["k"].reshape(flat), cache["v"].reshape(flat)), (layers, bases),
+    )
+    return x, {"k": ck.reshape(shape), "v": cv.reshape(shape)}
+
+
 @jax.named_scope("paged.attend")
 def _attend_paged(q, ck, cv, lens, cfg: TransformerConfig):
     """q: [b, H, HD] one token per slot; ck/cv: [b, m, KV, HD] gathered
@@ -105,8 +124,9 @@ def _attend_paged(q, ck, cv, lens, cfg: TransformerConfig):
 def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, lens):
     """One layer, one token per slot.
 
-    x: [b, 1, d]; ck/cv: [num_blocks, bs, KV, HD] (this layer's pool);
-    tables: [b, W] physical block ids; lens: [b] write positions.
+    x: [b, 1, d]; ck/cv: [blocks, bs, KV, HD], any pool that holds this
+    layer's blocks; tables: [b, W] block ids into it; lens: [b] write
+    positions.
     """
     b = x.shape[0]
     bs = ck.shape[1]
@@ -145,31 +165,18 @@ def paged_decode_step(
 ) -> Tuple[jax.Array, PagedCache]:
     """One decode iteration over all slots → (logits [b, V] fp32, cache').
 
-    The FULL pool rides the layer scan as a carry, updated per layer via
-    dynamic_update_index_in_dim — the standard in-place KV-cache shape.
-    Passing per-layer slices as scan xs/ys instead would stack a fresh
-    pool copy as the scan output (and chained windows would hold several
-    such copies): at 7B that is multiple GB of pure waste and an OOM on
-    a 16 GB chip."""
+    The stacked pool rides the layer scan as a carry that every layer
+    updates in place (``_scan_layers``). Passing per-layer slices as scan
+    xs/ys instead would stack a fresh pool copy as the scan output (and
+    chained windows would hold several such copies): at 7B that is
+    multiple GB of pure waste and an OOM on a 16 GB chip."""
+
+    def layer(x, ck, cv, lp, base):
+        return _paged_layer_step(x, lp, cfg, ck, cv, tables + base, lens)
+
     x = embed(params, tokens[:, None], cfg)
-    L = cfg.n_layers
-
-    def body(carry, xs):
-        x, ck_all, cv_all = carry
-        lp, i = xs
-        ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, i, 0, keepdims=False)
-        x, ck, cv = _paged_layer_step(x, lp, cfg, ck, cv, tables, lens)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, i, 0)
-        return (x, ck_all, cv_all), None
-
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-    )
-    logits = unembed(params, x, cfg)[:, 0]
-    return logits, {"k": ks, "v": vs}
+    x, cache = _scan_layers(layer, x, params["layers"], cache)
+    return unembed(params, x, cfg)[:, 0], cache
 
 
 def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array) -> jax.Array:
@@ -315,9 +322,9 @@ def paged_prefill_chunk(
 
     This is the suffix/chunked counterpart of ``paged_prefill``: instead
     of full attention over the whole prompt it scatters the chunk's K/V
-    into ``chunk_row`` and attends through the gathered ``table_row``
-    view under a causal position mask — so a prompt whose prefix is
-    already in the cache only pays compute for the novel suffix.
+    into ``chunk_row``'s blocks and attends through the gathered
+    ``table_row`` view under a causal position mask — so a prompt whose
+    prefix is already in the cache only pays compute for the novel suffix.
     ``start`` is traced: one compilation per chunk width C serves every
     chunk position. Returns (logits [C, V] fp32, cache')."""
     b, C = tokens.shape
@@ -326,35 +333,27 @@ def paged_prefill_chunk(
     KV, HD = cfg.n_kv_heads, cfg.head_dim
     nb = C // block_size
     positions = start + jnp.arange(C, dtype=jnp.int32)[None, :]  # [1, C]
-    x = embed(params, tokens, cfg)
-    L = cfg.n_layers
 
-    def body(carry, xs):
-        x, ck_all, cv_all = carry
-        lp, i = xs
-        ck = jax.lax.dynamic_index_in_dim(ck_all, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, i, 0, keepdims=False)
+    # Token j lands at (rows[j], offs[j]); padded tail rows point at the
+    # trash block via chunk_row. Rows, not whole blocks: a scatter of ONE
+    # block becomes an update-slice that copies the whole cache (v5e).
+    rows = jnp.repeat(chunk_row, block_size)
+    offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), nb)
+
+    def layer(x, ck, cv, lp, base):
         h = rms_norm(x, lp["attn_norm"])
         q, k, v = project_qkv(h, lp, cfg, positions)
-        # Scatter the chunk's K/V block-rows into the pool (padded tail
-        # rows point at the trash block via chunk_row).
-        ck = ck.at[chunk_row].set(k[0].reshape(nb, block_size, KV, HD))
-        cv = cv.at[chunk_row].set(v[0].reshape(nb, block_size, KV, HD))
-        ck_g = ck[table_row].reshape(W * block_size, KV, HD)
-        cv_g = cv[table_row].reshape(W * block_size, KV, HD)
+        ck = ck.at[rows + base, offs].set(k[0])
+        cv = cv.at[rows + base, offs].set(v[0])
+        ck_g = ck[table_row + base].reshape(W * block_size, KV, HD)
+        cv_g = cv[table_row + base].reshape(W * block_size, KV, HD)
         o = _attend_chunk(q[0], ck_g, cv_g, positions[0], cfg)
         x = x + (o @ lp["wo"].astype(o.dtype))[None]
-        x = mlp_block(x, lp, cfg)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, i, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, i, 0)
-        return (x, ck_all, cv_all), None
+        return mlp_block(x, lp, cfg), ck, cv
 
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-    )
-    logits = unembed(params, x, cfg)[0]
-    return logits, {"k": ks, "v": vs}
+    x = embed(params, tokens, cfg)
+    x, cache = _scan_layers(layer, x, params["layers"], cache)
+    return unembed(params, x, cfg)[0], cache
 
 
 def prefill_chunk_and_sample(

@@ -225,45 +225,53 @@ def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
     assert set(_kernel_names(text)) <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
 
 
-def test_engine_programs_compile_for_v5e(v5e):
-    """The decode window (AUTO param layout, as LLMEngine builds it) and a
-    prefill bucket, lowered for one v5e chip."""
+def _engine_shapes(cfg, pcfg, device):
+    """What ``LLMEngine._build_programs`` lowers with, for one described
+    chip: ``sds`` (shapes placed on it), the bf16 parameter shapes, their
+    AUTO layouts, and the cache's shapes."""
     from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
     from ray_tpu.models import transformer as tf
-    from ray_tpu.models.paged import (
-        PagedConfig, init_paged_cache, paged_decode_loop, prefill_and_sample,
+    from ray_tpu.models.paged import init_paged_cache
+
+    on_chip = SingleDeviceSharding(device)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: tf.init_params(k, cfg), jax.random.PRNGKey(0)),
     )
+    auto = jax.tree.map(lambda a: Format(Layout.AUTO, on_chip), params)
+    cache = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: init_paged_cache(cfg, pcfg))
+    )
+    return sds, params, auto, cache
+
+
+def test_engine_programs_compile_for_v5e(v5e):
+    """The decode window (AUTO param layout, as LLMEngine builds it) and a
+    prefill bucket, lowered for one v5e chip."""
+    from ray_tpu.models import transformer as tf
+    from ray_tpu.models.paged import PagedConfig, paged_decode_loop, prefill_and_sample
 
     cfg = tf.TransformerConfig(
         vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
         d_ff=128, max_seq_len=128, dtype=jnp.bfloat16, remat=False,
     )
     p = PagedConfig(block_size=8, num_blocks=17, max_batch=4, max_blocks_per_seq=4)
-    on_chip = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
-
-    params = jax.tree.map(
-        lambda a: sds(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda k: tf.init_params(k, cfg), jax.random.PRNGKey(0)),
-    )
-    cache = jax.tree.map(
-        lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: init_paged_cache(cfg, p))
-    )
+    sds, params, auto, cache = _engine_shapes(cfg, p, v5e[0])
     b, w = p.max_batch, p.max_blocks_per_seq
 
     def decode(params, tokens, cache, tables, lens, temps, key):
         return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
 
-    auto = jax.tree.map(lambda a: Format(Layout.AUTO, on_chip), params)
     compiled = jax.jit(
         decode, donate_argnums=(2,), in_shardings=(auto,) + (None,) * 6,
     ).lower(
-        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
-        sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
+        params, sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
         sds((b,), np.float32), sds((2,), np.uint32),
     ).compile()
     (params_fmt, *_), _ = compiled.input_formats
@@ -276,8 +284,66 @@ def test_engine_programs_compile_for_v5e(v5e):
     text = jax.jit(
         prefill, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 6,
     ).lower(
-        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
-        sds((1, 16), np.int32), cache, sds((2,), np.int32), sds((), np.int32),
+        params, sds((1, 16), np.int32), cache, sds((2,), np.int32), sds((), np.int32),
         sds((), np.float32), sds((2,), np.uint32),
     ).compile().as_text()
     assert "tpu_custom_call" in text  # prefill runs the flash kernel
+
+
+@pytest.mark.parametrize("program", ["decode_window", "chunk_one_block", "chunk_three_blocks"])
+def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
+    """The layer scan carries the stacked cache and every layer addresses
+    its own blocks in it: the compiled decode window and chunk programs
+    (donated cache, AUTO parameter layouts, as ``LLMEngine`` builds them)
+    hold no instruction whose result is one layer's pool, copy the cache
+    nowhere, and keep less than one layer's pool in temporaries. The
+    cache's rows are the serve cell's ([16, 8, 128]: the layouts the
+    compiler weighs are those of the chip); 67 blocks a layer is a
+    dimension no other array has. A chunk of ONE block is the case whose
+    scatter the compiler turns into a dynamic-update-slice."""
+    from ray_tpu.models import transformer as tf
+    from ray_tpu.models.paged import PagedConfig, paged_decode_loop, prefill_chunk_and_sample
+
+    cfg = tf.TransformerConfig(
+        vocab_size=256, d_model=1024, n_layers=3, n_heads=8, n_kv_heads=8,
+        d_ff=512, max_seq_len=128, dtype=jnp.bfloat16, remat=False,
+    )
+    p = PagedConfig(block_size=16, num_blocks=67, max_batch=4, max_blocks_per_seq=4)
+    sds, params, auto, cache = _engine_shapes(cfg, p, v5e[0])
+    b, w, bs = p.max_batch, p.max_blocks_per_seq, p.block_size
+    key = sds((2,), np.uint32)
+
+    if program == "decode_window":
+        def run(params, tokens, cache, tables, lens, temps, key):
+            return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
+
+        args = (sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
+                sds((b,), np.float32), key)
+    else:
+        nb = {"chunk_one_block": 1, "chunk_three_blocks": 3}[program]
+
+        def run(params, tokens, cache, table_row, chunk_row, start, last_idx, temp, key):
+            return prefill_chunk_and_sample(
+                params, cfg, tokens, cache, table_row, chunk_row, bs, start, last_idx,
+                temp, key,
+            )
+
+        args = (sds((1, nb * bs), np.int32), cache, sds((w,), np.int32), sds((nb,), np.int32),
+                sds((), np.int32), sds((), np.int32), sds((), np.float32), key)
+    compiled = jax.jit(
+        run, donate_argnums=(2,), in_shardings=(auto,) + (None,) * len(args),
+    ).lower(params, *args).compile()
+
+    rows = f"{bs},{cfg.n_kv_heads},{cfg.head_dim}]"
+    one_pool = f"bf16[{p.num_blocks},{rows}"
+    whole = (f"bf16[{cfg.n_layers * p.num_blocks},{rows}",
+             f"bf16[{cfg.n_layers},{p.num_blocks},{rows}")
+    results = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w-]+)\(", compiled.as_text(), re.M)
+    assert results
+    pools = [(shape, op) for shape, op in results if shape.startswith(one_pool)]
+    assert not pools, pools[:4]
+    copies = [(shape, op) for shape, op in results
+              if shape.startswith(whole) and op.startswith("copy")]
+    assert not copies, copies
+    pool_bytes = p.num_blocks * bs * cfg.n_kv_heads * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
