@@ -18,10 +18,11 @@ re-designed for XLA's static-shape world:
 - **Decode step** (``paged_decode_step``): fixed ``[max_batch]`` token
   vector in, next tokens out. Per layer inside one ``lax.scan``:
   scatter the new K/V into (block, offset) slots via batched
-  ``.at[].set``, gather the slot's blocks back as a contiguous
-  ``[b, W*bs, KV, HD]`` view, and run grouped-GQA einsum attention
-  under a per-slot length mask. Everything is static-shape; XLA sees
-  one compiled program regardless of which slots are live.
+  ``.at[].set``, then attend each slot's token to its blocks under a
+  per-slot length mask (``ops/paged_attention.py``: on a TPU a kernel
+  that reads the live blocks where they lie). Everything is
+  static-shape; XLA sees one compiled program regardless of which
+  slots are live.
 - **Prefill** (``paged_prefill``): full-attention forward over a padded
   prompt bucket, scattering each layer's roped K/V into the slot's
   blocks. Buckets (powers of two) bound the number of compilations.
@@ -48,6 +49,7 @@ from ray_tpu.models.transformer import (
     rms_norm,
     unembed,
 )
+from ray_tpu.ops.paged_attention import paged_attention
 
 PagedCache = Dict[str, jax.Array]
 
@@ -101,26 +103,6 @@ def _scan_layers(layer, x, layers: Params, cache: PagedCache):
     return x, {"k": ck.reshape(shape), "v": cv.reshape(shape)}
 
 
-@jax.named_scope("paged.attend")
-def _attend_paged(q, ck, cv, lens, cfg: TransformerConfig):
-    """q: [b, H, HD] one token per slot; ck/cv: [b, m, KV, HD] gathered
-    contiguous views; lens: [b] — position of the token just written
-    (attend over positions <= lens, i.e. the prefix INCLUDING itself)."""
-    b, H, HD = q.shape
-    KV = cfg.n_kv_heads
-    G = H // KV
-    qg = q.reshape(b, KV, G, HD)
-    scores = jnp.einsum(
-        "bkgd,bmkd->bkgm", qg.astype(jnp.float32), ck.astype(jnp.float32)
-    ) * (HD**-0.5)
-    m = ck.shape[1]
-    valid = jnp.arange(m)[None, :] <= lens[:, None]  # [b, m]
-    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    og = jnp.einsum("bkgm,bmkd->bkgd", probs, cv.astype(jnp.float32))
-    return og.reshape(b, H * HD).astype(q.dtype)
-
-
 def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, lens):
     """One layer, one token per slot.
 
@@ -128,7 +110,6 @@ def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, len
     layer's blocks; tables: [b, W] block ids into it; lens: [b] write
     positions.
     """
-    b = x.shape[0]
     bs = ck.shape[1]
     h = rms_norm(x, lp["attn_norm"])
     q, k, v = project_qkv(h, lp, cfg, lens[:, None])
@@ -141,14 +122,8 @@ def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, len
         off = lens % bs
         ck = ck.at[phys, off].set(k[:, 0])
         cv = cv.at[phys, off].set(v[:, 0])
-    with jax.named_scope("paged.gather"):
-        # Gather each slot's blocks into a contiguous [b, W*bs, KV, HD] view
-        # (post-scatter, so the just-written token attends to itself).
-        KV, HD = cfg.n_kv_heads, cfg.head_dim
-        W = tables.shape[1]
-        ck_g = ck[tables].reshape(b, W * bs, KV, HD)
-        cv_g = cv[tables].reshape(b, W * bs, KV, HD)
-    o = _attend_paged(q[:, 0], ck_g, cv_g, lens, cfg)
+    # After the scatter, so the token just written attends to itself.
+    o = paged_attention(q[:, 0], ck, cv, tables, lens)
     x = x + (o @ lp["wo"].astype(o.dtype))[:, None, :]
     with jax.named_scope("paged.mlp"):
         x = mlp_block(x, lp, cfg)
@@ -221,6 +196,11 @@ def paged_decode_loop(
     on one chip), while the unrolled chain is straight-line dataflow
     whose intermediate caches XLA reuses in place. Compile time grows
     linearly in n_steps (~seconds for window 8)."""
+    # A row whose table starts on the trash block holds no sequence (the
+    # host points idle and still-prefilling slots there), yet every window
+    # advances it: restart it, so that it reads and writes one block
+    # however long it has idled.
+    lens = jnp.where(tables[:, 0] == TRASH_BLOCK, 0, lens)
     seq = []
     for _ in range(n_steps):
         key, sub = jax.random.split(key)
@@ -290,7 +270,8 @@ def _attend_chunk(q, ck, cv, qpos, cfg: TransformerConfig):
     """q: [C, H, HD] chunk queries; ck/cv: [m, KV, HD] the slot's gathered
     block view (prefix + this chunk, post-scatter); qpos: [C] absolute
     positions — attend over cache positions <= qpos (causal, prefix
-    inclusive). Same f32 einsum/softmax math as ``_attend_paged``."""
+    inclusive). Same f32 einsum/softmax math as
+    ``reference_paged_attention``."""
     C, H, HD = q.shape
     KV = cfg.n_kv_heads
     G = H // KV

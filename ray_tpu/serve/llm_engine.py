@@ -444,6 +444,7 @@ class LLMEngine:
                       "finished": 0, "prefill_chunks": 0, "spec_windows": 0,
                       "h2d_ships": 0, "h2d_skips": 0, "prefix_hit_tokens": 0,
                       "prefix_lookup_tokens": 0, "prefix_evictions": 0,
+                      "decode_blocks_live": 0, "decode_blocks_table": 0,
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED}}
         # Milliseconds by phase of the iteration in progress (tracing.phase).
         self._phase_ms: Dict[str, float] = {}
@@ -1058,6 +1059,13 @@ class LLMEngine:
             # harvest has re-synced the mirror.
             return False
         self.stats["max_active"] = max(self.stats["max_active"], len(entries))
+        # Blocks the occupied slots' tokens lie in as the window starts,
+        # against the table the plain form of decode attention gathers whole.
+        occupied = [i for i, _rid, _gen in entries]
+        self.stats["decode_blocks_live"] += int(
+            (self.lens[occupied] // self.pcfg.block_size + 1).sum())
+        self.stats["decode_blocks_table"] += (
+            self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
         self.key, sub = jax.random.split(self.key)
         args = self._ship()
         seq, cur_out, lens_out, self.cache = self._decode(
@@ -1320,6 +1328,10 @@ class LLMEngine:
                 # Windows found in flight and not overlapped, by reason.
                 "blocked": {why: self.stats["spec_blocked_" + why]
                             for why in _SPEC_BLOCKED},
+                # Share of the block tables that held live tokens when
+                # their window was dispatched: what decode attention reads.
+                "decode_live_block_pct": 100.0 * self.stats["decode_blocks_live"]
+                / max(1, self.stats["decode_blocks_table"]),
                 "h2d_ships": self.stats["h2d_ships"],
                 "h2d_skips": self.stats["h2d_skips"],
             },

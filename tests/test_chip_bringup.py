@@ -300,7 +300,12 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
     cache's rows are the serve cell's ([16, 8, 128]: the layouts the
     compiler weighs are those of the chip); 67 blocks a layer is a
     dimension no other array has. A chunk of ONE block is the case whose
-    scatter the compiler turns into a dynamic-update-slice."""
+    scatter the compiler turns into a dynamic-update-slice. At these rows
+    the decode window runs the ``paged_attend`` kernel, whose pool operands
+    pin a layout: it must be the carry's own, and nothing may gather the
+    slots' padded tables. Its pool is the serve cell's 1,601 blocks a
+    layer: one of 67 (6.6 MB) the compiler parks in its fast memory for
+    the kernel and copies back, which no pool of an engine's size allows."""
     from ray_tpu.models import transformer as tf
     from ray_tpu.models.paged import PagedConfig, paged_decode_loop, prefill_chunk_and_sample
 
@@ -308,7 +313,8 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
         vocab_size=256, d_model=1024, n_layers=3, n_heads=8, n_kv_heads=8,
         d_ff=512, max_seq_len=128, dtype=jnp.bfloat16, remat=False,
     )
-    p = PagedConfig(block_size=16, num_blocks=67, max_batch=4, max_blocks_per_seq=4)
+    p = PagedConfig(block_size=16, num_blocks=1601 if program == "decode_window" else 67,
+                    max_batch=4, max_blocks_per_seq=4)
     sds, params, auto, cache = _engine_shapes(cfg, p, v5e[0])
     b, w, bs = p.max_batch, p.max_blocks_per_seq, p.block_size
     key = sds((2,), np.uint32)
@@ -347,3 +353,65 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
     assert not copies, copies
     pool_bytes = p.num_blocks * bs * cfg.n_kv_heads * cfg.head_dim * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    if program == "decode_window":
+        assert _kernel_names(compiled.as_text()) == ["paged_attend"] * 2  # one a step
+        padded = [(shape, op) for shape, op in results
+                  if shape.startswith((f"bf16[{b * w},{rows}", f"bf16[{b},{w},{rows}"))]
+        assert not padded, padded
+
+
+_KERNEL_AGAINST_PLAIN_FORM = """
+import os
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.models import transformer as tf
+from ray_tpu.models.paged import PagedConfig, init_paged_cache, paged_decode_step
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+cfg = tf.TransformerConfig(
+    vocab_size=512, d_model=4096, n_layers=3, n_heads=32, n_kv_heads=8,
+    d_ff=1024, max_seq_len=1568, dtype=jnp.bfloat16, remat=False,
+)
+p = PagedConfig(block_size=16, num_blocks=129, max_batch=8, max_blocks_per_seq=98)
+params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                      tf.init_params(jax.random.PRNGKey(0), cfg))
+kk, kv, kt = jax.random.split(jax.random.PRNGKey(1), 3)
+shape = init_paged_cache(cfg, p)["k"].shape
+cache = {"k": jax.random.normal(kk, shape, jnp.float32).astype(cfg.dtype),
+         "v": jax.random.normal(kv, shape, jnp.float32).astype(cfg.dtype)}
+# 8 slots x up to 16 live blocks of the 128, the rest of a row on the trash
+# block; slot 0 is idle and its lens has run on past the table
+lens = jnp.asarray([3000, 15, 16, 100, 127, 128, 200, 255], jnp.int32)
+tables = np.zeros((p.max_batch, p.max_blocks_per_seq), np.int32)
+tables[1:, :16] = np.asarray(jax.random.permutation(kt, jnp.arange(1, 113))).reshape(7, 16)
+tables = jnp.asarray(tables)
+tokens = jnp.arange(8, dtype=jnp.int32) * 7 + 1
+
+def step(force):
+    os.environ["RAY_TPU_FORCE_PALLAS"] = force  # read while tracing
+    run = jax.jit(lambda *a: paged_decode_step(params, cfg, *a)[0])
+    assert ("paged_attend" in run.lower(tokens, cache, tables, lens).as_text()) == (force == "1")
+    return np.asarray(run(tokens, cache, tables, lens))
+
+kernel, plain = step("1"), step("0")
+assert np.isfinite(kernel).all()
+assert np.array_equal(kernel.argmax(-1), plain.argmax(-1)), (kernel.argmax(-1), plain.argmax(-1))
+apart = (np.abs(kernel - plain).max(-1) / (plain.max(-1) - plain.min(-1))).max()
+assert apart < 2**-7, apart
+print("apart", apart)
+"""
+
+
+def test_paged_attend_kernel_reads_the_plain_forms_logits_on_the_chip():
+    """One decode step on a chip, kernel against the plain gather-and-einsum
+    form, at the serve cell's rows ([16, 8, 128], 4 q heads a kv head): the
+    same next token in every slot and logits a bf16 rounding apart. In a
+    process of its own: this one is held to the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    r = subprocess.run([sys.executable, "-c", _KERNEL_AGAINST_PLAIN_FORM], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]

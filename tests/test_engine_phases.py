@@ -150,6 +150,26 @@ def test_each_window_in_flight_is_overlapped_or_counted_once(tiny_model, paged, 
         why: s["spec_blocked_" + why] for why in llm_engine._SPEC_BLOCKED}
 
 
+def test_live_blocks_are_counted_against_the_table_at_each_dispatch(tiny_model):
+    """``decode_blocks_live`` is what decode attention has to read (the
+    blocks the occupied slots' tokens lie in as the window starts),
+    ``decode_blocks_table`` what the padded gather reads: every slot's
+    whole table."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=False)  # block 8, 4 slots, table of 8
+    reqs = [eng.add_request(list(range(1, 6)), 6), eng.add_request(list(range(1, 12)), 6)]
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    assert [len(list(r.tokens(timeout=5))) for r in reqs] == [6, 6]
+    s = eng.stats
+    # the first token comes from the prefill, five more from three windows of
+    # two, dispatched at lens 5, 7, 9 (1, 1, 2 blocks) and 11, 13, 15 (2, 2, 2)
+    assert s["steps"] == 3
+    assert s["decode_blocks_live"] == (1 + 1 + 2) + (2 + 2 + 2)
+    assert s["decode_blocks_table"] == 3 * 4 * 8
+    assert eng.report_state()["overlap"]["decode_live_block_pct"] == pytest.approx(100 * 10 / 96)
+
+
 def _force_idle(eng):
     for i, req in enumerate(eng.slots):  # as if an eos stop had been harvested
         if req is not None:
@@ -255,6 +275,9 @@ def test_traced_engine_puts_five_sibling_phases_on_the_host_plane(tmp_path):
 def test_decode_program_carries_the_paged_scopes(tiny_model):
     cfg, params = tiny_model
     text = _engine(cfg, params)._decode.as_text()
-    for scope in ("paged.scatter", "paged.gather", "paged.attend", "paged.mlp"):
+    # no ``paged.gather``: the gather of the padded table is the plain form of
+    # ``paged.attend`` now, and the kernel has none
+    assert "/paged.gather/" not in text
+    for scope in ("paged.scatter", "paged.attend", "paged.mlp"):
         assert any("op_name=" in line and f"/{scope}/" in line
                    for line in text.splitlines()), scope

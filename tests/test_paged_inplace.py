@@ -159,3 +159,21 @@ def test_prefill_chunk_equals_slice_and_put_back(model, case):
     _assert_bit_equal(got, want)
     assert not np.array_equal(np.asarray(got[1]["v"].astype(jnp.float32)),
                               np.asarray(cache["v"].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("idled", [10, 4 * BS, 10_000], ids=["a_window", "the_table", "long"])
+def test_decode_window_restarts_a_row_that_holds_no_sequence(model, idled):
+    """The host points an idle slot's row at the trash block and lets its
+    ``lens`` run on, a window's steps with every window it sits out. The
+    window restarts such a row at 0: however long it idled it touches the
+    first rows of the trash block and nothing else, so the sampled tokens
+    and the cache are the ones of a row that never ran on."""
+    cfg, params, cache = model
+    tables, lens, _ = DECODE_CASES["idle_on_trash"]
+    ran_on = np.array(lens)
+    assert ran_on[2] == 0 and (tables[2] == TRASH_BLOCK).all()
+    ran_on[2] = idled
+    window = jax.jit(lambda lens: paged.paged_decode_loop(
+        params, cfg, jnp.asarray([7, 19, 3, 42], jnp.int32), cache, jnp.asarray(tables),
+        lens, jnp.zeros(4, jnp.float32), jax.random.PRNGKey(0), 3))
+    _assert_bit_equal(window(jnp.asarray(ran_on, jnp.int32)), window(jnp.asarray(lens, jnp.int32)))
