@@ -1,6 +1,6 @@
 """7B north-star config: sharded AOT compile proof.
 
-The single-chip bench (bench.py) runs the largest config one v5e holds;
+The one-chip cells of chipbench/ run the largest configs one v5e holds;
 the BASELINE.json north star is tokens/s/chip AT 7B — which only exists
 sharded. This script AOT-compiles the FULL train step (loss + grads +
 adamw update, remat, flash attention) for a Llama-2-7B-shaped config

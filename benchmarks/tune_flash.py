@@ -14,9 +14,11 @@ import sys
 CHILD = r"""
 import os
 import time
+import types
 import jax
 import jax.numpy as jnp
-from ray_tpu.accelerators.tpu import peak_bf16_flops
+from chipbench.peaks import peaks_for
+from chipbench.work import train_flops_per_token
 from ray_tpu.models import transformer as tf
 from ray_tpu.parallel import MeshPlan, build_mesh, make_train_state, make_train_step
 from ray_tpu.parallel import mesh as mesh_lib
@@ -51,9 +53,13 @@ for _ in range(N):
     params, opt_state, m = step(params, opt_state, batch)
 _ = float(m["loss"])  # materialize: forces the whole chain
 dt = (time.perf_counter() - t0) / N
-flops_tok = tf.flops_per_token(cfg, 2048)
+# The benchmark's count of work and table of peaks (one yardstick: an MFU
+# here reads on the ledger's scale; an unknown device kind raises).
+dims = types.SimpleNamespace(vocab=cfg.vocab_size, hidden=D, layers=L, heads=H,
+                             kv_heads=H, head_dim=cfg.head_dim, ffn=FF)
+flops_tok = train_flops_per_token(dims, 2048)
 n_params = sum(int(x.size) for x in jax.tree.leaves(params))
-peak = peak_bf16_flops(jax.devices()[0].device_kind)  # unknown kind raises
+peak = peaks_for(jax.devices()[0].device_kind)["bf16_flops"]
 mfu = (flops_tok * BATCH * 2048 / dt) / (peak * jax.device_count())
 tps = BATCH * 2048 / dt
 print(f"RESULT {dt*1e3:.1f} ms/step  MFU {mfu:.2%}  {tps:.0f} tok/s  params {n_params/1e6:.0f}M", flush=True)

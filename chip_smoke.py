@@ -54,7 +54,7 @@ PHASE_TIMEOUT_S = {
 }
 MULTICHIP = tuple(name for name in PHASE_TIMEOUT_S if name.startswith("multichip_"))
 
-# 758M flagship (bench.py): the largest llama-shaped config whose fp32
+# 758M flagship: the largest llama-shaped config whose fp32
 # master weights + Adam moments + grads fit one v5e chip with remat.
 FLAGSHIP = dict(
     vocab_size=32000, d_model=2304, n_layers=10, n_heads=18, n_kv_heads=18,
@@ -382,7 +382,7 @@ def _phase_serve():
                 )
 
             t0 = _t.perf_counter()
-            # bench.py's serving configuration: 16+36+19 (overlap
+            # Sized to this phase's requests: 16+36+19 (overlap
             # overshoot) = 71 tokens → 9 blocks a slot, 16 slots = the pool.
             self.engine = LLMEngine(
                 init_bf16, cfg,

@@ -25,22 +25,6 @@ TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance/attributes/"
 
 
-# Published peak dense bf16 FLOP/s of one chip, keyed by jax's
-# ``device_kind`` (Google Cloud documentation, "TPU v5e": 197 TFLOP/s).
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
-
-
-def peak_bf16_flops(device_kind: str) -> float:
-    """A utilization needs the peak of the device that ran: a kind that
-    is not in the table is an error, never a default."""
-    if device_kind not in PEAK_BF16_FLOPS:
-        raise ValueError(
-            f"no published peak for device_kind {device_kind!r}; known: "
-            f"{sorted(PEAK_BF16_FLOPS)}"
-        )
-    return PEAK_BF16_FLOPS[device_kind]
-
-
 def jax_backend_initialized() -> bool:
     """True once THIS process has opened a jax backend — on a TPU host,
     once it owns its chips. Never imports jax and never opens the

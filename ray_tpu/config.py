@@ -278,7 +278,7 @@ class Config:
     profiling_sample_hz: int = 100
     # Continuous low-rate background sampler feeding the incident ring
     # (0 = off, the default; ~5-20 Hz keeps overhead well under the 3%
-    # budget measured by bench.py profiling_overhead_pct).
+    # budget; `python bench.py --cpu` reports it as profiling_overhead_pct).
     profiling_continuous_hz: float = 0.0
     # How many seconds of recent samples the incident ring retains.
     profiling_ring_s: float = 60.0

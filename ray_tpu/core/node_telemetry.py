@@ -93,21 +93,6 @@ def sample_devices() -> List[Dict]:
     return rows
 
 
-def peak_device_hbm_bytes() -> Optional[int]:
-    """Max peak_bytes_in_use across local devices (bench reporting);
-    None when no device exposes memory stats (CPU backends)."""
-    rows = sample_devices()
-    if not rows:
-        return None
-    return max(r["peak_bytes_in_use"] for r in rows)
-
-
-def peak_device_hbm_gb() -> Optional[float]:
-    """peak_device_hbm_bytes in GiB rounded for bench records."""
-    peak = peak_device_hbm_bytes()
-    return None if peak is None else round(peak / (1 << 30), 2)
-
-
 class _DeviceGauges:
     """Lazy per-process HBM gauges, flushed by the normal metrics
     pipeline (tags: device id + platform — bounded cardinality; the

@@ -163,14 +163,6 @@ def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array) -> jax.Ar
     return jnp.where(temps > 0, sampled, greedy)
 
 
-def paged_decode_sample_step(
-    params, cfg: TransformerConfig, tokens, cache, tables, lens, temps, key
-):
-    """decode + on-device sampling → (next_tokens [b], cache')."""
-    logits, cache = paged_decode_step(params, cfg, tokens, cache, tables, lens)
-    return sample_tokens(logits, temps, key), cache
-
-
 def paged_decode_loop(
     params: Params,
     cfg: TransformerConfig,
@@ -351,29 +343,3 @@ def prefill_chunk_and_sample(
     last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=0, keepdims=False)
     tok = sample_tokens(last[None, :], temp[None], key)[0]
     return tok, cache
-
-
-def make_jitted(cfg: TransformerConfig, decode_window: int = 1):
-    """Compile the decode window and prefill. ``params`` is a RUNTIME
-    argument, never closed over — closing over it would capture the
-    whole model (13.5 GB at 7B) as compile-time constants baked into the
-    HLO, which takes tens of minutes to lower. The cache is donated in
-    both programs (the pool updates in place, never double-buffered);
-    jit re-specializes prefill per prompt bucket automatically (one
-    compile per bucket).
-
-    ``decode_window``: steps per device call (see paged_decode_loop).
-    The returned decode fn always yields [window, b] tokens (window=1
-    included), so the engine has one shape contract."""
-
-    def _decode(params, tokens, cache, tables, lens, temps, key):
-        return paged_decode_loop(
-            params, cfg, tokens, cache, tables, lens, temps, key, decode_window
-        )
-
-    def _prefill(params, tokens, cache, block_row, block_size, real_len, temp, key):
-        return prefill_and_sample(params, cfg, tokens, cache, block_row, block_size, real_len, temp, key)
-
-    decode = jax.jit(_decode, donate_argnums=(2,))  # cache
-    prefill = jax.jit(_prefill, static_argnums=(4,), donate_argnums=(2,))  # cache
-    return decode, prefill

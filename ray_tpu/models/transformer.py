@@ -358,19 +358,3 @@ def loss_fn(
         return chunked_token_nll(params, h, targets, cfg, mask, chunk=logits_chunk)
     logits = forward(params, inputs, cfg, attn_fn)
     return token_nll(logits, targets, mask)
-
-
-def init_shapes(cfg: TransformerConfig):
-    return jax.tree.map(lambda x: x.shape, jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
-
-
-def num_params(cfg: TransformerConfig) -> int:
-    import math
-
-    return sum(math.prod(s) for s in jax.tree.leaves(init_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-
-def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
-    """Approximate training FLOPs/token (6·N params + attention term)."""
-    attn = 12 * cfg.n_layers * cfg.d_model * seq_len  # fwd+bwd QK^T and PV
-    return 6.0 * num_params(cfg) + attn

@@ -403,8 +403,9 @@ class LLMEngine:
          self.params) = self._build_programs(params)
         self.alloc = _BlockAllocator(p)
         self.key = jax.random.PRNGKey(seed)
-        # Slot state. Host-side numpy is the source of truth; the device
-        # keeps mirrors (``_dev``) that are re-uploaded ONLY when the
+        # Slot state. Host-side numpy is the source of truth and the
+        # scheduler thread its one owner; the device is handed copies
+        # (``_hand_over``), kept in ``_dev`` and re-uploaded ONLY when the
         # scheduler dirtied them — steady-state decode re-ships nothing
         # (cur/lens ride the decode program's own outputs).
         self.slots: List[Optional[Request]] = [None] * p.max_batch
@@ -601,9 +602,9 @@ class LLMEngine:
         # Decode window: already compiled (AOT) — this is its first
         # execution, so a program that does not fit fails at build time.
         seq, _cur, _lens, self.cache = self._decode(
-            self.params, jax.numpy.asarray(self.cur), self.cache,
-            jax.numpy.asarray(self.tables), jax.numpy.asarray(self.lens),
-            jax.numpy.asarray(self.temps), sub,
+            self.params, self._hand_over(self.cur), self.cache,
+            self._hand_over(self.tables), self._hand_over(self.lens),
+            self._hand_over(self.temps), sub,
         )
         jax.block_until_ready(seq)
         return n + 1
@@ -947,6 +948,10 @@ class LLMEngine:
                 crow[j] = blocks[b0 + j]
         last_idx = min(max(plen - 1 - start, 0), width - 1)
         self.key, sub = jax.random.split(self.key)
+        # toks/trow/crow (like _run_full_prefill's toks/row) are built per
+        # call and never written again, so a view is safe here: only the
+        # slot mirrors, which the scheduler mutates in place, need
+        # _hand_over's copy.
         tok, self.cache = self._prefill_chunk_fn(
             self.params, jax.numpy.asarray(toks), self.cache,
             jax.numpy.asarray(trow), jax.numpy.asarray(crow),
@@ -1017,14 +1022,27 @@ class LLMEngine:
         if (req.eos_id is not None and tok == req.eos_id) or req.remaining <= 0:
             self._finish(i)
 
+    @staticmethod
+    def _hand_over(host: np.ndarray) -> jax.Array:
+        """The one place where a host mirror becomes a device array: from
+        a COPY that nobody writes again. The scheduler mutates its mirrors
+        in place between dispatches (``_dispatch_window``, the harvest,
+        ``_free_slot``) while the program they were handed to runs
+        asynchronously, and a backend may alias the numpy buffer (the CPU
+        client does, on a 64-byte boundary) or read it after the call
+        returned. The copy is made on the host: ``jax.numpy.array(host,
+        copy=True)`` reads ``host`` from a device program of its own
+        (``convert_element_type``), as late as any other."""
+        return jax.numpy.asarray(host.copy())
+
     def _ship(self) -> Dict[str, jax.Array]:
-        """Device-resident decode inputs, re-uploading ONLY the arrays the
-        scheduler dirtied since the last dispatch (satellite: stop
-        re-shipping tables/lens/temps/cur wholesale every step)."""
+        """Device-resident decode inputs, re-uploading ONLY the mirrors the
+        scheduler dirtied since the last dispatch, each as a copy
+        (``_hand_over``)."""
         for name, host in (("tables", self.tables), ("lens", self.lens),
                            ("temps", self.temps), ("cur", self.cur)):
             if self._dev[name] is None or name in self._dirty:
-                self._dev[name] = jax.numpy.asarray(host)
+                self._dev[name] = self._hand_over(host)
                 self._dirty.discard(name)
                 self.stats["h2d_ships"] += 1
             else:
@@ -1042,9 +1060,12 @@ class LLMEngine:
     def _dispatch_window(self, speculative: bool = False) -> bool:
         """Dispatch ONE decode window over the decodable slots without
         reading it back: outputs (sampled tokens, advanced lens) stay on
-        device and feed the next window directly. Host mirrors advance in
-        lockstep (the device program advances EVERY row; idle rows write
-        to the trash block, and their mirror drift is clamped below)."""
+        device and feed the next window directly. The host advances the
+        ``lens`` of the rows it dispatched and no other: an idle or
+        still-prefilling row stays at the 0 ``_free_slot`` left it at. The
+        device's own ``lens`` output advances EVERY row; what an idle row
+        holds there is ``paged_decode_loop``'s to define (it restarts a
+        row whose table starts on the trash block)."""
         self._ensure_decode_blocks()
         entries = self._decode_entries()
         if not entries:
@@ -1074,18 +1095,7 @@ class LLMEngine:
         )
         self._dev["cur"] = cur_out
         self._dev["lens"] = lens_out
-        self.lens += self.window
-        if int(self.lens.max()) > (1 << 30):
-            # Idle/prefilling rows drift +window per dispatch (the device
-            # program advances EVERY row; their writes go to the trash
-            # block). Reset them to 0 well before int32 wrap — live rows
-            # are capacity-bounded far below this. Resetting (not
-            # clamping AT a ceiling, which would re-trigger every window)
-            # costs one lens re-ship per ~2^30/window dispatches.
-            for i in range(len(self.slots)):
-                if self.slots[i] is None or i in self._prefilling:
-                    self.lens[i] = 0
-            self._dirty.add("lens")
+        self.lens[occupied] += self.window
         self.stats["steps"] += 1
         self._inflight = (entries, seq)
         return True

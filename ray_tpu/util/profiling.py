@@ -506,7 +506,8 @@ class ContinuousSampler:
     samples — the flight recorder for CPU time. Default OFF
     (``profiling_continuous_hz = 0``); at the recommended 5-20 Hz the
     measured overhead on the CPU micro-bench is well under the 3% budget
-    (bench.py ``profiling_overhead_pct``)."""
+    (``python bench.py --cpu``: ``profiling_overhead_pct`` of its ingest
+    arm; a host-side figure, no chip is involved)."""
 
     MAX_RING = 50000
 

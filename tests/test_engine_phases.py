@@ -5,7 +5,8 @@ phases (``engine.harvest_wait``, ``engine.emit``, ``engine.admit``,
 ``engine.prefill_wait``, ``engine.dispatch``) with no span around them;
 every window found in flight is either overlapped (``spec_windows``) or
 counted under the reason it was not (``spec_blocked_*``); the decode
-program's layer carries ``paged.*`` scopes.
+program's layer carries ``paged.*`` scopes. The scheduler owns the slot
+mirrors: the device is handed copies, and only a dispatched row advances.
 """
 import json
 import os
@@ -14,6 +15,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models.paged import PagedConfig
@@ -168,6 +170,61 @@ def test_live_blocks_are_counted_against_the_table_at_each_dispatch(tiny_model):
     assert s["decode_blocks_live"] == (1 + 1 + 2) + (2 + 2 + 2)
     assert s["decode_blocks_table"] == 3 * 4 * 8
     assert eng.report_state()["overlap"]["decode_live_block_pct"] == pytest.approx(100 * 10 / 96)
+
+
+# ---------------------------------------------------------------------------
+# slot state: one owner
+# ---------------------------------------------------------------------------
+def _on_a_64_byte_boundary(a):
+    """``a``'s contents in a buffer that starts on a 64-byte boundary: the
+    placement at which the CPU client aliases a numpy array, not copies it
+    (where malloc puts the engine's own small mirrors is chance)."""
+    raw = np.zeros(a.nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    out = raw[start:start + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+@pytest.mark.parametrize("name", ["tables", "lens", "temps", "cur"])
+def test_the_device_is_handed_a_copy_of_each_mirror(tiny_model, name):
+    """The scheduler writes its mirrors in place right after a dispatch
+    (``lens`` advances, the harvest sets ``cur``, ``_free_slot`` resets a
+    row): what a program was handed must not move with them."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params)
+    host = _on_a_64_byte_boundary(getattr(eng, name))
+    host[...] = 3
+    setattr(eng, name, host)
+    eng._dirty.add(name)
+    shipped = eng._ship()[name]
+    host += 10
+    assert (np.asarray(shipped) == 3).all()
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_only_a_dispatched_row_advances(tiny_model, overlap):
+    """An idle row is 0 on the host from ``_free_slot`` on, however many
+    windows it sits out; an occupied row stands where its last token goes."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)  # 4 slots, windows of 2
+    prompts = [list(range(1, 6)), list(range(1, 12)), list(range(1, 4))]
+    reqs = [eng.add_request(p, n) for p, n in zip(prompts, (40, 40, 3))]
+    for _ in range(6):
+        eng.step()
+    assert eng.slots[3] is None and eng.slots[2] is None  # never used; finished
+    assert reqs[2].remaining == 0
+    assert eng.lens[3] == 0 and eng.lens[2] == 0
+    ahead = eng.window if eng._inflight is not None else 0
+    for i in (0, 1):
+        assert eng.slots[i] is reqs[i] and 3 < len(reqs[i].generated) < 40
+        # the last token harvested is not in the cache yet: it is written
+        # at ``lens`` by the next window (already dispatched under overlap)
+        assert eng.lens[i] == len(prompts[i]) + len(reqs[i].generated) - 1 + ahead
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    assert [len(list(r.tokens(timeout=5))) for r in reqs] == [40, 40, 3]
+    assert (eng.lens == 0).all()
 
 
 def _force_idle(eng):
