@@ -20,9 +20,10 @@ Host/device split:
 Iteration-level perf suite (all opt-in, see ``__init__``):
 - **Prefix-aware KV reuse** (``enable_prefix_cache``): full prompt
   blocks are published to a refcounted exact-match index at prefill
-  time and kept resident after release (LRU eviction on allocation
-  pressure); requests sharing a prefix map resident blocks into their
-  table and prefill only the novel suffix.
+  time and kept resident after release (evicted on allocation
+  pressure, the coldest that no waiting request matches first);
+  requests sharing a prefix map resident blocks into their table and
+  prefill only the novel suffix.
 - **Chunked prefill** (``prefill_chunk``): long prompts advance one
   fixed-size chunk per scheduler step, interleaved with decode windows,
   so an admission no longer head-of-line-blocks active streams.
@@ -99,6 +100,10 @@ class Request:
     # Caller's trace context at add_request time, so the pump thread can
     # parent engine spans under the request's serve-path span tree.
     trace_ctx: Optional[Dict[str, str]] = None
+    # While the request waits: the prefix-cache entries it matched when it
+    # entered the queue (``_PrefixCache.want``), which eviction spares. None
+    # = not looked up yet (or no cache); the scheduler thread alone sets it.
+    wanted: Optional[List[list]] = None
 
     @property
     def remaining(self) -> int:
@@ -194,17 +199,37 @@ class _PrefixCache:
     allocator when an allocation actually needs them (eviction cascades
     to cached descendants, since a re-used parent id must never re-link
     a stale child chain).
+
+    **Eviction order: the coldest block that no waiting request matches.**
+    A block is *wanted* while a request in the engine's queue matched it
+    when it entered (``want`` / ``unwant``; a count beside ``refs``).
+    ``evict_lru`` takes the coldest refcount-0 block that is not wanted;
+    with nothing wanted that is plain LRU. Only when every evictable
+    block is wanted does ``evict_wanted`` take ONE childless block, the
+    leaf end of the chain of the request furthest back in the queue.
+
+    Why plain LRU was the worst case for a FIFO queue: a finished turn's
+    blocks enter the LRU root first, so the coldest evictable block is
+    the FIRST block of some conversation, and evicting it cascades to the
+    whole chain. The LRU orders by release time, and a closed loop's
+    follow-up turn enters the queue at that same moment: the coldest
+    chain belongs to the request nearest the head of the queue, while the
+    chains of conversations that ended sit warmer and survive. The same
+    holds for a preempted request, whose blocks every later release
+    pushes colder while it waits.
     """
 
     ROOT = -1  # parent id for the first block of every prompt
 
     def __init__(self):
-        # (parent_bid, tokens) -> bid; bid -> [key, parent, refs]
+        # (parent_bid, tokens) -> bid; bid -> [key, parent, refs, wanted]
         self.table: Dict[tuple, int] = {}
         self.meta: Dict[int, list] = {}
         self.children: Dict[int, set] = {}
         # refcount-0 residents, coldest first (re-warmed on hit/release).
         self.lru: "collections.OrderedDict[int, None]" = collections.OrderedDict()
+        # Blocks freed by evictions that passed over a colder wanted block.
+        self.spared = 0
 
     @property
     def resident_blocks(self) -> int:
@@ -228,6 +253,22 @@ class _PrefixCache:
             bids.append(bid)
             parent = bid
         return bids
+
+    def want(self, tokens: Sequence[int], bs: int, limit: int) -> List[list]:
+        """Mark the chain ``match`` returns as wanted by one more waiting
+        request; returns the marked ``meta`` ENTRIES (not block ids: an
+        entry dies with its block, so ``unwant`` on a chain that lost
+        blocks meanwhile can never touch a block that reuses their ids).
+        A chain is marked from its root, so a block that is not wanted has
+        no wanted descendant."""
+        chain = [self.meta[b] for b in self.match(tokens, bs, limit)]
+        for m in chain:
+            m[3] += 1
+        return chain
+
+    def unwant(self, chain: Sequence[list]):
+        for m in chain:
+            m[3] -= 1
 
     def incref(self, bid: int):
         m = self.meta[bid]
@@ -257,14 +298,46 @@ class _PrefixCache:
         if cur is not None:
             return cur
         self.table[key] = bid
-        self.meta[bid] = [key, parent, 1]
+        self.meta[bid] = [key, parent, 1, 0]
         self.children.setdefault(parent, set()).add(bid)
         return bid
 
     def evict_lru(self) -> List[int]:
-        """Evict the coldest refcount-0 block plus its cached descendants
-        (a reused parent id must never re-link a stale child chain);
-        returns the FREED block ids (empty if nothing is evictable).
+        """Evict the coldest refcount-0 block that no waiting request
+        wants, plus its cached descendants; returns the FREED block ids
+        (empty if every evictable block is wanted, or none is)."""
+        passed = False
+        for bid in self.lru:
+            if not self.meta[bid][3]:
+                freed = self._drop(bid)
+                if passed:
+                    self.spared += len(freed)
+                return freed
+            passed = True
+        return []
+
+    def evict_wanted(self, chains: Sequence[Sequence[list]]) -> List[int]:
+        """Every evictable block is wanted: take ONE childless block, the
+        leaf end of the first of ``chains`` that has one to give (the
+        waiting requests' ``want`` chains, the LAST in the queue first).
+        What is left of that chain still hits, and no root cascades. A
+        chain whose last resident block is pinned, or has children of
+        another chain, gives nothing; if none gives (every evictable
+        block sits above a pinned descendant), the coldest goes whole."""
+        for chain in chains:
+            for m in reversed(chain):
+                bid = self.table.get(m[0])
+                if bid is None or self.meta[bid] is not m:
+                    continue  # evicted while its request waited
+                if m[2] == 0 and not self.children.get(bid):
+                    return self._drop(bid)
+                break
+        return self._drop(next(iter(self.lru))) if self.lru else []
+
+    def _drop(self, bid: int) -> List[int]:
+        """Unregister ``bid`` and its cached descendants (a reused parent
+        id must never re-link a stale child chain); returns the ones with
+        no reference, which the caller frees.
 
         A descendant with refs > 0 is possible: a request that registered
         a novel tail under a chain another request published first shares
@@ -274,26 +347,21 @@ class _PrefixCache:
         UNREGISTERED (its key would dangle off a reusable parent id) but
         never freed here — its live slot still maps it and returns it to
         the allocator on release."""
-        while self.lru:
-            bid, _ = self.lru.popitem(last=False)
-            if self.meta.get(bid, [None, None, -1])[2] != 0:
-                continue  # defensive: stale entry
-            freed: List[int] = []
-            stack = [bid]
-            while stack:
-                b = stack.pop()
-                m = self.meta.pop(b, None)
-                if m is None:
-                    continue
-                key, parent, refs = m
-                self.table.pop(key, None)
-                self.children.get(parent, set()).discard(b)
-                stack.extend(self.children.pop(b, ()))
-                self.lru.pop(b, None)
-                if refs == 0:
-                    freed.append(b)
-            return freed  # non-empty: the LRU root itself had refs == 0
-        return []
+        freed: List[int] = []
+        stack = [bid]
+        while stack:
+            b = stack.pop()
+            m = self.meta.pop(b, None)
+            if m is None:
+                continue
+            key, parent, refs, _wanted = m
+            self.table.pop(key, None)
+            self.children.get(parent, set()).discard(b)
+            stack.extend(self.children.pop(b, ()))
+            self.lru.pop(b, None)
+            if refs == 0:
+                freed.append(b)
+        return freed
 
 
 @dataclasses.dataclass
@@ -367,8 +435,9 @@ class LLMEngine:
         ``enable_prefix_cache``: keep refcounted prompt blocks resident
         after release and map them into later requests sharing the same
         prefix (system prompts, few-shot headers, preempt-resume), so
-        only the novel suffix is prefilled. LRU eviction of refcount-0
-        blocks replaces unconditional free.
+        only the novel suffix is prefilled. Eviction of refcount-0 blocks
+        under allocation pressure replaces unconditional free: the coldest
+        that no waiting request matches (``_PrefixCache`` has the order).
 
         ``prefill_chunk``: split prompts longer than this many tokens
         into fixed-size chunks interleaved with decode windows, so one
@@ -445,6 +514,7 @@ class LLMEngine:
                       "finished": 0, "prefill_chunks": 0, "spec_windows": 0,
                       "h2d_ships": 0, "h2d_skips": 0, "prefix_hit_tokens": 0,
                       "prefix_lookup_tokens": 0, "prefix_evictions": 0,
+                      "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED}}
         # Milliseconds by phase of the iteration in progress (tracing.phase).
@@ -738,20 +808,38 @@ class LLMEngine:
         self._dirty.update(("tables", "lens", "temps", "cur"))
 
     def _alloc_blocks(self, n: int) -> Optional[List[int]]:
-        """Allocate ``n`` blocks, evicting cold prefix-cache residents as
-        needed (LRU, refcount-0 only). None if even eviction can't cover."""
+        """Allocate ``n`` blocks, evicting prefix-cache residents as needed
+        (refcount-0 only): the coldest that no waiting request matches,
+        and only when all are matched, leaf blocks from the queue's tail
+        (``_PrefixCache``). None if even eviction can't cover."""
         if n <= 0:
             return []
         pc = self.prefix_cache
+        chains = None  # the queue is read only if the fallback engages
         while (
             self.alloc.available < n and pc is not None and pc.evictable_blocks
         ):
             freed = pc.evict_lru()
             if not freed:
+                if chains is None:
+                    with self._lock:
+                        chains = [r.wanted for r in reversed(self.waiting) if r.wanted]
+                freed = pc.evict_wanted(chains)
+                self.stats["prefix_evictions_wanted"] += len(freed)
+            if not freed:
                 break
             self.alloc.release(freed)
             self.stats["prefix_evictions"] += len(freed)
+        if pc is not None:
+            self.stats["prefix_evictions_spared"] = pc.spared
         return self.alloc.alloc(n)
+
+    def _want(self, req: Request):
+        """Mark what ``req`` matches in the prefix cache as wanted, for as
+        long as it waits (scheduler thread: the cache is this thread's)."""
+        full = req.full_prompt
+        req.wanted = self.prefix_cache.want(
+            full, self.pcfg.block_size, (len(full) - 1) // self.pcfg.block_size)
 
     def _finish(self, i: int):
         req = self.slots[i]
@@ -794,6 +882,8 @@ class LLMEngine:
         i = max(victims, key=lambda j: self.slots[j].rid)
         req = self.slots[i]
         self._free_slot(i)
+        if self.prefix_cache is not None:
+            self._want(req)  # the blocks it just released, while it waits
         with self._lock:
             self.waiting.appendleft(req)
         self.stats["preemptions"] += 1
@@ -827,6 +917,14 @@ class LLMEngine:
         table and only the novel suffix is prefilled."""
         p = self.pcfg
         bs = p.block_size
+        if self.prefix_cache is not None:
+            # Once per stay in the queue: arrivals stand at its tail,
+            # behind everything this thread has already looked up.
+            with self._lock:
+                new = list(itertools.takewhile(
+                    lambda r: r.wanted is None, reversed(self.waiting)))
+            for req in new:
+                self._want(req)
         while True:
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
@@ -835,6 +933,8 @@ class LLMEngine:
                 if not self.waiting:
                     return
                 req = self.waiting.popleft()
+            if self.prefix_cache is not None and req.wanted is None:
+                self._want(req)  # it arrived after the look at the queue's tail
             full = req.full_prompt
             plen = len(full)
             real_blocks = -(-plen // bs)  # ceil
@@ -850,9 +950,11 @@ class LLMEngine:
                 for b in hits:
                     self.prefix_cache.release(b)
                 with self._lock:
-                    self.waiting.appendleft(req)
+                    self.waiting.appendleft(req)  # still wanted: it still waits
                 return
             if self.prefix_cache is not None:
+                self.prefix_cache.unwant(req.wanted)  # the hits are pinned now
+                req.wanted = None
                 self.stats["prefix_lookup_tokens"] += plen
                 self.stats["prefix_hit_tokens"] += len(hits) * bs
             i = free_slots[0]
@@ -1265,11 +1367,20 @@ class LLMEngine:
                 ("spec_windows", m.engine_overlap_windows),
                 ("prefix_hit_tokens", m.engine_prefix_hit_tokens),
                 ("prefix_lookup_tokens", m.engine_prefix_lookup_tokens),
-                ("prefix_evictions", m.engine_prefix_evictions),
             ):
                 delta = s[key] - prev.get(key, 0)
                 if delta:
                     counter.inc(delta, t)
+            # Evicted blocks by how the victim was chosen: "lru" is what is
+            # neither of the two counted apart (three values, a closed set).
+            evicted, wanted, spared = (
+                s[key] - prev.get(key, 0) for key in (
+                    "prefix_evictions", "prefix_evictions_wanted", "prefix_evictions_spared"))
+            for choice, delta in (("wanted", wanted), ("spared", spared),
+                                  ("lru", evicted - wanted - spared)):
+                if delta:
+                    m.engine_prefix_evictions.inc(  # ray-tpu: lint-ignore[RTL004]
+                        delta, {**t, "choice": choice})
             for why in _SPEC_BLOCKED:
                 key = "spec_blocked_" + why
                 delta = s[key] - prev.get(key, 0)
@@ -1326,6 +1437,11 @@ class LLMEngine:
                 "hit_rate": self.stats["prefix_hit_tokens"]
                 / max(1, self.stats["prefix_lookup_tokens"]),
                 "evictions": self.stats["prefix_evictions"],
+                # Of those: blocks taken from a waiting request's chain (every
+                # evictable block was wanted), and blocks evicted in place of
+                # a colder chain that a waiting request matched.
+                "evictions_wanted": self.stats["prefix_evictions_wanted"],
+                "evictions_spared": self.stats["prefix_evictions_spared"],
             },
             overlap={
                 "enabled": self.overlap,

@@ -175,8 +175,12 @@ class _ServeMetrics:
         )
         self.engine_prefix_evictions = Counter(
             "serve_engine_prefix_evictions_total",
-            "Prefix-cache blocks evicted (LRU, refcount-0 only)",
-            dr,
+            "Prefix-cache blocks evicted (refcount-0 only), by how the victim "
+            "was chosen: lru (the coldest, no waiting request matched it), "
+            "spared (in place of a colder chain a waiting request matched), "
+            "wanted (every evictable block was matched: a leaf from the "
+            "queue's tail)",
+            dr + ("choice",),
         )
         self.engine_cached_blocks = Gauge(
             "serve_engine_prefix_cached_blocks",

@@ -6,6 +6,10 @@ IDENTICAL to the plain engine (same math, different scheduling /
 memory reuse), plus allocator/refcount invariants that guard against
 cross-request block aliasing.
 """
+import collections
+import random
+import time
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -56,7 +60,7 @@ def _cache_invariants(eng):
     assert TRASH_BLOCK not in free and TRASH_BLOCK not in cached
     # Every cached-but-referenced block must be mapped by some slot, and
     # every refcount must equal the number of slots mapping it.
-    for bid, (_key, _parent, refs) in pc.meta.items():
+    for bid, (_key, _parent, refs, _wanted) in pc.meta.items():
         mapped = sum(bl.count(bid) for bl in eng.slot_blocks)
         assert refs == mapped, f"block {bid}: refs {refs} != mapped {mapped}"
         if refs == 0:
@@ -67,6 +71,12 @@ def _cache_invariants(eng):
     # slot-owned cached blocks are counted via in_use∩cached == refs>0 set
     owned_or_resident += len(in_use & cached)
     assert owned_or_resident == eng.pcfg.usable_blocks
+    # A block is wanted once for every WAITING request that matched it when
+    # it entered the queue, and by nobody else: no mark outlives its stay.
+    marks = collections.Counter(id(m) for r in eng.waiting for m in r.wanted or ())
+    for bid, m in pc.meta.items():
+        assert m[3] == marks[id(m)], f"block {bid}: wanted {m[3]} != {marks[id(m)]}"
+    assert all(s is None or s.wanted is None for s in eng.slots)
 
 
 def test_prefix_cache_temp0_outputs_identical(tiny_model):
@@ -106,8 +116,8 @@ def test_prefix_cache_refcounts_and_concurrent_sharing(tiny_model):
     pc = eng.prefix_cache
     assert pc.resident_blocks == 2  # the two full shared blocks
     assert pc.evictable_blocks == 2  # all refs dropped at finish
-    for bid, (_k, _p, refs) in pc.meta.items():
-        assert refs == 0
+    for bid, (_k, _p, refs, wanted) in pc.meta.items():
+        assert refs == 0 and wanted == 0
     _cache_invariants(eng)
 
 
@@ -295,6 +305,267 @@ def test_prefix_cache_unit_eviction_cascades():
     pc.register(_PrefixCache.ROOT, (9, 9), 10)
     assert pc.match([1, 2, 3, 4], 2, 2) == []
     assert pc.match([9, 9, 3, 4], 2, 2) == [10]
+
+
+def _chain(pc, first_bid, tokens, bs=2):
+    """Register ``tokens`` as a chain of blocks first_bid, first_bid+1, ..
+    and release it: evictable, root coldest."""
+    parent, bids = _PrefixCache.ROOT, []
+    for j in range(len(tokens) // bs):
+        parent = pc.register(parent, tuple(tokens[j * bs:(j + 1) * bs]), first_bid + j)
+        bids.append(parent)
+    for b in bids:
+        pc.release(b)
+    return bids
+
+
+def _parents_evict_lru(pc):
+    """``evict_lru`` as it stood before eviction knew the queue (PR 30),
+    word for word but for the fourth field of ``meta``: the oracle for
+    'with nothing wanted the victims are the parent's'."""
+    while pc.lru:
+        bid, _ = pc.lru.popitem(last=False)
+        if pc.meta.get(bid, [None, None, -1])[2] != 0:
+            continue
+        freed = []
+        stack = [bid]
+        while stack:
+            b = stack.pop()
+            m = pc.meta.pop(b, None)
+            if m is None:
+                continue
+            key, parent, refs = m[:3]
+            pc.table.pop(key, None)
+            pc.children.get(parent, set()).discard(b)
+            stack.extend(pc.children.pop(b, ()))
+            pc.lru.pop(b, None)
+            if refs == 0:
+                freed.append(b)
+        return freed
+    return []
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_prefix_cache_unit_nothing_wanted_is_plain_lru(seed):
+    """Unit: with an empty queue (or traffic that shares nothing) no block
+    is wanted, and the freed ids come in the parent's order, cascade for
+    cascade, over random trees of chains, pins and releases."""
+    def build():
+        rng = random.Random(seed)
+        pc = _PrefixCache()
+        bids = []
+        for bid in range(1, 41):  # a random forest: parent = ROOT or an older block
+            parent = rng.choice([_PrefixCache.ROOT] + bids[-6:])
+            bids.append(pc.register(parent, (rng.randrange(10**6),), bid))
+        rng.shuffle(bids)
+        for b in bids[:32]:  # the rest stay pinned, some under released parents
+            pc.release(b)
+        for b in rng.sample(bids[:32], 8):  # re-warm a few, as a hit and release does
+            pc.incref(b)
+            pc.release(b)
+        return pc
+
+    ours, theirs = build(), build()
+    assert list(ours.lru) == list(theirs.lru) and ours.evictable_blocks == 32
+    # A request that matched nothing wants nothing.
+    assert ours.want([5, 5], 1, 2) == []
+    sequence = []
+    while ours.evictable_blocks:
+        freed = ours.evict_lru()
+        assert freed == _parents_evict_lru(theirs)
+        sequence.append(freed)
+    assert theirs.evictable_blocks == 0 and ours.meta.keys() == theirs.meta.keys()
+    assert any(len(f) > 1 for f in sequence)  # some cascades among them
+    assert ours.spared == 0
+
+
+def test_prefix_cache_unit_eviction_spares_the_wanted_chain():
+    """Unit (a): the coldest chain is one a waiting request matches, a
+    warmer one nobody asks for: the warmer one goes, whole, and the wanted
+    one still hits."""
+    pc = _PrefixCache()
+    cold = _chain(pc, 10, [1, 2, 3, 4, 5, 6])
+    warm = _chain(pc, 20, [7, 8, 9, 10])
+    assert list(pc.lru) == cold + warm
+    mine = pc.want([1, 2, 3, 4, 5, 6, 99], 2, 3)  # a follow-up in the queue
+    assert [m[3] for m in mine] == [1, 1, 1]
+    assert pc.evict_lru() == warm
+    assert pc.spared == len(warm)
+    assert pc.match([1, 2, 3, 4, 5, 6, 99], 2, 3) == cold
+    # Nothing unwanted is left: plain eviction declines, the caller falls back.
+    assert pc.evict_lru() == [] and pc.evictable_blocks == 3
+    # Admitted (or gone): the marks go, and the chain is plain LRU's again.
+    pc.unwant(mine)
+    assert pc.evict_lru() == cold and pc.resident_blocks == 0
+
+
+def test_prefix_cache_unit_fallback_takes_a_leaf_from_the_queues_tail():
+    """Unit (b): every evictable block is wanted. The victim is ONE
+    childless block, the leaf end of the chain of the request furthest
+    back; what is left of that chain still hits; the head's chain is
+    whole. The chains come last-in-queue first."""
+    pc = _PrefixCache()
+    head = _chain(pc, 10, [1, 2, 3, 4, 5, 6])  # coldest: released first
+    back = _chain(pc, 20, [7, 8, 9, 10, 11, 12])
+    w_head = pc.want([1, 2, 3, 4, 5, 6, 0], 2, 3)
+    w_back = pc.want([7, 8, 9, 10, 11, 12, 0], 2, 3)
+    assert pc.evict_lru() == []
+    assert pc.evict_wanted([w_back, w_head]) == [back[-1]]
+    assert pc.match([7, 8, 9, 10, 11, 12, 0], 2, 3) == back[:2]  # a partial chain survives
+    assert pc.evict_wanted([w_back, w_head]) == [back[1]]
+    assert pc.evict_wanted([w_back, w_head]) == [back[0]]
+    assert pc.match([1, 2, 3, 4, 5, 6, 0], 2, 3) == head  # untouched until now
+    assert pc.evict_wanted([w_back, w_head]) == [head[-1]]
+    # The marks of evicted blocks died with them: a block that reuses an id
+    # is nobody's, and unwant on the old chains leaves it alone.
+    again = pc.register(_PrefixCache.ROOT, (50, 51), back[0])
+    pc.unwant(w_back)
+    pc.unwant(w_head)
+    assert pc.meta[again][3] == 0
+    assert all(m[3] == 0 for m in pc.meta.values())
+
+
+def test_prefix_cache_unit_fallback_skips_a_chain_with_nothing_to_give():
+    """Unit: the tail request's last resident block is pinned by a running
+    twin (nothing of it is evictable), the next one's is a leaf: that one
+    goes. With only a pinned descendant below every evictable block, the
+    coldest goes whole and the pinned child is unregistered, not freed."""
+    pc = _PrefixCache()
+    twin = _chain(pc, 10, [1, 2, 3, 4])
+    other = _chain(pc, 20, [5, 6, 7, 8])
+    for b in twin:
+        pc.incref(b)  # a running request maps the same chain
+    w_tail = pc.want([1, 2, 3, 4, 0], 2, 2)
+    w_next = pc.want([5, 6, 7, 8, 0], 2, 2)
+    assert pc.evict_wanted([w_tail, w_next]) == [other[-1]]
+    assert pc.evict_wanted([w_tail, w_next]) == [other[0]]
+    assert pc.evict_wanted([w_tail, w_next]) == [] and pc.evictable_blocks == 0
+    # A pinned child under an evictable wanted parent.
+    pc.release(twin[0])
+    assert pc.evictable_blocks == 1 and pc.evict_lru() == []
+    assert pc.evict_wanted([w_tail]) == [twin[0]]
+    assert pc.resident_blocks == 0  # the child was unregistered with it
+    assert pc.release(twin[1]) is False  # its slot frees it to the allocator
+
+
+def _pump(eng):
+    while eng.active_count() or eng.waiting:
+        eng.step()
+        _cache_invariants(eng)
+
+
+def test_eviction_spares_the_queued_follow_ups_history(tiny_model):
+    """Engine (d): a conversation's first turn finishes, then another
+    request's; the follow-up turn waits behind a third. The third's
+    allocation must evict, and the coldest chain in the cache is the
+    follow-up's history (released first): plain LRU took it (0 hit tokens
+    for the follow-up at the parent), eviction that knows the queue takes
+    the chain nobody waits for. The follow-up hits on all of its history
+    that was ever cached, and serves what a cache-off engine serves."""
+    cfg, params = tiny_model
+    kw = dict(num_blocks=10, max_batch=1, max_blocks_per_seq=6)
+    eng = _engine(cfg, params, enable_prefix_cache=True, **kw)
+    history = [100 + i for i in range(24)]  # 3 full blocks
+    answer = eng.generate_batch([history], 6)[0]
+    eng.generate_batch([[150 + i for i in range(24)]], 6)  # ends: a warmer, dead chain
+    assert eng.prefix_cache.evictable_blocks == 6 and eng.alloc.available == 3
+    follow_up = history + answer + [7, 8]
+    before = dict(eng.stats)
+    ahead = eng.add_request([200 + i for i in range(24)], 6)  # 3 blocks, a 4th to decode
+    asked = eng.add_request(follow_up, 6)
+    eng.step()
+    assert eng.slots[0] is ahead and list(eng.waiting) == [asked]
+    assert [m[3] for m in asked.wanted] == [1, 1, 1]  # its history, marked while it waits
+    _pump(eng)
+    moved = {k: eng.stats[k] - before[k] for k in before}
+    assert moved["prefix_evictions"] == 3 and moved["prefix_evictions_spared"] == 3
+    assert moved["prefix_evictions_wanted"] == 0
+    assert moved["prefix_hit_tokens"] == len(history)
+    assert list(asked.tokens(timeout=60)) == _engine(cfg, params, **kw).generate_batch(
+        [follow_up], 6)[0]
+    snap = eng.report_state()["prefix_cache"]
+    assert (snap["evictions"], snap["evictions_spared"], snap["evictions_wanted"]) == (
+        eng.stats["prefix_evictions"], 3, 0)
+
+
+def test_preempted_request_is_wanted_while_it_waits(tiny_model):
+    """Engine (e): the pool runs out, the younger request is preempted and
+    requeued: the blocks it released are wanted from that moment. The
+    survivor then needs one block more than is free and every evictable
+    block is the waiting request's: the fallback takes ONE, the leaf, so
+    the resume hits on the two blocks left (the parent evicted the root,
+    the chain with it, and re-prefilled everything)."""
+    cfg, params = tiny_model
+    kw = dict(num_blocks=10, max_batch=2, max_blocks_per_seq=8)
+    prompts = [[100 + i for i in range(24)], [150 + i for i in range(24)]]
+    expect = _engine(cfg, params).generate_batch(prompts, 30)
+    eng = _engine(cfg, params, enable_prefix_cache=True, **kw)
+    reqs = [eng.add_request(p, 30) for p in prompts]
+    marks = set()
+    while eng.active_count() or eng.waiting:
+        eng.step()
+        _cache_invariants(eng)
+        if eng.waiting:
+            assert list(eng.waiting) == [reqs[1]]  # the younger one, back at the front
+            marks.add(tuple(m[3] for m in reqs[1].wanted))
+    assert eng.stats["preemptions"] == 1
+    # Wanted while it waited; the last mark's block was then evicted under it.
+    assert marks == {(1, 1, 1)}
+    assert eng.stats["prefix_evictions_wanted"] == 1
+    assert eng.stats["prefix_hit_tokens"] == 16  # resumed on the partial chain
+    assert [list(r.tokens(timeout=60)) for r in reqs] == expect
+    assert all(m[3] == 0 for m in eng.prefix_cache.meta.values())
+
+
+def test_wanted_marks_do_not_outlive_the_queue(tiny_model):
+    """Engine (f): marks follow a request into the queue and leave with it
+    — through admission, a put-back by ``_admit`` (no block for it yet),
+    ``stop`` with requests still waiting, and a restart. When the queue has
+    drained no block is wanted."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, enable_prefix_cache=True, num_blocks=13, max_batch=2,
+                  max_blocks_per_seq=6)
+    eng.generate_batch([SHARED], 2)  # two shared blocks, cached
+    reqs = [eng.add_request(SHARED + [60 + i, 61 + i], 20) for i in range(6)]
+    eng.step()
+    assert len(eng.waiting) == 4
+    shared = eng.prefix_cache.match(SHARED, 8, 2)
+    assert [eng.prefix_cache.meta[b][3] for b in shared] == [4, 4]
+    _cache_invariants(eng)
+    eng.start()
+    time.sleep(0.05)
+    eng.stop()  # mid-run: whoever still waits still wants
+    assert eng._thread is None
+    _cache_invariants(eng)
+    eng.start()
+    for r in reqs:
+        assert len(list(r.tokens(timeout=120))) == 20
+    eng.stop()
+    assert not eng.waiting and eng.active_count() == 0
+    _cache_invariants(eng)
+    assert all(m[3] == 0 for m in eng.prefix_cache.meta.values())
+    assert all(r.wanted is None for r in reqs)
+
+
+def test_eviction_choices_reach_the_registry_counter(tiny_model):
+    """``serve_engine_prefix_evictions_total`` carries how each evicted
+    block was chosen; the three choices add up to ``prefix_evictions``."""
+    from ray_tpu.serve.metrics import serve_metrics
+
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, enable_prefix_cache=True, num_blocks=10, max_batch=2,
+                  max_blocks_per_seq=8)
+    eng.metrics_tags = {"deployment": "evictions", "replica": "r0"}
+    eng.generate_batch([[100 + i for i in range(24)], [150 + i for i in range(24)]], 30)
+    eng.generate_batch([[200 + i for i in range(24)]], 6)
+    eng._maybe_flush_metrics(force=True)
+    counter = serve_metrics().engine_prefix_evictions
+    mine = {dict(tags)["choice"]: value for _n, _t, _d, tags, value in counter._drain()
+            if dict(tags)["deployment"] == "evictions"}
+    s = eng.stats
+    assert mine["wanted"] == s["prefix_evictions_wanted"] == 1
+    assert sum(mine.values()) == s["prefix_evictions"]
+    assert set(mine) <= {"lru", "spared", "wanted"} and mine["lru"] > 0
 
 
 @pytest.mark.slow
