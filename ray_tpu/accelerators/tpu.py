@@ -13,16 +13,26 @@ Reference: python/ray/_private/accelerators/tpu.py:71 —
 """
 from __future__ import annotations
 
+import errno
 import glob
+import logging
 import os
 import sys
-from typing import Dict, List, Optional
+import time
+from typing import Dict, List, Optional, Sequence
+
+logger = logging.getLogger("ray_tpu.tpu")
 
 TPU_VALID_CHIP_OPTIONS = (1, 2, 4, 8)
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 TPU_ACCELERATOR_TYPE_ENV = "TPU_ACCELERATOR_TYPE"  # e.g. "v5p-64"
 TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance/attributes/"
+# wait_for_chips: how often a held chip is tried again, and for how long. The
+# longest a dead holder was seen to keep a chip is 16 s (a four-chip trainer
+# giving back 32 GB of pinned mappings, PRs 36 and 37).
+CHIP_POLL_S = 0.05
+CHIP_WAIT_BOUND_S = 120.0
 
 
 def jax_backend_initialized() -> bool:
@@ -34,6 +44,33 @@ def jax_backend_initialized() -> bool:
     from jax._src import xla_bridge
 
     return xla_bridge.backends_are_initialized()
+
+
+def _open_and_close(path: str) -> None:
+    os.close(os.open(path, os.O_RDWR))
+
+
+def _holder_of(path: str) -> str:
+    """Who keeps ``path`` from being opened: the pid that has it among its
+    open files; else a zombie that still has threads, which is what a dead
+    TPU worker looks like while the kernel takes its mappings down (its table
+    of open files is already empty by then: PR 37, on the chip)."""
+    from ray_tpu.core.cluster_utils import proc_stat
+
+    dying = []
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            for fd in os.listdir(f"/proc/{pid}/fd"):
+                if os.readlink(f"/proc/{pid}/fd/{fd}") == path:
+                    return f"pid {pid}"
+        except OSError:
+            pass  # gone while we looked, or not ours to read
+        st = proc_stat(int(pid))
+        if st and st.dying:
+            dying.append(pid)
+    if dying:
+        return f"pid {', '.join(dying)} (exited, its threads still in the kernel)"
+    return "no process this one can see"
 
 
 class TPUAcceleratorManager:
@@ -112,6 +149,57 @@ class TPUAcceleratorManager:
             "TPU_CHIPS_PER_PROCESS_BOUNDS": "2,2,1" if n == 4 else f"1,{n},1",
             "TPU_PROCESS_BOUNDS": "1,1,1",
         }
+
+    @staticmethod
+    def chip_devices(chip_ids: Optional[List[int]]) -> List[str]:
+        """The device nodes that opening ``chip_ids`` will open (``None``: every
+        chip of the host, as ``visible_chips_env`` spells all of them): the
+        host's VFIO groups, else nothing (``/dev/accel*``, CPU).
+
+        Established on a four-chip v5e host, libtpu 0.0.34 (PR 37), one process
+        per ``TPU_VISIBLE_CHIPS=k`` reading ``/proc/self/fd`` once the backend
+        was open: chip k opens ``/dev/vfio/k`` and no other group, k = 0..3,
+        whatever the groups' minor numbers are (3,1,0,2 on that machine,
+        3,2,0,1 on PR 36's). A chip whose group is not there by that name is
+        left out: never wait on a device that may be a neighbour's."""
+        if chip_ids is None:
+            return sorted(glob.glob("/dev/vfio/[0-9]*"))
+        return [p for p in (f"/dev/vfio/{i}" for i in chip_ids) if os.path.exists(p)]
+
+    @staticmethod
+    def wait_for_chips(paths: Sequence[str]) -> float:
+        """Return, in seconds waited, once every device of ``paths`` can be
+        opened. A VFIO group admits one opener, and a worker that died keeps
+        its groups until its last thread has left the kernel (``cluster_utils
+        .is_gone``), seconds after the controller gave its chips to the next
+        one. So open each read-write and close it again, which is what libtpu
+        does a moment later and leaves nothing behind, and while that fails
+        with EBUSY try again every ``CHIP_POLL_S``. Past ``CHIP_WAIT_BOUND_S``
+        raise, naming the device and who holds it."""
+        t0 = time.monotonic()
+        for path in paths:
+            t_path, holder = time.monotonic(), None
+            while True:
+                try:
+                    _open_and_close(path)  # may itself block while the holder lets go
+                    break
+                except OSError as e:
+                    if e.errno != errno.EBUSY:
+                        raise
+                if holder is None:
+                    holder = _holder_of(path)  # while it can still be seen
+                waited = time.monotonic() - t0
+                if waited > CHIP_WAIT_BOUND_S:
+                    raise TimeoutError(
+                        f"{path} is still held after {waited:.0f} s, by {_holder_of(path)}: "
+                        "this worker was granted the chip and cannot open it"
+                    )
+                time.sleep(CHIP_POLL_S)
+            took = time.monotonic() - t_path
+            if took > CHIP_POLL_S:
+                logger.warning("waited %.1f s for %s, held by %s", took, path,
+                               holder or "no one it met (the open itself took that long)")
+        return time.monotonic() - t0
 
     @staticmethod
     def get_current_process_visible_accelerator_ids() -> Optional[List[int]]:

@@ -262,12 +262,13 @@ def teardown_cluster(name_or_path) -> dict:
     # 2. head: cluster-wide shutdown RPC, then kill the controller.
     try:
         from ray_tpu.core.client import CoreWorker
+        from ray_tpu.core.cluster_utils import end_cluster
         from ray_tpu.utils import rpc as _rpc
 
         runner = _rpc.EventLoopThread("down-admin")
         admin = CoreWorker(state["address"], mode="driver", loop_runner=runner)
         try:
-            admin._call("shutdown_cluster", timeout=5)
+            end_cluster(admin)
         finally:
             admin.disconnect()
             runner.stop()

@@ -185,18 +185,17 @@ def _start_controller(head_resources: dict, cfg_overrides: dict, owned: bool):
 
 
 def shutdown():
+    """Disconnect; where this process started the cluster, end it too, and
+    return only when no process of it is left (``cluster_utils.end_cluster``:
+    no live thread, so no socket and no chip still held)."""
     global _global_worker, _controller_proc, _session_dir
     if _global_worker is None:
         return
     try:
         if _controller_proc is not None:
-            try:
-                # Deliberate teardown: the controller dies on receipt, so
-                # never ride the reconnect window on its way down.
-                _global_worker._reconnect_dead = True
-                _global_worker._call("shutdown_cluster", timeout=5)
-            except Exception:
-                pass
+            from ray_tpu.core.cluster_utils import end_cluster
+
+            end_cluster(_global_worker)
     finally:
         from ray_tpu.core import log_plane
 
@@ -205,10 +204,9 @@ def shutdown():
         _global_worker.loop_runner.stop()
         _global_worker = None
         if _controller_proc is not None:
-            try:
-                _controller_proc.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                _controller_proc.kill()
+            # gone already, or past end_cluster's bound: either way reap it
+            _controller_proc.kill()
+            _controller_proc.wait(timeout=10)
             _controller_proc = None
         atexit.unregister(shutdown)
 

@@ -24,6 +24,7 @@ import json
 import logging
 import os
 import signal
+import socket
 import sys
 import time
 from dataclasses import dataclass, field
@@ -4486,8 +4487,20 @@ class Controller:
         return "pong"
 
     async def rpc_shutdown_cluster(self, peer):
+        """Start the teardown and name what it will end: (pid, start time)
+        of this process and of every worker and node agent on this host,
+        for ``cluster_utils.end_cluster`` to wait on. Pids of another host
+        mean nothing in the caller's /proc and are left out."""
+        from ray_tpu.core.cluster_utils import process_start
+        from ray_tpu.core.node_agent import _children
+
+        here = {n.node_id for n in self.nodes.values()
+                if n.hostname in ("localhost", socket.gethostname())}
+        pids = {os.getpid(), *_children}  # head workers, registered yet or not
+        pids.update(n.agent_pid for n in self.nodes.values() if n.node_id in here)
+        pids.update(w.pid for w in self.workers.values() if w.node_id in here)
         self._shutdown.set()
-        return True
+        return [(pid, process_start(pid)) for pid in sorted(pids) if pid]
 
     # =================================================================
     def _lc_key(self, spec: TaskSpec) -> Tuple[str, str]:
@@ -4809,6 +4822,11 @@ class Controller:
             if n.peer is not None:
                 await _notify_quiet(n.peer, "exit", what="cluster teardown")
         await asyncio.sleep(0.1)
+        # a head worker that was still dialing in never heard "exit" (the
+        # node agents end theirs the same way)
+        from ray_tpu.core.node_agent import kill_children
+
+        kill_children()
         server.close()
         self.head_store.destroy()
 

@@ -84,7 +84,7 @@ _INGEST_KINDS = frozenset(
 # Extra attrs forwarded from shipped events into the ring (never metric
 # tags): the self-healing "action" events carry their audit fields here.
 _INGEST_ATTRS = ("name", "node", "worker", "actuator", "trigger", "target",
-                 "outcome", "dry_run", "remote")
+                 "outcome", "dry_run", "remote", "chip_wait_ms")
 
 _DWELL_BOUNDARIES_MS = (
     1, 5, 10, 25, 50, 100, 250, 500, 1000, 2500, 5000, 15000, 60000,

@@ -443,6 +443,36 @@ class TaskExecutor:
 
         return tuple(res(a) for a in args), {k: res(v) for k, v in kwargs.items()}
 
+    def _wait_for_granted_chips(self, granted: float):
+        """Before a TPU task's code: the ``granted`` chips of this process
+        (its runtime env, applied by now, says which) may still be held by
+        their last owner's dying process; wait until they can be opened."""
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager as tpu
+        from ray_tpu.accelerators.tpu import jax_backend_initialized
+
+        if jax_backend_initialized():
+            return  # they are this process's already
+        ids = tpu.get_current_process_visible_accelerator_ids()
+        paths = tpu.chip_devices(ids)
+        if not paths or (ids is None and len(paths) != granted):
+            # no VFIO host; or a share of its chips and none by name (a plain
+            # task: only actors are given ids), and a neighbour may hold the rest
+            return
+        # Opens no backend and takes seconds, and the task's code imports it
+        # anyway: spend them before the wait, not after it.
+        import jax  # noqa: F401
+
+        waited = tpu.wait_for_chips(paths)
+        self._events.append(
+            {
+                "ts": time.time(),
+                "kind": "worker",
+                "id": self.core.worker_id.hex(),
+                "state": "CHIPS_READY",
+                "chip_wait_ms": round(waited * 1000.0, 1),
+            }
+        )
+
     def _run(self, spec: TaskSpec, kind: str, reply=None, inline_deps=None):
         if spec.task_id in self.cancelled:
             from ray_tpu.exceptions import TaskCancelledError
@@ -510,6 +540,10 @@ class TaskExecutor:
                         f"execute:{spec.name}", {"task_id": spec.task_id.hex()}
                     )
                     trace_span_cm.__enter__()
+            if spec.resources.get("TPU"):
+                from ray_tpu.core.resources import from_fp
+
+                self._wait_for_granted_chips(from_fp(spec.resources.get("TPU")))
             if spec.runtime_env and spec.runtime_env.get("jax_profiler"):
                 # per-task jax.profiler capture (reference: the nsight
                 # runtime-env plugin wraps the worker with the profiler)

@@ -102,11 +102,9 @@ def cmd_stop(args):
         print(str(e), file=sys.stderr)
         return 1
     from ray_tpu.core.api import _require_worker
+    from ray_tpu.core.cluster_utils import end_cluster
 
-    try:
-        _require_worker()._call("shutdown_cluster", timeout=5)
-    except Exception:
-        pass
+    end_cluster(_require_worker())
     try:
         os.unlink(_addr_file())
     except FileNotFoundError:
