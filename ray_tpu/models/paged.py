@@ -34,6 +34,7 @@ host↔device.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -258,88 +259,106 @@ def prefill_and_sample(
     return tok, cache
 
 
+def chunk_tile(width: int, block_size: int) -> int:
+    """Tokens in one tile of a chunk call ``width`` wide: four blocks, or
+    the largest share of four that divides the width (a width under four
+    blocks is one tile; 1,568 = 49 x 32). A tile is the unit of attention:
+    its queries go against ONE slot's table, so a slot's suffix is padded
+    to tiles, and a wider tile pads more while a narrower one gathers a
+    table for fewer queries."""
+    return math.gcd(width, 4 * block_size)
+
+
 def _attend_chunk(q, ck, cv, qpos, cfg: TransformerConfig):
-    """q: [C, H, HD] chunk queries; ck/cv: [m, KV, HD] the slot's gathered
-    block view (prefix + this chunk, post-scatter); qpos: [C] absolute
-    positions — attend over cache positions <= qpos (causal, prefix
-    inclusive). Same f32 einsum/softmax math as
-    ``reference_paged_attention``."""
-    C, H, HD = q.shape
+    """q: [n, C, H, HD] chunk queries by tile; ck/cv: [n, m, KV, HD] each
+    tile's gathered block view (its slot's prefix + the chunk, post-
+    scatter); qpos: [n, C] absolute positions — attend over cache
+    positions <= qpos (causal, prefix inclusive). Same f32 einsum/softmax
+    math as ``reference_paged_attention``. → [n, C, H*HD]."""
+    n, C, H, HD = q.shape
     KV = cfg.n_kv_heads
     G = H // KV
-    qg = q.reshape(C, KV, G, HD)
+    qg = q.reshape(n, C, KV, G, HD)
     scores = jnp.einsum(
-        "ckgd,mkd->ckgm", qg.astype(jnp.float32), ck.astype(jnp.float32)
+        "tckgd,tmkd->tckgm", qg.astype(jnp.float32), ck.astype(jnp.float32)
     ) * (HD**-0.5)
-    m = ck.shape[0]
-    valid = jnp.arange(m)[None, :] <= qpos[:, None]  # [C, m]
-    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    m = ck.shape[1]
+    valid = jnp.arange(m)[None, None, :] <= qpos[:, :, None]  # [n, C, m]
+    scores = jnp.where(valid[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    og = jnp.einsum("ckgm,mkd->ckgd", probs, cv.astype(jnp.float32))
-    return og.reshape(C, H * HD).astype(q.dtype)
+    og = jnp.einsum("tckgm,tmkd->tckgd", probs, cv.astype(jnp.float32))
+    return og.reshape(n, C, H * HD).astype(q.dtype)
 
 
 def paged_prefill_chunk(
     params: Params,
     cfg: TransformerConfig,
-    tokens: jax.Array,  # [1, C] int32, C a multiple of block_size (padded)
+    tokens: jax.Array,  # [1, T] int32 — n tiles of T // n tokens (``chunk_tile``)
     cache: PagedCache,
-    table_row: jax.Array,  # [W] int32 — the slot's FULL block table
-    chunk_row: jax.Array,  # [C // block_size] int32 — blocks receiving this chunk
+    table_rows: jax.Array,  # [n, W] int32 — per tile, its slot's FULL block table
+    chunk_row: jax.Array,  # [T // block_size] int32 — blocks receiving the tokens
     block_size: int,
-    start: jax.Array,  # scalar int32 — absolute position of tokens[0, 0]
+    starts: jax.Array,  # [n] int32 — per tile, absolute position of its first token
+    last_idx: jax.Array,  # [n] int32 — per segment, where on the token axis it ends
 ) -> Tuple[jax.Array, PagedCache]:
-    """Prefill positions ``start .. start+C-1`` of ONE slot, attending to
-    the slot's already-resident KV blocks (prefix-cache hits or earlier
-    chunks) plus the chunk itself.
+    """Prefill several slots' suffixes in ONE call: the token axis holds
+    them one after another, each padded to whole tiles, and a tile covers
+    positions ``starts[t] ..`` of the slot whose table is
+    ``table_rows[t]``, attending to that slot's already-resident KV
+    blocks (prefix-cache hits or earlier chunks) plus the chunk itself.
 
     This is the suffix/chunked counterpart of ``paged_prefill``: instead
-    of full attention over the whole prompt it scatters the chunk's K/V
-    into ``chunk_row``'s blocks and attends through the gathered
-    ``table_row`` view under a causal position mask — so a prompt whose
-    prefix is already in the cache only pays compute for the novel suffix.
-    ``start`` is traced: one compilation per chunk width C serves every
-    chunk position. Returns (logits [C, V] fp32, cache')."""
-    b, C = tokens.shape
-    assert b == 1 and C % block_size == 0
-    W = table_row.shape[0]
-    KV, HD = cfg.n_kv_heads, cfg.head_dim
-    nb = C // block_size
-    positions = start + jnp.arange(C, dtype=jnp.int32)[None, :]  # [1, C]
+    of full attention over the whole prompt it scatters the tokens' K/V
+    into ``chunk_row``'s blocks and attends, tile by tile, through the
+    gathered table under a causal position mask — so a prompt whose
+    prefix is already in the cache only pays compute for the novel
+    suffix. The projections, the FFN and the scatter run once over the
+    whole axis, and only the rows ``last_idx`` names (one a segment, the
+    prompt's final token where the segment is its final chunk) reach the
+    head. Everything but the shapes is traced: one compilation per width
+    T serves every mix of segments, a lone suffix or one chunk of a long
+    prompt among them. A tile nobody uses points at the trash block.
+    Returns (logits [n, V] fp32, cache')."""
+    b, T = tokens.shape
+    n, W = table_rows.shape
+    C = T // n
+    assert b == 1 and T % block_size == 0 and T % n == 0
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qpos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [n, C]
+    positions = qpos.reshape(1, T)
 
     # Token j lands at (rows[j], offs[j]); padded tail rows point at the
     # trash block via chunk_row. Rows, not whole blocks: a scatter of ONE
     # block becomes an update-slice that copies the whole cache (v5e).
     rows = jnp.repeat(chunk_row, block_size)
-    offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), nb)
+    offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), T // block_size)
 
     def layer(x, ck, cv, lp, base):
         h = rms_norm(x, lp["attn_norm"])
         q, k, v = project_qkv(h, lp, cfg, positions)
         ck = ck.at[rows + base, offs].set(k[0])
         cv = cv.at[rows + base, offs].set(v[0])
-        ck_g = ck[table_row + base].reshape(W * block_size, KV, HD)
-        cv_g = cv[table_row + base].reshape(W * block_size, KV, HD)
-        o = _attend_chunk(q[0], ck_g, cv_g, positions[0], cfg)
-        x = x + (o @ lp["wo"].astype(o.dtype))[None]
+        ck_g = ck[table_rows + base].reshape(n, W * block_size, KV, HD)
+        cv_g = cv[table_rows + base].reshape(n, W * block_size, KV, HD)
+        o = _attend_chunk(q.reshape(n, C, H, HD), ck_g, cv_g, qpos, cfg)
+        x = x + (o.reshape(1, T, H * HD) @ lp["wo"].astype(o.dtype))
         return mlp_block(x, lp, cfg), ck, cv
 
     x = embed(params, tokens, cfg)
     x, cache = _scan_layers(layer, x, params["layers"], cache)
-    return unembed(params, x, cfg)[0], cache
+    return unembed(params, x[:, last_idx], cfg)[0], cache
 
 
 def prefill_chunk_and_sample(
-    params, cfg: TransformerConfig, tokens, cache, table_row, chunk_row,
-    block_size: int, start, last_idx, temp, key,
+    params, cfg: TransformerConfig, tokens, cache, table_rows, chunk_row,
+    block_size: int, starts, last_idx, temps, key,
 ):
-    """Chunk prefill + on-device sampling at ``last_idx`` (chunk-relative
-    position of the prompt's final token, clamped by the caller). The
-    sampled token is only meaningful on the prompt's FINAL chunk; earlier
-    chunks never fetch it, so the extra sample costs no host sync."""
+    """Chunk prefill + on-device sampling of one token a segment, at
+    ``last_idx`` and that segment's temperature. A segment's token is only
+    meaningful where it holds its prompt's FINAL position; earlier chunks,
+    and the entries past the last segment, are never read by the host, so
+    the extra samples cost no sync. → (tokens [n] int32, cache')."""
     logits, cache = paged_prefill_chunk(
-        params, cfg, tokens, cache, table_row, chunk_row, block_size, start
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx
     )
-    last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=0, keepdims=False)
-    tok = sample_tokens(last[None, :], temp[None], key)[0]
-    return tok, cache
+    return sample_tokens(logits, temps, key), cache

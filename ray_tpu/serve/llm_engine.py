@@ -18,12 +18,15 @@ Host/device split:
   vLLM default), per-request streaming queues.
 
 Iteration-level perf suite (all opt-in, see ``__init__``):
-- **Prefix-aware KV reuse** (``enable_prefix_cache``): full prompt
-  blocks are published to a refcounted exact-match index at prefill
-  time and kept resident after release (evicted on allocation
+- **Prefix-aware KV reuse** (``enable_prefix_cache``): a request's full
+  blocks are published to a refcounted exact-match index, the prompt's
+  as its prefill ends and those its decode steps filled as its slot is
+  given back, and kept resident after release (evicted on allocation
   pressure, the coldest that no waiting request matches first);
   requests sharing a prefix map resident blocks into their table and
-  prefill only the novel suffix.
+  prefill only the novel suffix, so a conversation's next turn prefills
+  what the user added. The suffixes one iteration admits share ONE call
+  of the chunk program (``_run_chunks``).
 - **Chunked prefill** (``prefill_chunk``): long prompts advance one
   fixed-size chunk per scheduler step, interleaved with decode windows,
   so an admission no longer head-of-line-blocks active streams.
@@ -58,6 +61,7 @@ from jax.experimental.layout import Format, Layout
 from ray_tpu.models.paged import (
     TRASH_BLOCK,
     PagedConfig,
+    chunk_tile,
     init_paged_cache,
     paged_decode_loop,
     prefill_and_sample,
@@ -77,6 +81,15 @@ _engine_ids = itertools.count()
 _PHASES = ("harvest_wait", "emit", "admit", "prefill_wait", "dispatch")
 # Why a window in flight was not overlapped: ``stats["spec_blocked_<reason>"]``.
 _SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
+# The counters a recorded step carries as what the iteration added to them.
+_STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks",
+                "prefill_segments", "prefix_hit_tokens")
+# The width under which a chunk call's time is the read of the weights and no
+# longer its tokens' arithmetic: two FLOPs and two bytes a parameter a token
+# put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e, where the
+# chunk program read 16.2 / 17.2 / 20.35 ms at 64 / 128 / 256 tokens and
+# 0.086 ms a token above (PERF.md section 5). ``_run_suffixes`` packs by it.
+_WEIGHTS_WIDTH = 256
 
 
 @dataclasses.dataclass
@@ -188,10 +201,12 @@ class _BlockAllocator:
 
 
 class _PrefixCache:
-    """Refcounted index over prefill-resident KV blocks (vLLM automatic
-    prefix caching, re-done for this engine's allocator).
+    """Refcounted index over resident KV blocks (vLLM automatic prefix
+    caching, re-done for this engine's allocator).
 
-    Each FULL prompt block is keyed by ``(parent_block_id, block_tokens)``
+    Each FULL block of a request's prompt and answer (``LLMEngine.
+    _free_slot`` says which positions are certain) is keyed by
+    ``(parent_block_id, block_tokens)``
     — an exact-match chain, so a hit can never alias a different prefix
     (no hash collisions; the parent link makes position implicit). Blocks
     referenced by live slots are pinned (refs > 0); released blocks stay
@@ -432,10 +447,11 @@ class LLMEngine:
         metric series; defaults to the ambient serve replica context
         (set by the Replica actor) or a standalone placeholder.
 
-        ``enable_prefix_cache``: keep refcounted prompt blocks resident
-        after release and map them into later requests sharing the same
-        prefix (system prompts, few-shot headers, preempt-resume), so
-        only the novel suffix is prefilled. Eviction of refcount-0 blocks
+        ``enable_prefix_cache``: keep a request's full blocks, prompt and
+        answer, resident after release and map them into later requests
+        sharing the same prefix (system prompts, few-shot headers, a
+        conversation's next turn, preempt-resume), so only the novel
+        suffix is prefilled. Eviction of refcount-0 blocks
         under allocation pressure replaces unconditional free: the coldest
         that no waiting request matches (``_PrefixCache`` has the order).
 
@@ -466,6 +482,14 @@ class LLMEngine:
             prefill_chunk = min(prefill_chunk, p.max_seq_len)
         self.prefill_chunk = int(prefill_chunk or 0)
         self.prefix_cache = _PrefixCache() if enable_prefix_cache else None
+        # The widths a prompt or a chunk call is padded to: block-multiple
+        # powers of two, then the table's whole length. O(log max_seq_len)
+        # compilations per program.
+        self._widths = [p.block_size]
+        while self._widths[-1] * 2 < p.max_seq_len:
+            self._widths.append(self._widths[-1] * 2)
+        if self._widths[-1] < p.max_seq_len:
+            self._widths.append(p.max_seq_len)
         _leave_persistent_compile_cache()
         self.cache = init_paged_cache(cfg, p)
         (self._decode, self._prefill, self._prefill_chunk_fn,
@@ -515,6 +539,7 @@ class LLMEngine:
                       "h2d_ships": 0, "h2d_skips": 0, "prefix_hit_tokens": 0,
                       "prefix_lookup_tokens": 0, "prefix_evictions": 0,
                       "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
+                      "prefix_published_blocks": 0, "prefill_segments": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED}}
         # Milliseconds by phase of the iteration in progress (tracing.phase).
@@ -583,11 +608,11 @@ class LLMEngine:
                 params, cfg, tokens, cache, block_row, bs, real_len, temp, key
             )
 
-        def _chunk(params, tokens, cache, table_row, chunk_row, start, last_idx,
-                   temp, key):
+        def _chunk(params, tokens, cache, table_rows, chunk_row, starts, last_idx,
+                   temps, key):
             return prefill_chunk_and_sample(
-                params, cfg, tokens, cache, table_row, chunk_row, bs, start,
-                last_idx, temp, key,
+                params, cfg, tokens, cache, table_rows, chunk_row, bs, starts,
+                last_idx, temps, key,
             )
 
         sds = jax.ShapeDtypeStruct
@@ -636,17 +661,10 @@ class LLMEngine:
         decode window. All warmup writes scatter into the trash block, so
         live cache blocks are untouched. Returns the number of program
         executions (== compilations on a cold process)."""
-        p = self.pcfg
-        bs = p.block_size
-        sizes = []
-        b = bs
-        while b < p.max_seq_len:
-            sizes.append(b)
-            b *= 2
-        sizes.append(p.max_seq_len)
+        bs = self.pcfg.block_size
         self.key, sub = jax.random.split(self.key)
         n = 0
-        for S in sizes:
+        for S in self._widths:
             _tok, self.cache = self._prefill(
                 self.params, jax.numpy.asarray(np.zeros((1, S), np.int32)),
                 self.cache,
@@ -657,17 +675,11 @@ class LLMEngine:
         if self.prefill_chunk:
             chunk_sizes = [self.prefill_chunk]
         elif self.prefix_cache is not None:
-            chunk_sizes = sizes  # cache hits leave bucketed suffixes
+            chunk_sizes = self._widths  # cache hits leave bucketed suffixes
         else:
             chunk_sizes = []
-        trow = np.full(p.max_blocks_per_seq, TRASH_BLOCK, np.int32)
         for C in chunk_sizes:
-            _tok, self.cache = self._prefill_chunk_fn(
-                self.params, jax.numpy.asarray(np.zeros((1, C), np.int32)),
-                self.cache, jax.numpy.asarray(trow),
-                jax.numpy.asarray(np.full(C // bs, TRASH_BLOCK, np.int32)),
-                np.int32(0), np.int32(0), np.float32(0.0), sub,
-            )
+            self._chunk_call(C, [])  # no segment: every tile on the trash block
             n += 1
         # Decode window: already compiled (AOT) — this is its first
         # execution, so a program that does not fit fails at build time.
@@ -781,18 +793,28 @@ class LLMEngine:
     # ------------------------------------------------------------------
 
     def _bucket(self, n: int) -> int:
-        """Smallest block-multiple power-of-two-ish bucket >= n, bounding
-        prefill compilations to O(log max_seq_len)."""
-        b = self.pcfg.block_size
-        while b < n:
-            b *= 2
-        return min(b, self.pcfg.max_seq_len)
+        """Smallest of ``_widths`` >= n (the widest, if none is)."""
+        return next((w for w in self._widths if w >= n), self._widths[-1])
 
     def _free_slot(self, i: int):
+        """Give slot ``i`` back (a finish or a preemption). With the prefix
+        cache on, what its decode steps filled is published first: the full
+        blocks below position ``len(full) - 1`` of the HOST's transcript
+        (prompt + emitted tokens). A step writes the position of the token
+        it is FED, so the last emitted token's was never written, and all
+        that a window writes beyond the transcript (the steps after a cap or
+        an eos, a speculated window behind a stopped slot, the unharvested
+        window of a slot preempted under ``overlap``) lies at or above it. A
+        slot still in ``_prefilling`` publishes nothing: its prompt's blocks
+        are not all written yet."""
         pc = self.prefix_cache
         if pc is None:
             self.alloc.release(self.slot_blocks[i])
         else:
+            if i not in self._prefilling:
+                full = self.slots[i].full_prompt
+                self.stats["prefix_published_blocks"] += self._register_prefix(
+                    full, self.slot_blocks[i], len(full) - 1)
             for b in self.slot_blocks[i]:
                 # Cache-managed blocks stay RESIDENT (refcount drop, LRU
                 # when unreferenced); private blocks go back to the pool.
@@ -925,13 +947,14 @@ class LLMEngine:
                     lambda r: r.wanted is None, reversed(self.waiting)))
             for req in new:
                 self._want(req)
+        suffixes: List[tuple] = []  # what the hits left to prefill, in queue order
         while True:
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
-                return
+                break
             with self._lock:
                 if not self.waiting:
-                    return
+                    break
                 req = self.waiting.popleft()
             if self.prefix_cache is not None and req.wanted is None:
                 self._want(req)  # it arrived after the look at the queue's tail
@@ -951,7 +974,7 @@ class LLMEngine:
                     self.prefix_cache.release(b)
                 with self._lock:
                     self.waiting.appendleft(req)  # still wanted: it still waits
-                return
+                break
             if self.prefix_cache is not None:
                 self.prefix_cache.unwant(req.wanted)  # the hits are pinned now
                 req.wanted = None
@@ -962,32 +985,66 @@ class LLMEngine:
             self._slot_gen[i] += 1
             self.slot_blocks[i] = hits + got
             self.stats["admitted"] += 1
-            self._start_prefill(i, req, len(hits) * bs)
+            self._start_prefill(i, req, len(hits) * bs, suffixes)
+        self._run_suffixes(suffixes)
 
-    def _start_prefill(self, i: int, req: Request, start: int):
+    def _start_prefill(self, i: int, req: Request, start: int, suffixes: List[tuple]):
         """Begin prefilling slot ``i`` from absolute position ``start``
-        (block-aligned; positions below it are cache hits). Short work
-        runs to completion now; prompts longer than ``prefill_chunk``
-        enter the chunked queue and advance one chunk per step."""
+        (block-aligned; positions below it are cache hits). A prompt with
+        no hit runs its full-attention prefill now; a suffix after a hit
+        joins ``suffixes``, which ``_admit`` sends as packed chunk calls
+        when its loop ends; prompts longer than ``prefill_chunk`` enter the
+        chunked queue and advance one chunk per step."""
         full = req.full_prompt
         plen = len(full)
         if req.prefill_ts is None:  # first admission (not a resume)
             req.prefill_ts = time.time()
         self.stats["prefills"] += 1
         self.stats["prompt_tokens"] += plen - start
-        suffix = plen - start
-        if self.prefill_chunk and suffix > self.prefill_chunk:
+        if self.prefill_chunk and plen - start > self.prefill_chunk:
             self._prefilling[i] = _ChunkState(req, full, start, plen)
-            return
-        if start == 0:
-            tok = self._run_full_prefill(i, req, full)
+        elif start == 0:
+            self._finish_prefill(i, req, self._run_full_prefill(i, req, full), ())
         else:
-            # Suffix after a cache hit: one chunk-program call. Reuse the
-            # configured chunk width when set (one compiled shape serves
-            # every suffix); otherwise bucket the suffix length.
-            width = self.prefill_chunk or self._bucket(suffix)
-            tok = self._run_chunk(i, req, full, start, width)
-        self._finish_prefill(i, req, tok)
+            suffixes.append((i, req, full, start, plen))
+
+    def _chunk_width(self, lens: Sequence[int]) -> Optional[int]:
+        """The narrowest chunk call that holds segments of ``lens`` tokens,
+        each padded to that width's tiles: the configured chunk width when
+        set (one compiled shape serves every call), else one of
+        ``_widths``. None if they do not fit one call."""
+        bs = self.pcfg.block_size
+        for width in ([self.prefill_chunk] if self.prefill_chunk else self._widths):
+            tile = chunk_tile(width, bs)
+            if sum(-(-n // tile) * tile for n in lens) <= width:
+                return width
+        return None
+
+    def _run_suffixes(self, suffixes: List[tuple]):
+        """Send the suffixes one admission loop left as chunk calls, packed
+        in queue order. The next suffix joins the call being filled unless
+        the joined call would be wider than the two apart: together they do
+        not fit the widest, or the next width up is mostly padding (a whole
+        conversation re-prefilled 1,024 wide is better alone). A call counts
+        as no narrower than ``_WEIGHTS_WIDTH``, so narrow ones always join."""
+        def cost(width):
+            return max(width, _WEIGHTS_WIDTH)
+
+        group: List[tuple] = []
+        lens: List[int] = []
+        width = 0  # of the call that holds ``group``
+        for seg in suffixes:
+            *_, start, end = seg
+            alone = self._chunk_width([end - start])
+            joined = self._chunk_width(lens + [end - start])
+            if group and (joined is None or cost(joined) > cost(width) + cost(alone)):
+                self._run_chunks(width, group)
+                group, lens, joined = [], [], alone
+            group.append(seg)
+            lens.append(end - start)
+            width = joined
+        if group:
+            self._run_chunks(width, group)
 
     def _advance_chunked_prefills(self):
         """ONE chunk of forward progress per step, round-robin across
@@ -1001,11 +1058,8 @@ class LLMEngine:
         i = next((j for j in order if j > self._chunk_rr), order[0])
         self._chunk_rr = i
         st = self._prefilling[i]
-        tok = self._run_chunk(i, st.req, st.tokens, st.pos, self.prefill_chunk)
-        st.pos += self.prefill_chunk
-        if st.pos >= st.plen:
-            del self._prefilling[i]
-            self._finish_prefill(i, st.req, tok)
+        start, st.pos = st.pos, min(st.pos + self.prefill_chunk, st.plen)
+        self._run_chunks(self.prefill_chunk, [(i, st.req, st.tokens, start, st.pos)])
 
     def _run_full_prefill(self, i: int, req: Request, full: List[int]):
         """Whole-prompt full-attention prefill (bucketed); returns the
@@ -1028,45 +1082,64 @@ class LLMEngine:
         )
         return tok
 
-    def _run_chunk(self, i: int, req: Request, full: List[int], start: int,
-                   width: int):
-        """One chunk-program invocation covering positions
-        ``start .. start+width-1`` of slot ``i`` (attends to the slot's
-        resident prefix); returns the sampled token (meaningful only when
-        the chunk covers the prompt's final position)."""
+    def _run_chunks(self, width: int, segs: List[tuple]):
+        """ONE chunk-program call ``width`` wide over ``segs``, each ``(slot,
+        request, tokens, start, end)``: positions ``start .. end-1`` of that
+        slot. A segment that ends its prompt leaves the chunked queue and
+        finishes its prefill with the token sampled for it."""
+        toks = self._chunk_call(width, segs)
+        self.stats["prefill_chunks"] += 1
+        self.stats["prefill_segments"] += len(segs)
+        for k, (i, req, full, _start, end) in enumerate(segs):
+            if end == len(full):
+                self._prefilling.pop(i, None)
+                self._finish_prefill(i, req, toks, k)
+
+    def _chunk_call(self, width: int, segs: List[tuple]):
+        """Build the chunk program's inputs for ``segs`` on a token axis
+        ``width`` wide and call it: the one place that does. The axis is
+        ``width // tile`` tiles; a segment takes whole tiles, one after
+        another (each attends through its slot's table, from its own
+        absolute position, and the slot's blocks under it receive its
+        tokens; a padded tail past the slot's blocks lands on the trash
+        block, as every tile no segment uses does). Returns the sampled
+        tokens ``[tiles]`` on the device, segment ``k``'s at ``k``."""
         p = self.pcfg
         bs = p.block_size
-        plen = len(full)
-        end = min(start + width, plen)
+        tile = chunk_tile(width, bs)
+        n = width // tile
         toks = np.zeros((1, width), np.int32)
-        toks[0, : end - start] = full[start:end]
-        blocks = self.slot_blocks[i]
-        trow = np.full(p.max_blocks_per_seq, TRASH_BLOCK, np.int32)
-        trow[: len(blocks)] = blocks
+        trows = np.full((n, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
         crow = np.full(width // bs, TRASH_BLOCK, np.int32)
-        b0 = start // bs
-        for j in range(width // bs):
-            if b0 + j < len(blocks):
-                crow[j] = blocks[b0 + j]
-        last_idx = min(max(plen - 1 - start, 0), width - 1)
+        starts = np.zeros(n, np.int32)
+        last_idx = np.zeros(n, np.int32)
+        temps = np.zeros(n, np.float32)
+        at = 0  # the next free tile's first position on the axis
+        for k, (i, req, full, start, end) in enumerate(segs):
+            blocks = self.slot_blocks[i]
+            tiles = -(-(end - start) // tile)
+            t0 = at // tile
+            toks[0, at:at + end - start] = full[start:end]
+            trows[t0:t0 + tiles, :len(blocks)] = blocks
+            starts[t0:t0 + tiles] = start + tile * np.arange(tiles)
+            under = blocks[start // bs:start // bs + tiles * tile // bs]
+            crow[at // bs:at // bs + len(under)] = under
+            last_idx[k] = at + end - start - 1
+            temps[k] = req.temperature
+            at += tiles * tile
         self.key, sub = jax.random.split(self.key)
-        # toks/trow/crow (like _run_full_prefill's toks/row) are built per
-        # call and never written again, so a view is safe here: only the
-        # slot mirrors, which the scheduler mutates in place, need
-        # _hand_over's copy.
-        tok, self.cache = self._prefill_chunk_fn(
-            self.params, jax.numpy.asarray(toks), self.cache,
-            jax.numpy.asarray(trow), jax.numpy.asarray(crow),
-            np.int32(start), np.int32(last_idx),
-            np.float32(req.temperature), sub,
-        )
-        self.stats["prefill_chunks"] += 1
-        return tok
+        # Built per call and never written again, so the program may read
+        # them where they lie: only the slot mirrors, which the scheduler
+        # mutates in place, need _hand_over's copy.
+        out, self.cache = self._prefill_chunk_fn(
+            self.params, toks, self.cache, trows, crow, starts, last_idx, temps, sub)
+        return out
 
-    def _finish_prefill(self, i: int, req: Request, tok):
+    def _finish_prefill(self, i: int, req: Request, toks, k):
         """Prompt fully KV-resident: publish the slot to the decode set
         (tables/lens/temps become decode-visible) and queue the first
-        sampled token for the batched flush."""
+        sampled token, ``toks[k]`` of a program's device output (``k`` is
+        ``()`` for a scalar), for the batched flush."""
         full = req.full_prompt
         blocks = self.slot_blocks[i]
         self.tables[i] = TRASH_BLOCK
@@ -1075,41 +1148,50 @@ class LLMEngine:
         self.temps[i] = req.temperature
         self._dirty.update(("tables", "lens", "temps"))
         if self.prefix_cache is not None:
-            self._register_prefix(full, blocks)
+            self._register_prefix(full, blocks, len(full))
         # Defer the device→host read: prefill dispatches pipeline without
         # syncing; _flush_prefills fetches every pending first token in
         # one transfer after the admission loop.
-        self._pending_first.append((i, req, tok))
+        self._pending_first.append((i, req, toks, k))
 
-    def _register_prefix(self, full: List[int], blocks: List[int]):
-        """Publish the slot's freshly-prefilled FULL blocks into the
-        prefix index (the trailing partial block receives decode writes
-        and is never shared). Already-cached chain links keep their
-        canonical block id as the parent for the next key."""
+    def _register_prefix(self, full: List[int], blocks: List[int], written: int) -> int:
+        """Publish the slot's FULL blocks below position ``written`` into
+        the prefix index; returns how many were not in it yet. The caller
+        says how far ``full``'s keys and values are certainly in place: the
+        prompt's length as a prefill ends (the trailing partial block still
+        receives decode writes and is never shared), the transcript's bound
+        as the slot is given back (``_free_slot``). Already-cached chain
+        links keep their canonical block id as the parent for the next key."""
         bs = self.pcfg.block_size
         pc = self.prefix_cache
         parent = _PrefixCache.ROOT
-        for j in range(len(full) // bs):
+        new = 0
+        for j in range(written // bs):
             toks = tuple(full[j * bs:(j + 1) * bs])
             cur = pc.table.get((parent, toks))
             if cur is not None:
                 parent = cur  # a hit we mapped, or a concurrent duplicate
                 continue
             parent = pc.register(parent, toks, blocks[j])
+            new += 1
+        return new
 
     def _flush_prefills(self):
         if not self._pending_first:
             return
         pend, self._pending_first = self._pending_first, []
         with tracing.phase("engine.prefill_wait", self._phase_ms):
-            vals = jax.device_get([t for _, _, t in pend])  # one batched transfer
+            # One batched transfer; a call's segments share its one array.
+            outs = {id(t): t for _, _, t, _ in pend}
+            vals = dict(zip(outs, jax.device_get(list(outs.values()))))
         with tracing.phase("engine.emit", self._phase_ms):
-            for (i, req, _), v in zip(pend, vals):
+            for i, req, t, k in pend:
                 if self.slots[i] is not req:
                     continue  # preempted between prefill and flush
-                self.cur[i] = int(v)
+                tok = int(vals[id(t)][k])
+                self.cur[i] = tok
                 self._dirty.add("cur")
-                self._emit(i, int(v))
+                self._emit(i, tok)
 
     def _emit(self, i: int, tok: int):
         """Record + stream one generated token; retire the slot when done.
@@ -1264,9 +1346,7 @@ class LLMEngine:
         ``_PHASES`` (here and in ``_harvest_window``/``_flush_prefills``),
         none inside another and none around them all; the step record
         carries their milliseconds."""
-        s0 = (self.stats["tokens"], self.stats["prefills"],
-              self.stats["preemptions"], self.stats["admitted"],
-              self.stats["prefill_chunks"], self.stats["prefix_hit_tokens"])
+        before = {k: self.stats[k] for k in _STEP_COUNTS}
         t_step = time.perf_counter()
         ph = self._phase_ms = {}
         worked = False
@@ -1302,13 +1382,11 @@ class LLMEngine:
                 worked = True
                 if not self.overlap:
                     self._harvest()  # classic synchronous window
-        s1 = (self.stats["tokens"], self.stats["prefills"],
-              self.stats["preemptions"], self.stats["admitted"],
-              self.stats["prefill_chunks"], self.stats["prefix_hit_tokens"])
+        moved = {k: self.stats[k] - before[k] for k in _STEP_COUNTS}
         # Record even decode-less iterations that did work — e.g. a
         # max_new_tokens=1 request finishes entirely inside the prefill
         # flush and must still appear in the step ring.
-        worked = worked or s1 != s0
+        worked = worked or any(moved.values())
         if worked:
             pc = self.prefix_cache
             self.recorder.record_step({
@@ -1318,12 +1396,13 @@ class LLMEngine:
                 "kv_blocks_free": self.alloc.available,
                 "kv_utilization": 1.0 - self.alloc.available
                 / max(1, self.pcfg.usable_blocks),
-                "tokens": s1[0] - s0[0],
-                "prefills": s1[1] - s0[1],
-                "preemptions": s1[2] - s0[2],
-                "admitted": s1[3] - s0[3],
-                "chunks": s1[4] - s0[4],
-                "prefix_hit_tokens": s1[5] - s0[5],
+                "tokens": moved["tokens"],
+                "prefills": moved["prefills"],
+                "preemptions": moved["preemptions"],
+                "admitted": moved["admitted"],
+                "chunks": moved["prefill_chunks"],  # chunk-program calls, and the
+                "segments": moved["prefill_segments"],  # suffixes or chunks in them
+                "prefix_hit_tokens": moved["prefix_hit_tokens"],
                 "cached_blocks": pc.resident_blocks if pc else 0,
                 # Host cost of this iteration, whole and by phase; the
                 # scheduler's own share is wall less the two waits.
@@ -1364,6 +1443,8 @@ class LLMEngine:
                 ("prefills", m.engine_prefills),
                 ("preemptions", m.engine_preemptions),
                 ("prefill_chunks", m.engine_prefill_chunks),
+                ("prefill_segments", m.engine_prefill_segments),
+                ("prefix_published_blocks", m.engine_prefix_published_blocks),
                 ("spec_windows", m.engine_overlap_windows),
                 ("prefix_hit_tokens", m.engine_prefix_hit_tokens),
                 ("prefix_lookup_tokens", m.engine_prefix_lookup_tokens),
@@ -1442,6 +1523,9 @@ class LLMEngine:
                 # a colder chain that a waiting request matched.
                 "evictions_wanted": self.stats["prefix_evictions_wanted"],
                 "evictions_spared": self.stats["prefix_evictions_spared"],
+                # Blocks that entered the index as their slot was given back
+                # (an answer's, a preempted request's), not at a prefill's end.
+                "published_blocks": self.stats["prefix_published_blocks"],
             },
             overlap={
                 "enabled": self.overlap,

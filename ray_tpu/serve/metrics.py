@@ -187,9 +187,22 @@ class _ServeMetrics:
             "KV blocks resident in the prefix cache (pinned + evictable)",
             dr,
         )
+        self.engine_prefix_published_blocks = Counter(
+            "serve_engine_prefix_published_blocks_total",
+            "Blocks that entered the prefix cache as their slot was given back "
+            "(an answer's, a preempted request's), beside a prefill's own",
+            dr,
+        )
         self.engine_prefill_chunks = Counter(
             "serve_engine_prefill_chunks_total",
-            "Chunk-program invocations (chunked/suffix prefill)",
+            "Chunk-program calls (chunked/suffix prefill); one holds the "
+            "suffixes an iteration admitted",
+            dr,
+        )
+        self.engine_prefill_segments = Counter(
+            "serve_engine_prefill_segments_total",
+            "Slots' suffixes or chunks prefilled by chunk-program calls "
+            "(segments a call = this / serve_engine_prefill_chunks_total)",
             dr,
         )
         self.engine_overlap_windows = Counter(

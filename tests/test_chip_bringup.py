@@ -282,7 +282,8 @@ def test_engine_programs_compile_for_v5e(v5e):
     assert "tpu_custom_call" in text  # prefill runs the flash kernel
 
 
-@pytest.mark.parametrize("program", ["decode_window", "chunk_one_block", "chunk_three_blocks"])
+@pytest.mark.parametrize(
+    "program", ["decode_window", "chunk_one_block", "chunk_three_blocks", "chunk_two_tiles"])
 def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
     """The layer scan carries the stacked cache and every layer addresses
     its own blocks in it: the compiled decode window and chunk programs
@@ -292,14 +293,17 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
     cache's rows are the serve cell's ([16, 8, 128]: the layouts the
     compiler weighs are those of the chip); 67 blocks a layer is a
     dimension no other array has. A chunk of ONE block is the case whose
-    scatter the compiler turns into a dynamic-update-slice. At these rows
+    scatter the compiler turns into a dynamic-update-slice; three blocks are
+    three tiles of one, and eight are two tiles of four, the serve cell's
+    tile, each gathering a table of its own. At these rows
     the decode window runs the ``paged_attend`` kernel, whose pool operands
     pin a layout: it must be the carry's own, and nothing may gather the
     slots' padded tables. Its pool is the serve cell's 1,601 blocks a
     layer: one of 67 (6.6 MB) the compiler parks in its fast memory for
     the kernel and copies back, which no pool of an engine's size allows."""
     from ray_tpu.models import transformer as tf
-    from ray_tpu.models.paged import PagedConfig, paged_decode_loop, prefill_chunk_and_sample
+    from ray_tpu.models.paged import (
+        PagedConfig, chunk_tile, paged_decode_loop, prefill_chunk_and_sample)
 
     cfg = tf.TransformerConfig(
         vocab_size=256, d_model=1024, n_layers=3, n_heads=8, n_kv_heads=8,
@@ -318,16 +322,18 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
         args = (sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
                 sds((b,), np.float32), key)
     else:
-        nb = {"chunk_one_block": 1, "chunk_three_blocks": 3}[program]
+        nb = {"chunk_one_block": 1, "chunk_three_blocks": 3, "chunk_two_tiles": 8}[program]
+        n = nb * bs // chunk_tile(nb * bs, bs)
+        assert n == {1: 1, 3: 3, 8: 2}[nb]
 
-        def run(params, tokens, cache, table_row, chunk_row, start, last_idx, temp, key):
+        def run(params, tokens, cache, table_rows, chunk_row, starts, last_idx, temps, key):
             return prefill_chunk_and_sample(
-                params, cfg, tokens, cache, table_row, chunk_row, bs, start, last_idx,
-                temp, key,
+                params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx,
+                temps, key,
             )
 
-        args = (sds((1, nb * bs), np.int32), cache, sds((w,), np.int32), sds((nb,), np.int32),
-                sds((), np.int32), sds((), np.int32), sds((), np.float32), key)
+        args = (sds((1, nb * bs), np.int32), cache, sds((n, w), np.int32), sds((nb,), np.int32),
+                sds((n,), np.int32), sds((n,), np.int32), sds((n,), np.float32), key)
     compiled = jax.jit(
         run, donate_argnums=(2,), in_shardings=(auto,) + (None,) * len(args),
     ).lower(params, *args).compile()

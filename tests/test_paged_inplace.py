@@ -66,7 +66,22 @@ def _ref_decode_step(params, cfg, tokens, cache, tables, lens):
     return unembed(params, x, cfg)[:, 0], cache
 
 
+def _ref_attend_chunk(q, ck, cv, qpos, cfg):
+    """One slot's chunk against its gathered view: the plain form that
+    ``paged._attend_chunk`` batches over tiles."""
+    C, H, HD = q.shape
+    KV = cfg.n_kv_heads
+    qg = q.reshape(C, KV, H // KV, HD)
+    scores = jnp.einsum("ckgd,mkd->ckgm", qg.astype(jnp.float32), ck.astype(jnp.float32))
+    valid = jnp.arange(ck.shape[0])[None, :] <= qpos[:, None]
+    scores = jnp.where(valid[:, None, None, :], scores * (HD**-0.5), -1e30)
+    og = jnp.einsum("ckgm,mkd->ckgd", jax.nn.softmax(scores, axis=-1), cv.astype(jnp.float32))
+    return og.reshape(C, H * HD).astype(q.dtype)
+
+
 def _ref_prefill_chunk(params, cfg, tokens, cache, table_row, chunk_row, bs, start):
+    """ONE slot's chunk, the pool taken out of the stack and put back: the
+    oracle of the packed program, which it serves a segment at a time."""
     C = tokens.shape[1]
     W, nb = table_row.shape[0], C // bs
     KV, HD = cfg.n_kv_heads, cfg.head_dim
@@ -79,7 +94,7 @@ def _ref_prefill_chunk(params, cfg, tokens, cache, table_row, chunk_row, bs, sta
         cv = cv.at[chunk_row].set(v[0].reshape(nb, bs, KV, HD))
         ck_g = ck[table_row].reshape(W * bs, KV, HD)
         cv_g = cv[table_row].reshape(W * bs, KV, HD)
-        o = paged._attend_chunk(q[0], ck_g, cv_g, positions[0], cfg)
+        o = _ref_attend_chunk(q[0], ck_g, cv_g, positions[0], cfg)
         x = x + (o @ lp["wo"].astype(o.dtype))[None]
         return mlp_block(x, lp, cfg), ck, cv
 
@@ -132,32 +147,74 @@ def test_decode_step_equals_slice_and_put_back(model, case):
                               np.asarray(cache["k"].astype(jnp.float32)))
 
 
+# case -> (tile, [segment: (table_row, blocks under its tiles, start, real tokens)]).
+# A segment takes whole tiles of the token axis, one after another; the
+# tiles left over belong to nobody.
 CHUNK_CASES = {
-    # (table_row, chunk_row, start): one block, straight after a resident prefix
-    "all_live": ([3, 7, 1, 12], [1], 2 * BS),
+    # one block, straight after a resident prefix
+    "all_live": (BS, [([3, 7, 1, 12], [1], 2 * BS, BS)]),
     # a chunk padded to two blocks whose tail block is the trash block
-    "idle_on_trash": ([5, 2, 9, TRASH_BLOCK], [9, TRASH_BLOCK], 2 * BS),
+    "idle_on_trash": (2 * BS, [([5, 2, 9, TRASH_BLOCK], [9, TRASH_BLOCK], 2 * BS, 5)]),
     # a chunk of three blocks that are nowhere near each other in the pool
-    "crosses_block": ([11, 6, 8, 10], [6, 8, 10], BS),
+    "crosses_block": (BS, [([11, 6, 8, 10], [6, 8, 10], BS, 3 * BS)]),
+    # two slots' suffixes in one call, the second ending inside its last block
+    "two_slots": (2 * BS, [([3, 7, 1, 12], [1, 12], 2 * BS, 2 * BS),
+                           ([5, 2, 9, 4], [2, 9], BS, BS + 4)]),
+    # five slots, a tile each, at five different depths of their tables
+    "five_slots": (BS, [([3, 7, TRASH_BLOCK, TRASH_BLOCK], [7], BS, BS),
+                        ([5, 2, 9, TRASH_BLOCK], [9], 2 * BS, 3),
+                        ([11, 6, TRASH_BLOCK, TRASH_BLOCK], [6], BS, 1),
+                        ([10, 8, 1, 12], [12], 3 * BS, BS - 1),
+                        ([4, TRASH_BLOCK, TRASH_BLOCK, TRASH_BLOCK], [4], 0, BS)]),
+    # one slot on two of four tiles: the other two lie on the trash block
+    "idle_tiles": (BS, [([5, 2, 9, 4], [9, 4], 2 * BS, BS + 2)]),
 }
+_IDLE_TILES = {"idle_tiles": 2}
 
 
 @pytest.mark.parametrize("case", list(CHUNK_CASES))
 def test_prefill_chunk_equals_slice_and_put_back(model, case):
+    """The packed chunk program against its segments served one call each
+    by the plain form: the same logits at every segment's last token and
+    the same cache, to the bit, outside the trash block (which takes every
+    padded tail and every idle tile, in whatever order)."""
     cfg, params, cache = model
-    table_row, chunk_row, start = CHUNK_CASES[case]
-    C = len(chunk_row) * BS
-    tokens = (jnp.arange(C, dtype=jnp.int32)[None, :] * 5 + 1) % cfg.vocab_size
-    args = (tokens, cache, jnp.asarray(table_row, jnp.int32),
-            jnp.asarray(chunk_row, jnp.int32))
-    got = jax.jit(
-        lambda t, c, tr, cr, s: paged.paged_prefill_chunk(params, cfg, t, c, tr, cr, BS, s)
-    )(*args, jnp.int32(start))
-    want = jax.jit(
-        lambda t, c, tr, cr, s: _ref_prefill_chunk(params, cfg, t, c, tr, cr, BS, s)
-    )(*args, jnp.int32(start))
-    _assert_bit_equal(got, want)
-    assert not np.array_equal(np.asarray(got[1]["v"].astype(jnp.float32)),
+    tile, segments = CHUNK_CASES[case]
+    rng = np.random.default_rng(5)
+    tokens, table_rows, chunk_row, starts, last_idx = [], [], [], [], []
+    want_logits, want_cache = [], cache
+    ref = jax.jit(lambda t, c, tr, cr, s: _ref_prefill_chunk(params, cfg, t, c, tr, cr, BS, s))
+    for table_row, under, start, real in segments:
+        width = len(under) * BS
+        toks = np.zeros(width, np.int32)
+        toks[:real] = rng.integers(1, cfg.vocab_size, real)
+        last_idx.append(len(tokens) * tile + real - 1)
+        for t in range(width // tile):
+            tokens.append(toks[t * tile:(t + 1) * tile])
+            table_rows.append(table_row)
+            starts.append(start + t * tile)
+        chunk_row += under
+        logits, want_cache = ref(jnp.asarray(toks)[None], want_cache,
+                                 jnp.asarray(table_row, jnp.int32),
+                                 jnp.asarray(under, jnp.int32), jnp.int32(start))
+        want_logits.append(logits[real - 1])
+    for _ in range(_IDLE_TILES.get(case, 0)):
+        tokens.append(np.zeros(tile, np.int32))
+        table_rows.append([TRASH_BLOCK] * 4)
+        starts.append(0)
+        chunk_row += [TRASH_BLOCK] * (tile // BS)
+    n = len(tokens)
+    last_idx += [0] * (n - len(segments))
+    got_logits, got_cache = jax.jit(
+        lambda t, c, tr, cr, s, li: paged.paged_prefill_chunk(params, cfg, t, c, tr, cr, BS, s, li)
+    )(jnp.asarray(np.concatenate(tokens))[None], cache, jnp.asarray(table_rows, jnp.int32),
+      jnp.asarray(chunk_row, jnp.int32), jnp.asarray(starts, jnp.int32),
+      jnp.asarray(last_idx, jnp.int32))
+    assert got_logits.shape == (n, cfg.vocab_size)  # a row a tile, whoever uses it
+    live = lambda c: {name: a[:, TRASH_BLOCK + 1:] for name, a in c.items()}
+    _assert_bit_equal((got_logits[:len(segments)], live(got_cache)),
+                      (jnp.stack(want_logits), live(want_cache)))
+    assert not np.array_equal(np.asarray(got_cache["v"].astype(jnp.float32)),
                               np.asarray(cache["v"].astype(jnp.float32)))
 
 

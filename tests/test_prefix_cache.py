@@ -114,8 +114,11 @@ def test_prefix_cache_refcounts_and_concurrent_sharing(tiny_model):
     rest = eng.generate_batch(prompts[1:], 6)
     assert [first[0]] + rest == expect
     pc = eng.prefix_cache
-    assert pc.resident_blocks == 2  # the two full shared blocks
-    assert pc.evictable_blocks == 2  # all refs dropped at finish
+    # The two full shared blocks, and the third block of each request, which
+    # its answer filled (21 + 6 tokens: positions 0..25 are written).
+    assert pc.resident_blocks == 5
+    assert pc.evictable_blocks == 5  # all refs dropped at finish
+    assert eng.stats["prefix_published_blocks"] == 3
     for bid, (_k, _p, refs, wanted) in pc.meta.items():
         assert refs == 0 and wanted == 0
     _cache_invariants(eng)
@@ -488,16 +491,24 @@ def test_eviction_spares_the_queued_follow_ups_history(tiny_model):
         eng.stats["prefix_evictions"], 3, 0)
 
 
-def test_preempted_request_is_wanted_while_it_waits(tiny_model):
+@pytest.mark.parametrize("prompt_tokens,wanted,taken,hit_tokens", [
+    (24, (1, 1, 1, 1), 2, 16), (8, (1, 1, 1, 1), 0, 32)], ids=["prompts_blocks", "answers_blocks"])
+def test_preempted_request_is_wanted_while_it_waits(tiny_model, prompt_tokens, wanted, taken,
+                                                    hit_tokens):
     """Engine (e): the pool runs out, the younger request is preempted and
-    requeued: the blocks it released are wanted from that moment. The
-    survivor then needs one block more than is free and every evictable
-    block is the waiting request's: the fallback takes ONE, the leaf, so
-    the resume hits on the two blocks left (the parent evicted the root,
-    the chain with it, and re-prefilled everything)."""
+    requeued: the blocks it released, those its decode steps filled among
+    them, are wanted from that moment. The survivor then needs blocks and
+    every evictable one is the waiting request's: the fallback takes them
+    ONE at a time, from the leaf, so the resume hits on what is left (the
+    parent evicted the root, the chain with it, and re-prefilled
+    everything). With prompts of 24 tokens the survivor takes two, the
+    block the preempted answer had filled and the prompt's last, and the
+    resume hits on the prompt's first two; with prompts of one block the
+    survivor ends before it needs any, and the resume hits on four blocks,
+    three of them its own answer's, found again where it left them."""
     cfg, params = tiny_model
     kw = dict(num_blocks=10, max_batch=2, max_blocks_per_seq=8)
-    prompts = [[100 + i for i in range(24)], [150 + i for i in range(24)]]
+    prompts = [[100 + i for i in range(prompt_tokens)], [150 + i for i in range(prompt_tokens)]]
     expect = _engine(cfg, params).generate_batch(prompts, 30)
     eng = _engine(cfg, params, enable_prefix_cache=True, **kw)
     reqs = [eng.add_request(p, 30) for p in prompts]
@@ -509,10 +520,10 @@ def test_preempted_request_is_wanted_while_it_waits(tiny_model):
             assert list(eng.waiting) == [reqs[1]]  # the younger one, back at the front
             marks.add(tuple(m[3] for m in reqs[1].wanted))
     assert eng.stats["preemptions"] == 1
-    # Wanted while it waited; the last mark's block was then evicted under it.
-    assert marks == {(1, 1, 1)}
-    assert eng.stats["prefix_evictions_wanted"] == 1
-    assert eng.stats["prefix_hit_tokens"] == 16  # resumed on the partial chain
+    # Wanted while it waited; the last marks' blocks were then evicted under it.
+    assert marks == {wanted}
+    assert eng.stats["prefix_evictions_wanted"] == taken
+    assert eng.stats["prefix_hit_tokens"] == hit_tokens  # resumed on the partial chain
     assert [list(r.tokens(timeout=60)) for r in reqs] == expect
     assert all(m[3] == 0 for m in eng.prefix_cache.meta.values())
 
@@ -563,9 +574,144 @@ def test_eviction_choices_reach_the_registry_counter(tiny_model):
     mine = {dict(tags)["choice"]: value for _n, _t, _d, tags, value in counter._drain()
             if dict(tags)["deployment"] == "evictions"}
     s = eng.stats
-    assert mine["wanted"] == s["prefix_evictions_wanted"] == 1
+    assert mine["wanted"] == s["prefix_evictions_wanted"] == 2
     assert sum(mine.values()) == s["prefix_evictions"]
     assert set(mine) <= {"lru", "spared", "wanted"} and mine["lru"] > 0
+
+
+# -- a given-back slot publishes its answer's blocks ---------------------------
+GIVE_BACK = {
+    # answer tokens, engine options, blocks the answer adds to the prompt's one.
+    # prompt 12 + answer: positions 0 .. len-2 are written, so a block is
+    # published when len - 1 reaches its end.
+    "ends_inside_a_block": (8, {}, 1),  # 20 tokens: 19 written, blocks [0,8) [8,16)
+    "last_token_opens_a_block": (5, {}, 1),  # 17: 16 written, and no third block
+    "last_token_ends_a_block": (4, {}, 0),  # 16: position 15 was never written
+    "one_more_fills_it": (13, {}, 2),  # 25: 24 written, blocks up to [16,24)
+    "window_overshoots": (8, dict(decode_window=4), 1),  # steps 9-12 wrote 20..22
+    "speculated_window_behind_it": (8, dict(decode_window=4, overlap=True), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(GIVE_BACK))
+def test_a_given_back_slot_publishes_what_its_steps_filled(tiny_model, case):
+    """A finished request's full blocks below position ``len(prompt +
+    answer) - 1`` are in the prefix cache, and none above: the last sampled
+    token's K/V was never written, and what a window wrote past the end is
+    overshoot. The next turn of the conversation hits on all of them, and
+    serves the tokens it serves with the cache off."""
+    cfg, params = tiny_model
+    n_new, kw, answers_blocks = GIVE_BACK[case]
+    history = [100 + i for i in range(12)]
+    eng = _engine(cfg, params, enable_prefix_cache=True, **kw)
+    answer = eng.generate_batch([history], n_new)[0]
+    assert eng.prefix_cache.resident_blocks == 1 + answers_blocks
+    assert eng.stats["prefix_published_blocks"] == answers_blocks
+    assert eng.prefix_cache.resident_blocks == (len(history) + n_new - 1) // 8
+    _cache_invariants(eng)
+    follow_up = history + answer + [7, 8, 9]
+    before = eng.stats["prefix_hit_tokens"]
+    got = eng.generate_batch([follow_up], 6)[0]
+    assert eng.stats["prefix_hit_tokens"] - before == 8 * (1 + answers_blocks)
+    assert got == _engine(cfg, params, **kw).generate_batch([follow_up], 6)[0]
+    assert eng.report_state()["prefix_cache"]["published_blocks"] == (
+        eng.stats["prefix_published_blocks"])
+    _cache_invariants(eng)
+
+
+def test_an_answer_cut_by_eos_publishes_nothing_of_the_windows_overshoot(tiny_model):
+    """An eos inside a window stops the request there; the window's later
+    steps, and a speculated window behind it, wrote K/V of tokens that were
+    never served into the slot's blocks. None of that is published: the
+    follow-up hits on the served transcript alone and is served as with
+    the cache off."""
+    cfg, params = tiny_model
+    kw = dict(decode_window=4, overlap=True)
+    history = [100 + i for i in range(12)]
+    whole = _engine(cfg, params, **kw).generate_batch([history], 16)[0]
+    at = next(k for k in range(5, 16) if whole[k] not in whole[:k])  # its first appearance
+    eng = _engine(cfg, params, enable_prefix_cache=True, **kw)
+    answer = eng.generate_batch([history], 16, eos_id=whole[at])[0]
+    assert answer == whole[:at + 1]
+    assert eng.prefix_cache.resident_blocks == (len(history) + len(answer) - 1) // 8
+    follow_up = history + answer + [7, 8, 9]
+    assert eng.generate_batch([follow_up], 6)[0] == _engine(cfg, params, **kw).generate_batch(
+        [follow_up], 6)[0]
+    _cache_invariants(eng)
+
+
+# -- the suffixes one iteration admits share one chunk call ----------------------
+_WIDE = dict(num_blocks=129, max_batch=8, max_blocks_per_seq=32)  # widths 8 .. 256, tiles of 32
+
+
+def _suffixes(k):
+    """k prompts over SHARED's two blocks, their suffixes 3 .. 27 tokens."""
+    return [SHARED + [30 + 7 * i + j for j in range(3 + 6 * i)] for i in range(k)]
+
+
+@pytest.mark.parametrize("k", [1, 2, 5])
+def test_packed_suffixes_serve_what_they_serve_a_call_each(tiny_model, k):
+    """k requests that hit on a shared prefix and are admitted in one
+    iteration reach the device as ONE chunk call of k segments, and serve
+    the tokens they serve one call each (admitted one after another) and
+    with the cache off."""
+    cfg, params = tiny_model
+    prompts = _suffixes(k)
+    expect = _engine(cfg, params, **_WIDE).generate_batch(prompts, 8)
+    one_each = _engine(cfg, params, enable_prefix_cache=True, **_WIDE)
+    one_each.generate_batch([SHARED], 2)
+    assert [one_each.generate_batch([p], 8)[0] for p in prompts] == expect
+    assert one_each.stats["prefill_chunks"] == one_each.stats["prefill_segments"] == k
+    eng = _engine(cfg, params, enable_prefix_cache=True, **_WIDE)
+    eng.generate_batch([SHARED], 2)
+    assert eng.generate_batch(prompts, 8) == expect
+    assert (eng.stats["prefill_chunks"], eng.stats["prefill_segments"]) == (1, k)
+    assert eng.stats["prefix_hit_tokens"] == 16 * k
+    assert [(st["chunks"], st["segments"]) for st in eng.recorder.snapshot()["steps"]
+            if st["segments"]] == [(1, k)]
+    _cache_invariants(eng)
+
+
+def test_a_wide_suffix_takes_a_call_of_its_own(tiny_model, monkeypatch):
+    """The next suffix starts a call of its own when the joined call would
+    be wider than the two apart. Suffixes of 104, 28 and 30 tokens: 104
+    fills a call of 128, and 28 more would make it 256 wide, mostly
+    padding, against 128 + 32 apart; the two narrow ones share a call of
+    64, which is what they cost apart."""
+    from ray_tpu.serve import llm_engine
+
+    monkeypatch.setattr(llm_engine, "_WEIGHTS_WIDTH", 32)  # the toy's tile, as 256 is four of the cell's
+    cfg, params = tiny_model
+    prompts = [SHARED + [40 + n + j for j in range(n)] for n in (100, 24, 26)]
+    expect = _engine(cfg, params, **_WIDE).generate_batch(prompts, 4)
+    eng = _engine(cfg, params, enable_prefix_cache=True, **_WIDE)
+    eng.generate_batch([SHARED], 2)
+    widths = []
+    call = eng._chunk_call
+    monkeypatch.setattr(eng, "_chunk_call", lambda w, segs: widths.append((w, len(segs))) or call(w, segs))
+    assert eng.generate_batch(prompts, 4) == expect
+    assert widths == [(128, 1), (64, 2)]
+
+
+@pytest.mark.parametrize("k", [2, 3, 5, 8])
+def test_packed_calls_compile_nothing_after_one_request_a_width(tiny_model, k):
+    """The compiled shape depends on the call's width alone: after ONE
+    request for each width (what the benchmark's warm-up plays), calls of
+    k segments at any of them find their program compiled."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, enable_prefix_cache=True, **_WIDE)
+    eng.generate_batch([SHARED], 2)
+    for width in eng._widths:  # a lone suffix of exactly this width
+        eng.generate_batch([SHARED + [50 + width + j for j in range(min(width - 7, 230))]], 2)
+    compiled = eng._prefill_chunk_fn._cache_size()
+    assert compiled == len(eng._widths)
+    before = dict(eng.stats)
+    for n in (2, 9, 20):  # k suffixes of n tokens: calls 64 to 256 wide
+        eng.generate_batch([SHARED + [90 + 11 * i + n + j for j in range(n)] for i in range(k)], 2)
+    assert eng.stats["prefill_segments"] - before["prefill_segments"] == 3 * k
+    assert eng.stats["prefill_chunks"] - before["prefill_chunks"] == 3
+    assert eng._prefill_chunk_fn._cache_size() == compiled
+    _cache_invariants(eng)
 
 
 @pytest.mark.slow

@@ -10,7 +10,8 @@ slots, window 10, overlap, prefix cache) under the traffic of
 ``chipbench/traffic/sessions-shared.json`` made by
 ``chipbench/generators/sessions.py`` (48 clients, 4 system prompts of 512,
 4 turns). Only the three compiled programs are stand-ins: they return
-random tokens and note the width they were called at. A client's next turn
+random tokens and note the width they were called at, the chunk program
+(the packed signature) also the tiles each segment took. A client's next turn
 joins the queue after the iteration in which its answer finished, as over
 HTTP it arrives after that iteration's admission. It counts; it times
 nothing, and no number from it is a device metric.
@@ -27,8 +28,18 @@ against chip (ledger, PR 30, and my chip runs, PR 31, four same-seed pairs):
   125-128 windows = 45-47; this eviction 30.8-31.5 against 4,311-4,604 in
   138-142 = 31-33.
 
+Since PR 39 a given-back slot publishes its answer's blocks and the
+suffixes of one iteration share a packed chunk call. The replay read, and
+the chip then read (PERF.md section 6, PR 39): hits 86.9-87.5% in the
+window (88.8-89.1% later); 340-359 real prefill tokens an iteration in
+1.19-1.27 program calls of 2.6-2.7 segments, 504-541 tokens wide in all
+(widths 64: 7-10, 128: 28, 256: 31-40, 512: 80-81, 1,024: 11-18, 1,568:
+0-3 of 163-174 calls), where one call a suffix made 3.2 calls.
+
 Under plain LRU one prefill call in eight was then a re-prefill over 512
-tokens wide (11-16% of calls; 2.0-2.3% now). What it also says, and no chip
+tokens wide (11-16% of calls; 2.0-2.3% before PR 39; 16-18 segments of
+439-445, 3.6-4.0%, in the window now that hits are counted on answers
+too). What it also says, and no chip
 run has checked: the fault is a transient of 48 conversations started
 together. Over iterations 100-400 plain LRU reads 77.4-78.4% and 5.1-7.4%
 wide calls; from iteration 400 on it takes the same victims as this
@@ -42,7 +53,7 @@ import numpy as np
 import pytest
 
 from chipbench.generators import sessions
-from ray_tpu.models.paged import PagedConfig
+from ray_tpu.models.paged import TRASH_BLOCK, PagedConfig
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.serve import llm_engine
 
@@ -63,15 +74,27 @@ class _Programs:
     def __init__(self, seed: int, vocab: int, window: int):
         self.rng = np.random.default_rng(seed)
         self.vocab, self.window = vocab, window
-        self.widths = []  # one entry a prefill or chunk call: its padded width
+        self.calls = []  # one entry a prefill or chunk call: (padded width, [a segment's tiles' width])
 
     def decode(self, params, cur, cache, tables, lens, temps, key):
         seq = self.rng.integers(0, self.vocab, (self.window, len(cur)), dtype=np.int32)
         return seq, seq[-1], np.asarray(lens) + self.window, cache
 
     def prefill(self, params, toks, cache, *rest):
-        self.widths.append(toks.shape[1])
+        self.calls.append((toks.shape[1], [toks.shape[1]]))
         return np.int32(self.rng.integers(0, self.vocab)), cache
+
+    def chunk(self, params, toks, cache, table_rows, chunk_row, starts, last_idx, temps, key):
+        """The packed signature. A segment's tiles follow one another under
+        one slot's table row; a tile nobody uses lies on the trash block."""
+        live = (table_rows != TRASH_BLOCK).any(axis=1)
+        tile = toks.shape[1] // len(starts)
+        goes_on = (table_rows[1:] == table_rows[:-1]).all(axis=1) & (
+            starts[1:] == starts[:-1] + tile)
+        first = np.flatnonzero(live & ~np.concatenate([[False], goes_on]))
+        ends = np.concatenate([first[1:], [int(live.sum())]])  # live tiles come first
+        self.calls.append((toks.shape[1], [int(t) * tile for t in ends - first]))
+        return self.rng.integers(0, self.vocab, len(starts), dtype=np.int32), cache
 
 
 def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
@@ -81,7 +104,7 @@ def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
     monkeypatch.setattr(llm_engine, "init_paged_cache", lambda cfg, p: {})
     monkeypatch.setattr(
         llm_engine.LLMEngine, "_build_programs",
-        lambda self, params: (progs.decode, progs.prefill, progs.prefill, params))
+        lambda self, params: (progs.decode, progs.prefill, progs.chunk, params))
     eng = llm_engine.LLMEngine(
         None, TransformerConfig.tiny(), PagedConfig(**conf["paged"]),
         decode_window=eng_conf["decode_window"], overlap=eng_conf["overlap"],
@@ -112,13 +135,16 @@ def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
     base = None
     for it in range(warmup + iterations):
         if it == warmup:
-            base, progs.widths = dict(eng.stats), []
+            base, progs.calls = dict(eng.stats), []
         eng.step()
         for c in clients:
             if c.req.remaining <= 0:
                 c.speak()
     d = {k: eng.stats[k] - base[k] for k in base if isinstance(base[k], int)}
-    widths = np.asarray(progs.widths)
+    widths = np.asarray([w for w, _ in progs.calls])
+    segments = np.asarray([s for _, each in progs.calls for s in each])
+    assert len(segments) == d["prefills"]  # the stand-ins count what the engine counts
+    assert len(widths) - d["prefill_chunks"] == d["prefills"] - d["prefill_segments"]  # full prefills
     return {
         "hit_pct": 100.0 * d["prefix_hit_tokens"] / d["prefix_lookup_tokens"],
         "prefills_per_iteration": d["prefills"] / iterations,
@@ -126,9 +152,13 @@ def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
         "real_tokens_per_iteration": d["prompt_tokens"] / iterations,
         "padded_tokens_per_iteration": float(widths.sum()) / iterations,
         "calls": len(widths),
-        "wide_pct": 100.0 * float((widths > 512).mean()),
+        "calls_per_iteration": len(widths) / iterations,
+        "segments_per_chunk_call": d["prefill_segments"] / d["prefill_chunks"],
+        # A conversation re-prefilled whole: a segment over 512 tokens wide.
+        "wide_segments": int((segments > 512).sum()),
         "by_width": {int(w): int((widths == w).sum()) for w in np.unique(widths)},
         "preemptions": d["preemptions"],
+        "widths": list(eng._widths),
         "stats": d,
     }
 
@@ -137,21 +167,41 @@ WINDOW = dict(warmup=50, iterations=137)  # the cell's own: 15 s of ramp, 40 s m
 LATER = dict(warmup=100, iterations=300)
 
 
-@pytest.mark.parametrize("phase,wide_limit", [(LATER, 2.0), (WINDOW, 3.0)],
+@pytest.mark.parametrize("phase,hit_floor,wide_limit", [(LATER, 88.0, 1.6), (WINDOW, 85.0, 4.1)],
                          ids=["iterations_100_400", "the_cells_window"])
 @pytest.mark.parametrize("seed", [3900000022, 3900000011])
-def test_sessions_replay_hit_share_and_wide_suffixes(seed, phase, wide_limit, monkeypatch):
-    """Eviction that knows the queue keeps the follow-up's history: of the
-    prompt tokens looked up, >= 79% hit (the traffic's own ceiling, with
-    answers not cached, is 82%), and fewer than 2% of the prefill calls are
-    over 512 tokens wide (3% inside the cell's window, where the pool is
-    filling for the first time). Plain LRU read 77.4-78.4% and 5.1-7.4%
-    later, 72.9-75.5% and 11.4-16.0% in the window."""
+def test_sessions_replay_hit_share_and_wide_suffixes(seed, phase, hit_floor, wide_limit,
+                                                     monkeypatch):
+    """Eviction that knows the queue keeps the follow-up's history, and a
+    given-back slot leaves its answer there too: of the prompt tokens
+    looked up, >= 85% hit in the cell's window (read: 86.9-87.5; with
+    answers not cached the traffic's ceiling was 82%), >= 88% later. What
+    the hits leave reaches the device in <= 1.5 program calls an iteration
+    (read: 1.19-1.27, of 2.6-2.7 segments a chunk call), each at a width
+    the benchmark's warm-up has played. A conversation re-prefilled whole
+    (a segment over 512 tokens) stays as rare as it was: 16-18 of 439-445
+    in the window, where the pool fills for the first time, 9-14 of ~960
+    later. Plain LRU read 72.9-75.5% hits and 11.4-16.0% wide calls."""
+    from chipbench.drivers.serve import warm_requests
+
     got = replay(seed, monkeypatch=monkeypatch, **phase)
     print(json.dumps({k: v for k, v in got.items() if k != "stats"}), got["stats"])
     assert got["preemptions"] == 0
-    assert got["hit_pct"] >= 79.0, got
-    assert got["wide_pct"] < wide_limit, got
+    assert got["hit_pct"] >= hit_floor, got
+    assert got["calls_per_iteration"] <= 1.5, got
+    assert got["segments_per_chunk_call"] >= 2.0, got
+    assert 100.0 * got["wide_segments"] / got["stats"]["prefills"] <= wide_limit, got
+    assert got["stats"]["prefix_published_blocks"] > 0
+    # Every width was played once, through the served path, before the window:
+    # a suffix of just that many tokens after the two blocks the warm-up shares.
+    conf, _ = _cell()
+    with open(os.path.join(_CHIPBENCH, "traffic", "sessions-shared.json")) as f:
+        warm = json.load(f)["warm"]
+    played = [p for p, _n in warm_requests(warm, conf["paged"], conf["vocab_size"], margin=22)]
+    shared = min(played, key=len)  # the two blocks its chunk requests share
+    warmed = {min(w for w in got["widths"] if w >= len(p) - len(shared))
+              for p in played if len(p) > len(shared) and p[:len(shared)] == shared}
+    assert set(got["by_width"]) <= warmed, (got["by_width"], warmed)
     # The fallback never engages here: ended sessions always leave a chain
     # that nobody waits for.
     assert got["stats"]["prefix_evictions_wanted"] == 0
