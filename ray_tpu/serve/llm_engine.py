@@ -78,7 +78,21 @@ _engine_ids = itertools.count()
 # because a reader of the device trace lays an idle gap to the host span that
 # covers most of it, and an enclosing span always would. Each recorded step
 # carries ``<name>_ms``.
-_PHASES = ("harvest_wait", "emit", "admit", "prefill_wait", "dispatch")
+_PHASES = ("harvest_wait", "emit", "admit", "prefill_wait", "dispatch", "record")
+# The parts of ``admit`` and of ``dispatch``, ``tracing.phase("engine.admit.plan")``
+# and so on, NESTED in their parent and never in one another: a gap that lies
+# inside one part takes its name (the shortest span that covers it), a gap
+# across several keeps the parent's. A span per admitted request and per
+# program call, never per token, block or slot. Steps carry ``<part>_ms`` too.
+_SUB_PHASES = ("admit_plan", "admit_build", "admit_key", "admit_launch",
+               "dispatch_blocks", "dispatch_key", "dispatch_ship", "dispatch_launch")
+# A recorded step's ``<name>_ms`` and the span it is the milliseconds of.
+_STEP_MS = tuple((f"{name}_ms", "engine." + name) for name in _PHASES) + tuple(
+    (f"{sub}_ms", "engine." + sub.replace("_", ".", 1)) for sub in _SUB_PHASES)
+# Where the scheduler thread can be while the device has nothing queued
+# (``LLMEngine._at``): ``stats["starved_us_<where>"]``. ``between`` is outside
+# every phase: the turn of the loop, and what a step does between two phases.
+_STARVED = _SUB_PHASES + ("emit", "record", "between")
 # Why a window in flight was not overlapped: ``stats["spec_blocked_<reason>"]``.
 _SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
 # The counters a recorded step carries as what the iteration added to them.
@@ -517,8 +531,9 @@ class LLMEngine:
             "tables": None, "lens": None, "temps": None, "cur": None,
         }
         self._dirty = {"tables", "lens", "temps", "cur"}
-        # In-flight speculated window: ([(slot, rid), ...], seq device
-        # array). Harvested (ONE host sync) at the top of the next step.
+        # In-flight speculated window: ([(slot, rid, gen), ...], seq device
+        # array, number of its program call). Harvested (ONE host sync) at
+        # the top of the next step.
         self._inflight: Optional[tuple] = None
         # Slots mid-chunked-prefill (excluded from the decode set);
         # _chunk_rr rotates which slot advances each step.
@@ -528,6 +543,7 @@ class LLMEngine:
         # Prefill first-tokens awaiting ONE batched device→host transfer
         # (per-prefill int() syncs each pay a full link round-trip).
         self._pending_first: List = []
+        self._pending_launch = 0  # the newest program call among them
         self._lock = threading.Lock()
         self._wake = threading.Event()
         self._stop = threading.Event()
@@ -541,13 +557,32 @@ class LLMEngine:
                       "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
                       "prefix_published_blocks": 0, "prefill_segments": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
-                      **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED}}
+                      **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED},
+                      "starved_us": 0, "unloaded_us": 0,
+                      **{f"starved_us_{where}": 0 for where in _STARVED}}
         # Milliseconds by phase of the iteration in progress (tracing.phase).
         self._phase_ms: Dict[str, float] = {}
+        # The account of the time the device waits for the host. Every program
+        # takes the donated cache of the one before it, so the device runs them
+        # in the order of their calls: once the host has read an output of
+        # call number ``_launches`` nothing is queued until the next call
+        # returns. ``_empty_since`` is that moment (whole microseconds of
+        # ``perf_counter_ns``; None while something is queued), ``_where`` the
+        # bucket of ``_STARVED`` the thread is in, ``_starved`` the iteration's
+        # microseconds by bucket, which ``step`` adds to ``stats`` as it ends:
+        # to ``starved_us`` and its bucket if the step found work, else to
+        # ``unloaded_us`` (idle for want of load, not the host's doing).
+        self._launches = 0
+        self._empty_since: Optional[int] = None
+        self._where = "between"
+        self._starved: Dict[str, int] = {}
+        self._idle = True  # the last step found no work
         if warmup_buckets:
             t0 = time.perf_counter()
             self.stats["warmup_compiles"] = self._warmup()
             self.stats["warmup_s"] = round(time.perf_counter() - t0, 3)
+        # Nothing is queued: warm-up waited for its last program.
+        self._born_us = self._empty_since = time.perf_counter_ns() // 1000
         # -- telemetry ---------------------------------------------------
         # Flight recorder: bounded rings appended on the scheduler thread.
         self.recorder = FlightRecorder()
@@ -937,56 +972,68 @@ class LLMEngine:
         """Move waiting requests into free slots while blocks allow; a
         prefix-cache hit maps already-resident blocks into the slot's
         table and only the novel suffix is prefilled."""
-        p = self.pcfg
-        bs = p.block_size
-        if self.prefix_cache is not None:
-            # Once per stay in the queue: arrivals stand at its tail,
-            # behind everything this thread has already looked up.
-            with self._lock:
-                new = list(itertools.takewhile(
-                    lambda r: r.wanted is None, reversed(self.waiting)))
-            for req in new:
-                self._want(req)
         suffixes: List[tuple] = []  # what the hits left to prefill, in queue order
+        look = self.prefix_cache is not None
         while True:
-            free_slots = [i for i, s in enumerate(self.slots) if s is None]
-            if not free_slots:
+            with tracing.phase("engine.admit.plan", self._phase_ms):
+                self._at("admit_plan")
+                if look:
+                    # Once per stay in the queue: arrivals stand at its tail,
+                    # behind everything this thread has already looked up.
+                    with self._lock:
+                        new = list(itertools.takewhile(
+                            lambda r: r.wanted is None, reversed(self.waiting)))
+                    for req in new:
+                        self._want(req)
+                    look = False
+                placed = self._place_next()
+            if placed is None:
                 break
-            with self._lock:
-                if not self.waiting:
-                    break
-                req = self.waiting.popleft()
-            if self.prefix_cache is not None and req.wanted is None:
-                self._want(req)  # it arrived after the look at the queue's tail
-            full = req.full_prompt
-            plen = len(full)
-            real_blocks = -(-plen // bs)  # ceil
-            hits: List[int] = []
-            if self.prefix_cache is not None:
-                # Pin hits BEFORE allocating — the allocation may evict
-                # refcount-0 residents, which a matched block must not be.
-                hits = self.prefix_cache.match(full, bs, (plen - 1) // bs)
-                for b in hits:
-                    self.prefix_cache.incref(b)
-            got = self._alloc_blocks(real_blocks - len(hits))
-            if got is None:
-                for b in hits:
-                    self.prefix_cache.release(b)
-                with self._lock:
-                    self.waiting.appendleft(req)  # still wanted: it still waits
-                break
-            if self.prefix_cache is not None:
-                self.prefix_cache.unwant(req.wanted)  # the hits are pinned now
-                req.wanted = None
-                self.stats["prefix_lookup_tokens"] += plen
-                self.stats["prefix_hit_tokens"] += len(hits) * bs
-            i = free_slots[0]
-            self.slots[i] = req
-            self._slot_gen[i] += 1
-            self.slot_blocks[i] = hits + got
-            self.stats["admitted"] += 1
-            self._start_prefill(i, req, len(hits) * bs, suffixes)
+            self._start_prefill(*placed, suffixes)
         self._run_suffixes(suffixes)
+
+    def _place_next(self) -> Optional[tuple]:
+        """Give the queue's head a free slot and its blocks: ``(slot,
+        request, first position to prefill)``, or None when no slot is
+        free, nobody waits, or the blocks cannot be had (the request goes
+        back to the head of the queue)."""
+        bs = self.pcfg.block_size
+        i = next((j for j, s in enumerate(self.slots) if s is None), None)
+        if i is None:
+            return None
+        with self._lock:
+            if not self.waiting:
+                return None
+            req = self.waiting.popleft()
+        if self.prefix_cache is not None and req.wanted is None:
+            self._want(req)  # it arrived after the look at the queue's tail
+        full = req.full_prompt
+        plen = len(full)
+        real_blocks = -(-plen // bs)  # ceil
+        hits: List[int] = []
+        if self.prefix_cache is not None:
+            # Pin hits BEFORE allocating — the allocation may evict
+            # refcount-0 residents, which a matched block must not be.
+            hits = self.prefix_cache.match(full, bs, (plen - 1) // bs)
+            for b in hits:
+                self.prefix_cache.incref(b)
+        got = self._alloc_blocks(real_blocks - len(hits))
+        if got is None:
+            for b in hits:
+                self.prefix_cache.release(b)
+            with self._lock:
+                self.waiting.appendleft(req)  # still wanted: it still waits
+            return None
+        if self.prefix_cache is not None:
+            self.prefix_cache.unwant(req.wanted)  # the hits are pinned now
+            req.wanted = None
+            self.stats["prefix_lookup_tokens"] += plen
+            self.stats["prefix_hit_tokens"] += len(hits) * bs
+        self.slots[i] = req
+        self._slot_gen[i] += 1
+        self.slot_blocks[i] = hits + got
+        self.stats["admitted"] += 1
+        return i, req, len(hits) * bs
 
     def _start_prefill(self, i: int, req: Request, start: int, suffixes: List[tuple]):
         """Begin prefilling slot ``i`` from absolute position ``start``
@@ -1065,21 +1112,27 @@ class LLMEngine:
         """Whole-prompt full-attention prefill (bucketed); returns the
         first sampled token as a DEVICE scalar."""
         bs = self.pcfg.block_size
-        plen = len(full)
-        S = self._bucket(plen)
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :plen] = full
-        # Block row covers the padded bucket; entries past the real
-        # prompt scatter into the trash block.
-        row = np.full(S // bs, TRASH_BLOCK, np.int32)
-        nreal = -(-plen // bs)
-        row[:nreal] = self.slot_blocks[i]
-        self.key, sub = jax.random.split(self.key)
-        tok, self.cache = self._prefill(
-            self.params, jax.numpy.asarray(toks), self.cache,
-            jax.numpy.asarray(row),
-            np.int32(plen), np.float32(req.temperature), sub,
-        )
+        ph = self._phase_ms
+        with tracing.phase("engine.admit.build", ph):
+            self._at("admit_build")
+            plen = len(full)
+            S = self._bucket(plen)
+            toks = np.zeros((1, S), np.int32)
+            toks[0, :plen] = full
+            # Block row covers the padded bucket; entries past the real
+            # prompt scatter into the trash block.
+            row = np.full(S // bs, TRASH_BLOCK, np.int32)
+            nreal = -(-plen // bs)
+            row[:nreal] = self.slot_blocks[i]
+        sub = self._split_key("admit")
+        with tracing.phase("engine.admit.launch", ph):
+            self._at("admit_launch")
+            tok, self.cache = self._prefill(
+                self.params, jax.numpy.asarray(toks), self.cache,
+                jax.numpy.asarray(row),
+                np.int32(plen), np.float32(req.temperature), sub,
+            )
+            self._launched()
         return tok
 
     def _run_chunks(self, width: int, segs: List[tuple]):
@@ -1106,33 +1159,39 @@ class LLMEngine:
         tokens ``[tiles]`` on the device, segment ``k``'s at ``k``."""
         p = self.pcfg
         bs = p.block_size
-        tile = chunk_tile(width, bs)
-        n = width // tile
-        toks = np.zeros((1, width), np.int32)
-        trows = np.full((n, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
-        crow = np.full(width // bs, TRASH_BLOCK, np.int32)
-        starts = np.zeros(n, np.int32)
-        last_idx = np.zeros(n, np.int32)
-        temps = np.zeros(n, np.float32)
-        at = 0  # the next free tile's first position on the axis
-        for k, (i, req, full, start, end) in enumerate(segs):
-            blocks = self.slot_blocks[i]
-            tiles = -(-(end - start) // tile)
-            t0 = at // tile
-            toks[0, at:at + end - start] = full[start:end]
-            trows[t0:t0 + tiles, :len(blocks)] = blocks
-            starts[t0:t0 + tiles] = start + tile * np.arange(tiles)
-            under = blocks[start // bs:start // bs + tiles * tile // bs]
-            crow[at // bs:at // bs + len(under)] = under
-            last_idx[k] = at + end - start - 1
-            temps[k] = req.temperature
-            at += tiles * tile
-        self.key, sub = jax.random.split(self.key)
-        # Built per call and never written again, so the program may read
-        # them where they lie: only the slot mirrors, which the scheduler
-        # mutates in place, need _hand_over's copy.
-        out, self.cache = self._prefill_chunk_fn(
-            self.params, toks, self.cache, trows, crow, starts, last_idx, temps, sub)
+        ph = self._phase_ms
+        with tracing.phase("engine.admit.build", ph):
+            self._at("admit_build")
+            tile = chunk_tile(width, bs)
+            n = width // tile
+            toks = np.zeros((1, width), np.int32)
+            trows = np.full((n, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
+            crow = np.full(width // bs, TRASH_BLOCK, np.int32)
+            starts = np.zeros(n, np.int32)
+            last_idx = np.zeros(n, np.int32)
+            temps = np.zeros(n, np.float32)
+            at = 0  # the next free tile's first position on the axis
+            for k, (i, req, full, start, end) in enumerate(segs):
+                blocks = self.slot_blocks[i]
+                tiles = -(-(end - start) // tile)
+                t0 = at // tile
+                toks[0, at:at + end - start] = full[start:end]
+                trows[t0:t0 + tiles, :len(blocks)] = blocks
+                starts[t0:t0 + tiles] = start + tile * np.arange(tiles)
+                under = blocks[start // bs:start // bs + tiles * tile // bs]
+                crow[at // bs:at // bs + len(under)] = under
+                last_idx[k] = at + end - start - 1
+                temps[k] = req.temperature
+                at += tiles * tile
+        sub = self._split_key("admit")
+        with tracing.phase("engine.admit.launch", ph):
+            self._at("admit_launch")
+            # Built per call and never written again, so the program may read
+            # them where they lie: only the slot mirrors, which the scheduler
+            # mutates in place, need _hand_over's copy.
+            out, self.cache = self._prefill_chunk_fn(
+                self.params, toks, self.cache, trows, crow, starts, last_idx, temps, sub)
+            self._launched()
         return out
 
     def _finish_prefill(self, i: int, req: Request, toks, k):
@@ -1153,6 +1212,7 @@ class LLMEngine:
         # syncing; _flush_prefills fetches every pending first token in
         # one transfer after the admission loop.
         self._pending_first.append((i, req, toks, k))
+        self._pending_launch = self._launches  # the call that made ``toks``
 
     def _register_prefix(self, full: List[int], blocks: List[int], written: int) -> int:
         """Publish the slot's FULL blocks below position ``written`` into
@@ -1184,7 +1244,9 @@ class LLMEngine:
             # One batched transfer; a call's segments share its one array.
             outs = {id(t): t for _, _, t, _ in pend}
             vals = dict(zip(outs, jax.device_get(list(outs.values()))))
+        self._drained(self._pending_launch)
         with tracing.phase("engine.emit", self._phase_ms):
+            self._at("emit")
             for i, req, t, k in pend:
                 if self.slots[i] is not req:
                     continue  # preempted between prefill and flush
@@ -1192,6 +1254,7 @@ class LLMEngine:
                 self.cur[i] = tok
                 self._dirty.add("cur")
                 self._emit(i, tok)
+        self._at("between")
 
     def _emit(self, i: int, tok: int):
         """Record + stream one generated token; retire the slot when done.
@@ -1250,38 +1313,46 @@ class LLMEngine:
         device's own ``lens`` output advances EVERY row; what an idle row
         holds there is ``paged_decode_loop``'s to define (it restarts a
         row whose table starts on the trash block)."""
-        self._ensure_decode_blocks()
-        entries = self._decode_entries()
-        if not entries:
-            return False
-        if speculative and "cur" in self._dirty:
-            # The host ``cur`` mirror LAGS the in-flight window (its live
-            # rows are window N-1's tokens until the harvest), so a dirty
-            # cur — a prefill flush, or a preemption the _ensure above
-            # just performed — must not be shipped wholesale now: it
-            # would rewind every other slot by one window. Abort the
-            # speculation; the synchronous path re-dispatches after the
-            # harvest has re-synced the mirror.
-            return False
-        self.stats["max_active"] = max(self.stats["max_active"], len(entries))
-        # Blocks the occupied slots' tokens lie in as the window starts,
-        # against the table the plain form of decode attention gathers whole.
-        occupied = [i for i, _rid, _gen in entries]
-        self.stats["decode_blocks_live"] += int(
-            (self.lens[occupied] // self.pcfg.block_size + 1).sum())
-        self.stats["decode_blocks_table"] += (
-            self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
-        self.key, sub = jax.random.split(self.key)
-        args = self._ship()
-        seq, cur_out, lens_out, self.cache = self._decode(
-            self.params, args["cur"], self.cache,
-            args["tables"], args["lens"], args["temps"], sub,
-        )
+        ph = self._phase_ms
+        with tracing.phase("engine.dispatch.blocks", ph):
+            self._at("dispatch_blocks")
+            self._ensure_decode_blocks()
+            entries = self._decode_entries()
+            if not entries:
+                return False
+            if speculative and "cur" in self._dirty:
+                # The host ``cur`` mirror LAGS the in-flight window (its live
+                # rows are window N-1's tokens until the harvest), so a dirty
+                # cur — a prefill flush, or a preemption the _ensure above
+                # just performed — must not be shipped wholesale now: it
+                # would rewind every other slot by one window. Abort the
+                # speculation; the synchronous path re-dispatches after the
+                # harvest has re-synced the mirror.
+                return False
+            self.stats["max_active"] = max(self.stats["max_active"], len(entries))
+            # Blocks the occupied slots' tokens lie in as the window starts,
+            # against the table the plain form of decode attention gathers whole.
+            occupied = [i for i, _rid, _gen in entries]
+            self.stats["decode_blocks_live"] += int(
+                (self.lens[occupied] // self.pcfg.block_size + 1).sum())
+            self.stats["decode_blocks_table"] += (
+                self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
+        sub = self._split_key("dispatch")
+        with tracing.phase("engine.dispatch.ship", ph):
+            self._at("dispatch_ship")
+            args = self._ship()
+        with tracing.phase("engine.dispatch.launch", ph):
+            self._at("dispatch_launch")
+            seq, cur_out, lens_out, self.cache = self._decode(
+                self.params, args["cur"], self.cache,
+                args["tables"], args["lens"], args["temps"], sub,
+            )
+            self._launched()
         self._dev["cur"] = cur_out
         self._dev["lens"] = lens_out
         self.lens[occupied] += self.window
         self.stats["steps"] += 1
-        self._inflight = (entries, seq)
+        self._inflight = (entries, seq, self._launches)
         return True
 
     def _harvest(self) -> bool:
@@ -1294,10 +1365,12 @@ class LLMEngine:
         """Read one dispatched window's tokens (ONE host sync) and emit
         them. Slots freed/reused since dispatch fail the rid check and
         their lanes are discarded (overshoot)."""
-        entries, seq = pending
+        entries, seq, launch = pending
         with tracing.phase("engine.harvest_wait", self._phase_ms):
             nxt = np.asarray(seq)  # [window, b]: the host blocks on the device
+        self._drained(launch)  # not behind a speculated window: that is newer
         with tracing.phase("engine.emit", self._phase_ms):
+            self._at("emit")
             for i, rid, gen in entries:
                 req = self.slots[i]
                 if req is None or req.rid != rid or self._slot_gen[i] != gen:
@@ -1307,6 +1380,7 @@ class LLMEngine:
                         break  # finished mid-window; rest is overshoot
                     self.cur[i] = nxt[k, i]
                     self._emit(i, int(nxt[k, i]))
+        self._at("between")
         return True
 
     def _can_speculate(self) -> Optional[str]:
@@ -1330,6 +1404,51 @@ class LLMEngine:
             return "finishing"
         return None
 
+    # -- the starvation account: integer adds at phase boundaries and launches --
+
+    def _at(self, where: str):
+        """The scheduler thread enters ``where`` (one of ``_STARVED``): the
+        microseconds the device has had nothing queued since the last
+        boundary go to the bucket the thread leaves."""
+        since = self._empty_since
+        if since is not None:
+            now = time.perf_counter_ns() // 1000
+            self._starved[self._where] = self._starved.get(self._where, 0) + now - since
+            self._empty_since = now
+        self._where = where
+
+    def _launched(self):
+        """A program call has RETURNED: its own host time was part of what
+        the device waited for, and the wait ends here."""
+        self._launches += 1
+        self._at(self._where)
+        self._empty_since = None
+
+    def _drained(self, launch: int):
+        """The host has read an output of program call number ``launch``:
+        if that is the newest, nothing is queued from now on."""
+        if launch == self._launches:
+            self._empty_since = time.perf_counter_ns() // 1000
+
+    def _split_key(self, part: str):
+        """The next sampling key, as the ``key`` part of ``admit`` or
+        ``dispatch``: device programs of its own, which end no starvation."""
+        with tracing.phase("engine." + part + ".key", self._phase_ms):
+            self._at(part + "_key")
+            self.key, sub = jax.random.split(self.key)
+        return sub
+
+    def _settle(self, unloaded: bool):
+        """Add the microseconds gathered since the last call to ``stats``:
+        as the host's doing, by bucket, or as idle for want of load."""
+        if unloaded:
+            self.stats["unloaded_us"] += sum(self._starved.values())
+        else:
+            for where, us in self._starved.items():
+                self.stats["starved_us"] += us
+                self.stats["starved_us_" + where] += us
+        self._starved.clear()
+
     def step(self) -> bool:
         """One scheduler iteration: [speculate] → harvest → admit → page
         → decode. Returns True if any device work ran (False = idle).
@@ -1342,10 +1461,15 @@ class LLMEngine:
         later prefill is always overwritten AFTER the stale window's
         writes land).
 
-        The iteration runs as the five sibling ``tracing.phase``s of
+        The iteration runs as the six sibling ``tracing.phase``s of
         ``_PHASES`` (here and in ``_harvest_window``/``_flush_prefills``),
-        none inside another and none around them all; the step record
-        carries their milliseconds."""
+        none inside another and none around them all, with the parts of
+        ``admit`` and ``dispatch`` (``_SUB_PHASES``) nested in those two; the
+        step record carries their milliseconds, and how many of them the
+        device had nothing queued (``starved_ms``; the time BETWEEN two
+        steps is no step's and goes to ``stats`` alone)."""
+        self._at("between")
+        self._settle(self._idle)  # the turn of the loop, on the last step's verdict
         before = {k: self.stats[k] for k in _STEP_COUNTS}
         t_step = time.perf_counter()
         ph = self._phase_ms = {}
@@ -1364,6 +1488,7 @@ class LLMEngine:
                         if not self._dispatch_window(speculative=True):
                             # _ensure_decode_blocks preempted: cur is dirty
                             blocked = "dirty_cur"
+                    self._at("between")
                 if blocked is None:
                     self.stats["spec_windows"] += 1
                     overlapped = 1
@@ -1374,10 +1499,12 @@ class LLMEngine:
         with tracing.phase("engine.admit", ph):
             self._admit()
             self._advance_chunked_prefills()
+        self._at("between")
         self._flush_prefills()
         if self._inflight is None:
             with tracing.phase("engine.dispatch", ph):
                 dispatched = self._dispatch_window()
+            self._at("between")
             if dispatched:
                 worked = True
                 if not self.overlap:
@@ -1388,29 +1515,45 @@ class LLMEngine:
         # flush and must still appear in the step ring.
         worked = worked or any(moved.values())
         if worked:
-            pc = self.prefix_cache
-            self.recorder.record_step({
-                "ts": time.time(),
-                "active": self.active_count(),
-                "waiting": len(self.waiting),
-                "kv_blocks_free": self.alloc.available,
-                "kv_utilization": 1.0 - self.alloc.available
-                / max(1, self.pcfg.usable_blocks),
-                "tokens": moved["tokens"],
-                "prefills": moved["prefills"],
-                "preemptions": moved["preemptions"],
-                "admitted": moved["admitted"],
-                "chunks": moved["prefill_chunks"],  # chunk-program calls, and the
-                "segments": moved["prefill_segments"],  # suffixes or chunks in them
-                "prefix_hit_tokens": moved["prefix_hit_tokens"],
-                "cached_blocks": pc.resident_blocks if pc else 0,
-                # Host cost of this iteration, whole and by phase; the
-                # scheduler's own share is wall less the two waits.
+            with tracing.phase("engine.record", ph):
+                self._at("record")
+                pc = self.prefix_cache
+                rec = {
+                    "ts": time.time(),
+                    "active": self.active_count(),
+                    "waiting": len(self.waiting),
+                    "kv_blocks_free": self.alloc.available,
+                    "kv_utilization": 1.0 - self.alloc.available
+                    / max(1, self.pcfg.usable_blocks),
+                    "tokens": moved["tokens"],
+                    "prefills": moved["prefills"],
+                    "preemptions": moved["preemptions"],
+                    "admitted": moved["admitted"],
+                    "chunks": moved["prefill_chunks"],  # chunk-program calls, and the
+                    "segments": moved["prefill_segments"],  # suffixes or chunks in them
+                    "prefix_hit_tokens": moved["prefix_hit_tokens"],
+                    "cached_blocks": pc.resident_blocks if pc else 0,
+                    "overlapped": overlapped,
+                }
+                self._maybe_flush_metrics()
+        self._at("between")
+        if worked:
+            st = self._starved
+            # Host cost of this iteration, whole and by phase (the six add up
+            # to it); the scheduler's own share is wall less the two waits,
+            # and ``starved_ms`` the part of that with nothing queued.
+            rec.update({
                 "wall_ms": (time.perf_counter() - t_step) * 1e3,
-                **{f"{name}_ms": ph.get("engine." + name, 0.0) for name in _PHASES},
-                "overlapped": overlapped,
+                **{field: ph.get(span, 0.0) for field, span in _STEP_MS},
+                "starved_ms": sum(st.values()) / 1e3,
+                "starved_admit_ms": sum(
+                    us for where, us in st.items() if where.startswith("admit_")) / 1e3,
+                "starved_dispatch_ms": sum(
+                    us for where, us in st.items() if where.startswith("dispatch_")) / 1e3,
             })
-            self._maybe_flush_metrics()
+            self.recorder.record_step(rec)
+        self._settle(unloaded=not worked)
+        self._idle = not worked
         return worked
 
     # ------------------------------------------------------------------
@@ -1468,6 +1611,12 @@ class LLMEngine:
                 if delta:  # four fixed reasons, not an open set of series
                     m.engine_overlap_blocked.inc(  # ray-tpu: lint-ignore[RTL004]
                         delta, {**t, "reason": why})
+            for where in _STARVED:
+                key = "starved_us_" + where
+                delta = s[key] - prev.get(key, 0)
+                if delta:  # eleven fixed places
+                    m.engine_device_starved.inc(  # ray-tpu: lint-ignore[RTL004]
+                        delta / 1e6, {**t, "where": where})
             self._flushed_stats = s
             m.engine_active.set(self.active_count(), t)
             m.engine_waiting.set(len(self.waiting), t)
@@ -1544,6 +1693,13 @@ class LLMEngine:
                 / max(1, self.stats["decode_blocks_table"]),
                 "h2d_ships": self.stats["h2d_ships"],
                 "h2d_skips": self.stats["h2d_skips"],
+                # Of the time since the engine was built, the share the
+                # device had nothing queued while there was work for it, and
+                # where the scheduler thread was meanwhile.
+                "device_starved_pct": 100.0 * self.stats["starved_us"]
+                / max(1, time.perf_counter_ns() // 1000 - self._born_us),
+                "starved_us": {where: self.stats["starved_us_" + where]
+                               for where in _STARVED},
             },
         )
         try:

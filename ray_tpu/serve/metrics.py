@@ -217,6 +217,14 @@ class _ServeMetrics:
             "(idle, admission, dirty_cur, finishing)",
             dr + ("reason",),
         )
+        self.engine_device_starved = Counter(
+            "serve_engine_device_starved_seconds_total",
+            "Seconds the device had nothing queued while the engine had work "
+            "for it, by where the scheduler thread was (admit_plan, admit_build, "
+            "admit_key, admit_launch, dispatch_blocks, dispatch_key, "
+            "dispatch_ship, dispatch_launch, emit, record, between)",
+            dr + ("where",),
+        )
 
 
 def serve_metrics() -> _ServeMetrics:

@@ -57,7 +57,10 @@ _storm_threshold = 5
 _storm_window_s = 60.0
 _MAX_TRACKED_FUNCTIONS = 256
 
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+) with global shapes and types (.*?)\.?\s*(?:Argument mapping|$)")
+# jax >= 0.4.30 logs the wrapper too, "Compiling jit(fn) with ...": callers
+# (``maybe_bucket(name, n)``, the storm records) know the function's bare name.
+_COMPILING_RE = re.compile(
+    r"^Compiling (?:\w+\()?([^\s)]+)\)? with global shapes and types (.*?)\.?\s*(?:Argument mapping|$)")
 _BACKEND_COMPILE = "backend_compile"
 
 

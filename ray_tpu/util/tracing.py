@@ -15,19 +15,30 @@ context attach).
 """
 from __future__ import annotations
 
+import atexit
 import contextlib
 import json
 import os
 import sys
 import threading
 import time
-import uuid
 from typing import Any, Dict, List, Optional
 
 _state = threading.local()
 _enabled = False
 _sink_path: Optional[str] = None
 _sink_lock = threading.Lock()
+# Spans wait here, ``(thread name, record)`` each, and are encoded and reach
+# the file in batches: when ``_FLUSH_COUNT`` have gathered, when the flusher
+# thread finds some ``_FLUSH_AGE_S`` old, when ``collect_spans`` reads this
+# process's own file, on ``disable_tracing`` and at exit (a process that
+# leaves by ``os._exit`` loses what the last ``_FLUSH_AGE_S`` gathered).
+# Opening the file for every span cost a ``phase`` 31-38 us on tmpfs and
+# 270 on a disk against 1.3 without the sink; held back it costs 6.
+_buffer: List[tuple] = []
+_FLUSH_COUNT = 256
+_FLUSH_AGE_S = 0.5
+_flusher_stop: Optional[threading.Event] = None
 # Sink bound (single rotation): when the JSONL file would exceed the cap
 # it is renamed to <path>.1 (overwriting any previous rotation) and a
 # fresh file starts — long RAY_TPU_TRACE=1 runs keep at most 2x the cap
@@ -57,7 +68,7 @@ def maybe_enable_from_env() -> bool:
 def enable_tracing(session_dir: Optional[str] = None):
     """Turn on span recording in this process (reference:
     ``ray.init(_tracing_startup_hook=...)`` opt-in)."""
-    global _enabled, _sink_path, _sink_bytes, _max_sink_bytes
+    global _enabled, _sink_path, _sink_bytes, _max_sink_bytes, _flusher_stop
     _enabled = True
     if session_dir is None:
         from ray_tpu.core import api
@@ -77,16 +88,27 @@ def enable_tracing(session_dir: Optional[str] = None):
         except OSError:
             _sink_bytes = 0
         _named_tids.clear()
+        if _flusher_stop is None:
+            # A process that wrote a span and fell idle still hands it over.
+            _flusher_stop = threading.Event()
+            threading.Thread(target=_flush_loop, args=(_flusher_stop,), daemon=True,
+                             name="trace-sink").start()
+            atexit.register(flush)
 
 
 def disable_tracing():
     """Stop span recording in this process (tests)."""
-    global _enabled, _sink_path, _sink_bytes
+    global _enabled, _sink_path, _sink_bytes, _flusher_stop
     _enabled = False
+    flush()
     with _sink_lock:
         _sink_path = None
         _sink_bytes = 0
         _named_tids.clear()
+        if _flusher_stop is not None:
+            _flusher_stop.set()
+            _flusher_stop = None
+            atexit.unregister(flush)
 
 
 def tracing_enabled() -> bool:
@@ -120,48 +142,68 @@ def _meta_event(name: str, tid: int, value: str) -> Dict[str, Any]:
 
 
 def _write(rec: Dict[str, Any]):
-    global _sink_bytes
     if _sink_path is None:
         return
-    lines = []
-    tid = rec.get("tid")
-    try:
-        with _sink_lock:
-            # Encoded bytes, not str length: the cap must track the real
-            # file size even for multi-byte span names/args.
-            line = (json.dumps(rec) + "\n").encode("utf-8")
-            if _sink_bytes + len(line) > _max_sink_bytes and _sink_bytes > 0:
-                # Single rotation: the previous half replaces any older
-                # .1 file, so disk use is bounded at ~2x the cap.
-                os.replace(_sink_path, _sink_path + ".1")
-                _sink_bytes = 0
-                _named_tids.clear()
-            if not _named_tids:
-                lines.append(
-                    (json.dumps(_meta_event("process_name", 0, _process_name()))
-                     + "\n").encode("utf-8")
-                )
-                _named_tids.add(0)
-            if tid is not None and tid not in _named_tids:
-                _named_tids.add(tid)
-                lines.append(
-                    (json.dumps(
-                        _meta_event(
-                            "thread_name", tid, threading.current_thread().name
-                        )
-                    ) + "\n").encode("utf-8")
-                )
-            lines.append(line)
-            with open(_sink_path, "ab") as f:
-                for ln in lines:
-                    f.write(ln)
-                    _sink_bytes += len(ln)
-    except (OSError, ValueError):
-        # Telemetry must never take down the traced path: a full disk or
-        # removed session dir silently drops spans (the sink is
-        # best-effort by design; spans also close inside engine pump
-        # threads and request finally blocks).
-        pass
+    entry = (threading.current_thread().name, rec)
+    with _sink_lock:
+        _buffer.append(entry)
+        full = len(_buffer) >= _FLUSH_COUNT
+    if full:
+        flush()
+
+
+def _flush_loop(stop: threading.Event):
+    while not stop.wait(_FLUSH_AGE_S):
+        flush()
+
+
+def flush():
+    """Write the buffered spans to this process's sink file, rotating it
+    and naming its rows as they land."""
+    global _sink_bytes
+    with _sink_lock:
+        waiting, _buffer[:] = list(_buffer), []
+        if not waiting or _sink_path is None:
+            return
+        try:
+            f = open(_sink_path, "ab")
+            try:
+                for thread_name, rec in waiting:
+                    # Encoded bytes, not str length: the cap must track the real
+                    # file size even for multi-byte span names/args.
+                    line = (json.dumps(rec) + "\n").encode("utf-8")
+                    tid = rec.get("tid")
+                    if _sink_bytes + len(line) > _max_sink_bytes and _sink_bytes > 0:
+                        # Single rotation: the previous half replaces any older
+                        # .1 file, so disk use is bounded at ~2x the cap.
+                        f.close()
+                        os.replace(_sink_path, _sink_path + ".1")
+                        _sink_bytes = 0
+                        _named_tids.clear()
+                        f = open(_sink_path, "ab")
+                    out = []
+                    if not _named_tids:
+                        out.append(_meta_event("process_name", 0, _process_name()))
+                        _named_tids.add(0)
+                    if tid is not None and tid not in _named_tids:
+                        _named_tids.add(tid)
+                        out.append(_meta_event("thread_name", tid, thread_name))
+                    for ln in [(json.dumps(m) + "\n").encode("utf-8") for m in out] + [line]:
+                        f.write(ln)
+                        _sink_bytes += len(ln)
+            finally:
+                f.close()
+        except (OSError, ValueError):
+            # Telemetry must never take down the traced path: a full disk or
+            # removed session dir silently drops spans (the sink is
+            # best-effort by design; spans also close inside engine pump
+            # threads and request finally blocks).
+            pass
+
+
+def _new_id() -> str:
+    """64 random bits as hex (a fifth of ``uuid.uuid4().hex[:16]``'s cost)."""
+    return os.urandom(8).hex()
 
 
 def current_context() -> Optional[Dict[str, str]]:
@@ -209,8 +251,8 @@ def start_span(name: str, attributes: Optional[Dict[str, Any]] = None):
         return
     parent = getattr(_state, "span", None)
     span = {
-        "trace_id": parent["trace_id"] if parent else uuid.uuid4().hex[:16],
-        "span_id": uuid.uuid4().hex[:16],
+        "trace_id": parent["trace_id"] if parent else _new_id(),
+        "span_id": _new_id(),
         "parent_id": parent["span_id"] if parent else None,
         "name": name,
     }
@@ -252,8 +294,8 @@ def record_span(
     handler thread — where the ambient thread-local parent can't flow."""
     if not _enabled:
         return
-    span_id = uuid.uuid4().hex[:16]
-    trace_id = ctx["trace_id"] if ctx else uuid.uuid4().hex[:16]
+    span_id = _new_id()
+    trace_id = ctx["trace_id"] if ctx else _new_id()
     parent_id = ctx["parent_id"] if ctx else None
     _write(
         {
@@ -300,10 +342,11 @@ class phase:
     phase the host was in; with or without a trace running it costs
     about a microsecond; (b) adds the elapsed ``time.perf_counter()``
     milliseconds to ``into[name]``; (c) with ``RAY_TPU_TRACE`` on, also
-    writes the span to the JSONL sink, where ``ray-tpu timeline`` finds
-    it. Make phases siblings: a reader that labels a gap with the span
-    covering most of it would name an enclosing span every time. Never
-    one per token."""
+    hands the span to the JSONL sink, where ``ray-tpu timeline`` finds
+    it. Make phases siblings, or parts of one nested in it: a reader that
+    labels a gap with the span covering most of it (of equals the
+    shortest) names the part a gap lies in, and would name a span around
+    the whole loop every time. Never one per token."""
 
     __slots__ = ("name", "into", "_ann", "_t0", "_wall0")
 
@@ -330,25 +373,10 @@ class phase:
         return False
 
 
-def trace_span(name: Optional[str] = None):
-    """Decorator form of ``start_span``."""
-
-    def deco(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with start_span(name or fn.__qualname__):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
-
-
 def collect_spans(session_dir: str) -> List[dict]:
     """Merge every process's span file (rotated ``.jsonl.1`` halves
     included) into one Chrome-trace event list."""
+    flush()  # what this process still holds
     events: List[dict] = []
     logs = os.path.join(session_dir, "logs")
     if not os.path.isdir(logs):

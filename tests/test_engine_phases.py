@@ -1,8 +1,11 @@
 """The engine's scheduler thread on the profiler's clock.
 
-``tracing.phase`` is the one primitive; ``LLMEngine.step`` is five sibling
+``tracing.phase`` is the one primitive; ``LLMEngine.step`` is six sibling
 phases (``engine.harvest_wait``, ``engine.emit``, ``engine.admit``,
-``engine.prefill_wait``, ``engine.dispatch``) with no span around them;
+``engine.prefill_wait``, ``engine.dispatch``, ``engine.record``) with no span
+around them, and the parts of ``admit`` and ``dispatch`` nested in those two;
+the time the device has nothing queued is counted by where the scheduler
+thread was (``stats["starved_us_*"]``), apart from idleness for want of load;
 every window found in flight is either overlapped (``spec_windows``) or
 counted under the reason it was not (``spec_blocked_*``); the decode
 program's layer carries ``paged.*`` scopes. The scheduler owns the slot
@@ -12,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +30,8 @@ from ray_tpu.util import tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASE_FIELDS = [f"{name}_ms" for name in llm_engine._PHASES]
+SUB_FIELDS = [f"{sub}_ms" for sub in llm_engine._SUB_PHASES]
+BUCKETS = ["starved_us_" + where for where in llm_engine._STARVED]
 BLOCKED = ["spec_blocked_" + why for why in llm_engine._SPEC_BLOCKED]
 
 
@@ -70,6 +76,42 @@ def test_phase_span_reaches_the_jsonl_sink_under_ray_tpu_trace(tmp_path, monkeyp
     assert spans[0]["dur"] >= 0 and into["engine.admit"] >= 0.0
 
 
+def _lines(path):
+    return sum(1 for _ in open(path)) if os.path.exists(path) else 0
+
+
+@pytest.mark.parametrize("by", ["count", "age"])
+def test_the_sink_keeps_spans_in_memory_and_writes_them_in_batches(tmp_path, monkeypatch, by):
+    """Not a file opened for every span: the writer hands over a full batch
+    itself, and a flusher thread what a process that fell idle still holds."""
+    monkeypatch.setattr(tracing, "_FLUSH_AGE_S", 3600.0 if by == "count" else 0.05)
+    tracing.enable_tracing(str(tmp_path))
+    try:
+        path = tracing._sink_path
+        n = tracing._FLUSH_COUNT - 1 if by == "count" else 3
+        for _ in range(n):
+            with tracing.phase("engine.x", {}):
+                pass
+        if by == "count":
+            assert _lines(path) == 0  # held back
+            with tracing.phase("engine.x", {}):
+                pass
+        else:
+            deadline = time.time() + 10
+            while time.time() < deadline and not _lines(path):
+                time.sleep(0.02)
+        # the batch, with the two rows' names before it
+        assert _lines(path) == (n + 1 if by == "count" else n) + 2
+        with tracing.phase("engine.y", {}):
+            pass
+        # reading this process's own spans hands over what it still holds
+        names = [e["name"] for e in tracing.collect_spans(str(tmp_path)) if e.get("ph") == "X"]
+        assert names.count("engine.x") >= n and names[-1] == "engine.y"
+    finally:
+        tracing.disable_tracing()
+    assert tracing._flusher_stop is None and not tracing._buffer
+
+
 def test_phase_without_jax_still_times(monkeypatch):
     # ``import jax.profiler`` raises ImportError while these are None.
     monkeypatch.setitem(sys.modules, "jax", None)
@@ -106,17 +148,151 @@ def test_every_recorded_step_carries_wall_and_phase_times(tiny_model, overlap):
     assert all(len(o) == 7 for o in outs)
     steps = list(eng.recorder.steps)
     assert steps
+    assert len(PHASE_FIELDS) == 6 and len(SUB_FIELDS) == 8
     for rec in steps:
         assert rec["wall_ms"] >= 0.0 and rec["overlapped"] in (0, 1)
-        assert all(rec[f] >= 0.0 for f in PHASE_FIELDS), rec
-        # siblings: none is counted inside another
-        assert sum(rec[f] for f in PHASE_FIELDS) <= rec["wall_ms"] + 1.0, rec
+        assert all(rec[f] >= 0.0 for f in PHASE_FIELDS + SUB_FIELDS), rec
+        # siblings: none is counted inside another, and the six cover the step
+        assert sum(rec[f] for f in PHASE_FIELDS) <= rec["wall_ms"], rec
+        # the parts lie inside their parent, which keeps its whole time
+        for parent in ("admit", "dispatch"):
+            parts = [f for f in SUB_FIELDS if f.startswith(parent + "_")]
+            assert len(parts) == 4 and sum(rec[f] for f in parts) <= rec[parent + "_ms"], rec
+        assert rec["record_ms"] > 0.0
+        assert 0.0 <= rec["starved_admit_ms"] + rec["starved_dispatch_ms"] <= rec["starved_ms"]
     assert any(r["dispatch_ms"] > 0 for r in steps)
+    assert all(any(r[f] > 0 for r in steps) for f in SUB_FIELDS)
     assert any(r["harvest_wait_ms"] > 0 for r in steps)
     assert any(r["prefill_wait_ms"] > 0 for r in steps)
     assert sum(r["overlapped"] for r in steps) == eng.stats["spec_windows"]
     if not overlap:
         assert eng.stats["spec_windows"] == 0 and not any(eng.stats[k] for k in BLOCKED)
+
+
+# ---------------------------------------------------------------------------
+# The account of the time the device waits for the host
+# ---------------------------------------------------------------------------
+def _starved(eng):
+    return {k: eng.stats[k] for k in ["starved_us", "unloaded_us"] + BUCKETS}
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_the_buckets_sum_to_starved_us_after_every_step(tiny_model, overlap):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    for i in range(6):
+        eng.add_request([i + 1, i + 2, i + 3], 9)
+    in_steps = 0.0
+    while eng.active_count() or eng.waiting:
+        eng.step()
+        s = eng.stats
+        assert sum(s[k] for k in BUCKETS) == s["starved_us"]
+        assert all(isinstance(s[k], int) and s[k] >= 0 for k in BUCKETS + ["unloaded_us"])
+    for rec in eng.recorder.steps:
+        # only outside the two waits can the host know the queue empty
+        assert rec["starved_ms"] <= (
+            rec["wall_ms"] - rec["harvest_wait_ms"] - rec["prefill_wait_ms"] + 1.0), rec
+        in_steps += rec["starved_ms"]
+    # what lies between two steps is no step's; ``between`` is that and the
+    # moments of a step outside its phases
+    total, between = eng.stats["starved_us"], eng.stats["starved_us_between"]
+    assert total - between - 1.0 <= in_steps * 1e3 <= total + 1.0
+    assert eng.stats["starved_us"] > 0 and eng.stats["starved_us_dispatch_launch"] > 0
+    snap = eng.report_state()["overlap"]
+    assert snap["starved_us"] == {w: eng.stats["starved_us_" + w] for w in llm_engine._STARVED}
+    assert 0.0 < snap["device_starved_pct"] <= 100.0
+
+
+def test_a_speculated_step_adds_nothing_between_its_harvest_and_its_next_launch(tiny_model):
+    """The window dispatched before the harvest is newer than the one read:
+    the queue is not empty, whatever the host does meanwhile."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=True)
+    eng.add_request([5, 9, 2, 11], 40)
+    speculated = 0
+    while eng.active_count() or eng.waiting:
+        before = eng.stats["starved_us"]
+        eng.step()
+        rec = eng.recorder.steps[-1]
+        if rec["overlapped"]:
+            speculated += 1
+            time.sleep(0.002)  # host time the device does not wait for
+            assert rec["starved_ms"] == 0.0 and eng._empty_since is None
+            assert eng.stats["starved_us"] == before
+    assert speculated >= 5 and speculated == eng.stats["spec_windows"]
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_an_idle_engine_is_unloaded_not_starved(tiny_model, overlap):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    time.sleep(0.01)
+    assert eng.step() is False  # never had work: idle since it was built
+    assert eng.stats["unloaded_us"] >= 10_000 and eng.stats["starved_us"] == 0
+    eng.generate_batch([[1, 2, 3]], max_new_tokens=5)
+    assert eng.step() is False  # the verdict: no work left
+    before = _starved(eng)
+    assert before["starved_us"] > 0
+    for _ in range(2):
+        time.sleep(0.02)
+        assert eng.step() is False
+    after = _starved(eng)
+    assert after["unloaded_us"] - before["unloaded_us"] >= 40_000
+    assert {k: after[k] for k in after if k != "unloaded_us"} == {
+        k: before[k] for k in before if k != "unloaded_us"}
+    # work again: the wait for the host is counted again, the idleness is not
+    eng.generate_batch([[4, 5, 6]], max_new_tokens=5)
+    assert eng.stats["starved_us"] > after["starved_us"]
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_slow_launch_is_counted_in_dispatch_launch_alone(tiny_model, overlap):
+    """A launch ends starvation when the call RETURNS: its own host time is
+    part of what the device waited for."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    eng.add_request([5, 9, 2, 11], 40)
+    eng.step()
+    eng.step()
+    decode = eng._decode
+
+    def slow(*args):
+        time.sleep(0.02)
+        return decode(*args)
+
+    eng._decode = slow
+    eng._dirty.add("cur")  # with overlap: no speculation, so the harvest comes first
+    before = _starved(eng)
+    eng.step()
+    eng._decode = decode
+    moved = {k: v - before[k] for k, v in _starved(eng).items()}
+    assert moved["starved_us_dispatch_launch"] >= 20_000
+    others = sum(moved[k] for k in BUCKETS if k != "starved_us_dispatch_launch")
+    assert others < 20_000 and moved["unloaded_us"] == 0
+    assert moved["starved_us"] == moved["starved_us_dispatch_launch"] + others
+    rec = eng.recorder.steps[-1]
+    assert rec["starved_dispatch_ms"] >= 20.0 and rec["dispatch_launch_ms"] >= 20.0
+    assert rec["starved_admit_ms"] < 20.0
+    while eng.active_count():
+        eng.step()
+
+
+def test_starved_seconds_reach_the_registry_counter(tiny_model):
+    from ray_tpu.serve.metrics import serve_metrics
+
+    cfg, params = tiny_model
+    eng = _engine(cfg, params)
+    eng.metrics_tags = {"deployment": "starved", "replica": "r0"}
+    eng.generate_batch([[1, 2, 3], [4, 5, 6]], max_new_tokens=5)
+    eng._maybe_flush_metrics(force=True)
+    counter = serve_metrics().engine_device_starved
+    assert counter.name == "serve_engine_device_starved_seconds_total"
+    mine = {dict(tags)["where"]: value for _n, _t, _d, tags, value in counter._drain()
+            if dict(tags)["deployment"] == "starved"}
+    assert set(mine) <= set(llm_engine._STARVED) and len(llm_engine._STARVED) == 11
+    assert mine == pytest.approx({w: eng.stats["starved_us_" + w] / 1e6
+                                  for w in llm_engine._STARVED if eng.stats["starved_us_" + w]})
+    assert mine["dispatch_launch"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -304,13 +480,14 @@ finally:
     stopped = profiling.device_trace_control("stop")
 assert stopped["ok"], stopped
 [path] = glob.glob(os.path.join(stopped["dir"], "plugins", "profile", "*", "*.xplane.pb"))
-names = {ev.name for plane in ProfileData.from_file(path).planes
-         if plane.name.startswith("/host:") for line in plane.lines for ev in line.events}
-print(json.dumps({"engine": sorted(n for n in names if n.startswith("engine."))}))
+spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+         for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+         for line in plane.lines for ev in line.events if ev.name.startswith("engine.")]
+print(json.dumps({"engine": spans}))
 """
 
 
-def test_traced_engine_puts_five_sibling_phases_on_the_host_plane(tmp_path):
+def test_traced_engine_puts_six_sibling_phases_and_their_parts_on_the_host_plane(tmp_path):
     # A fresh interpreter, as test_device_trace_control_rejects_double_start:
     # stop_trace dumps every computation the process has ever run.
     proc = subprocess.run(
@@ -322,8 +499,22 @@ def test_traced_engine_puts_five_sibling_phases_on_the_host_plane(tmp_path):
     verdict = json.loads(proc.stdout.strip().splitlines()[-1])
     if "skip" in verdict:
         pytest.skip(f"backend can't trace: {verdict['skip']}")
-    # the five, and nothing around them
-    assert verdict["engine"] == sorted("engine." + name for name in llm_engine._PHASES)
+    spans = verdict["engine"]
+    tops = {"engine." + name for name in llm_engine._PHASES}
+    parts = {"engine." + sub.replace("_", ".", 1) for sub in llm_engine._SUB_PHASES}
+    # the six with the eight inside two of them, and nothing around them
+    assert {name for name, _a, _b in spans} == tops | parts and len(parts) == 8
+
+    def inside(a, b, outer):
+        return [n for n, oa, ob in outer if oa <= a and b <= ob]
+
+    top_spans = [s for s in spans if s[0] in tops]
+    part_spans = [s for s in spans if s[0] in parts]
+    for name, a, b in top_spans:  # siblings: none inside another
+        assert inside(a, b, top_spans) == [name], (name, a, b)
+    for name, a, b in part_spans:  # in its own parent, and in no other part
+        assert inside(a, b, top_spans) == [name.rsplit(".", 1)[0]], (name, a, b)
+        assert inside(a, b, part_spans) == [name], (name, a, b)
 
 
 # ---------------------------------------------------------------------------

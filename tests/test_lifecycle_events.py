@@ -364,6 +364,7 @@ def test_span_sink_rotation(tmp_path, monkeypatch):
         for _ in range(100):
             with tracing.start_span("spin"):
                 pass
+        tracing.flush()  # spans reach the file in batches; the cap holds line by line
         logs = os.listdir(os.path.join(str(tmp_path), "logs"))
         spans = [f for f in logs if f.startswith("spans-")]
         assert any(f.endswith(".jsonl.1") for f in spans)

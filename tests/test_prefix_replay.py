@@ -136,6 +136,7 @@ def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
     for it in range(warmup + iterations):
         if it == warmup:
             base, progs.calls = dict(eng.stats), []
+            eng.recorder.steps.clear()
         eng.step()
         for c in clients:
             if c.req.remaining <= 0:
@@ -160,6 +161,7 @@ def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
         "preemptions": d["preemptions"],
         "widths": list(eng._widths),
         "stats": d,
+        "steps": list(eng.recorder.steps),  # the last 256 at most
     }
 
 
@@ -185,7 +187,7 @@ def test_sessions_replay_hit_share_and_wide_suffixes(seed, phase, hit_floor, wid
     from chipbench.drivers.serve import warm_requests
 
     got = replay(seed, monkeypatch=monkeypatch, **phase)
-    print(json.dumps({k: v for k, v in got.items() if k != "stats"}), got["stats"])
+    print(json.dumps({k: v for k, v in got.items() if k not in ("stats", "steps")}), got["stats"])
     assert got["preemptions"] == 0
     assert got["hit_pct"] >= hit_floor, got
     assert got["calls_per_iteration"] <= 1.5, got
@@ -206,3 +208,27 @@ def test_sessions_replay_hit_share_and_wide_suffixes(seed, phase, hit_floor, wid
     # that nobody waits for.
     assert got["stats"]["prefix_evictions_wanted"] == 0
     assert got["stats"]["prefix_evictions_spared"] > 0
+
+
+def test_sessions_replay_the_starvation_account_closes(monkeypatch):
+    """Over the cell's own window every microsecond the device had nothing
+    queued lies in exactly one bucket, all of it is the host's doing (the
+    closed loop never leaves the engine without work), and a step's share
+    fits inside its wall time less the two waits. The stand-ins return at
+    once, so this counts host time only: no device metric."""
+    got = replay(3900000022, monkeypatch=monkeypatch, **WINDOW)
+    d, steps = got["stats"], got["steps"]
+    buckets = ["starved_us_" + where for where in llm_engine._STARVED]
+    assert sum(d[k] for k in buckets) == d["starved_us"] > 0
+    assert d["unloaded_us"] == 0
+    assert len(steps) == WINDOW["iterations"]
+    in_steps = sum(r["starved_ms"] for r in steps) * 1e3
+    assert d["starved_us"] - d["starved_us_between"] - 1.0 <= in_steps <= d["starved_us"] + 1.0
+    for r in steps:
+        assert r["starved_ms"] <= r["wall_ms"] - r["harvest_wait_ms"] - r["prefill_wait_ms"] + 1.0, r
+        assert r["starved_admit_ms"] + r["starved_dispatch_ms"] <= r["starved_ms"] + 1e-9
+        assert sum(r[f"{name}_ms"] for name in llm_engine._PHASES) <= r["wall_ms"]
+    # every launch of the window was made on an empty queue or behind a
+    # speculated window; with 123 of 128 windows not overlapped most were
+    assert d["starved_us_dispatch_launch"] > 0 and d["starved_us_admit_launch"] > 0
+    assert d["starved_us_admit_plan"] > 0 and d["starved_us_admit_build"] > 0
