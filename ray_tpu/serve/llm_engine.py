@@ -17,7 +17,7 @@ Host/device split:
   generated prefix folded into the prompt — recompute-on-resume, the
   vLLM default), per-request streaming queues.
 
-Iteration-level perf suite (all opt-in, see ``__init__``):
+Iteration-level perf suite (opt-in unless it says otherwise, see ``__init__``):
 - **Prefix-aware KV reuse** (``enable_prefix_cache``): a request's full
   blocks are published to a refcounted exact-match index, the prompt's
   as its prefill ends and those its decode steps filled as its slot is
@@ -35,6 +35,10 @@ Iteration-level perf suite (all opt-in, see ``__init__``):
   host consumes/schedules while the device keeps stepping. Decode
   inputs live on device and only scheduler-dirtied arrays are re-shipped
   (``_ship``).
+- **The window behind the prefill** (always): a prefill program puts the
+  token it sampled into the device's ``cur``, so the step dispatches its
+  decode window before the host has read that token and the dispatch's
+  host work runs while the device prefills (``step``).
 
 Threading: ``step()`` is single-threaded; ``start()`` runs it in a pump
 thread so serve replicas can stream from concurrent handler threads
@@ -97,7 +101,7 @@ _STARVED = _SUB_PHASES + ("emit", "record", "between")
 _SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
 # The counters a recorded step carries as what the iteration added to them.
 _STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks",
-                "prefill_segments", "prefix_hit_tokens")
+                "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill")
 # The width under which a chunk call's time is the read of the weights and no
 # longer its tokens' arithmetic: two FLOPs and two bytes a parameter a token
 # put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e, where the
@@ -557,6 +561,7 @@ class LLMEngine:
                       "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
                       "prefix_published_blocks": 0, "prefill_segments": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
+                      "windows_behind_prefill": 0, "prefill_flushed_first": 0,
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED},
                       "starved_us": 0, "unloaded_us": 0,
                       **{f"starved_us_{where}": 0 for where in _STARVED}}
@@ -638,17 +643,26 @@ class LLMEngine:
             # dispatch re-upload nothing from the host.
             return seq, seq[-1], lens + window, cache
 
-        def _prefill(params, tokens, cache, block_row, real_len, temp, key):
-            return prefill_and_sample(
+        # A prefill program also puts what it sampled into the device's
+        # ``cur``, at the slot it sampled it for, so the decode window that
+        # follows needs nothing from the host (``step``). An index past the
+        # last slot is dropped: warm-up, and a segment that does not end its
+        # prompt. The slot rides an argument the call had (every host
+        # argument is a transfer of its own, paid while the device waits).
+        def _prefill(params, tokens, cache, block_row, len_slot, temp, key, cur):
+            real_len, slot = len_slot
+            tok, cache = prefill_and_sample(
                 params, cfg, tokens, cache, block_row, bs, real_len, temp, key
             )
+            return tok, cache, cur.at[slot].set(tok, mode="drop")
 
-        def _chunk(params, tokens, cache, table_rows, chunk_row, starts, last_idx,
-                   temps, key):
-            return prefill_chunk_and_sample(
+        def _chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+            starts, last_idx, slot_of = per_tile
+            toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts,
                 last_idx, temps, key,
             )
+            return toks, cache, cur.at[slot_of].set(toks, mode="drop")
 
         sds = jax.ShapeDtypeStruct
         b, W = p.max_batch, p.max_blocks_per_seq
@@ -681,7 +695,7 @@ class LLMEngine:
             params = jax.device_put(params, params_fmt)
         prefill = jax.jit(
             _prefill, donate_argnums=(2,),
-            in_shardings=(params_fmt, None, None, None, None, None, None),
+            in_shardings=(params_fmt,) + (None,) * 7,
         )
         chunk = jax.jit(
             _chunk, donate_argnums=(2,),
@@ -700,11 +714,12 @@ class LLMEngine:
         self.key, sub = jax.random.split(self.key)
         n = 0
         for S in self._widths:
-            _tok, self.cache = self._prefill(
+            _tok, self.cache, self._dev["cur"] = self._prefill(
                 self.params, jax.numpy.asarray(np.zeros((1, S), np.int32)),
                 self.cache,
                 jax.numpy.asarray(np.full(S // bs, TRASH_BLOCK, np.int32)),
-                np.int32(1), np.float32(0.0), sub,
+                np.asarray([1, self.pcfg.max_batch], np.int32),  # no slot's
+                np.float32(0.0), sub, self._device_cur(),
             )
             n += 1
         if self.prefill_chunk:
@@ -718,8 +733,8 @@ class LLMEngine:
             n += 1
         # Decode window: already compiled (AOT) — this is its first
         # execution, so a program that does not fit fails at build time.
-        seq, _cur, _lens, self.cache = self._decode(
-            self.params, self._hand_over(self.cur), self.cache,
+        seq, self._dev["cur"], _lens, self.cache = self._decode(
+            self.params, self._device_cur(), self.cache,
             self._hand_over(self.tables), self._hand_over(self.lens),
             self._hand_over(self.temps), sub,
         )
@@ -946,11 +961,16 @@ class LLMEngine:
         self.stats["preemptions"] += 1
         return True
 
-    def _ensure_decode_blocks(self) -> None:
+    def _ensure_decode_blocks(self) -> bool:
         """Every active slot must own the blocks the coming window's
         writes land in (positions lens .. lens+window-1 — the table is
         fixed for the whole device call); allocate on demand, preempting
-        if the pool is exhausted."""
+        if the pool is exhausted. False if a slot is left without: there
+        is nobody to preempt, or first tokens are still unread. A victim
+        may be the slot one of them belongs to (the youngest goes first),
+        and giving a slot back dirties ``cur``, whose wholesale ship would
+        lose what the prefill programs put into the device's: the host
+        reads them first (``step``)."""
         bs = self.pcfg.block_size
         for i in range(len(self.slots)):
             while self.slots[i] is not None and i not in self._prefilling:
@@ -965,8 +985,9 @@ class LLMEngine:
                     continue
                 # Pool exhausted: evict the youngest slot (possibly i
                 # itself, in which case the outer while sees it freed).
-                if not self._preempt_one():
-                    return  # nothing evictable; retry next step
+                if self._pending_first or not self._preempt_one():
+                    return False  # retry after the flush, or next step
+        return True
 
     def _admit(self):
         """Move waiting requests into free slots while blocks allow; a
@@ -1127,10 +1148,11 @@ class LLMEngine:
         sub = self._split_key("admit")
         with tracing.phase("engine.admit.launch", ph):
             self._at("admit_launch")
-            tok, self.cache = self._prefill(
+            tok, self.cache, self._dev["cur"] = self._prefill(
                 self.params, jax.numpy.asarray(toks), self.cache,
                 jax.numpy.asarray(row),
-                np.int32(plen), np.float32(req.temperature), sub,
+                np.asarray([plen, i], np.int32), np.float32(req.temperature), sub,
+                self._device_cur(),
             )
             self._launched()
         return tok
@@ -1167,8 +1189,12 @@ class LLMEngine:
             toks = np.zeros((1, width), np.int32)
             trows = np.full((n, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
             crow = np.full(width // bs, TRASH_BLOCK, np.int32)
-            starts = np.zeros(n, np.int32)
-            last_idx = np.zeros(n, np.int32)
+            # By tile: its first absolute position; by segment k: the axis
+            # position of its last token, and whose ``cur`` its sampled token
+            # is (no slot's, unless the segment ends its prompt).
+            per_tile = np.zeros((3, n), np.int32)
+            starts, last_idx, slot_of = per_tile
+            slot_of[:] = p.max_batch
             temps = np.zeros(n, np.float32)
             at = 0  # the next free tile's first position on the axis
             for k, (i, req, full, start, end) in enumerate(segs):
@@ -1182,6 +1208,8 @@ class LLMEngine:
                 crow[at // bs:at // bs + len(under)] = under
                 last_idx[k] = at + end - start - 1
                 temps[k] = req.temperature
+                if end == len(full):
+                    slot_of[k] = i
                 at += tiles * tile
         sub = self._split_key("admit")
         with tracing.phase("engine.admit.launch", ph):
@@ -1189,16 +1217,20 @@ class LLMEngine:
             # Built per call and never written again, so the program may read
             # them where they lie: only the slot mirrors, which the scheduler
             # mutates in place, need _hand_over's copy.
-            out, self.cache = self._prefill_chunk_fn(
-                self.params, toks, self.cache, trows, crow, starts, last_idx, temps, sub)
+            out, self.cache, self._dev["cur"] = self._prefill_chunk_fn(
+                self.params, toks, self.cache, trows, crow, per_tile, temps, sub,
+                self._device_cur())
             self._launched()
         return out
 
     def _finish_prefill(self, i: int, req: Request, toks, k):
         """Prompt fully KV-resident: publish the slot to the decode set
-        (tables/lens/temps become decode-visible) and queue the first
-        sampled token, ``toks[k]`` of a program's device output (``k`` is
-        ``()`` for a scalar), for the batched flush."""
+        (tables/lens/temps become decode-visible, and dirty; the program
+        that sampled the first token has put it into the device's ``cur``
+        already) and queue that token, ``toks[k]`` of the program's device
+        output (``k`` is ``()`` for a scalar), for the batched flush, which
+        comes after the window's dispatch: the host reads it to emit it,
+        the window does not wait for the host to."""
         full = req.full_prompt
         blocks = self.slot_blocks[i]
         self.tables[i] = TRASH_BLOCK
@@ -1210,7 +1242,7 @@ class LLMEngine:
             self._register_prefix(full, blocks, len(full))
         # Defer the device→host read: prefill dispatches pipeline without
         # syncing; _flush_prefills fetches every pending first token in
-        # one transfer after the admission loop.
+        # one transfer, behind the decode window's dispatch (``step``).
         self._pending_first.append((i, req, toks, k))
         self._pending_launch = self._launches  # the call that made ``toks``
 
@@ -1237,6 +1269,14 @@ class LLMEngine:
         return new
 
     def _flush_prefills(self):
+        """Read the first tokens the iteration's prefill programs sampled (ONE
+        transfer: the host blocks until the last of them has run) and emit
+        them. ``step`` calls this AFTER it has dispatched the decode window,
+        which took those tokens from the device's ``cur``: what the read
+        returns goes into the host's mirror and leaves it no dirtier, the
+        device holds it already. Only when that dispatch has to preempt,
+        which would dirty the host's ``cur`` between a prefill's launch and
+        the ship, does the flush come first (``_ensure_decode_blocks``)."""
         if not self._pending_first:
             return
         pend, self._pending_first = self._pending_first, []
@@ -1251,8 +1291,7 @@ class LLMEngine:
                 if self.slots[i] is not req:
                     continue  # preempted between prefill and flush
                 tok = int(vals[id(t)][k])
-                self.cur[i] = tok
-                self._dirty.add("cur")
+                self.cur[i] = tok  # as the device's ``cur[i]`` is since the program ran
                 self._emit(i, tok)
         self._at("between")
 
@@ -1285,7 +1324,8 @@ class LLMEngine:
     def _ship(self) -> Dict[str, jax.Array]:
         """Device-resident decode inputs, re-uploading ONLY the mirrors the
         scheduler dirtied since the last dispatch, each as a copy
-        (``_hand_over``)."""
+        (``_hand_over``). A prefill dirties three of the four: its first
+        token reached ``cur`` on the device (``_device_cur``)."""
         for name, host in (("tables", self.tables), ("lens", self.lens),
                            ("temps", self.temps), ("cur", self.cur)):
             if self._dev[name] is None or name in self._dirty:
@@ -1295,6 +1335,24 @@ class LLMEngine:
             else:
                 self.stats["h2d_skips"] += 1
         return self._dev
+
+    def _device_cur(self) -> jax.Array:
+        """The device's ``cur``, for a prefill program to put its first token
+        in and hand on (to the next prefill call of the iteration, then to
+        the decode window). With no window in flight the device's copy is
+        as good as the mirror on every occupied row: the harvest wrote the
+        mirror from that window's own output, a prefill's token reached the
+        device first, and the rows ``_free_slot`` zeroed are idle, whose
+        token no one reads. So the mirror stops being dirty here and is not
+        shipped. Behind a speculated window the device is AHEAD of the
+        mirror: its copy is the one to use, and a dirty mirror stays dirty
+        until the harvest has caught up (``_can_speculate``)."""
+        if self._dev["cur"] is None:  # the first program call of all
+            self._dev["cur"] = self._hand_over(self.cur)
+            self.stats["h2d_ships"] += 1
+        if self._inflight is None:
+            self._dirty.discard("cur")
+        return self._dev["cur"]
 
     def _decode_entries(self) -> List[tuple]:
         """(slot, rid, slot_gen) for every decodable slot — occupied and
@@ -1316,18 +1374,16 @@ class LLMEngine:
         ph = self._phase_ms
         with tracing.phase("engine.dispatch.blocks", ph):
             self._at("dispatch_blocks")
-            self._ensure_decode_blocks()
-            entries = self._decode_entries()
+            entries = self._ensure_decode_blocks() and self._decode_entries()
             if not entries:
                 return False
             if speculative and "cur" in self._dirty:
                 # The host ``cur`` mirror LAGS the in-flight window (its live
                 # rows are window N-1's tokens until the harvest), so a dirty
-                # cur — a prefill flush, or a preemption the _ensure above
-                # just performed — must not be shipped wholesale now: it
-                # would rewind every other slot by one window. Abort the
-                # speculation; the synchronous path re-dispatches after the
-                # harvest has re-synced the mirror.
+                # cur — a preemption the _ensure above just performed — must
+                # not be shipped wholesale now: it would rewind every other
+                # slot by one window. Abort the speculation; the synchronous
+                # path re-dispatches after the harvest has re-synced the mirror.
                 return False
             self.stats["max_active"] = max(self.stats["max_active"], len(entries))
             # Blocks the occupied slots' tokens lie in as the window starts,
@@ -1352,8 +1408,17 @@ class LLMEngine:
         self._dev["lens"] = lens_out
         self.lens[occupied] += self.window
         self.stats["steps"] += 1
+        # Queued behind this iteration's prefill programs, their tokens unread.
+        self.stats["windows_behind_prefill"] += bool(self._pending_first)
         self._inflight = (entries, seq, self._launches)
         return True
+
+    def _dispatch(self, speculative: bool = False) -> bool:
+        """``_dispatch_window`` as the iteration's ``engine.dispatch`` phase."""
+        with tracing.phase("engine.dispatch", self._phase_ms):
+            dispatched = self._dispatch_window(speculative)
+        self._at("between")
+        return dispatched
 
     def _harvest(self) -> bool:
         if self._inflight is None:
@@ -1388,9 +1453,11 @@ class LLMEngine:
         go ahead, else the reason not to (one of ``_SPEC_BLOCKED``):
         ``idle``, nothing decodable; ``admission``, a waiting request
         could use a free slot first (it should join N+1, not N+2);
-        ``dirty_cur``, the host cur lags the in-flight window, so sync
-        first; ``finishing``, a slot's cap-finish inside N is already
-        certain (the speculated window would be pure waste). An
+        ``dirty_cur``, a slot was given back since the last ship and the
+        host cur lags the in-flight window, so sync first (a prefill's
+        flush does not dirty it: its token is on the device before the
+        host reads it); ``finishing``, a slot's cap-finish inside N is
+        already certain (the speculated window would be pure waste). An
         eos-stopped slot can still waste one window — capacity covers it
         (the 2*window-1 overlap margin)."""
         entries = self._decode_entries()
@@ -1451,7 +1518,25 @@ class LLMEngine:
 
     def step(self) -> bool:
         """One scheduler iteration: [speculate] → harvest → admit → page
-        → decode. Returns True if any device work ran (False = idle).
+        → decode → flush. Returns True if any device work ran (False = idle).
+
+        A step that launched a prefill dispatches its decode window BEFORE
+        it reads the prefill's tokens: the programs that sampled them put
+        them into the device's ``cur``, so the window queues behind the
+        prefill and everything the dispatch costs the host (blocks, key,
+        ship, launch) runs while the device prefills, not on a device that
+        the read of the first tokens has just drained. The flush follows,
+        ahead of any harvest of that window, so a request's first token is
+        emitted before its window's. A request that ends AT its first token
+        then has a lane in a window already in flight: the harvest's rid and
+        generation check discards it, and its blocks are freed under that
+        window as a speculated window's are (below). The one case in which
+        the read comes first: the dispatch's own ``_ensure_decode_blocks``
+        has to preempt. Giving a slot back dirties the host's ``cur``, and
+        a wholesale ship of it would lose the device's first tokens (the
+        victim may be the very slot that owns one); so the dispatch gives
+        up, the flush comes first, and the preemption and the ship after
+        it (``prefill_flushed_first``).
 
         With ``overlap`` the device is double-buffered: window N+1 is
         dispatched from N's device-resident outputs BEFORE N's tokens are
@@ -1483,12 +1568,8 @@ class LLMEngine:
                 # Exactly one of spec_windows and the spec_blocked_* counts
                 # goes up for every window found in flight.
                 blocked = self._can_speculate()
-                if blocked is None:
-                    with tracing.phase("engine.dispatch", ph):
-                        if not self._dispatch_window(speculative=True):
-                            # _ensure_decode_blocks preempted: cur is dirty
-                            blocked = "dirty_cur"
-                    self._at("between")
+                if blocked is None and not self._dispatch(speculative=True):
+                    blocked = "dirty_cur"  # _ensure_decode_blocks preempted
                 if blocked is None:
                     self.stats["spec_windows"] += 1
                     overlapped = 1
@@ -1500,15 +1581,18 @@ class LLMEngine:
             self._admit()
             self._advance_chunked_prefills()
         self._at("between")
-        self._flush_prefills()
+        dispatched = False
         if self._inflight is None:
-            with tracing.phase("engine.dispatch", ph):
-                dispatched = self._dispatch_window()
-            self._at("between")
-            if dispatched:
-                worked = True
-                if not self.overlap:
-                    self._harvest()  # classic synchronous window
+            dispatched = self._dispatch()
+            if not dispatched and self._pending_first:
+                self._flush_prefills()  # read first, then preempt and ship
+                dispatched = self._dispatch()
+                self.stats["prefill_flushed_first"] += dispatched
+        self._flush_prefills()
+        if dispatched:
+            worked = True
+            if not self.overlap:
+                self._harvest()  # classic synchronous window
         moved = {k: self.stats[k] - before[k] for k in _STEP_COUNTS}
         # Record even decode-less iterations that did work — e.g. a
         # max_new_tokens=1 request finishes entirely inside the prefill
@@ -1534,6 +1618,7 @@ class LLMEngine:
                     "prefix_hit_tokens": moved["prefix_hit_tokens"],
                     "cached_blocks": pc.resident_blocks if pc else 0,
                     "overlapped": overlapped,
+                    "behind_prefill": moved["windows_behind_prefill"],
                 }
                 self._maybe_flush_metrics()
         self._at("between")
@@ -1589,6 +1674,8 @@ class LLMEngine:
                 ("prefill_segments", m.engine_prefill_segments),
                 ("prefix_published_blocks", m.engine_prefix_published_blocks),
                 ("spec_windows", m.engine_overlap_windows),
+                ("windows_behind_prefill", m.engine_windows_behind_prefill),
+                ("prefill_flushed_first", m.engine_prefill_flushed_first),
                 ("prefix_hit_tokens", m.engine_prefix_hit_tokens),
                 ("prefix_lookup_tokens", m.engine_prefix_lookup_tokens),
             ):
@@ -1693,6 +1780,11 @@ class LLMEngine:
                 / max(1, self.stats["decode_blocks_table"]),
                 "h2d_ships": self.stats["h2d_ships"],
                 "h2d_skips": self.stats["h2d_skips"],
+                # Windows queued behind their iteration's prefill programs,
+                # the first tokens unread; and those that had to wait for
+                # the read (their dispatch had to preempt).
+                "windows_behind_prefill": self.stats["windows_behind_prefill"],
+                "prefill_flushed_first": self.stats["prefill_flushed_first"],
                 # Of the time since the engine was built, the share the
                 # device had nothing queued while there was work for it, and
                 # where the scheduler thread was meanwhile.

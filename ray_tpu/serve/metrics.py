@@ -211,6 +211,18 @@ class _ServeMetrics:
             "(host/device overlap)",
             dr,
         )
+        self.engine_windows_behind_prefill = Counter(
+            "serve_engine_windows_behind_prefill_total",
+            "Decode windows dispatched behind their iteration's prefill programs, "
+            "before the host had read the prefills' first tokens",
+            dr,
+        )
+        self.engine_prefill_flushed_first = Counter(
+            "serve_engine_prefill_flushed_first_total",
+            "Decode windows that waited for the host to read the prefills' first "
+            "tokens: the dispatch had to preempt, which dirties the host's token mirror",
+            dr,
+        )
         self.engine_overlap_blocked = Counter(
             "serve_engine_overlap_blocked_total",
             "Decode windows found in flight and NOT overlapped, by reason "

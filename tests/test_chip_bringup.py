@@ -268,16 +268,18 @@ def test_engine_programs_compile_for_v5e(v5e):
     ).compile()
     (params_fmt, *_), _ = compiled.input_formats
 
-    def prefill(params, tokens, cache, block_row, real_len, temp, key):
-        return prefill_and_sample(
+    def prefill(params, tokens, cache, block_row, len_slot, temp, key, cur):
+        real_len, slot = len_slot
+        tok, cache = prefill_and_sample(
             params, cfg, tokens, cache, block_row, p.block_size, real_len, temp, key
         )
+        return tok, cache, cur.at[slot].set(tok, mode="drop")  # the first token, for the window
 
     text = jax.jit(
-        prefill, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 6,
+        prefill, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 7,
     ).lower(
-        params, sds((1, 16), np.int32), cache, sds((2,), np.int32), sds((), np.int32),
-        sds((), np.float32), sds((2,), np.uint32),
+        params, sds((1, 16), np.int32), cache, sds((2,), np.int32), sds((2,), np.int32),
+        sds((), np.float32), sds((2,), np.uint32), sds((b,), np.int32),
     ).compile().as_text()
     assert "tpu_custom_call" in text  # prefill runs the flash kernel
 
@@ -326,14 +328,16 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
         n = nb * bs // chunk_tile(nb * bs, bs)
         assert n == {1: 1, 3: 3, 8: 2}[nb]
 
-        def run(params, tokens, cache, table_rows, chunk_row, starts, last_idx, temps, key):
-            return prefill_chunk_and_sample(
+        def run(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+            starts, last_idx, slot_of = per_tile
+            toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx,
                 temps, key,
             )
+            return toks, cache, cur.at[slot_of].set(toks, mode="drop")
 
         args = (sds((1, nb * bs), np.int32), cache, sds((n, w), np.int32), sds((nb,), np.int32),
-                sds((n,), np.int32), sds((n,), np.int32), sds((n,), np.float32), key)
+                sds((3, n), np.int32), sds((n,), np.float32), key, sds((b,), np.int32))
     compiled = jax.jit(
         run, donate_argnums=(2,), in_shardings=(auto,) + (None,) * len(args),
     ).lower(params, *args).compile()

@@ -180,8 +180,8 @@ def _starved(eng):
 def test_the_buckets_sum_to_starved_us_after_every_step(tiny_model, overlap):
     cfg, params = tiny_model
     eng = _engine(cfg, params, overlap=overlap)
-    for i in range(6):
-        eng.add_request([i + 1, i + 2, i + 3], 9)
+    for i in range(6):  # answers of unlike lengths: one ends, the rest need a window on an empty queue
+        eng.add_request([i + 1, i + 2, i + 3], 9 + i)
     in_steps = 0.0
     while eng.active_count() or eng.waiting:
         eng.step()
@@ -283,7 +283,11 @@ def test_starved_seconds_reach_the_registry_counter(tiny_model):
     cfg, params = tiny_model
     eng = _engine(cfg, params)
     eng.metrics_tags = {"deployment": "starved", "replica": "r0"}
-    eng.generate_batch([[1, 2, 3], [4, 5, 6]], max_new_tokens=5)
+    # the first ends alone: the other's next window is launched on an empty queue
+    reqs = [eng.add_request([1, 2, 3], 5), eng.add_request([4, 5, 6], 9)]
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    assert [len(r.generated) for r in reqs] == [5, 9]
     eng._maybe_flush_metrics(force=True)
     counter = serve_metrics().engine_device_starved
     assert counter.name == "serve_engine_device_starved_seconds_total"
@@ -293,6 +297,177 @@ def test_starved_seconds_reach_the_registry_counter(tiny_model):
     assert mine == pytest.approx({w: eng.stats["starved_us_" + w] / 1e6
                                   for w in llm_engine._STARVED if eng.stats["starved_us_" + w]})
     assert mine["dispatch_launch"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The decode window behind the prefill: first tokens reach ``cur`` on the device
+# ---------------------------------------------------------------------------
+def _flush_first(eng):
+    """Force the flush-first order on every step: the dispatch gives up
+    while first tokens are unread, as it does when it would have to preempt,
+    so the flush comes first and the ship of the mirrors after it."""
+    ensure = eng._ensure_decode_blocks
+    eng._ensure_decode_blocks = lambda: not eng._pending_first and ensure()
+
+
+def _prefill_steps_that_dispatched(eng):
+    """Steps that launched a prefill and then dispatched a window themselves
+    (a speculated window is dispatched before the step admits)."""
+    return sum(1 for r in eng.recorder.steps
+               if r["prefills"] and not r["overlapped"] and r["dispatch_launch_ms"] > 0)
+
+
+# roomy: several requests admitted in one step, two that end AT their first
+# token; tight: five of nine usable blocks' worth of answers, so a dispatch has
+# to preempt on a step whose first tokens are unread
+_POOLS = {"roomy": ({}, [20, 1, 24, 9, 14, 1, 3, 11]),
+          "tight": (dict(num_blocks=10, max_blocks_per_seq=4), [24, 20, 16, 12, 8, 24])}
+
+
+@pytest.mark.parametrize("pool", list(_POOLS))
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("overlap", [False, True])
+def test_the_same_seed_gives_the_same_tokens_behind_the_prefill_as_flushed_first(
+        tiny_model, overlap, temperature, pool):
+    """The order of the key splits is the same either way (admit's, then
+    the window's; the flush takes none), an idle or doomed lane moves no
+    other row's sample, and a preemption waits for the flush: a seed gives
+    the same tokens whether the window queues behind the prefill or waits
+    for the host to read it, as every step did before."""
+    cfg, params = tiny_model
+    paged, lens = _POOLS[pool]
+    outs = {}
+    for order in ("behind", "flushed_first"):
+        eng = _engine(cfg, params, overlap=overlap, **paged)
+        if order == "flushed_first":
+            _flush_first(eng)
+        reqs = [eng.add_request([i + 1, i + 2, i + 3, i + 4], n, temperature=temperature)
+                for i, n in enumerate(lens)]
+        while eng.active_count() or eng.waiting:
+            eng.step()
+            assert not eng._pending_first  # every step reads what it launched
+        outs[order] = [r.generated for r in reqs], dict(eng.stats)
+        s = eng.stats
+        assert (s["windows_behind_prefill"] + s["prefill_flushed_first"]
+                == _prefill_steps_that_dispatched(eng) > 0)
+        assert sum(r["behind_prefill"] for r in eng.recorder.steps) == s["windows_behind_prefill"]
+    (behind, sb), (flushed, sf) = outs["behind"], outs["flushed_first"]
+    assert [len(o) for o in behind] == lens and behind == flushed
+    assert sf["windows_behind_prefill"] == 0 and sf["prefill_flushed_first"] > 0
+    assert sb["windows_behind_prefill"] > 0
+    assert sb["preemptions"] == sf["preemptions"] and sb["steps"] == sf["steps"]
+    if pool == "tight":  # the preemption fell on a step with first tokens unread
+        assert sb["preemptions"] > 0 and sb["prefill_flushed_first"] > 0
+    else:
+        assert sb["prefill_flushed_first"] == 0
+        # nothing is shipped for a first token: the flush dirties no mirror
+        assert sb["h2d_ships"] < sf["h2d_ships"]
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_first_token_is_emitted_before_its_windows(tiny_model, overlap):
+    """dispatch, then the flush, then (``overlap`` off) the harvest of that
+    window; a request that ends AT its first token leaves a lane in the
+    window in flight, which the harvest discards."""
+    from ray_tpu.models.generate import generate
+
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    order, emitted = [], []
+    for name in ("_dispatch_window", "_flush_prefills", "_harvest_window"):
+        def logged(*a, _f=getattr(eng, name), _name=name, **kw):
+            if _name != "_flush_prefills" or eng._pending_first:
+                order.append(_name)
+            return _f(*a, **kw)
+        setattr(eng, name, logged)
+    emit = eng._emit
+    eng._emit = lambda i, tok: (emitted.append((eng.slots[i].rid, order[-1])), emit(i, tok))
+    prompts = [[5, 9, 2, 11], [17, 1, 8], [30, 31, 32]]
+    reqs = [eng.add_request(p, n) for p, n in zip(prompts, (7, 1, 7))]
+    eng.step()
+    assert order == ["_dispatch_window", "_flush_prefills"] + (
+        [] if overlap else ["_harvest_window"])
+    assert eng.recorder.steps[-1]["behind_prefill"] == 1
+    assert reqs[1].remaining == 0 and eng.slots[1] is None  # ended in the flush
+    if overlap:  # its lane rides the window in flight
+        assert [i for i, _rid, _gen in eng._inflight[0]] == [0, 1, 2]
+    late = eng.add_request([40, 41, 42], 5)  # takes the slot that was given back
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    for p, r in zip(prompts + [[40, 41, 42]], reqs + [late]):
+        n = r.max_new_tokens
+        assert r.generated == list(np.asarray(generate(params, cfg, jnp.asarray([p]), n)[0]))
+    first = {}
+    for rid, during in emitted:
+        first.setdefault(rid, during)
+    assert set(first.values()) == {"_flush_prefills"} and len(first) == 4
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_window_behind_a_prefill_starves_nobody_in_dispatch(tiny_model, overlap):
+    """What the dispatch costs the host runs under the prefill program: a
+    slow launch adds to no ``dispatch_*`` bucket, and the buckets still
+    sum to ``starved_us``."""
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, overlap=overlap)
+    eng.add_request([5, 9, 2, 11], 40)
+    eng.step()
+    eng.step()
+    decode = eng._decode
+
+    def slow(*args):
+        time.sleep(0.02)
+        return decode(*args)
+
+    eng._decode = slow
+    eng.add_request([7, 8, 9], 40)  # with overlap: ``admission`` blocks the speculation
+    before = _starved(eng)
+    ships = eng.stats["h2d_ships"]
+    eng.step()
+    eng._decode = decode
+    moved = {k: v - before[k] for k, v in _starved(eng).items()}
+    rec = eng.recorder.steps[-1]
+    assert rec["prefills"] == 1 and rec["behind_prefill"] == 1 and not rec["overlapped"]
+    assert rec["dispatch_launch_ms"] >= 20.0 and rec["starved_dispatch_ms"] == 0.0
+    assert all(moved[k] == 0 for k in BUCKETS if k.startswith("starved_us_dispatch_")), moved
+    assert moved["starved_us_admit_launch"] > 0 and moved["unloaded_us"] == 0
+    assert moved["starved_us"] == sum(moved[k] for k in BUCKETS)
+    # tables, lens and temps: the first token is in the device's ``cur`` already
+    assert eng.stats["h2d_ships"] - ships == 3 and "cur" not in eng._dirty
+    if not overlap:  # harvested: the mirror and the device agree on the occupied rows
+        assert (np.asarray(eng._dev["cur"])[:2] == eng.cur[:2]).all() and eng.cur[1] != 0
+    while eng.active_count():
+        eng.step()
+
+
+def test_windows_behind_prefill_reach_the_report_and_the_registry(tiny_model):
+    from ray_tpu.serve.metrics import serve_metrics
+
+    cfg, params = tiny_model
+    paged, lens = _POOLS["tight"]
+    eng = _engine(cfg, params, **paged)
+    eng.metrics_tags = {"deployment": "behind", "replica": "r0"}
+    reqs = [eng.add_request([i + 1, i + 2, i + 3, i + 4], n) for i, n in enumerate(lens)]
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    assert [len(r.generated) for r in reqs] == lens
+    s = eng.stats
+    assert s["windows_behind_prefill"] > 0 and s["prefill_flushed_first"] > 0
+    snap = eng.report_state()
+    assert snap["stats"]["windows_behind_prefill"] == s["windows_behind_prefill"]
+    assert {k: snap["overlap"][k] for k in ("windows_behind_prefill", "prefill_flushed_first")} == {
+        k: s[k] for k in ("windows_behind_prefill", "prefill_flushed_first")}
+    assert all("behind_prefill" in r for r in snap["steps"])
+    m = serve_metrics()
+    for counter, name, key in (
+            (m.engine_windows_behind_prefill, "serve_engine_windows_behind_prefill_total",
+             "windows_behind_prefill"),
+            (m.engine_prefill_flushed_first, "serve_engine_prefill_flushed_first_total",
+             "prefill_flushed_first")):
+        assert counter.name == name
+        mine = [value for _n, _t, _d, tags, value in counter._drain()
+                if dict(tags)["deployment"] == "behind"]
+        assert mine == [s[key]]
 
 
 # ---------------------------------------------------------------------------

@@ -36,6 +36,11 @@ window (88.8-89.1% later); 340-359 real prefill tokens an iteration in
 (widths 64: 7-10, 128: 28, 256: 31-40, 512: 80-81, 1,024: 11-18, 1,568:
 0-3 of 163-174 calls), where one call a suffix made 3.2 calls.
 
+Since PR 41 a step that launched a prefill dispatches its decode window
+before it reads the prefill's tokens. The replay counts how often that
+engages: 133-134 of the window's 137 iterations (the other 3-4 were
+speculated), none flushed first, 2.94-2.96 mirrors shipped a window.
+
 Under plain LRU one prefill call in eight was then a re-prefill over 512
 tokens wide (11-16% of calls; 2.0-2.3% before PR 39; 16-18 segments of
 439-445, 3.6-4.0%, in the window now that hits are counted on answers
@@ -80,13 +85,23 @@ class _Programs:
         seq = self.rng.integers(0, self.vocab, (self.window, len(cur)), dtype=np.int32)
         return seq, seq[-1], np.asarray(lens) + self.window, cache
 
-    def prefill(self, params, toks, cache, *rest):
-        self.calls.append((toks.shape[1], [toks.shape[1]]))
-        return np.int32(self.rng.integers(0, self.vocab)), cache
+    @staticmethod
+    def _put(cur, slots, toks):
+        """``cur.at[slots].set(toks, mode="drop")`` on the host."""
+        cur, slots = np.array(cur), np.atleast_1d(slots)
+        keep = slots < len(cur)
+        cur[slots[keep]] = np.atleast_1d(toks)[keep]
+        return cur
 
-    def chunk(self, params, toks, cache, table_rows, chunk_row, starts, last_idx, temps, key):
+    def prefill(self, params, toks, cache, block_row, len_slot, temp, key, cur):
+        self.calls.append((toks.shape[1], [toks.shape[1]]))
+        tok = np.int32(self.rng.integers(0, self.vocab))
+        return tok, cache, self._put(cur, len_slot[1], tok)
+
+    def chunk(self, params, toks, cache, table_rows, chunk_row, per_tile, temps, key, cur):
         """The packed signature. A segment's tiles follow one another under
         one slot's table row; a tile nobody uses lies on the trash block."""
+        starts, _last_idx, slot_of = per_tile
         live = (table_rows != TRASH_BLOCK).any(axis=1)
         tile = toks.shape[1] // len(starts)
         goes_on = (table_rows[1:] == table_rows[:-1]).all(axis=1) & (
@@ -94,7 +109,8 @@ class _Programs:
         first = np.flatnonzero(live & ~np.concatenate([[False], goes_on]))
         ends = np.concatenate([first[1:], [int(live.sum())]])  # live tiles come first
         self.calls.append((toks.shape[1], [int(t) * tile for t in ends - first]))
-        return self.rng.integers(0, self.vocab, len(starts), dtype=np.int32), cache
+        out = self.rng.integers(0, self.vocab, len(starts), dtype=np.int32)
+        return out, cache, self._put(cur, slot_of, out)
 
 
 def replay(seed: int, warmup: int, iterations: int, monkeypatch) -> dict:
@@ -194,6 +210,12 @@ def test_sessions_replay_hit_share_and_wide_suffixes(seed, phase, hit_floor, wid
     assert got["segments_per_chunk_call"] >= 2.0, got
     assert 100.0 * got["wide_segments"] / got["stats"]["prefills"] <= wide_limit, got
     assert got["stats"]["prefix_published_blocks"] > 0
+    # Nearly every iteration admits (94-99% on the chip, PERF.md section 5), and
+    # each of those queues its window behind the chunk call, first tokens unread
+    # (read: 133-134 of 137 in the window, 292-296 of 300 later; the rest were
+    # speculated). No preemption, so none had to read first.
+    assert got["stats"]["windows_behind_prefill"] / got["stats"]["steps"] >= 0.9, got["stats"]
+    assert got["stats"]["prefill_flushed_first"] == 0
     # Every width was played once, through the served path, before the window:
     # a suffix of just that many tokens after the two blocks the warm-up shares.
     conf, _ = _cell()
@@ -228,7 +250,11 @@ def test_sessions_replay_the_starvation_account_closes(monkeypatch):
         assert r["starved_ms"] <= r["wall_ms"] - r["harvest_wait_ms"] - r["prefill_wait_ms"] + 1.0, r
         assert r["starved_admit_ms"] + r["starved_dispatch_ms"] <= r["starved_ms"] + 1e-9
         assert sum(r[f"{name}_ms"] for name in llm_engine._PHASES) <= r["wall_ms"]
-    # every launch of the window was made on an empty queue or behind a
-    # speculated window; with 123 of 128 windows not overlapped most were
-    assert d["starved_us_dispatch_launch"] > 0 and d["starved_us_admit_launch"] > 0
+    # the prefill calls were launched on an empty queue (4 of 137 windows were
+    # speculated); a decode window queued behind one of them starves nobody,
+    # whatever its dispatch costs the host
+    assert d["starved_us_admit_launch"] > 0
     assert d["starved_us_admit_plan"] > 0 and d["starved_us_admit_build"] > 0
+    behind = [r for r in steps if r["behind_prefill"]]
+    assert len(behind) == d["windows_behind_prefill"] >= 0.9 * len(steps)
+    assert all(r["starved_dispatch_ms"] == 0.0 and r["dispatch_ms"] > 0.0 for r in behind)

@@ -409,3 +409,10 @@ def test_engine_perf_suite_reported(ray_start_regular):
     assert summary["prefix_cached_blocks"] >= 2
     assert summary["overlap_windows"] >= 1
     assert 0 < summary["overlap_occupancy"] <= 1
+    # what GET /api/serve/engine serves: every prefill step queued its window
+    # behind the prefill, and none had to read the first token first
+    pushed = next(v for v in state_api.serve_state().values()
+                  if v["tags"]["deployment"] == dep)
+    assert pushed["overlap"]["windows_behind_prefill"] == 3
+    assert pushed["overlap"]["prefill_flushed_first"] == 0
+    assert pushed["stats"]["windows_behind_prefill"] == 3
