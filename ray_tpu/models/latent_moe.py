@@ -1,0 +1,378 @@
+"""Decoder with latent attention and sigmoid-routed experts, for the paged
+serving path (``models/paged.py`` reaches it through ``paged_model``).
+
+One layer, for a token's hidden state ``h`` at position ``t`` (RMSNorm
+throughout; names are the published configuration's):
+
+1. ``x = attn_norm(h)``; ``c_q = q_norm(x W_dq)``; ``q = c_q W_uq``: per head
+   ``[q_nope | q_rope]``, ``q_rope`` rotated to ``t``.
+2. ``[c_kv | k_r] = x W_dkv``; ``c = kv_norm(c_kv)``; ``k_rope = RoPE(k_r, t)``,
+   ONE for all heads. The cache row of the token is ``[c | k_rope]``
+   (``row_width``: padded with zeros to whole lane tiles of 128).
+3. Attention. Expanded: per head ``[k_nope | v] = c W_ukv``, scores ``(q_nope .
+   k_nope + q_rope . k_rope) / sqrt(nope + rope)``. Absorbed, the same numbers:
+   ``qa = q_nope W_uk^T`` scores against the row itself, the weighted sum of
+   ``c`` goes through ``W_uv`` after. The served path is absorbed in decode
+   and prefill alike (``ops/latent_attention.py``); ``expanded_attention`` is
+   the other form, for the tests.
+4. Sandwich norms: ``h += post_attn_norm(a)``; ``y = mlp_norm(h)``; ``h +=
+   post_mlp_norm(m)``.
+5. ``m``: a SwiGLU of ``intermediate_size`` in the ``first_k_dense_replace``
+   leading layers (``params["lead"]``, run before the scan), else the expert
+   layer: ``s = sigmoid(float32(y) W_r)`` over ALL ``n_routed_experts``, the
+   ``num_experts_per_tok`` largest, ``g = routed_scaling_factor * s / sum of
+   the chosen``, ``m = shared(y) + sum g_e expert_e(y)``.
+
+**The expert layer is told which experts it holds** (``held_first``,
+``held_count``: this chip's share of a layer that several chips divide). It
+routes over all of them, sorts the token-expert pairs that land here by
+expert, multiplies them as groups (``jax.lax.ragged_dot``: no capacity, no
+dropped token) and adds the shared expert for every token. What the absent
+experts would have added is left out; a token none of whose experts is here
+gets the shared expert only. It also counts: pairs computed here, held experts
+with at least one pair.
+
+Parameters: ``embed``, ``final_norm``, ``lm_head``; ``lead`` and ``layers``
+(stacked by layer: attention, norms, and in ``layers`` the router and the
+shared expert); and ``experts``, the held routed experts of ALL expert layers,
+``[expert layers, held, ...]``, which no scan slices: the grouped product is a
+kernel, a kernel's operand has to exist in memory, and a layer's slice of the
+stack would be copied there at every step (three copies of 0.5 GB a layer at
+the published widths). The kernel is handed the whole stack as ``layers x
+held`` groups of which only the layer's own hold rows.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import paged
+from ray_tpu.models.transformer import Params, _rope, rms_norm
+from ray_tpu.ops.latent_attention import latent_attention, latent_chunk_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    vocab_size: int = 153600
+    hidden_size: int = 7680
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 3
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    rope_theta: float = 25.6e6
+    rms_norm_eps: float = 1e-5
+    # This chip's share of every expert layer: experts held_first ..
+    # held_first + held_count - 1 (None: all of them).
+    held_first: int = 0
+    held_count: Optional[int] = None
+    dtype: Any = jnp.bfloat16  # compute dtype
+
+    @property
+    def held(self) -> int:
+        return self.n_routed_experts if self.held_count is None else self.held_count
+
+    @property
+    def row_width(self) -> int:
+        """Numbers in one cache row: the latent and the rotary key, padded
+        with zeros to whole lane tiles (a TPU pads the pool's rows so in
+        memory whatever their logical width)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+    @classmethod
+    def tiny(cls, **kw):
+        """Small config for tests: two leading dense layers, two expert layers."""
+        return cls(**{**dict(
+            vocab_size=256, hidden_size=64, num_hidden_layers=4, first_k_dense_replace=2,
+            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128, moe_intermediate_size=32,
+            n_routed_experts=8, num_experts_per_tok=2, dtype=jnp.float32), **kw})
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+
+def layer_shapes(cfg: LatentMoEConfig, experts: bool) -> dict:
+    """name -> shape of one layer's parameters (a leading dense layer, or an
+    expert layer less its routed experts: ``expert_shapes``)."""
+    D, H = cfg.hidden_size, cfg.num_attention_heads
+    out = {
+        "attn_norm": (D,), "q_norm": (cfg.q_lora_rank,), "kv_norm": (cfg.kv_lora_rank,),
+        "post_attn_norm": (D,), "mlp_norm": (D,), "post_mlp_norm": (D,),
+        "w_dq": (D, cfg.q_lora_rank),
+        "w_uq": (cfg.q_lora_rank, H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "w_dkv": (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "w_ukv": (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": (H * cfg.v_head_dim, D),
+    }
+    if not experts:
+        F = cfg.intermediate_size
+        return {**out, "w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
+    S = cfg.moe_intermediate_size * cfg.n_shared_experts
+    return {**out, "router": (D, cfg.n_routed_experts),
+            "shared_gate": (D, S), "shared_up": (D, S), "shared_down": (S, D)}
+
+
+def expert_shapes(cfg: LatentMoEConfig) -> dict:
+    """name -> shape of ``params["experts"]``: every expert layer's held experts."""
+    L, E = cfg.num_hidden_layers - cfg.first_k_dense_replace, cfg.held
+    D, F = cfg.hidden_size, cfg.moe_intermediate_size
+    return {"e_gate": (L, E, D, F), "e_up": (L, E, D, F), "e_down": (L, E, F, D)}
+
+
+def init_params(key: jax.Array, cfg: LatentMoEConfig) -> Params:
+    """Seeded float32 parameters: norms one, matrices normal at 1/sqrt(fan_in),
+    the embedding unit variance; ``lead`` and ``layers`` stacked by layer."""
+    def stack(key, n, experts):
+        tree = {}
+        for j, (name, shape) in enumerate(layer_shapes(cfg, experts).items()):
+            if name.endswith("norm"):
+                tree[name] = jnp.ones((n,) + shape, jnp.float32)
+            else:
+                tree[name] = jax.random.normal(
+                    jax.random.fold_in(key, j), (n,) + shape, jnp.float32) * shape[-2] ** -0.5
+        return tree
+
+    k_emb, k_lead, k_layers, k_experts, k_out = jax.random.split(key, 5)
+    lead = cfg.first_k_dense_replace
+    D = cfg.hidden_size
+    return {
+        "embed": jax.random.normal(k_emb, (cfg.vocab_size, D), jnp.float32),
+        "lead": stack(k_lead, lead, False),
+        "layers": stack(k_layers, cfg.num_hidden_layers - lead, True),
+        "experts": {name: jax.random.normal(jax.random.fold_in(k_experts, j), shape, jnp.float32)
+                    * shape[-2] ** -0.5 for j, (name, shape) in enumerate(expert_shapes(cfg).items())},
+        "final_norm": jnp.ones((D,), jnp.float32),
+        "lm_head": jax.random.normal(k_out, (D, cfg.vocab_size), jnp.float32) * D ** -0.5,
+    }
+
+
+# ---------------------------------------------------------------------------
+# Attention: projections and both forms
+# ---------------------------------------------------------------------------
+
+
+def _norm(x, scale, cfg):
+    return rms_norm(x, scale, cfg.rms_norm_eps)
+
+
+def _up_kv(lp: Params, cfg: LatentMoEConfig, dtype):
+    """``W_ukv`` as (W_uk [rank, H, nope], W_uv [rank, H, v])."""
+    w = lp["w_ukv"].astype(dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+@jax.named_scope("latent.project")
+def project(h, lp: Params, cfg: LatentMoEConfig, positions):
+    """Normed hidden [b, s, D] → (q_nope [b, s, H, nope], roped q_rope [b, s,
+    H, rope], cache rows [b, s, row_width] = ``[c | roped k_rope | 0]``)."""
+    b, s, _ = h.shape
+    H, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    c_q = _norm(h @ lp["w_dq"].astype(h.dtype), lp["q_norm"], cfg)
+    q = (c_q @ lp["w_uq"].astype(h.dtype)).reshape(b, s, H, nope + rope)
+    q_rope = _rope(q[..., nope:], positions, cfg.rope_theta)
+    kv = h @ lp["w_dkv"].astype(h.dtype)
+    c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg)
+    k_rope = _rope(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)[:, :, 0]
+    pad = jnp.zeros((b, s, cfg.row_width - cfg.kv_lora_rank - rope), h.dtype)
+    return q[..., :nope], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
+
+
+@jax.named_scope("latent.project")
+def absorb(q_nope, q_rope, lp: Params, cfg: LatentMoEConfig):
+    """The query that scores against a cache row: ``[q_nope W_uk^T | q_rope |
+    0]`` per head, [.., H, row_width]."""
+    w_uk, _ = _up_kv(lp, cfg, q_nope.dtype)
+    qa = jnp.einsum("...hn,chn->...hc", q_nope, w_uk)
+    pad = jnp.zeros(qa.shape[:-1] + (cfg.row_width - cfg.kv_lora_rank - q_rope.shape[-1],), qa.dtype)
+    return jnp.concatenate([qa, q_rope, pad], axis=-1)
+
+
+def attention_out(u, lp: Params, cfg: LatentMoEConfig):
+    """u: [.., H, rank] per-head weighted sums of latents → the attention
+    block's output [.., D]: through ``W_uv`` and ``W_o``."""
+    _, w_uv = _up_kv(lp, cfg, u.dtype)
+    o = jnp.einsum("...hc,chv->...hv", u, w_uv)
+    return o.reshape(o.shape[:-2] + (-1,)) @ lp["wo"].astype(u.dtype)
+
+
+def absorbed_attention(q_nope, q_rope, rows, lp: Params, cfg: LatentMoEConfig):
+    """Causal attention of ONE sequence in the absorbed form, plain float32
+    einsums: q_nope/q_rope [s, H, .], rows [s, row_width] → u [s, H, rank]."""
+    q = absorb(q_nope, q_rope, lp, cfg).astype(jnp.float32)
+    rows = rows.astype(jnp.float32)
+    s = q.shape[0]
+    scores = jnp.einsum("qhr,kr->hqk", q, rows) * cfg.softmax_scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None], scores, -1e30)
+    return jnp.einsum("hqk,kc->qhc", jax.nn.softmax(scores, axis=-1), rows[:, :cfg.kv_lora_rank])
+
+
+def expanded_attention(q_nope, q_rope, rows, lp: Params, cfg: LatentMoEConfig):
+    """The same in the expanded form: every row up-projected to per-head keys
+    and values. → o [s, H, v] (``absorbed_attention``'s ``u`` through ``W_uv``)."""
+    rows = rows.astype(jnp.float32)
+    c, k_rope = rows[:, :cfg.kv_lora_rank], rows[:, cfg.kv_lora_rank:][:, :cfg.qk_rope_head_dim]
+    w_uk, w_uv = _up_kv(lp, cfg, jnp.float32)
+    k_nope = jnp.einsum("kc,chn->khn", c, w_uk)
+    v = jnp.einsum("kc,chv->khv", c, w_uv)
+    s = c.shape[0]
+    scores = (jnp.einsum("qhn,khn->hqk", q_nope.astype(jnp.float32), k_nope)
+              + jnp.einsum("qhr,kr->hqk", q_rope.astype(jnp.float32), k_rope)) * cfg.softmax_scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None], scores, -1e30)
+    return jnp.einsum("hqk,khv->qhv", jax.nn.softmax(scores, axis=-1), v)
+
+
+# ---------------------------------------------------------------------------
+# Feed-forward: dense, and this chip's share of an expert layer
+# ---------------------------------------------------------------------------
+
+
+def _swiglu(y, gate, up, down):
+    return (jax.nn.silu(y @ gate.astype(y.dtype)) * (y @ up.astype(y.dtype))) @ down.astype(y.dtype)
+
+
+def route(y, lp: Params, cfg: LatentMoEConfig):
+    """y: [T, D] → (experts [T, k] int32 among ALL routed experts, gates [T, k]
+    float32): sigmoid scores in float32 (a product of the activation and the
+    router in their own precision, accumulated in float32, is the float32
+    product of those numbers), the k largest, normalised over the chosen,
+    times ``routed_scaling_factor``."""
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            y, lp["router"].astype(y.dtype), preferred_element_type=jnp.float32))
+        top, experts = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+        gates = cfg.routed_scaling_factor * top / jnp.sum(top, axis=-1, keepdims=True)
+        return experts.astype(jnp.int32), gates
+
+
+def routed_experts(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
+    """The held experts' part of the layer's result for y [T, D], and the
+    counts (pairs computed here, held experts touched, 1) as int32 [3].
+    ``held`` is ``params["experts"]`` whole and ``layer`` this layer's number
+    among the expert layers (it may be traced).
+
+    Every token-expert pair gets a key: its expert's index among the held
+    ones, or ``held`` if the expert lives elsewhere; a stable sort by key
+    puts the pairs of this chip first, grouped by expert, and ``ragged_dot``
+    multiplies the groups (rows past the last group are not computed; a
+    group of no rows reads no weights: the other layers' experts)."""
+    T, D = y.shape
+    k, E = cfg.num_experts_per_tok, cfg.held
+    experts, gates = route(y, lp, cfg)
+    with jax.named_scope("moe.experts"):
+        local = experts - cfg.held_first
+        here = (local >= 0) & (local < E)
+        key = jnp.where(here, local, E).reshape(T * k)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0).astype(jnp.int32)
+        pairs = jnp.sum(sizes)
+        x = y[order // k]  # [T*k, D]: the token of each sorted pair
+        n_layers = held["e_gate"].shape[0]
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
+
+        def dot(a, w):  # w: [layers, E, in, out], as layers x E groups (a bitcast)
+            return jax.lax.ragged_dot(a, w.reshape((-1,) + w.shape[2:]).astype(a.dtype), groups)
+
+        out = dot(jax.nn.silu(dot(x, held["e_gate"])) * dot(x, held["e_up"]), held["e_down"])
+        out = jnp.where((jnp.arange(T * k) < pairs)[:, None], out, 0)
+        # Back to (token, choice) order; a pair computed elsewhere weighs 0.
+        out = out[jnp.argsort(order)].reshape(T, k, D)
+        weight = jnp.where(here, gates, 0.0)  # float32, as the sum over the choices
+        m = jnp.sum(out.astype(jnp.float32) * weight[..., None], axis=1).astype(y.dtype)
+    counts = jnp.stack([pairs, jnp.sum(sizes > 0).astype(jnp.int32), jnp.int32(1)])
+    return m, counts
+
+
+def expert_layer(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
+    """y: [T, D] → (shared expert + this chip's routed part, counts [3])."""
+    m, counts = routed_experts(y, lp, cfg, held, layer)
+    with jax.named_scope("moe.shared"):
+        m = m + _swiglu(y, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    return m, counts
+
+
+def _finish(x, a, lp: Params, cfg: LatentMoEConfig, params: Params, index):
+    """Layer ``index`` after its attention output ``a`` [b, s, D]: sandwich
+    norms around the feed-forward, which is the expert layer where the
+    layer's parameters hold a router. → (x, counts or None)."""
+    x = x + _norm(a, lp["post_attn_norm"], cfg)
+    y = _norm(x, lp["mlp_norm"], cfg)
+    if "router" in lp:
+        m, counts = expert_layer(y.reshape(-1, y.shape[-1]), lp, cfg, params["experts"],
+                                 index - cfg.first_k_dense_replace)
+        m = m.reshape(y.shape)
+    else:
+        m, counts = _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+    return x + _norm(m, lp["post_mlp_norm"], cfg), counts
+
+
+# ---------------------------------------------------------------------------
+# The paged programs' layer bodies
+# ---------------------------------------------------------------------------
+
+
+def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, params, index):
+    """One layer, one token a slot. x: [b, 1, D]; pools: (rows [P, bs, R],);
+    tables: [b, W] block ids into it; lens: [b] write positions."""
+    (pool,) = pools
+    bs = pool.shape[1]
+    q_nope, q_rope, rows = project(_norm(x, lp["attn_norm"], cfg), lp, cfg, lens[:, None])
+    q = absorb(q_nope[:, 0], q_rope[:, 0], lp, cfg)
+    with jax.named_scope("latent.scatter"):
+        phys = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
+        pool = pool.at[phys, lens % bs].set(rows[:, 0])
+    # After the scatter, so the token just written attends to itself.
+    u = latent_attention(q, pool, tables, lens, cfg.softmax_scale, cfg.kv_lora_rank)
+    x, counts = _finish(x, attention_out(u, lp, cfg)[:, None, :], lp, cfg, params, index)
+    return x, (pool,), counts
+
+
+def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at, offs, qpos,
+                 params, index):
+    """One layer over a chunk call's token axis. x: [1, T, D]; table_rows:
+    [n, W] each tile's slot's table; token j's row lands at (rows_at[j],
+    offs[j]); qpos: [n, C] absolute positions by tile."""
+    (pool,) = pools
+    n, C = qpos.shape
+    q_nope, q_rope, rows = project(
+        _norm(x, lp["attn_norm"], cfg), lp, cfg, qpos.reshape(1, n * C))
+    q = absorb(q_nope[0], q_rope[0], lp, cfg)  # [T, H, R]
+    with jax.named_scope("latent.scatter"):
+        pool = pool.at[rows_at, offs].set(rows[0])
+    u = latent_chunk_attention(
+        q.reshape((n, C) + q.shape[1:]), pool, table_rows, qpos,
+        cfg.softmax_scale, cfg.kv_lora_rank)
+    a = attention_out(u.reshape((1, n * C) + u.shape[2:]), lp, cfg)
+    x, counts = _finish(x, a, lp, cfg, params, index)
+    return x, (pool,), counts
+
+
+@paged.paged_model.register
+def _(cfg: LatentMoEConfig) -> paged.PagedModel:
+    return paged.PagedModel(
+        rows={"rows": (cfg.row_width,)},
+        n_layers=cfg.num_hidden_layers,
+        decode_layer=functools.partial(_decode_layer, cfg),
+        chunk_layer=functools.partial(_chunk_layer, cfg),
+    )

@@ -5,11 +5,24 @@ The reference's LLM-serving story is vLLM running as Ray actors (SURVEY
 block-paged KV memory and iteration-level (continuous) batching — are
 re-designed for XLA's static-shape world:
 
-- **Physical cache**: one pool of fixed-size blocks per layer,
-  ``[L, num_blocks, block_size, kv_heads, head_dim]``. Block 0 is a
-  reserved trash block that idle decode slots harmlessly write to, so
-  the decode step never branches on slot liveness. The layer scans
-  address it in place, as one flat pool (``_scan_layers``).
+- **Physical cache**: pools of fixed-size blocks, stacked by layer:
+  ``[L, num_blocks, block_size, *row]`` each. WHICH pools, and what one
+  cached row is, is the model's (``paged_model(cfg).rows``): the dense
+  decoder has ``k`` and ``v`` with rows ``[kv_heads, head_dim]``; the
+  latent-attention decoder (``models/latent_moe.py``) ONE pool whose row
+  is the token's latent and rotary key. Block 0 is a reserved trash block
+  that idle decode slots harmlessly write to, so the decode step never
+  branches on slot liveness. The layer scans address the pools in place,
+  as flat pools (``_scan_layers``).
+- **Models**: the programs below are one skeleton (embed, the layers over
+  the carried pools, the head, sampling) around a model's two layer
+  bodies, one token a slot and a chunk call's token axis
+  (``PagedModel.decode_layer``, ``.chunk_layer``). ``paged_model``
+  dispatches on the TYPE of the configuration object; no flag selects.
+  Parameters are ``embed``, ``final_norm``, ``lm_head``, ``layers`` (one
+  body, stacked, scanned) and, where a model has leading layers whose
+  parameters have other shapes, ``lead`` (stacked, run one by one BEFORE
+  the scan, at block bases 0, num_blocks, ..).
 - **Block tables**: each decode slot owns a row ``[max_blocks_per_seq]``
   of physical block ids. Tables/lengths are tiny int32 arrays passed
   into the jitted step each iteration — the host allocator (see
@@ -17,15 +30,21 @@ re-designed for XLA's static-shape world:
   device program never sees allocation logic.
 - **Decode step** (``paged_decode_step``): fixed ``[max_batch]`` token
   vector in, next tokens out. Per layer inside one ``lax.scan``:
-  scatter the new K/V into (block, offset) slots via batched
+  scatter the token's new row(s) into (block, offset) slots via batched
   ``.at[].set``, then attend each slot's token to its blocks under a
-  per-slot length mask (``ops/paged_attention.py``: on a TPU a kernel
-  that reads the live blocks where they lie). Everything is
-  static-shape; XLA sees one compiled program regardless of which
-  slots are live.
-- **Prefill** (``paged_prefill``): full-attention forward over a padded
-  prompt bucket, scattering each layer's roped K/V into the slot's
-  blocks. Buckets (powers of two) bound the number of compilations.
+  per-slot length mask (``ops/paged_attention.py``,
+  ``ops/latent_attention.py``: on a TPU kernels that read the live
+  blocks where they lie). Everything is static-shape; XLA sees one
+  compiled program regardless of which slots are live.
+- **Prefill** (``paged_prefill``): the dense decoder's full-attention
+  forward over a padded prompt bucket, scattering each layer's roped K/V
+  into the slot's blocks; a model without such a program (``prefill`` is
+  None) prefills a whole prompt as ONE tile of the chunk program.
+  Buckets (powers of two) bound the number of compilations.
+- **Counts**: a layer body may return int32 counts (the expert layer's
+  pairs and touched experts); their sums ride the outputs the host reads
+  anyway, as extra rows of the window's tokens and extra entries of a
+  chunk call's, and a model without them compiles to what it always did.
 
 Sampling is on-device and per-slot (greedy where ``temps == 0``, else
 temperature-scaled categorical), so one step moves only ``[b]`` int32s
@@ -34,8 +53,9 @@ host↔device.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Dict, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,33 +95,79 @@ class PagedConfig:
         return self.num_blocks - 1  # minus trash
 
 
-def init_paged_cache(cfg: TransformerConfig, pcfg: PagedConfig) -> PagedCache:
-    shape = (
-        cfg.n_layers,
-        pcfg.num_blocks,
-        pcfg.block_size,
-        cfg.n_kv_heads,
-        cfg.head_dim,
-    )
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+class PagedModel(NamedTuple):
+    """What a model gives the paged programs (``paged_model(cfg)``)."""
+
+    # Pool name -> shape of ONE cached row; a pool is [L, blocks, bs, *row].
+    rows: Dict[str, Tuple[int, ...]]
+    n_layers: int  # leading ones included
+    # One token a slot: (x [b, 1, d], pools, lp, tables [b, W], lens [b],
+    # params, index) -> (x, pools, counts). ``pools`` is a tuple in ``rows``'
+    # order, each ANY flat pool that holds this layer's blocks at ``tables``'
+    # ids; ``lp`` the layer's own slice of ``lead`` or ``layers``; ``params``
+    # the whole tree and ``index`` the layer's number, for what a model keeps
+    # outside the scanned stack (weights a kernel must be handed whole, not as
+    # a slice that would be copied); ``counts`` is None or an int32 vector
+    # that the programs sum over layers and steps.
+    decode_layer: Callable
+    # A chunk call's token axis: (x [1, T, d], pools, lp, table_rows [n, W],
+    # rows_at [T], offs [T], qpos [n, C], params, index) -> (x, pools, counts):
+    # token j's row lands at (rows_at[j], offs[j]); tile t attends through
+    # table_rows[t].
+    chunk_layer: Callable
+    # (params, tokens, cache, block_row, block_size) -> (logits [S, V], cache),
+    # or None: a whole prompt is then one tile of the chunk program.
+    prefill: Optional[Callable] = None
 
 
-def _scan_layers(layer, x, layers: Params, cache: PagedCache):
-    """Scan ``layer(x, ck, cv, lp, base) -> (x, ck, cv)`` over the layers,
-    carrying the stacked cache as one flat pool ``[L*num_blocks, bs, KV,
-    HD]`` (a bitcast, both ways). ``base`` is the layer's first block in
-    it: a layer addresses its block ``b`` at ``base + b`` and updates the
-    carry in place; no layer's pool is taken out of the stack or put back,
-    and only ``base`` knows how layers are laid out. → (x, cache')."""
-    shape = cache["k"].shape
-    L, nb = shape[:2]
-    flat = (L * nb,) + shape[2:]
-    bases = jnp.arange(L, dtype=jnp.int32) * nb
-    (x, ck, cv), _ = jax.lax.scan(
-        lambda carry, xs: (layer(*carry, *xs), None),
-        (x, cache["k"].reshape(flat), cache["v"].reshape(flat)), (layers, bases),
-    )
-    return x, {"k": ck.reshape(shape), "v": cv.reshape(shape)}
+@functools.singledispatch
+def paged_model(cfg) -> PagedModel:
+    """The model behind a configuration object, by the object's type."""
+    raise TypeError(f"no paged model is registered for {type(cfg).__name__}")
+
+
+def _add_counts(total, counts):
+    """Sum of two layers' (or steps') counts, either of which may be None."""
+    if counts is None or total is None:
+        return counts if total is None else total
+    return total + counts
+
+
+def init_paged_cache(cfg, pcfg: PagedConfig) -> PagedCache:
+    model = paged_model(cfg)
+    lead = (model.n_layers, pcfg.num_blocks, pcfg.block_size)
+    return {name: jnp.zeros(lead + row, cfg.dtype) for name, row in model.rows.items()}
+
+
+def _scan_layers(layer, x, params: Params, cache: PagedCache):
+    """Run ``layer(x, pools, lp, base, index) -> (x, pools, counts)`` over the
+    layers, carrying each stacked pool as one flat pool ``[L*num_blocks, bs,
+    *row]`` (a bitcast, both ways). ``base`` is the first block of layer
+    ``index`` in it: a layer addresses its block ``b`` at ``base + b`` and
+    updates the carry in place; no layer's pool is taken out of the stack or
+    put back, and only ``base`` knows how layers are laid out. Leading layers
+    (``params["lead"]``, their own parameter tree, stacked) run one by one
+    before the scan over ``params["layers"]``, at bases 0, num_blocks, ..; the
+    scanned ones follow. → (x, cache', summed counts or None)."""
+    shapes = {name: pool.shape for name, pool in cache.items()}
+    L, nb = next(iter(shapes.values()))[:2]
+    pools = tuple(pool.reshape((L * nb,) + pool.shape[2:]) for pool in cache.values())
+    lead = params.get("lead")
+    n_lead = 0 if lead is None else jax.tree.leaves(lead)[0].shape[0]
+    total = None
+    for j in range(n_lead):
+        x, pools, counts = layer(x, pools, jax.tree.map(lambda a: a[j], lead), j * nb, j)
+        total = _add_counts(total, counts)
+
+    def body(carry, xs):
+        lp, base = xs
+        x, pools, counts = layer(*carry, lp, base, base // nb)
+        return (x, pools), counts
+
+    bases = jnp.arange(n_lead, L, dtype=jnp.int32) * nb
+    (x, pools), counts = jax.lax.scan(body, (x, pools), (params["layers"], bases))
+    total = _add_counts(total, None if counts is None else counts.sum(0))
+    return x, {name: pool.reshape(shapes[name]) for name, pool in zip(shapes, pools)}, total
 
 
 def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, lens):
@@ -131,9 +197,21 @@ def _paged_layer_step(x, lp: Params, cfg: TransformerConfig, ck, cv, tables, len
     return x, ck, cv
 
 
+def _decode_step(params: Params, cfg, tokens, cache: PagedCache, tables, lens):
+    """``paged_decode_step`` with the layers' summed counts (or None) third."""
+    model = paged_model(cfg)
+
+    def layer(x, pools, lp, base, index):
+        return model.decode_layer(x, pools, lp, tables + base, lens, params, index)
+
+    x = embed(params, tokens[:, None], cfg)
+    x, cache, counts = _scan_layers(layer, x, params, cache)
+    return unembed(params, x, cfg)[:, 0], cache, counts
+
+
 def paged_decode_step(
     params: Params,
-    cfg: TransformerConfig,
+    cfg,
     tokens: jax.Array,  # [b] int32 — the tokens AT positions ``lens``
     cache: PagedCache,
     tables: jax.Array,  # [b, W] int32
@@ -141,18 +219,25 @@ def paged_decode_step(
 ) -> Tuple[jax.Array, PagedCache]:
     """One decode iteration over all slots → (logits [b, V] fp32, cache').
 
-    The stacked pool rides the layer scan as a carry that every layer
+    The stacked pools ride the layer scan as a carry that every layer
     updates in place (``_scan_layers``). Passing per-layer slices as scan
     xs/ys instead would stack a fresh pool copy as the scan output (and
     chained windows would hold several such copies): at 7B that is
     multiple GB of pure waste and an OOM on a 16 GB chip."""
+    logits, cache, _ = _decode_step(params, cfg, tokens, cache, tables, lens)
+    return logits, cache
 
-    def layer(x, ck, cv, lp, base):
-        return _paged_layer_step(x, lp, cfg, ck, cv, tables + base, lens)
 
-    x = embed(params, tokens[:, None], cfg)
-    x, cache = _scan_layers(layer, x, params["layers"], cache)
-    return unembed(params, x, cfg)[:, 0], cache
+def _with_counts(out: jax.Array, counts) -> jax.Array:
+    """``out`` (int32, tokens on its leading axis) with the counts behind it,
+    one more leading entry each: they reach the host on the transfer that
+    brings the tokens. None: ``out`` as it is."""
+    if counts is None:
+        return out
+    extra = jnp.broadcast_to(
+        counts.astype(out.dtype).reshape((-1,) + (1,) * (out.ndim - 1)),
+        counts.shape[:1] + out.shape[1:])
+    return jnp.concatenate([out, extra])
 
 
 def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array) -> jax.Array:
@@ -166,7 +251,7 @@ def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array) -> jax.Ar
 
 def paged_decode_loop(
     params: Params,
-    cfg: TransformerConfig,
+    cfg,
     tokens: jax.Array,  # [b] int32 — tokens AT positions ``lens``
     cache: PagedCache,
     tables: jax.Array,  # [b, W] — FIXED across the window
@@ -181,7 +266,9 @@ def paged_decode_loop(
     latency (decisive when the host↔device link is slow; still a win on
     local PCIe). Requires every slot's block table to cover positions
     ``lens .. lens+n_steps-1`` (the engine allocates the window horizon
-    up front). Returns ([n_steps, b] sampled tokens, cache').
+    up front). Returns ([n_steps, b] sampled tokens, cache'); where the
+    model's layers count, the counts summed over the window follow the
+    tokens as further rows, one a count (``_with_counts``).
 
     The window is UNROLLED (Python loop, n_steps is static), not a
     lax.scan: a scan carry holding the KV pool double-buffers it on top
@@ -194,30 +281,47 @@ def paged_decode_loop(
     # advances it: restart it, so that it reads and writes one block
     # however long it has idled.
     lens = jnp.where(tables[:, 0] == TRASH_BLOCK, 0, lens)
-    seq = []
+    seq, total = [], None
     for _ in range(n_steps):
         key, sub = jax.random.split(key)
-        logits, cache = paged_decode_step(params, cfg, tokens, cache, tables, lens)
+        logits, cache, counts = _decode_step(params, cfg, tokens, cache, tables, lens)
         tokens = sample_tokens(logits, temps, sub)
         lens = lens + 1
         seq.append(tokens)
-    return jnp.stack(seq), cache
+        total = _add_counts(total, counts)
+    return _with_counts(jnp.stack(seq), total), cache
 
 
 def paged_prefill(
     params: Params,
-    cfg: TransformerConfig,
+    cfg,
     tokens: jax.Array,  # [1, S] int32, S a multiple of block_size (padded)
     cache: PagedCache,
     block_row: jax.Array,  # [S // block_size] int32 physical block ids
     block_size: int,
 ) -> Tuple[jax.Array, PagedCache]:
-    """Full-attention prefill of ONE slot, scattering K/V into its blocks.
+    """Prefill of ONE slot from position 0, scattering its rows into its
+    blocks: the model's own whole-prompt program, else one tile of the chunk
+    program whose table is the prompt's own blocks.
 
     Returns (logits [S, V] fp32, cache'). Padded tail positions hold
-    garbage K/V inside the last real block; they are masked by the
-    length mask during decode and overwritten as the sequence grows.
+    garbage inside the last real block; they are masked by the length
+    mask during decode and overwritten as the sequence grows.
     """
+    model = paged_model(cfg)
+    if model.prefill is not None:
+        return model.prefill(params, tokens, cache, block_row, block_size)
+    S = tokens.shape[1]
+    logits, cache, _ = _prefill_chunk(
+        params, cfg, tokens, cache, block_row[None, :], block_row, block_size,
+        jnp.zeros((1,), jnp.int32), jnp.arange(S, dtype=jnp.int32))
+    return logits, cache
+
+
+def _dense_prefill(cfg: TransformerConfig, params: Params, tokens, cache: PagedCache,
+                   block_row, block_size: int):
+    """The dense decoder's whole-prompt program: full (flash) attention over
+    the padded bucket, every layer's roped K/V scattered after the scan."""
     b, S = tokens.shape
     assert b == 1 and S % block_size == 0
     positions = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -245,7 +349,7 @@ def paged_prefill(
 
 
 def prefill_and_sample(
-    params, cfg: TransformerConfig, tokens, cache, block_row, block_size: int,
+    params, cfg, tokens, cache, block_row, block_size: int,
     real_len, temp, key,
 ):
     """Prefill one slot and sample its first generated token on-device.
@@ -290,9 +394,60 @@ def _attend_chunk(q, ck, cv, qpos, cfg: TransformerConfig):
     return og.reshape(n, C, H * HD).astype(q.dtype)
 
 
+def _dense_decode_layer(cfg: TransformerConfig, x, pools, lp: Params, tables, lens, _params, _index):
+    """The dense decoder's layer, one token a slot (``PagedModel.decode_layer``)."""
+    x, ck, cv = _paged_layer_step(x, lp, cfg, *pools, tables, lens)
+    return x, (ck, cv), None
+
+
+def _dense_chunk_layer(cfg: TransformerConfig, x, pools, lp: Params, table_rows, rows_at, offs,
+                       qpos, _params, _index):
+    """The dense decoder's layer over a chunk call's token axis (``PagedModel
+    .chunk_layer``): K/V of the tokens into their blocks, then every tile
+    through its slot's gathered table."""
+    ck, cv = pools
+    n, C = qpos.shape
+    W, bs = table_rows.shape[1], ck.shape[1]
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    h = rms_norm(x, lp["attn_norm"])
+    q, k, v = project_qkv(h, lp, cfg, qpos.reshape(1, n * C))
+    ck = ck.at[rows_at, offs].set(k[0])
+    cv = cv.at[rows_at, offs].set(v[0])
+    ck_g = ck[table_rows].reshape(n, W * bs, KV, HD)
+    cv_g = cv[table_rows].reshape(n, W * bs, KV, HD)
+    o = _attend_chunk(q.reshape(n, C, H, HD), ck_g, cv_g, qpos, cfg)
+    x = x + (o.reshape(1, n * C, H * HD) @ lp["wo"].astype(o.dtype))
+    return mlp_block(x, lp, cfg), (ck, cv), None
+
+
+def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, chunk_row,
+                   block_size: int, starts, last_idx):
+    """``paged_prefill_chunk`` with the layers' summed counts (or None) third."""
+    b, T = tokens.shape
+    n = table_rows.shape[0]
+    C = T // n
+    assert b == 1 and T % block_size == 0 and T % n == 0
+    qpos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [n, C]
+
+    # Token j lands at (rows[j], offs[j]); padded tail rows point at the
+    # trash block via chunk_row. Rows, not whole blocks: a scatter of ONE
+    # block becomes an update-slice that copies the whole cache (v5e).
+    rows = jnp.repeat(chunk_row, block_size)
+    offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), T // block_size)
+    model = paged_model(cfg)
+
+    def layer(x, pools, lp, base, index):
+        return model.chunk_layer(
+            x, pools, lp, table_rows + base, rows + base, offs, qpos, params, index)
+
+    x = embed(params, tokens, cfg)
+    x, cache, counts = _scan_layers(layer, x, params, cache)
+    return unembed(params, x[:, last_idx], cfg)[0], cache, counts
+
+
 def paged_prefill_chunk(
     params: Params,
-    cfg: TransformerConfig,
+    cfg,
     tokens: jax.Array,  # [1, T] int32 — n tiles of T // n tokens (``chunk_tile``)
     cache: PagedCache,
     table_rows: jax.Array,  # [n, W] int32 — per tile, its slot's FULL block table
@@ -304,13 +459,13 @@ def paged_prefill_chunk(
     """Prefill several slots' suffixes in ONE call: the token axis holds
     them one after another, each padded to whole tiles, and a tile covers
     positions ``starts[t] ..`` of the slot whose table is
-    ``table_rows[t]``, attending to that slot's already-resident KV
+    ``table_rows[t]``, attending to that slot's already-resident
     blocks (prefix-cache hits or earlier chunks) plus the chunk itself.
 
     This is the suffix/chunked counterpart of ``paged_prefill``: instead
-    of full attention over the whole prompt it scatters the tokens' K/V
+    of full attention over the whole prompt it scatters the tokens' rows
     into ``chunk_row``'s blocks and attends, tile by tile, through the
-    gathered table under a causal position mask — so a prompt whose
+    slot's table under a causal position mask — so a prompt whose
     prefix is already in the cache only pays compute for the novel
     suffix. The projections, the FFN and the scatter run once over the
     whole axis, and only the rows ``last_idx`` names (one a segment, the
@@ -319,46 +474,34 @@ def paged_prefill_chunk(
     T serves every mix of segments, a lone suffix or one chunk of a long
     prompt among them. A tile nobody uses points at the trash block.
     Returns (logits [n, V] fp32, cache')."""
-    b, T = tokens.shape
-    n, W = table_rows.shape
-    C = T // n
-    assert b == 1 and T % block_size == 0 and T % n == 0
-    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    qpos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [n, C]
-    positions = qpos.reshape(1, T)
-
-    # Token j lands at (rows[j], offs[j]); padded tail rows point at the
-    # trash block via chunk_row. Rows, not whole blocks: a scatter of ONE
-    # block becomes an update-slice that copies the whole cache (v5e).
-    rows = jnp.repeat(chunk_row, block_size)
-    offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), T // block_size)
-
-    def layer(x, ck, cv, lp, base):
-        h = rms_norm(x, lp["attn_norm"])
-        q, k, v = project_qkv(h, lp, cfg, positions)
-        ck = ck.at[rows + base, offs].set(k[0])
-        cv = cv.at[rows + base, offs].set(v[0])
-        ck_g = ck[table_rows + base].reshape(n, W * block_size, KV, HD)
-        cv_g = cv[table_rows + base].reshape(n, W * block_size, KV, HD)
-        o = _attend_chunk(q.reshape(n, C, H, HD), ck_g, cv_g, qpos, cfg)
-        x = x + (o.reshape(1, T, H * HD) @ lp["wo"].astype(o.dtype))
-        return mlp_block(x, lp, cfg), ck, cv
-
-    x = embed(params, tokens, cfg)
-    x, cache = _scan_layers(layer, x, params["layers"], cache)
-    return unembed(params, x[:, last_idx], cfg)[0], cache
+    logits, cache, _ = _prefill_chunk(
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx)
+    return logits, cache
 
 
 def prefill_chunk_and_sample(
-    params, cfg: TransformerConfig, tokens, cache, table_rows, chunk_row,
+    params, cfg, tokens, cache, table_rows, chunk_row,
     block_size: int, starts, last_idx, temps, key,
 ):
     """Chunk prefill + on-device sampling of one token a segment, at
     ``last_idx`` and that segment's temperature. A segment's token is only
     meaningful where it holds its prompt's FINAL position; earlier chunks,
     and the entries past the last segment, are never read by the host, so
-    the extra samples cost no sync. → (tokens [n] int32, cache')."""
-    logits, cache = paged_prefill_chunk(
+    the extra samples cost no sync. → (tokens [n] int32, cache'); where the
+    model's layers count, the call's counts follow the tokens (``_with_counts``)."""
+    logits, cache, counts = _prefill_chunk(
         params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx
     )
-    return sample_tokens(logits, temps, key), cache
+    return _with_counts(sample_tokens(logits, temps, key), counts), cache
+
+
+@paged_model.register
+def _(cfg: TransformerConfig) -> PagedModel:
+    row = (cfg.n_kv_heads, cfg.head_dim)
+    return PagedModel(
+        rows={"k": row, "v": row},
+        n_layers=cfg.n_layers,
+        decode_layer=functools.partial(_dense_decode_layer, cfg),
+        chunk_layer=functools.partial(_dense_chunk_layer, cfg),
+        prefill=functools.partial(_dense_prefill, cfg),
+    )

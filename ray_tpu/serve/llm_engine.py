@@ -54,7 +54,7 @@ import os
 import queue
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Union
 
 logger = logging.getLogger("ray_tpu.serve.engine")
 
@@ -73,6 +73,9 @@ from ray_tpu.models.paged import (
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util import tracing
+
+if TYPE_CHECKING:
+    from ray_tpu.models.latent_moe import LatentMoEConfig
 
 _req_ids = itertools.count()
 _engine_ids = itertools.count()
@@ -104,10 +107,21 @@ _STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks
                 "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill")
 # The width under which a chunk call's time is the read of the weights and no
 # longer its tokens' arithmetic: two FLOPs and two bytes a parameter a token
-# put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e, where the
-# chunk program read 16.2 / 17.2 / 20.35 ms at 64 / 128 / 256 tokens and
-# 0.086 ms a token above (PERF.md section 5). ``_run_suffixes`` packs by it.
+# put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e whatever the
+# model, as long as a token multiplies every weight the call reads (an expert
+# layer's token multiplies a few of them: its ridge lies higher, and a fixed
+# ``prefill_chunk`` leaves this packing no width to choose). Measured on the
+# dense decoder of Mistral-7B's widths, whose chunk program read 16.2 / 17.2 /
+# 20.35 ms at 64 / 128 / 256 tokens and 0.086 ms a token above (PERF.md
+# section 5). ``_run_suffixes`` packs by it.
 _WEIGHTS_WIDTH = 256
+# Counts an expert model's programs return behind their tokens
+# (``paged._with_counts``), in this order: token-expert pairs computed by the
+# experts held here; held experts with at least one pair, summed over expert
+# layers and steps (or calls); expert layers x steps (or calls) counted. They
+# arrive on the transfers that bring the tokens: a chunk call whose output the
+# host never reads (no segment of it ends a prompt) is not counted.
+_MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps")
 
 
 @dataclasses.dataclass
@@ -435,7 +449,7 @@ class LLMEngine:
     def __init__(
         self,
         params,
-        cfg: TransformerConfig,
+        cfg: Union[TransformerConfig, "LatentMoEConfig"],
         pcfg: Optional[PagedConfig] = None,
         *,
         decode_window: int = 1,
@@ -509,6 +523,9 @@ class LLMEngine:
         if self._widths[-1] < p.max_seq_len:
             self._widths.append(p.max_seq_len)
         _leave_persistent_compile_cache()
+        # Whether the programs return counts behind their tokens (``_MOE_COUNTS``):
+        # ``_build_programs`` reads it off the decode program's output.
+        self._counted = False
         self.cache = init_paged_cache(cfg, p)
         (self._decode, self._prefill, self._prefill_chunk_fn,
          self.params) = self._build_programs(params)
@@ -562,6 +579,7 @@ class LLMEngine:
                       "prefix_published_blocks": 0, "prefill_segments": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       "windows_behind_prefill": 0, "prefill_flushed_first": 0,
+                      **{name: 0 for name in _MOE_COUNTS},
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED},
                       "starved_us": 0, "unloaded_us": 0,
                       **{f"starved_us_{where}": 0 for where in _STARVED}}
@@ -640,8 +658,9 @@ class LLMEngine:
             )
             # Also return next-window inputs (last sampled tokens, advanced
             # lens) as DEVICE outputs: chained windows and speculative
-            # dispatch re-upload nothing from the host.
-            return seq, seq[-1], lens + window, cache
+            # dispatch re-upload nothing from the host. (Rows past the
+            # window's, where there are any, are counts: ``_MOE_COUNTS``.)
+            return seq, seq[window - 1], lens + window, cache
 
         # A prefill program also puts what it sampled into the device's
         # ``cur``, at the slot it sampled it for, so the decode window that
@@ -662,7 +681,8 @@ class LLMEngine:
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts,
                 last_idx, temps, key,
             )
-            return toks, cache, cur.at[slot_of].set(toks, mode="drop")
+            # (Entries past the tiles', where there are any, are counts.)
+            return toks, cache, cur.at[slot_of].set(toks[:slot_of.shape[0]], mode="drop")
 
         sds = jax.ShapeDtypeStruct
         b, W = p.max_batch, p.max_blocks_per_seq
@@ -687,6 +707,8 @@ class LLMEngine:
         )
         compiled = dec.lower(*args_s).compile()
         (params_fmt, *_), _kwargs_fmt = compiled.input_formats
+        # Whether this model's programs carry counts behind their tokens.
+        self._counted = compiled.out_info[0].shape[0] > window
         if callable(params):
             # Materialize weights directly in the program's layout —
             # no second copy ever exists on device.
@@ -1285,6 +1307,10 @@ class LLMEngine:
             outs = {id(t): t for _, _, t, _ in pend}
             vals = dict(zip(outs, jax.device_get(list(outs.values()))))
         self._drained(self._pending_launch)
+        if self._counted:
+            for v in vals.values():
+                if v.ndim:  # a chunk call's; a whole-prompt program's is one token
+                    self._count(v[-len(_MOE_COUNTS):])
         with tracing.phase("engine.emit", self._phase_ms):
             self._at("emit")
             for i, req, t, k in pend:
@@ -1294,6 +1320,11 @@ class LLMEngine:
                 self.cur[i] = tok  # as the device's ``cur[i]`` is since the program ran
                 self._emit(i, tok)
         self._at("between")
+
+    def _count(self, counts):
+        """Add the counts one program call returned behind its tokens."""
+        for name, n in zip(_MOE_COUNTS, counts):
+            self.stats[name] += int(n)
 
     def _emit(self, i: int, tok: int):
         """Record + stream one generated token; retire the slot when done.
@@ -1434,6 +1465,8 @@ class LLMEngine:
         with tracing.phase("engine.harvest_wait", self._phase_ms):
             nxt = np.asarray(seq)  # [window, b]: the host blocks on the device
         self._drained(launch)  # not behind a speculated window: that is newer
+        if self._counted:
+            self._count(nxt[self.window:, 0])
         with tracing.phase("engine.emit", self._phase_ms):
             self._at("emit")
             for i, rid, gen in entries:
@@ -1678,6 +1711,9 @@ class LLMEngine:
                 ("prefill_flushed_first", m.engine_prefill_flushed_first),
                 ("prefix_hit_tokens", m.engine_prefix_hit_tokens),
                 ("prefix_lookup_tokens", m.engine_prefix_lookup_tokens),
+                ("moe_pairs_here", m.engine_moe_pairs_here),
+                ("moe_experts_touched", m.engine_moe_experts_touched),
+                ("moe_layer_steps", m.engine_moe_layer_steps),
             ):
                 delta = s[key] - prev.get(key, 0)
                 if delta:
@@ -1762,6 +1798,17 @@ class LLMEngine:
                 # Blocks that entered the index as their slot was given back
                 # (an answer's, a preempted request's), not at a prefill's end.
                 "published_blocks": self.stats["prefix_published_blocks"],
+            },
+            # An expert model's own counts (all 0 for a dense one): pairs the
+            # held experts computed, and held experts touched a counted layer.
+            moe={
+                "pairs_here": self.stats["moe_pairs_here"],
+                "experts_touched": self.stats["moe_experts_touched"],
+                "layer_steps": self.stats["moe_layer_steps"],
+                "experts_touched_per_layer_step": self.stats["moe_experts_touched"]
+                / max(1, self.stats["moe_layer_steps"]),
+                "pairs_per_touched_expert": self.stats["moe_pairs_here"]
+                / max(1, self.stats["moe_experts_touched"]),
             },
             overlap={
                 "enabled": self.overlap,

@@ -205,6 +205,24 @@ class _ServeMetrics:
             "(segments a call = this / serve_engine_prefill_chunks_total)",
             dr,
         )
+        self.engine_moe_pairs_here = Counter(
+            "serve_engine_moe_pairs_here_total",
+            "Token-expert pairs computed by the experts this replica holds "
+            "(an expert model's counted program calls)",
+            dr,
+        )
+        self.engine_moe_experts_touched = Counter(
+            "serve_engine_moe_experts_touched_total",
+            "Held experts with at least one pair, summed over expert layers and "
+            "counted steps or calls",
+            dr,
+        )
+        self.engine_moe_layer_steps = Counter(
+            "serve_engine_moe_layer_steps_total",
+            "Expert layers x steps or calls counted (touched experts a layer = "
+            "serve_engine_moe_experts_touched_total / this)",
+            dr,
+        )
         self.engine_overlap_windows = Counter(
             "serve_engine_overlap_windows_total",
             "Decode windows dispatched before the previous window was read "

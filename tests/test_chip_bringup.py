@@ -417,3 +417,104 @@ def test_paged_attend_kernel_reads_the_plain_forms_logits_on_the_chip():
     r = subprocess.run([sys.executable, "-c", _KERNEL_AGAINST_PLAIN_FORM], cwd=REPO, env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (models/latent_moe.py): the decode kernel
+# ---------------------------------------------------------------------------
+def test_latent_attend_compiles_for_v5e_at_the_published_widths(v5e):
+    """The decode kernel of the latent cache at the widths it is served at (128
+    heads against rows of 512 + 64 numbers padded to 640, blocks of 64, 32
+    slots, a table of 145): one named Pallas call, and no copy of the pool."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.latent_attention import latent_attention
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(
+        lambda q, pool, tables, lens: latent_attention(q, pool, tables, lens, 192 ** -0.5, 512)
+    ).lower(sds((32, 128, 640), jnp.bfloat16), sds((5 * 513, 64, 640), jnp.bfloat16),
+            sds((32, 145), np.int32), sds((32,), np.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["latent_attend"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_latent_prefill_attend_compiles_for_v5e_at_the_published_widths(v5e):
+    """The prefill kernel at the served widths: a chunk call's four tiles of 256
+    queries x 128 heads, each through its slot's table of 145 blocks of 64."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.latent_attention import latent_chunk_attention
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(
+        lambda q, pool, tables, qpos: latent_chunk_attention(q, pool, tables, qpos, 192 ** -0.5, 512)
+    ).lower(sds((4, 256, 128, 640), jnp.bfloat16), sds((5 * 513, 64, 640), jnp.bfloat16),
+            sds((4, 145), np.int32), sds((4, 256), np.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["latent_prefill_attend"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+_LATENT_KERNEL_AGAINST_PLAIN_FORM = """
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.ops import latent_attention as LA
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+kp, kq, kt = jax.random.split(jax.random.PRNGKey(1), 3)
+b, H, R, rank, bs, W, P = 8, 128, 640, 512, 64, 145, 1200
+pool = jax.random.normal(kp, (P, bs, R), jnp.float32).astype(jnp.bfloat16)
+q = (jax.random.normal(kq, (b, H, R), jnp.float32) * 0.3).astype(jnp.bfloat16)
+# slot 0 is idle (trash block) and its lens has run on past the table
+lens = jnp.asarray([20000, 0, 63, 64, 1000, 4095, 8191, 9279], jnp.int32)
+tables = np.zeros((b, W), np.int32)
+tables[1:] = np.asarray(jax.random.permutation(kt, jnp.arange(1, P)))[:7 * W].reshape(7, W)
+tables = jnp.asarray(tables)
+assert LA._tiles(pool, rank)
+kernel = np.asarray(jax.jit(lambda *a: LA._latent_attend(*a, 192 ** -0.5, rank))(
+    q, pool, tables, lens), np.float32)
+plain = np.asarray(jax.jit(lambda *a: LA.reference_latent_attention(*a, 192 ** -0.5, rank))(
+    q, pool, tables, lens), np.float32)
+assert np.isfinite(kernel).all()
+apart = np.abs(kernel - plain).max() / np.abs(plain).max()
+assert apart < 2**-6, apart  # both round their output to bfloat16; the kernel's weights too
+print("apart", apart)
+
+# the prefill kernel against the plain walk: four tiles of 256 queries, one on the trash block
+n, C = 4, 256
+qc = (jax.random.normal(kq, (n, C, H, R), jnp.float32) * 0.3).astype(jnp.bfloat16)
+starts = jnp.asarray([0, 0, 4000, 8960], jnp.int32)
+qpos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+per = LA._KV_ROWS // bs
+tb = jnp.pad(tables[:n], ((0, 0), (0, -W % per)))
+kernel = np.asarray(jax.jit(lambda *a: LA._latent_prefill_attend(*a, 192 ** -0.5, rank, per))(
+    qc, pool, tb, starts), np.float32)
+plain = np.asarray(jax.jit(lambda *a: LA._plain_chunk_attention(*a, 192 ** -0.5, rank, per))(
+    qc, pool, tb, qpos), np.float32)
+assert np.isfinite(kernel).all()
+apart = np.abs(kernel - plain).max() / np.abs(plain).max()
+assert apart < 2**-6, apart
+print("prefill apart", apart)
+"""
+
+
+def test_latent_kernels_read_the_plain_forms_sums_on_the_chip():
+    """The decode and the prefill kernel against their plain forms on a chip, at
+    the served widths, contexts from one token to the table's end. In a
+    process of its own: this one is held to the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    r = subprocess.run([sys.executable, "-c", _LATENT_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]

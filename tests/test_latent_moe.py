@@ -1,0 +1,296 @@
+"""The latent-attention expert decoder (``models/latent_moe.py``) through the
+paged programs and ``LLMEngine``, against the benchmark's plain float32
+reference (``chipbench/reference_latent_moe.py``) on seeded weights, at a small
+size on the CPU.
+
+Tolerances. Program and reference both run in float32 here and differ only in
+the order of their sums (absorbed against expanded attention, an online
+softmax, grouped against per-expert products): logits of size ~4 read 2e-6 to
+5e-6 apart. ``TOL`` leaves that two orders of room; a program computing in
+bfloat16 reads ~3e-2 and fails it (``test_bfloat16_fails_the_tolerance``).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import reference_latent_moe as R
+from chipbench import weights_latent_moe as W
+from ray_tpu.models import latent_moe as lm
+from ray_tpu.models import paged
+from ray_tpu.models.paged import TRASH_BLOCK, PagedConfig
+from ray_tpu.serve.llm_engine import LLMEngine
+
+TOL = 5e-4
+BS = 8
+CONF = dict(
+    vocab_size=256, hidden_size=64, num_hidden_layers=4, first_k_dense_replace=2,
+    num_attention_heads=4, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128, moe_intermediate_size=32,
+    n_routed_experts_published=8, num_experts_per_tok=2, n_shared_experts=1,
+    routed_scaling_factor=2.5, rope_theta=25.6e6, rms_norm_eps=1e-5,
+    experts_held_first=2, n_routed_experts=4)
+SEED = 2**31 + 11
+
+
+def dims_of(**kw) -> W.Dims:
+    return W.Dims.from_config({**CONF, **kw})
+
+
+def make(dims: W.Dims, dtype=jnp.float32):
+    key = W.seed_key(SEED)
+    params = jax.jit(lambda k: W.make_params(k, dims, dtype))(key)
+    return key, W.program_config(dims, dtype), params
+
+
+@pytest.fixture(scope="module")
+def model():
+    dims = dims_of()
+    return (dims,) + make(dims)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(3).integers(0, CONF["vocab_size"], (1, 40)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def ref_logits(model, tokens):
+    dims, key, _cfg, _params = model
+    return np.asarray(R.stream_logits(key, jnp.asarray(tokens), dims, jnp.float32)[0])
+
+
+def _served(params, cfg, tokens):
+    """Positions 0-23 by ``paged_prefill``, 24-39 by a chunk call on that cached
+    prefix, position 39 once more by a decode step: the three programs' logits."""
+    p = PagedConfig(block_size=BS, num_blocks=33, max_batch=4, max_blocks_per_seq=8)
+    cache = paged.init_paged_cache(cfg, p)
+    row = jnp.asarray([1, 2, 3], jnp.int32)
+    pre, cache = jax.jit(lambda t, c: paged.paged_prefill(params, cfg, t, c, row, BS))(
+        jnp.asarray(tokens[:, :24]), cache)
+    table = np.full((1, 8), TRASH_BLOCK, np.int32)
+    table[0, :5] = [1, 2, 3, 4, 5]
+    tile = paged.chunk_tile(16, BS)
+    n = 16 // tile
+    chunk, cache = jax.jit(lambda t, c: paged.paged_prefill_chunk(
+        params, cfg, t, c, jnp.asarray(np.repeat(table, n, 0)), jnp.asarray([4, 5], jnp.int32), BS,
+        jnp.asarray([24 + tile * i for i in range(n)], jnp.int32),
+        jnp.asarray([15] * n, jnp.int32)))(jnp.asarray(tokens[:, 24:40]), cache)
+    tables = np.full((4, 8), TRASH_BLOCK, np.int32)
+    tables[1] = table[0]
+    lens = np.zeros(4, np.int32)
+    lens[1] = 39
+    cur = np.zeros(4, np.int32)
+    cur[1] = tokens[0, 39]
+    dec, cache = jax.jit(lambda c: paged.paged_decode_step(
+        params, cfg, jnp.asarray(cur), c, jnp.asarray(tables), jnp.asarray(lens)))(cache)
+    return np.asarray(pre), np.asarray(chunk[0]), np.asarray(dec[1]), cache
+
+
+def test_prefill_chunk_and_decode_through_the_cache_agree_with_the_reference(model, tokens, ref_logits):
+    _dims, _key, cfg, params = model
+    pre, chunk, dec, _ = _served(params, cfg, tokens)
+    assert np.abs(pre - ref_logits[:24]).max() < TOL
+    assert np.abs(chunk - ref_logits[39]).max() < TOL  # the chunk's last row, on a cached prefix
+    assert np.abs(dec - ref_logits[39]).max() < TOL  # the same position, one token a slot
+
+
+def test_bfloat16_fails_the_tolerance(tokens, ref_logits):
+    """The comparison tells precisions apart: the same programs in bfloat16
+    (weights rounded, as the reference's) miss the float32 logits by far more."""
+    dims = dims_of()
+    key, cfg, params = make(dims, jnp.bfloat16)
+    ref = np.asarray(R.stream_logits(key, jnp.asarray(tokens), dims, jnp.bfloat16)[0])
+    pre, chunk, dec, _ = _served(params, cfg, tokens)
+    assert np.abs(pre - ref[:24]).max() > 10 * TOL
+    assert np.abs(dec - ref[39]).max() > 10 * TOL
+
+
+def test_the_leading_layers_cache_is_block_base_0(model, tokens):
+    """Layer ``i`` writes pool ``i`` of the stack, the leading (unscanned)
+    layers first: after a prefill into blocks 1-3 every layer's pool holds
+    rows there and nowhere else, and layer 0's rows are the leading layer's
+    own latents (its ``project`` of the embedded tokens)."""
+    _dims, _key, cfg, params = model
+    _, _, _, cache = _served(params, cfg, tokens)
+    (pool,) = cache.values()
+    assert pool.shape[0] == cfg.num_hidden_layers
+    written = np.abs(np.asarray(pool)).sum(axis=(2, 3)) > 0  # [L, blocks]
+    assert written[:, 1:6].all() and not written[:, 6:].any()
+    x = params["embed"][jnp.asarray(tokens[:, :24])]
+    lead0 = jax.tree.map(lambda a: a[0], params["lead"])
+    _, _, rows = lm.project(lm._norm(x, lead0["attn_norm"], cfg), lead0, cfg,
+                            jnp.arange(24, dtype=jnp.int32)[None])
+    np.testing.assert_allclose(np.asarray(pool[0, 1:4]).reshape(24, -1), np.asarray(rows[0]),
+                               atol=1e-6)
+
+
+def test_absorbed_and_expanded_attention_agree():
+    cfg = lm.LatentMoEConfig.tiny()  # the module's own small size and seeded init
+    params = lm.init_params(jax.random.PRNGKey(2), cfg)
+    lp = jax.tree.map(lambda a: a[1], params["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(5), (1, 19, cfg.hidden_size), jnp.float32)
+    q_nope, q_rope, rows = lm.project(h, lp, cfg, jnp.arange(19, dtype=jnp.int32)[None])
+    u = lm.absorbed_attention(q_nope[0], q_rope[0], rows[0], lp, cfg)
+    _, w_uv = lm._up_kv(lp, cfg, jnp.float32)
+    o = lm.expanded_attention(q_nope[0], q_rope[0], rows[0], lp, cfg)
+    # float32 sums in another order: 1e-6 of values of size ~1
+    np.testing.assert_allclose(np.asarray(jnp.einsum("qhc,chv->qhv", u, w_uv)), np.asarray(o),
+                               atol=2e-5)
+
+
+def test_router_takes_the_largest_normalises_and_scales(model):
+    _dims, _key, cfg, params = model
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    y = jax.random.normal(jax.random.PRNGKey(6), (33, cfg.hidden_size), jnp.float32)
+    experts, gates = lm.route(y, lp, cfg)
+    scores = 1 / (1 + np.exp(-np.asarray(y, np.float64) @ np.asarray(lp["router"], np.float64)))
+    want = np.argsort(-scores, axis=-1)[:, :cfg.num_experts_per_tok]
+    assert np.array_equal(np.sort(np.asarray(experts), -1), np.sort(want, -1))
+    chosen = np.take_along_axis(scores, np.asarray(experts), axis=-1)
+    np.testing.assert_allclose(np.asarray(gates), 2.5 * chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.5, rtol=1e-5)
+
+
+def test_a_token_with_no_expert_here_gets_the_shared_expert_only(model):
+    """Held experts 2-5 of 8, two a token: some tokens choose none of them. Their
+    routed part is exactly 0 and the layer's output is the shared expert's."""
+    _dims, _key, cfg, params = model
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    y = jax.random.normal(jax.random.PRNGKey(7), (64, cfg.hidden_size), jnp.float32)
+    experts, _ = lm.route(y, lp, cfg)
+    none_here = ~np.isin(np.asarray(experts), np.arange(2, 6)).any(-1)
+    assert none_here.any() and not none_here.all()
+    routed, _ = lm.routed_experts(y, lp, cfg, params["experts"], 0)
+    assert np.abs(np.asarray(routed)[none_here]).max() == 0
+    assert (np.abs(np.asarray(routed)[~none_here]).sum(-1) > 0).all()  # the others got something
+    whole, _ = lm.expert_layer(y, lp, cfg, params["experts"], 0)
+    shared = lm._swiglu(y, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    np.testing.assert_allclose(np.asarray(whole)[none_here], np.asarray(shared)[none_here], atol=1e-6)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 experts on 4 chips of 2: the routed parts that the four shares compute
+    (the program's expert layer, told which experts it holds) plus the shared
+    expert ONCE equal the uncut layer of the reference, which holds all 8."""
+    index = 2  # the first expert layer
+    uncut = dims_of(experts_held_first=0, n_routed_experts=8)
+    key = W.seed_key(SEED)
+    y = jax.random.normal(jax.random.PRNGKey(8), (48, CONF["hidden_size"]), jnp.float32)
+    shared, routed = R.expert_ffn(key, index, y, uncut, jnp.float32)
+    want = np.asarray(shared + routed)
+    total = np.asarray(shared).copy()
+    pairs = 0
+    for first in (0, 2, 4, 6):
+        dims = dims_of(experts_held_first=first, n_routed_experts=2)
+        cfg = W.program_config(dims, jnp.float32)
+        lp, held = jax.jit(lambda k, dims=dims: (
+            W.layer_params(k, index, dims, True), W.held_params(k, index, dims)))(key)
+        part, counts = lm.routed_experts(y, lp, cfg, jax.tree.map(lambda a: a[None], held), 0)
+        total += np.asarray(part)
+        pairs += int(counts[0])
+    assert pairs == 48 * CONF["num_experts_per_tok"]  # every pair computed on exactly one chip
+    np.testing.assert_allclose(total, want, atol=2e-5)  # float32, sums in another order
+
+
+def test_the_counters_add_up_over_a_known_batch(model):
+    """pairs = the (token, choice) pairs whose expert is held; touched = the
+    held experts among the chosen; one layer counted."""
+    _dims, _key, cfg, params = model
+    lp = jax.tree.map(lambda a: a[1], params["layers"])
+    y = jax.random.normal(jax.random.PRNGKey(9), (21, cfg.hidden_size), jnp.float32)
+    experts = np.asarray(lm.route(y, lp, cfg)[0])
+    here = (experts >= 2) & (experts < 6)
+    _, counts = lm.routed_experts(y, lp, cfg, params["experts"], 1)
+    assert [int(c) for c in counts] == [int(here.sum()), len(set(experts[here].tolist())), 1]
+
+
+def _deficits(ref, served):
+    """The reference's largest logit less its logit of the served token, over
+    the spread of its logits, at each generated position."""
+    chosen = np.take_along_axis(ref, np.asarray(served)[:, None], axis=-1)[:, 0]
+    return (ref.max(-1) - chosen) / ref.std(-1)
+
+
+def test_engine_serves_the_references_logits_with_cache_chunks_preemption_and_resume(model):
+    """``LLMEngine`` end to end on the configuration: a prefix cache, a fixed
+    prefill chunk, a pool so small that requests are preempted and resumed.
+    Every served token (greedy) is the reference's own choice at its position,
+    the reference being fed the served tokens as a forced continuation; the
+    three counters moved and add up."""
+    dims, key, cfg, params = model
+    p = PagedConfig(block_size=BS, num_blocks=22, max_batch=4, max_blocks_per_seq=16)
+    eng = LLMEngine(params, cfg, p, decode_window=3, overlap=True, enable_prefix_cache=True,
+                    prefill_chunk=16, seed=1)
+    rng = np.random.default_rng(4)
+    doc = rng.integers(0, CONF["vocab_size"], 36).tolist()
+    prompts = [doc + rng.integers(0, CONF["vocab_size"], 4 + i).tolist() for i in range(5)]
+    reqs = [eng.add_request(pr, 30) for pr in prompts]
+    for _ in range(2000):
+        if all(len(r.generated) == 30 for r in reqs):
+            break
+        eng.step()
+    assert [len(r.generated) for r in reqs] == [30] * 5
+    s = eng.stats
+    assert s["preemptions"] > 0 and s["prefix_hit_tokens"] > 0 and s["prefill_chunks"] > 0
+    expert_layers = dims.layers - dims.lead
+    assert s["moe_layer_steps"] >= expert_layers * s["steps"] * 3 - expert_layers * 3
+    assert s["moe_layer_steps"] % expert_layers == 0
+    assert 0 < s["moe_experts_touched"] <= dims.held * s["moe_layer_steps"]
+    assert s["moe_experts_touched"] <= s["moe_pairs_here"] <= 4 * 2 * 16 * s["moe_layer_steps"]
+    for pr, r in zip(prompts, reqs):
+        seq = np.asarray([pr + r.generated[:-1]], np.int32)
+        ref = np.asarray(R.stream_logits(key, jnp.asarray(seq), dims, jnp.float32)[0])
+        d = _deficits(ref[len(pr) - 1:], r.generated)
+        # 0 where the served token is the reference's largest; float32 rounding
+        # can swap a near-tie, which reads a deficit of its own size (~1e-6)
+        assert d.max() < 1e-4, d.max()
+
+
+# --- the two kernels of ops/latent_attention.py under the interpreter --------
+def _pool_and_tables(rng, slots, W, P=64, bs=16, R=256):
+    pool = jnp.asarray(rng.normal(size=(P, bs, R)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(P - 1)[:slots * W].reshape(slots, W) + 1, jnp.int32)
+    return pool, tables.at[0].set(TRASH_BLOCK)  # slot 0 holds nothing
+
+
+def test_latent_attend_kernel_reads_the_plain_forms_sums(monkeypatch):
+    """The decode kernel under the Pallas interpreter against the gather-and-
+    einsum form: several steps a slot, a context of one token, one that ends
+    on a block's last row, an idle slot whose ``lens`` has run past the table."""
+    from ray_tpu.ops import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_ROWS_PER_STEP", 64)  # 4 blocks a step
+    rng = np.random.default_rng(0)
+    pool, tables = _pool_and_tables(rng, 5, 9)
+    q = jnp.asarray(rng.normal(size=(5, 8, 256)), jnp.float32)
+    lens = jnp.asarray([5000, 0, 15, 100, 143], jnp.int32)
+    want = LA.reference_latent_attention(q, pool, tables, lens, 0.1, 128)
+    got = LA._latent_attend(q, pool, tables, lens, 0.1, 128, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)  # float32, other order
+
+
+def test_latent_prefill_kernel_reads_the_plain_forms_sums(monkeypatch):
+    """The prefill kernel under the interpreter against the plain walk, and
+    that against one query at a time in the decode's plain form: a tile on the
+    trash block, tiles deep in their tables, steps of two blocks."""
+    from ray_tpu.ops import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_KV_ROWS", 32)
+    monkeypatch.setattr(LA, "_QUERIES_PER_STEP", 4)
+    rng = np.random.default_rng(1)
+    pool, tables = _pool_and_tables(rng, 3, 9)
+    q = jnp.asarray(rng.normal(size=(3, 8, 8, 256)), jnp.float32)
+    starts = jnp.asarray([0, 40, 130], jnp.int32)
+    qpos = starts[:, None] + jnp.arange(8)[None]
+    padded = jnp.pad(tables, ((0, 0), (0, 1)))
+    plain = LA._plain_chunk_attention(q, pool, padded, qpos, 0.1, 128, 2)
+    kernel = LA._latent_prefill_attend(q, pool, padded, starts, 0.1, 128, 2, interpret=True)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain), atol=1e-5)
+    one_by_one = jnp.stack([jnp.stack([LA.reference_latent_attention(
+        q[t, c][None], pool, tables[t][None], qpos[t, c][None], 0.1, 128)[0]
+        for c in range(8)]) for t in range(3)])
+    np.testing.assert_allclose(np.asarray(plain), np.asarray(one_by_one), atol=1e-5)
