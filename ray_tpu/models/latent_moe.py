@@ -349,10 +349,11 @@ def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, para
 
 
 def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at, offs, qpos,
-                 params, index):
+                 live, params, index):
     """One layer over a chunk call's token axis. x: [1, T, D]; table_rows:
     [n, W] each tile's slot's table; token j's row lands at (rows_at[j],
-    offs[j]); qpos: [n, C] absolute positions by tile."""
+    offs[j]); qpos: [n, C] absolute positions by tile; live: [n] a tile's
+    real tokens, the attention of the others is zeros."""
     (pool,) = pools
     n, C = qpos.shape
     q_nope, q_rope, rows = project(
@@ -361,7 +362,7 @@ def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at
     with jax.named_scope("latent.scatter"):
         pool = pool.at[rows_at, offs].set(rows[0])
     u = latent_chunk_attention(
-        q.reshape((n, C) + q.shape[1:]), pool, table_rows, qpos,
+        q.reshape((n, C) + q.shape[1:]), pool, table_rows, qpos, live,
         cfg.softmax_scale, cfg.kv_lora_rank)
     a = attention_out(u.reshape((1, n * C) + u.shape[2:]), lp, cfg)
     x, counts = _finish(x, a, lp, cfg, params, index)

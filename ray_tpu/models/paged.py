@@ -111,9 +111,10 @@ class PagedModel(NamedTuple):
     # that the programs sum over layers and steps.
     decode_layer: Callable
     # A chunk call's token axis: (x [1, T, d], pools, lp, table_rows [n, W],
-    # rows_at [T], offs [T], qpos [n, C], params, index) -> (x, pools, counts):
-    # token j's row lands at (rows_at[j], offs[j]); tile t attends through
-    # table_rows[t].
+    # rows_at [T], offs [T], qpos [n, C], live [n], params, index) -> (x, pools,
+    # counts): token j's row lands at (rows_at[j], offs[j]); tile t attends
+    # through table_rows[t], and its first live[t] tokens are real (what a
+    # layer gives for the others nothing reads, so it may skip them).
     chunk_layer: Callable
     # (params, tokens, cache, block_row, block_size) -> (logits [S, V], cache),
     # or None: a whole prompt is then one tile of the chunk program.
@@ -314,7 +315,8 @@ def paged_prefill(
     S = tokens.shape[1]
     logits, cache, _ = _prefill_chunk(
         params, cfg, tokens, cache, block_row[None, :], block_row, block_size,
-        jnp.zeros((1,), jnp.int32), jnp.arange(S, dtype=jnp.int32))
+        jnp.zeros((1,), jnp.int32), jnp.arange(S, dtype=jnp.int32),
+        jnp.full((1,), S, jnp.int32))
     return logits, cache
 
 
@@ -401,10 +403,11 @@ def _dense_decode_layer(cfg: TransformerConfig, x, pools, lp: Params, tables, le
 
 
 def _dense_chunk_layer(cfg: TransformerConfig, x, pools, lp: Params, table_rows, rows_at, offs,
-                       qpos, _params, _index):
+                       qpos, _live, _params, _index):
     """The dense decoder's layer over a chunk call's token axis (``PagedModel
     .chunk_layer``): K/V of the tokens into their blocks, then every tile
-    through its slot's gathered table."""
+    through its slot's gathered table. Padded queries are computed like real
+    ones: a tile is a gather and two products whose cost a mask would not cut."""
     ck, cv = pools
     n, C = qpos.shape
     W, bs = table_rows.shape[1], ck.shape[1]
@@ -421,7 +424,7 @@ def _dense_chunk_layer(cfg: TransformerConfig, x, pools, lp: Params, table_rows,
 
 
 def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, chunk_row,
-                   block_size: int, starts, last_idx):
+                   block_size: int, starts, last_idx, live):
     """``paged_prefill_chunk`` with the layers' summed counts (or None) third."""
     b, T = tokens.shape
     n = table_rows.shape[0]
@@ -438,7 +441,7 @@ def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, c
 
     def layer(x, pools, lp, base, index):
         return model.chunk_layer(
-            x, pools, lp, table_rows + base, rows + base, offs, qpos, params, index)
+            x, pools, lp, table_rows + base, rows + base, offs, qpos, live, params, index)
 
     x = embed(params, tokens, cfg)
     x, cache, counts = _scan_layers(layer, x, params, cache)
@@ -455,6 +458,7 @@ def paged_prefill_chunk(
     block_size: int,
     starts: jax.Array,  # [n] int32 — per tile, absolute position of its first token
     last_idx: jax.Array,  # [n] int32 — per segment, where on the token axis it ends
+    live: Optional[jax.Array] = None,  # [n] int32 — per tile, its real tokens (None: all)
 ) -> Tuple[jax.Array, PagedCache]:
     """Prefill several slots' suffixes in ONE call: the token axis holds
     them one after another, each padded to whole tiles, and a tile covers
@@ -472,16 +476,21 @@ def paged_prefill_chunk(
     prompt's final token where the segment is its final chunk) reach the
     head. Everything but the shapes is traced: one compilation per width
     T serves every mix of segments, a lone suffix or one chunk of a long
-    prompt among them. A tile nobody uses points at the trash block.
+    prompt among them. A tile nobody uses points at the trash block and has
+    ``live`` 0; a segment's last tile holds its remainder, and a model may
+    leave the padding behind it uncomputed (``PagedModel.chunk_layer``).
     Returns (logits [n, V] fp32, cache')."""
+    n = table_rows.shape[0]
+    if live is None:
+        live = jnp.full((n,), tokens.shape[1] // n, jnp.int32)
     logits, cache, _ = _prefill_chunk(
-        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx)
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live)
     return logits, cache
 
 
 def prefill_chunk_and_sample(
     params, cfg, tokens, cache, table_rows, chunk_row,
-    block_size: int, starts, last_idx, temps, key,
+    block_size: int, starts, last_idx, live, temps, key,
 ):
     """Chunk prefill + on-device sampling of one token a segment, at
     ``last_idx`` and that segment's temperature. A segment's token is only
@@ -490,7 +499,7 @@ def prefill_chunk_and_sample(
     the extra samples cost no sync. → (tokens [n] int32, cache'); where the
     model's layers count, the call's counts follow the tokens (``_with_counts``)."""
     logits, cache, counts = _prefill_chunk(
-        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live
     )
     return _with_counts(sample_tokens(logits, temps, key), counts), cache
 

@@ -17,17 +17,20 @@ sums the row's first ``rank`` numbers: the same numbers as the expanded form
   elsewhere ``reference_latent_attention``, the gather of the padded table and
   float32 einsums (CPU, shapes that do not tile, the tests' oracle). Decided
   from what the code can see, as ``ops/paged_attention.py`` decides.
-- ``latent_chunk_attention(q, pool, tables, qpos, scale, rank)``: PREFILL, a
-  tile of queries a slot, also absorbed: a tile walks its slot's table
-  ``_KV_ROWS`` rows at a time up to its own last position (a dynamic trip
-  count: a tile on the trash block walks one step) under an online softmax, so
-  neither the padded table nor the ``[queries, heads, keys]`` scores of a 9k
-  prefix ever exist. Products in the cache's dtype with float32 accumulation,
+- ``latent_chunk_attention(q, pool, tables, qpos, live, scale, rank)``:
+  PREFILL, a tile of queries a slot, also absorbed: a tile walks its slot's
+  table ``_KV_ROWS`` rows at a time up to the position of its last LIVE query
+  (a dynamic trip count) under an online softmax, so neither the padded table
+  nor the ``[queries, heads, keys]`` scores of a 9k prefix ever exist.
+  ``live[t]`` of tile ``t``'s queries are real, the first ones; the work is
+  done ``_QUERIES_PER_STEP`` queries at a time, and a group with no real
+  query walks nothing and gives zeros (a tile nobody uses has ``live`` 0 and
+  costs nothing). Products in the cache's dtype with float32 accumulation,
   softmax in float32. On a TPU where the rows tile the Pallas kernel
-  ``latent_prefill_attend`` (``_QUERIES_PER_STEP`` queries x all heads a
-  program, the scores never leave VMEM), else ``_plain_chunk_attention``, the
-  same walk in plain ``jax.numpy`` (whose float32 scores go through HBM: at the
-  served widths they, not the products, are its time). The expanded
+  ``latent_prefill_attend`` (a group x all heads a program, the scores never
+  leave VMEM), else ``_plain_chunk_attention``, the same walk in plain
+  ``jax.numpy`` (whose float32 scores go through HBM: at the served widths
+  they, not the products, are its time). The expanded
   alternative (up-project each gathered step to per-head keys and values)
   costs ``2 * rank * H * (nope + v)`` operations a cached row a tile before any
   score; PERF.md has the forms timed.
@@ -219,7 +222,7 @@ def latent_attention(q, pool, tables, lens, scale: float, rank: int):
 
 
 def _latent_prefill_kernel(
-    tables_ref, starts_ref,  # scalar prefetch (SMEM): [n, steps * per], [n]
+    tables_ref, starts_ref, live_ref,  # scalar prefetch (SMEM): [n, steps * per], [n], [n]
     q_ref,  # VMEM [1, qb * H, R]: this program's queries, rows are (query, head)
     pool_hbm,  # the pool, left in HBM: [P, bs, R]
     o_ref,  # VMEM [1, qb * H, rank]
@@ -229,47 +232,63 @@ def _latent_prefill_kernel(
     """Program ``g`` holds queries ``(g % nq) * qb ..`` of tile ``g // nq``, at
     consecutive positions from the tile's start, and walks the tile's table a
     step of ``per`` blocks at a time up to its own last query, the next step's
-    blocks in flight into the other buffer while this one is folded in."""
+    blocks in flight into the other buffer while this one is folded in. Only
+    the tile's first ``live_ref[t]`` queries are real: a program that holds
+    one runs whole, one that holds none copies nothing, walks nothing and
+    writes zeros."""
     nq = pl.num_programs(0) // starts_ref.shape[0]
     g = pl.program_id(0)
     t = g // nq
-    first = starts_ref[t] + (g % nq) * qb  # position of the program's first query
+    at = (g % nq) * qb  # the program's first query, within its tile
+    real = at < live_ref[t]  # the first is real, or none is
     kv = per * bs
-    n_steps = jnp.minimum((first + qb - 1) // kv + 1, tables_ref.shape[1] // per)
 
-    def blocks(step, which, act):
-        for j in range(per):
-            blk = tables_ref[t, step * per + j]
-            act(pltpu.make_async_copy(
-                pool_hbm.at[blk], buf.at[which, pl.ds(j * bs, bs)], sems.at[which]))
+    # Zeros, not whatever the block held: the rows go on through the layer, and
+    # the next layer scatters what becomes of them into the pool, where both
+    # kernels multiply masked probabilities of 0 into them (0 * NaN is NaN).
+    @pl.when(jnp.logical_not(real))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    blocks(0, 0, lambda c: c.start())
-    q = q_ref[0]
-    shape = (qb * heads, kv)
-    row_pos = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // heads
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    @pl.when(real)
+    def _():
+        first = starts_ref[t] + at  # position of the program's first query
+        n_steps = jnp.minimum((first + qb - 1) // kv + 1, tables_ref.shape[1] // per)
 
-    def step_body(step, carry):
-        which, *softmax = carry
+        def blocks(step, which, act):
+            for j in range(per):
+                blk = tables_ref[t, step * per + j]
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[blk], buf.at[which, pl.ds(j * bs, bs)], sems.at[which]))
 
-        @pl.when(step + 1 < n_steps)
-        def _():
-            blocks(step + 1, 1 - which, lambda c: c.start())
+        blocks(0, 0, lambda c: c.start())
+        q = q_ref[0]
+        shape = (qb * heads, kv)
+        row_pos = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // heads
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
-        # DMA semaphores inside a kernel, not threading Events: no timeout exists
-        blocks(step, which, lambda c: c.wait())  # ray-tpu: lint-ignore[RTL008]
-        valid = step * kv + col <= row_pos
-        return (1 - which,) + _fold(q, buf[which], valid, softmax, scale, rank)
+        def step_body(step, carry):
+            which, *softmax = carry
 
-    _, _, l, acc = jax.lax.fori_loop(
-        0, n_steps, step_body, (0,) + _fold_start(qb * heads, rank))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+            @pl.when(step + 1 < n_steps)
+            def _():
+                blocks(step + 1, 1 - which, lambda c: c.start())
+
+            # DMA semaphores inside a kernel, not threading Events: no timeout exists
+            blocks(step, which, lambda c: c.wait())  # ray-tpu: lint-ignore[RTL008]
+            valid = step * kv + col <= row_pos
+            return (1 - which,) + _fold(q, buf[which], valid, softmax, scale, rank)
+
+        _, _, l, acc = jax.lax.fori_loop(
+            0, n_steps, step_body, (0,) + _fold_start(qb * heads, rank))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def _latent_prefill_attend(q, pool, tables, starts, scale: float, rank: int, per: int, *,
+def _latent_prefill_attend(q, pool, tables, starts, live, scale: float, rank: int, per: int, *,
                            interpret: bool = False):
     """q: [n, C, H, R]; tables: [n, steps * per] (padded); starts: [n] the
-    position of each tile's first query, the others follow it one by one."""
+    position of each tile's first query, the others follow it one by one;
+    live: [n] how many of each tile's queries are real."""
     n, C, H, R = q.shape
     bs = pool.shape[1]
     qb = _QUERIES_PER_STEP
@@ -278,7 +297,7 @@ def _latent_prefill_attend(q, pool, tables, starts, scale: float, rank: int, per
         functools.partial(_latent_prefill_kernel, bs=bs, per=per, rank=rank, scale=scale,
                           qb=qb, heads=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(n * nq,),
             in_specs=[
                 pl.BlockSpec((1, qb * H, R), lambda g, *_: (g, 0, 0)),
@@ -292,46 +311,56 @@ def _latent_prefill_attend(q, pool, tables, starts, scale: float, rank: int, per
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="latent_prefill_attend",
-    )(tables, starts, q.reshape(n * nq, qb * H, R), pool)
+    )(tables, starts, live, q.reshape(n * nq, qb * H, R), pool)
     return out.reshape(n, C, H, rank)
 
 
 @jax.named_scope("latent.attend")
-def latent_chunk_attention(q, pool, tables, qpos, scale: float, rank: int):
+def latent_chunk_attention(q, pool, tables, qpos, live, scale: float, rank: int):
     """Prefill: q [n, C, H, R] absorbed queries by tile; pool [P, bs, R];
     tables [n, W] each tile's slot's block ids (the chunk's own rows are in
     the pool already); qpos [n, C] absolute positions, CONSECUTIVE within a
-    tile. A query attends to the cached positions <= its own. → [n, C, H,
-    rank] in ``q.dtype``."""
+    tile; live [n] how many of a tile's queries, its first ones, are real. A
+    query attends to the cached positions <= its own. → [n, C, H, rank] in
+    ``q.dtype``: zeros from ``live`` rounded up to ``_QUERIES_PER_STEP`` on
+    (the padded queries short of that are computed like real ones)."""
     C = q.shape[1]
     bs = pool.shape[1]
     W = tables.shape[1]
     per = max(1, min(_KV_ROWS // bs, W))  # blocks a step
     tables = jnp.pad(tables, ((0, 0), (0, -W % per)))  # the trash block, masked
     if _use_pallas() and _tiles(pool, rank) and C % _QUERIES_PER_STEP == 0:
-        return _latent_prefill_attend(q, pool, tables, qpos[:, 0], scale, rank, per)
-    return _plain_chunk_attention(q, pool, tables, qpos, scale, rank, per)
+        return _latent_prefill_attend(q, pool, tables, qpos[:, 0], live, scale, rank, per)
+    return _plain_chunk_attention(q, pool, tables, qpos, live, scale, rank, per)
 
 
-def _plain_chunk_attention(q, pool, tables, qpos, scale: float, rank: int, per: int):
+def _plain_chunk_attention(q, pool, tables, qpos, live, scale: float, rank: int, per: int):
     """``latent_chunk_attention`` in plain ``jax.numpy``; ``tables`` padded to
-    whole steps of ``per`` blocks."""
+    whole steps of ``per`` blocks. The kernel's output, padding included: a
+    tile's queries count in the kernel's groups of ``_QUERIES_PER_STEP``."""
     n, C, H, R = q.shape
     bs = pool.shape[1]
     kv = per * bs
+    qb = _QUERIES_PER_STEP
 
     def tile(args):
-        qt, row, pos = args  # [C, H, R], [steps * per], [C]
+        qt, row, pos, real = args  # [C, H, R], [steps * per], [C], []
         q2 = qt.reshape(C * H, R)
         pos2 = jnp.repeat(pos, H)[:, None]  # [C*H, 1]
+        computed = jnp.minimum(-(-real // qb) * qb, C)  # whole groups
+        keep = jnp.repeat(jnp.arange(C) < computed, H)[:, None]
 
         def step(j, carry):
             blocks = jax.lax.dynamic_slice_in_dim(row, j * per, per)
             valid = j * kv + jnp.arange(kv)[None, :] <= pos2
             return _fold(q2, pool[blocks].reshape(kv, R), valid, carry, scale, rank)
 
-        _, l, acc = jax.lax.fori_loop(
-            0, jnp.max(pos) // kv + 1, step, _fold_start(C * H, rank))
-        return (acc / l).reshape(C, H, rank).astype(q.dtype)
+        # Up to the last computed query's position; no step where there is none.
+        n_steps = jnp.where(computed > 0, (pos[0] + computed - 1) // kv + 1, 0)
+        _, l, acc = jax.lax.fori_loop(0, n_steps, step, _fold_start(C * H, rank))
+        # Past the computed rows zeros, and never 0 / 0: ``l`` is 0 where no
+        # step ran, and short of its whole sum where the walk ended early.
+        out = jnp.where(keep, acc / jnp.where(keep, l, 1.0), 0.0)
+        return out.reshape(C, H, rank).astype(q.dtype)
 
-    return jax.lax.map(tile, (q, tables, qpos))
+    return jax.lax.map(tile, (q, tables, qpos, live))

@@ -577,6 +577,7 @@ class LLMEngine:
                       "prefix_lookup_tokens": 0, "prefix_evictions": 0,
                       "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
                       "prefix_published_blocks": 0, "prefill_segments": 0,
+                      "prefill_tile_queries": 0, "prefill_live_queries": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       "windows_behind_prefill": 0, "prefill_flushed_first": 0,
                       **{name: 0 for name in _MOE_COUNTS},
@@ -676,10 +677,10 @@ class LLMEngine:
             return tok, cache, cur.at[slot].set(tok, mode="drop")
 
         def _chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
-            starts, last_idx, slot_of = per_tile
+            starts, last_idx, slot_of, live = per_tile
             toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts,
-                last_idx, temps, key,
+                last_idx, live, temps, key,
             )
             # (Entries past the tiles', where there are any, are counts.)
             return toks, cache, cur.at[slot_of].set(toks[:slot_of.shape[0]], mode="drop")
@@ -1213,9 +1214,12 @@ class LLMEngine:
             crow = np.full(width // bs, TRASH_BLOCK, np.int32)
             # By tile: its first absolute position; by segment k: the axis
             # position of its last token, and whose ``cur`` its sampled token
-            # is (no slot's, unless the segment ends its prompt).
-            per_tile = np.zeros((3, n), np.int32)
-            starts, last_idx, slot_of = per_tile
+            # is (no slot's, unless the segment ends its prompt); by tile
+            # again: how many of its tokens are real (a segment's last tile
+            # holds its remainder, a tile no segment uses none). One array:
+            # every host argument is a transfer of its own.
+            per_tile = np.zeros((4, n), np.int32)
+            starts, last_idx, slot_of, live = per_tile
             slot_of[:] = p.max_batch
             temps = np.zeros(n, np.float32)
             at = 0  # the next free tile's first position on the axis
@@ -1226,6 +1230,7 @@ class LLMEngine:
                 toks[0, at:at + end - start] = full[start:end]
                 trows[t0:t0 + tiles, :len(blocks)] = blocks
                 starts[t0:t0 + tiles] = start + tile * np.arange(tiles)
+                live[t0:t0 + tiles] = np.minimum(tile, end - starts[t0:t0 + tiles])
                 under = blocks[start // bs:start // bs + tiles * tile // bs]
                 crow[at // bs:at // bs + len(under)] = under
                 last_idx[k] = at + end - start - 1
@@ -1233,6 +1238,10 @@ class LLMEngine:
                 if end == len(full):
                     slot_of[k] = i
                 at += tiles * tile
+            # Queries of the tiles the segments took, and the real ones among
+            # them: their ratio is the share of a taken tile that is real.
+            self.stats["prefill_tile_queries"] += at
+            self.stats["prefill_live_queries"] += int(live.sum())
         sub = self._split_key("admit")
         with tracing.phase("engine.admit.launch", ph):
             self._at("admit_launch")
@@ -1705,6 +1714,8 @@ class LLMEngine:
                 ("preemptions", m.engine_preemptions),
                 ("prefill_chunks", m.engine_prefill_chunks),
                 ("prefill_segments", m.engine_prefill_segments),
+                ("prefill_tile_queries", m.engine_prefill_tile_queries),
+                ("prefill_live_queries", m.engine_prefill_live_queries),
                 ("prefix_published_blocks", m.engine_prefix_published_blocks),
                 ("spec_windows", m.engine_overlap_windows),
                 ("windows_behind_prefill", m.engine_windows_behind_prefill),
@@ -1798,6 +1809,17 @@ class LLMEngine:
                 # Blocks that entered the index as their slot was given back
                 # (an answer's, a preempted request's), not at a prefill's end.
                 "published_blocks": self.stats["prefix_published_blocks"],
+            },
+            # Chunk-program calls: the segments in them, the queries of the
+            # tiles those took and the real ones among them (a segment is
+            # padded to whole tiles; a model may skip the padding).
+            prefill={
+                "chunks": self.stats["prefill_chunks"],
+                "segments": self.stats["prefill_segments"],
+                "tile_queries": self.stats["prefill_tile_queries"],
+                "live_queries": self.stats["prefill_live_queries"],
+                "live_query_pct": 100.0 * self.stats["prefill_live_queries"]
+                / max(1, self.stats["prefill_tile_queries"]),
             },
             # An expert model's own counts (all 0 for a dense one): pairs the
             # held experts computed, and held experts touched a counted layer.

@@ -205,6 +205,18 @@ class _ServeMetrics:
             "(segments a call = this / serve_engine_prefill_chunks_total)",
             dr,
         )
+        self.engine_prefill_tile_queries = Counter(
+            "serve_engine_prefill_tile_queries_total",
+            "Queries of the tiles that chunk-program calls' segments took (a "
+            "segment is padded to whole tiles)",
+            dr,
+        )
+        self.engine_prefill_live_queries = Counter(
+            "serve_engine_prefill_live_queries_total",
+            "Real queries among serve_engine_prefill_tile_queries_total (the "
+            "rest is padding, which the latent prefill kernel skips)",
+            dr,
+        )
         self.engine_moe_pairs_here = Counter(
             "serve_engine_moe_pairs_here_total",
             "Token-expert pairs computed by the experts this replica holds "

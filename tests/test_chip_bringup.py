@@ -329,15 +329,15 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
         assert n == {1: 1, 3: 3, 8: 2}[nb]
 
         def run(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
-            starts, last_idx, slot_of = per_tile
+            starts, last_idx, slot_of, live = per_tile
             toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx,
-                temps, key,
+                live, temps, key,
             )
             return toks, cache, cur.at[slot_of].set(toks, mode="drop")
 
         args = (sds((1, nb * bs), np.int32), cache, sds((n, w), np.int32), sds((nb,), np.int32),
-                sds((3, n), np.int32), sds((n,), np.float32), key, sds((b,), np.int32))
+                sds((4, n), np.int32), sds((n,), np.float32), key, sds((b,), np.int32))
     compiled = jax.jit(
         run, donate_argnums=(2,), in_shardings=(auto,) + (None,) * len(args),
     ).lower(params, *args).compile()
@@ -445,7 +445,8 @@ def test_latent_attend_compiles_for_v5e_at_the_published_widths(v5e):
 
 def test_latent_prefill_attend_compiles_for_v5e_at_the_published_widths(v5e):
     """The prefill kernel at the served widths: a chunk call's four tiles of 256
-    queries x 128 heads, each through its slot's table of 145 blocks of 64."""
+    queries x 128 heads, each through its slot's table of 145 blocks of 64 and
+    with its count of real queries."""
     from jax.sharding import SingleDeviceSharding
 
     from ray_tpu.ops.latent_attention import latent_chunk_attention
@@ -456,9 +457,10 @@ def test_latent_prefill_attend_compiles_for_v5e_at_the_published_widths(v5e):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = jax.jit(
-        lambda q, pool, tables, qpos: latent_chunk_attention(q, pool, tables, qpos, 192 ** -0.5, 512)
+        lambda q, pool, tables, qpos, live: latent_chunk_attention(
+            q, pool, tables, qpos, live, 192 ** -0.5, 512)
     ).lower(sds((4, 256, 128, 640), jnp.bfloat16), sds((5 * 513, 64, 640), jnp.bfloat16),
-            sds((4, 145), np.int32), sds((4, 256), np.int32)).compile()
+            sds((4, 145), np.int32), sds((4, 256), np.int32), sds((4,), np.int32)).compile()
     assert _kernel_names(compiled.as_text()) == ["latent_prefill_attend"]
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
@@ -487,21 +489,28 @@ apart = np.abs(kernel - plain).max() / np.abs(plain).max()
 assert apart < 2**-6, apart  # both round their output to bfloat16; the kernel's weights too
 print("apart", apart)
 
-# the prefill kernel against the plain walk: four tiles of 256 queries, one on the trash block
+# the prefill kernel against the plain walk: four tiles of 256 queries, one on the trash block,
+# every query real and then counts that are none, partial, one and short of a program's 16
 n, C = 4, 256
 qc = (jax.random.normal(kq, (n, C, H, R), jnp.float32) * 0.3).astype(jnp.bfloat16)
 starts = jnp.asarray([0, 0, 4000, 8960], jnp.int32)
 qpos = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
 per = LA._KV_ROWS // bs
 tb = jnp.pad(tables[:n], ((0, 0), (0, -W % per)))
-kernel = np.asarray(jax.jit(lambda *a: LA._latent_prefill_attend(*a, 192 ** -0.5, rank, per))(
-    qc, pool, tb, starts), np.float32)
-plain = np.asarray(jax.jit(lambda *a: LA._plain_chunk_attention(*a, 192 ** -0.5, rank, per))(
-    qc, pool, tb, qpos), np.float32)
-assert np.isfinite(kernel).all()
-apart = np.abs(kernel - plain).max() / np.abs(plain).max()
-assert apart < 2**-6, apart
-print("prefill apart", apart)
+attend = jax.jit(lambda *a: LA._latent_prefill_attend(*a, 192 ** -0.5, rank, per))
+walk = jax.jit(lambda *a: LA._plain_chunk_attention(*a, 192 ** -0.5, rank, per))
+for live in ([C, C, C, C], [0, 120, 1, 250], [0, 0, 0, 0]):
+    lv = jnp.asarray(live, jnp.int32)
+    kernel = np.asarray(attend(qc, pool, tb, starts, lv), np.float32)
+    plain = np.asarray(walk(qc, pool, tb, qpos, lv), np.float32)
+    assert np.isfinite(kernel).all() and np.isfinite(plain).all()
+    apart = np.abs(kernel - plain).max() / np.abs(plain).max() if any(live) else np.abs(kernel).max()
+    assert apart < 2**-6, (live, apart)
+    for t, real in enumerate(live):
+        computed = -(-real // LA._QUERIES_PER_STEP) * LA._QUERIES_PER_STEP
+        assert not kernel[t, computed:].any() and not plain[t, computed:].any(), (live, t)
+        assert real == 0 or kernel[t, :real].any(), (live, t)
+    print("prefill live", live, "apart", apart)
 """
 
 

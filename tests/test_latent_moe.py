@@ -215,6 +215,17 @@ def _deficits(ref, served):
     return (ref.max(-1) - chosen) / ref.std(-1)
 
 
+def _assert_every_served_token_is_the_references_choice(key, dims, prompts, reqs):
+    """The reference is fed prompt + served tokens as a forced continuation."""
+    for pr, r in zip(prompts, reqs):
+        seq = np.asarray([pr + r.generated[:-1]], np.int32)
+        ref = np.asarray(R.stream_logits(key, jnp.asarray(seq), dims, jnp.float32)[0])
+        d = _deficits(ref[len(pr) - 1:], r.generated)
+        # 0 where the served token is the reference's largest; float32 rounding
+        # can swap a near-tie, which reads a deficit of its own size (~1e-6)
+        assert d.max() < 1e-4, d.max()
+
+
 def test_engine_serves_the_references_logits_with_cache_chunks_preemption_and_resume(model):
     """``LLMEngine`` end to end on the configuration: a prefix cache, a fixed
     prefill chunk, a pool so small that requests are preempted and resumed.
@@ -241,13 +252,64 @@ def test_engine_serves_the_references_logits_with_cache_chunks_preemption_and_re
     assert s["moe_layer_steps"] % expert_layers == 0
     assert 0 < s["moe_experts_touched"] <= dims.held * s["moe_layer_steps"]
     assert s["moe_experts_touched"] <= s["moe_pairs_here"] <= 4 * 2 * 16 * s["moe_layer_steps"]
-    for pr, r in zip(prompts, reqs):
-        seq = np.asarray([pr + r.generated[:-1]], np.int32)
-        ref = np.asarray(R.stream_logits(key, jnp.asarray(seq), dims, jnp.float32)[0])
-        d = _deficits(ref[len(pr) - 1:], r.generated)
-        # 0 where the served token is the reference's largest; float32 rounding
-        # can swap a near-tie, which reads a deficit of its own size (~1e-6)
-        assert d.max() < 1e-4, d.max()
+    _assert_every_served_token_is_the_references_choice(key, dims, prompts, reqs)
+
+
+def test_engine_tells_the_chunk_program_each_tiles_real_queries_and_counts_them(model, monkeypatch):
+    """Suffixes shorter than their tiles, admitted in one iteration: the chunk
+    call's ``per_tile`` has a fourth row, the real queries of each tile (a
+    one-tile segment its length, a two-tile one a full tile and its
+    remainder, a tile no segment uses 0), the attention skips what lies past
+    them in programs of 4, and every served token is still the reference's
+    choice. ``prefill_live_queries`` / ``prefill_tile_queries`` count both
+    sides over the batch, and reach the report and the registry."""
+    from ray_tpu.ops import latent_attention as LA
+    from ray_tpu.serve.metrics import serve_metrics
+
+    monkeypatch.setattr(LA, "_QUERIES_PER_STEP", 4)
+    dims, key, cfg, params = model
+    p = PagedConfig(block_size=BS, num_blocks=129, max_batch=4, max_blocks_per_seq=32)
+    eng = LLMEngine(params, cfg, p, decode_window=3, overlap=True, enable_prefix_cache=True, seed=1)
+    eng.metrics_tags = {"deployment": "live-queries", "replica": "r0"}
+    assert paged.chunk_tile(128, BS) == 32
+    rng = np.random.default_rng(5)
+    doc = rng.integers(0, CONF["vocab_size"], 16).tolist()  # two blocks
+    eng.generate_batch([doc], 2)  # a prompt with no hit: no chunk call, the doc is published
+    assert (eng.stats["prefill_chunks"], eng.stats["prefill_tile_queries"]) == (0, 0)
+    rows = []
+    call = eng._prefill_chunk_fn
+    monkeypatch.setattr(eng, "_prefill_chunk_fn",
+                        lambda *a: rows.append(np.array(a[5])) or call(*a))
+    prompts = [doc + rng.integers(0, CONF["vocab_size"], n).tolist() for n in (5, 40)]
+    reqs = [eng.add_request(pr, 6) for pr in prompts]
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    # One call 128 wide: tiles of 32 for 5 and 32 + 8 tokens, and one to spare.
+    (per_tile,) = rows
+    assert per_tile.shape == (4, 4)
+    assert per_tile[0].tolist() == [16, 16, 48, 0] and per_tile[3].tolist() == [5, 32, 8, 0]
+    s = eng.stats
+    assert (s["prefill_chunks"], s["prefill_segments"]) == (1, 2)
+    assert (s["prefill_tile_queries"], s["prefill_live_queries"]) == (3 * 32, 5 + 40)
+    assert [len(r.generated) for r in reqs] == [6, 6]
+    _assert_every_served_token_is_the_references_choice(key, dims, prompts, reqs)
+    # A lone suffix of three tokens: a call one block wide, which is its tile.
+    rows.clear()
+    eng.generate_batch([doc + [7, 8, 9]], 2)
+    assert [r.tolist() for r in rows] == [[[16], [2], [0], [3]]]
+    assert (s["prefill_tile_queries"], s["prefill_live_queries"]) == (96 + 8, 45 + 3)
+    snap = eng.report_state()
+    assert snap["prefill"] == {
+        "chunks": 2, "segments": 3, "tile_queries": 104, "live_queries": 48,
+        "live_query_pct": pytest.approx(100 * 48 / 104)}
+    assert snap["stats"]["prefill_live_queries"] == 48
+    m = serve_metrics()
+    for counter, name, want in (
+            (m.engine_prefill_tile_queries, "serve_engine_prefill_tile_queries_total", 104),
+            (m.engine_prefill_live_queries, "serve_engine_prefill_live_queries_total", 48)):
+        assert counter.name == name
+        assert [value for _n, _t, _d, tags, value in counter._drain()
+                if dict(tags)["deployment"] == "live-queries"] == [want]
 
 
 # --- the two kernels of ops/latent_attention.py under the interpreter --------
@@ -273,10 +335,17 @@ def test_latent_attend_kernel_reads_the_plain_forms_sums(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)  # float32, other order
 
 
-def test_latent_prefill_kernel_reads_the_plain_forms_sums(monkeypatch):
+# ``live`` by tile (slot 0's on the trash block, slots 1 and 2 deep in their
+# tables, 8 queries a tile in programs of 4): full; partial and not a multiple
+# of a program; one real query; none, deep in a table and on the trash tile.
+@pytest.mark.parametrize("live", [(8, 8, 8), (8, 4, 3), (5, 1, 6), (0, 0, 7), (0, 8, 0)],
+                         ids=lambda v: "live" + "-".join(map(str, v)))
+def test_latent_prefill_kernel_reads_the_plain_forms_sums(monkeypatch, live):
     """The prefill kernel under the interpreter against the plain walk, and
     that against one query at a time in the decode's plain form: a tile on the
-    trash block, tiles deep in their tables, steps of two blocks."""
+    trash block, tiles deep in their tables, steps of two blocks. The two
+    forms agree on the WHOLE output, padding included: from ``live`` rounded
+    up to a program on it is exactly 0, below ``live`` the oracle's."""
     from ray_tpu.ops import latent_attention as LA
 
     monkeypatch.setattr(LA, "_KV_ROWS", 32)
@@ -287,10 +356,83 @@ def test_latent_prefill_kernel_reads_the_plain_forms_sums(monkeypatch):
     starts = jnp.asarray([0, 40, 130], jnp.int32)
     qpos = starts[:, None] + jnp.arange(8)[None]
     padded = jnp.pad(tables, ((0, 0), (0, 1)))
-    plain = LA._plain_chunk_attention(q, pool, padded, qpos, 0.1, 128, 2)
-    kernel = LA._latent_prefill_attend(q, pool, padded, starts, 0.1, 128, 2, interpret=True)
-    np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain), atol=1e-5)
-    one_by_one = jnp.stack([jnp.stack([LA.reference_latent_attention(
+    lv = jnp.asarray(live, jnp.int32)
+    plain = np.asarray(LA._plain_chunk_attention(q, pool, padded, qpos, lv, 0.1, 128, 2))
+    kernel = np.asarray(
+        LA._latent_prefill_attend(q, pool, padded, starts, lv, 0.1, 128, 2, interpret=True))
+    assert np.isfinite(kernel).all() and np.isfinite(plain).all()
+    np.testing.assert_allclose(kernel, plain, atol=1e-5)
+    one_by_one = np.asarray(jnp.stack([jnp.stack([LA.reference_latent_attention(
         q[t, c][None], pool, tables[t][None], qpos[t, c][None], 0.1, 128)[0]
-        for c in range(8)]) for t in range(3)])
-    np.testing.assert_allclose(np.asarray(plain), np.asarray(one_by_one), atol=1e-5)
+        for c in range(8)]) for t in range(3)]))
+    for t, real in enumerate(live):
+        computed = -(-real // 4) * 4
+        for out in (kernel, plain):
+            assert not out[t, computed:].any()  # exactly 0, not merely small
+            np.testing.assert_allclose(out[t, :real], one_by_one[t, :real], atol=1e-5)
+
+
+def test_a_skipped_program_writes_zeros_over_what_its_block_held(monkeypatch):
+    """A program past its tile's count WRITES its block of the output: the
+    kernel's result does not depend on what the buffer held before (the
+    interpreter hands a kernel an output full of NaN where it can)."""
+    from ray_tpu.ops import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_KV_ROWS", 32)
+    monkeypatch.setattr(LA, "_QUERIES_PER_STEP", 4)
+    rng = np.random.default_rng(2)
+    pool, tables = _pool_and_tables(rng, 2, 9)
+    q = jnp.asarray(rng.normal(size=(2, 8, 8, 256)), jnp.float32)
+    padded = jnp.pad(tables, ((0, 0), (0, 1)))
+    args = (q, pool, padded, jnp.asarray([0, 70], jnp.int32))
+    with jax.debug_nans(True):  # raises if a NaN leaves the kernel
+        out = np.asarray(LA._latent_prefill_attend(
+            *args, jnp.asarray([0, 2], jnp.int32), 0.1, 128, 2, interpret=True))
+    assert not out[0].any() and not out[1, 4:].any() and out[1, :2].any()
+
+
+def test_a_pool_that_took_a_chunk_calls_padded_rows_stays_finite_through_decode(
+        monkeypatch, model, tokens):
+    """The 0 x NaN hazard: a chunk call scatters its PADDED tokens' latent rows
+    too, some into the slot's own last block past its length, and the next
+    layer's come from what the attention gave for them. Both attentions
+    multiply masked probabilities of 0 into those rows, so they must be
+    finite: zeros go in, and the decode step that follows reads its
+    position's logits off the reference."""
+    from ray_tpu.ops import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_QUERIES_PER_STEP", 4)
+    _dims, key, cfg, params = model
+    dims = dims_of()
+    p = PagedConfig(block_size=BS, num_blocks=33, max_batch=4, max_blocks_per_seq=8)
+    cache = paged.init_paged_cache(cfg, p)
+    # 17 real tokens on a chunk axis of 32, one tile (``chunk_tile(32, 8)``):
+    # queries 17-19 are padding computed with query 16, 20-31 are skipped, and
+    # the slot's last block (positions 16-23) takes rows of both kinds; the
+    # fourth block of the axis lands on the trash block.
+    plen = 17
+    tile = paged.chunk_tile(32, BS)
+    assert tile == 32
+    table = np.full((1, 8), TRASH_BLOCK, np.int32)
+    table[0, :3] = [1, 2, 3]
+    toks = np.zeros((1, 32), np.int32)
+    toks[0, :plen] = tokens[0, :plen]
+    logits, cache = jax.jit(lambda t, c: paged.paged_prefill_chunk(
+        params, cfg, t, c, jnp.asarray(table), jnp.asarray([1, 2, 3, TRASH_BLOCK], jnp.int32), BS,
+        jnp.asarray([0], jnp.int32), jnp.asarray([plen - 1], jnp.int32),
+        jnp.asarray([plen], jnp.int32)))(jnp.asarray(toks), cache)
+    (pool,) = cache.values()
+    assert np.isfinite(np.asarray(pool)).all()
+    ref = np.asarray(R.stream_logits(key, jnp.asarray(tokens[:, :plen + 1]), dims, jnp.float32)[0])
+    assert np.abs(np.asarray(logits[0]) - ref[plen - 1]).max() < TOL
+    tables = np.full((4, 8), TRASH_BLOCK, np.int32)
+    tables[2] = table[0]
+    lens = np.zeros(4, np.int32)
+    lens[2] = plen
+    cur = np.zeros(4, np.int32)
+    cur[2] = tokens[0, plen]
+    dec, cache = jax.jit(lambda c: paged.paged_decode_step(
+        params, cfg, jnp.asarray(cur), c, jnp.asarray(tables), jnp.asarray(lens)))(cache)
+    assert np.isfinite(np.asarray(dec)).all()
+    assert np.isfinite(np.asarray(next(iter(cache.values())))).all()
+    assert np.abs(np.asarray(dec[2]) - ref[plen]).max() < TOL

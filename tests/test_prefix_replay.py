@@ -100,9 +100,11 @@ class _Programs:
 
     def chunk(self, params, toks, cache, table_rows, chunk_row, per_tile, temps, key, cur):
         """The packed signature. A segment's tiles follow one another under
-        one slot's table row; a tile nobody uses lies on the trash block."""
-        starts, _last_idx, slot_of = per_tile
-        live = (table_rows != TRASH_BLOCK).any(axis=1)
+        one slot's table row; a tile nobody uses lies on the trash block and
+        counts no real query."""
+        starts, _last_idx, slot_of, real = per_tile
+        live = real > 0
+        assert (live == (table_rows != TRASH_BLOCK).any(axis=1)).all()
         tile = toks.shape[1] // len(starts)
         goes_on = (table_rows[1:] == table_rows[:-1]).all(axis=1) & (
             starts[1:] == starts[:-1] + tile)
