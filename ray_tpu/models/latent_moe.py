@@ -332,10 +332,11 @@ def _finish(x, a, lp: Params, cfg: LatentMoEConfig, params: Params, index):
 # ---------------------------------------------------------------------------
 
 
-def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, params, index):
+def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, params, index, bases):
     """One layer, one token a slot. x: [b, 1, D]; pools: (rows [P, bs, R],);
-    tables: [b, W] block ids into it; lens: [b] write positions."""
+    tables: [b, W] block ids into it, from ``bases[0]``; lens: [b] write positions."""
     (pool,) = pools
+    tables = tables + bases[0]
     bs = pool.shape[1]
     q_nope, q_rope, rows = project(_norm(x, lp["attn_norm"], cfg), lp, cfg, lens[:, None])
     q = absorb(q_nope[:, 0], q_rope[:, 0], lp, cfg)
@@ -349,12 +350,13 @@ def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, para
 
 
 def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at, offs, qpos,
-                 live, params, index):
+                 live, params, index, bases, _slot_of):
     """One layer over a chunk call's token axis. x: [1, T, D]; table_rows:
     [n, W] each tile's slot's table; token j's row lands at (rows_at[j],
     offs[j]); qpos: [n, C] absolute positions by tile; live: [n] a tile's
     real tokens, the attention of the others is zeros."""
     (pool,) = pools
+    table_rows, rows_at = table_rows + bases[0], rows_at + bases[0]
     n, C = qpos.shape
     q_nope, q_rope, rows = project(
         _norm(x, lp["attn_norm"], cfg), lp, cfg, qpos.reshape(1, n * C))
@@ -372,8 +374,7 @@ def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at
 @paged.paged_model.register
 def _(cfg: LatentMoEConfig) -> paged.PagedModel:
     return paged.PagedModel(
-        rows={"rows": (cfg.row_width,)},
-        n_layers=cfg.num_hidden_layers,
+        pools={"rows": paged.Pool(row=(cfg.row_width,), layers=cfg.num_hidden_layers)},
         decode_layer=functools.partial(_decode_layer, cfg),
         chunk_layer=functools.partial(_chunk_layer, cfg),
     )

@@ -5,24 +5,35 @@ The reference's LLM-serving story is vLLM running as Ray actors (SURVEY
 block-paged KV memory and iteration-level (continuous) batching — are
 re-designed for XLA's static-shape world:
 
-- **Physical cache**: pools of fixed-size blocks, stacked by layer:
-  ``[L, num_blocks, block_size, *row]`` each. WHICH pools, and what one
-  cached row is, is the model's (``paged_model(cfg).rows``): the dense
+- **Physical cache**: pools stacked by layer, each declared by the model
+  (``paged_model(cfg).pools``, a ``Pool`` each) with its OWN count of
+  layers, its own row and its own unit. A pool of ``blocks`` is
+  ``[layers, num_blocks, block_size, *row]``: tokens in fixed-size blocks
+  that the host allocates. A pool of ``slots`` is ``[layers, max_batch,
+  *row]``: ONE row a decode slot, slot ``i``'s at index ``i``, for state
+  that does not grow with the sequence (a state-space layer's). The dense
   decoder has ``k`` and ``v`` with rows ``[kv_heads, head_dim]``; the
   latent-attention decoder (``models/latent_moe.py``) ONE pool whose row
-  is the token's latent and rotary key. Block 0 is a reserved trash block
-  that idle decode slots harmlessly write to, so the decode step never
-  branches on slot liveness. The layer scans address the pools in place,
-  as flat pools (``_scan_layers``).
+  is the token's latent and rotary key; the hybrid state-space decoder
+  (``models/hybrid_ssm.py``) ``k`` and ``v`` for its few attention layers
+  and two slot pools for its many state-space ones. Block 0 is a reserved
+  trash block that idle decode slots harmlessly write to, so the decode
+  step never branches on slot liveness for a pool of blocks. A pool of
+  slots has no trash row: a recurrent update is not idempotent, so an idle
+  or still-prefilling slot (``lens`` 0 all through a window) must be left
+  alone by the layer itself. The layer scans address the pools in place, as
+  flat pools (``_scan_layers``).
 - **Models**: the programs below are one skeleton (embed, the layers over
   the carried pools, the head, sampling) around a model's two layer
   bodies, one token a slot and a chunk call's token axis
   (``PagedModel.decode_layer``, ``.chunk_layer``). ``paged_model``
   dispatches on the TYPE of the configuration object; no flag selects.
   Parameters are ``embed``, ``final_norm``, ``lm_head``, ``layers`` (one
-  body, stacked, scanned) and, where a model has leading layers whose
-  parameters have other shapes, ``lead`` (stacked, run one by one BEFORE
-  the scan, at block bases 0, num_blocks, ..).
+  body, stacked, scanned: one layer, or one PERIOD of several layers of
+  several kinds, each pool giving the body as many of its layers as it has
+  per period) and, where a model has leading layers whose parameters have
+  other shapes, ``lead`` (stacked, run one by one BEFORE the scan, each
+  on one layer of every pool).
 - **Block tables**: each decode slot owns a row ``[max_blocks_per_seq]``
   of physical block ids. Tables/lengths are tiny int32 arrays passed
   into the jitted step each iteration — the host allocator (see
@@ -55,7 +66,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -95,30 +106,57 @@ class PagedConfig:
         return self.num_blocks - 1  # minus trash
 
 
+class Pool(NamedTuple):
+    """One pool of a model's cache: ``[layers, units, *row]`` where a unit is
+    a block of tokens (``[num_blocks, block_size]``) or a decode slot
+    (``[max_batch]``)."""
+
+    row: Tuple[int, ...]  # what one token of a block, or one slot, holds
+    layers: int  # layers that keep such rows (leading ones included)
+    unit: str = "blocks"  # or "slots"
+    dtype: Optional[Any] = None  # None: the configuration's
+
+    def units(self, pcfg: "PagedConfig") -> Tuple[int, ...]:
+        return (pcfg.max_batch,) if self.unit == "slots" else (pcfg.num_blocks, pcfg.block_size)
+
+
 class PagedModel(NamedTuple):
     """What a model gives the paged programs (``paged_model(cfg)``)."""
 
-    # Pool name -> shape of ONE cached row; a pool is [L, blocks, bs, *row].
-    rows: Dict[str, Tuple[int, ...]]
-    n_layers: int  # leading ones included
+    pools: Dict[str, Pool]
     # One token a slot: (x [b, 1, d], pools, lp, tables [b, W], lens [b],
-    # params, index) -> (x, pools, counts). ``pools`` is a tuple in ``rows``'
-    # order, each ANY flat pool that holds this layer's blocks at ``tables``'
-    # ids; ``lp`` the layer's own slice of ``lead`` or ``layers``; ``params``
-    # the whole tree and ``index`` the layer's number, for what a model keeps
-    # outside the scanned stack (weights a kernel must be handed whole, not as
-    # a slice that would be copied); ``counts`` is None or an int32 vector
-    # that the programs sum over layers and steps.
+    # params, index, bases) -> (x, pools, counts). ``pools`` is a tuple in
+    # the declared order, each ANY flat pool ``[layers * units, ..]``, and
+    # ``bases`` a tuple beside it: the first unit (block, or slot row) of the
+    # first of this call's layers in that pool, the next layer's ``units``
+    # further. A layer addresses block ``tables[i, j]`` at ``bases[k] +
+    # tables[i, j]`` and slot ``i``'s row at ``bases[k] + i``. ``lp`` is the
+    # body's own slice of ``lead`` or ``layers``; ``params`` the whole tree
+    # and ``index`` the number of the call (leading layers, then scanned
+    # bodies), for what a model keeps outside the scanned stack (weights a
+    # kernel must be handed whole, not as a slice that would be copied);
+    # ``counts`` is None or an int32 vector that the programs sum over layers
+    # and steps. A slot whose ``lens`` is 0 holds no sequence: its rows of a
+    # slot pool must come back as they went in.
     decode_layer: Callable
     # A chunk call's token axis: (x [1, T, d], pools, lp, table_rows [n, W],
-    # rows_at [T], offs [T], qpos [n, C], live [n], params, index) -> (x, pools,
-    # counts): token j's row lands at (rows_at[j], offs[j]); tile t attends
-    # through table_rows[t], and its first live[t] tokens are real (what a
-    # layer gives for the others nothing reads, so it may skip them).
+    # rows_at [T], offs [T], qpos [n, C], live [n], params, index, bases,
+    # slot_of [n]) -> (x, pools, counts): token j's row lands at (rows_at[j],
+    # offs[j]) (block ids as ``tables``' are, less the base); tile t attends
+    # through table_rows[t], its first live[t] tokens are real (what a layer
+    # gives for the others nothing reads, so it may skip them) and it belongs
+    # to decode slot slot_of[t] (``max_batch`` where to none): a segment's
+    # tiles lie one after another, begin at qpos[t, 0] (0: from nothing; above:
+    # from what the slot's rows hold) and leave the slot's rows as the
+    # segment's last real token leaves them.
     chunk_layer: Callable
     # (params, tokens, cache, block_row, block_size) -> (logits [S, V], cache),
-    # or None: a whole prompt is then one tile of the chunk program.
+    # or None: a whole prompt is then tiles of the chunk program.
     prefill: Optional[Callable] = None
+    # (params, tokens, cfg) -> x and (params, x, cfg) -> float32 logits, where
+    # they are not ``models/transformer.py``'s (a scaled embedding, a tied head).
+    embed: Callable = embed
+    unembed: Callable = unembed
 
 
 @functools.singledispatch
@@ -135,38 +173,54 @@ def _add_counts(total, counts):
 
 
 def init_paged_cache(cfg, pcfg: PagedConfig) -> PagedCache:
-    model = paged_model(cfg)
-    lead = (model.n_layers, pcfg.num_blocks, pcfg.block_size)
-    return {name: jnp.zeros(lead + row, cfg.dtype) for name, row in model.rows.items()}
+    return {name: jnp.zeros((pool.layers,) + pool.units(pcfg) + pool.row, pool.dtype or cfg.dtype)
+            for name, pool in paged_model(cfg).pools.items()}
 
 
-def _scan_layers(layer, x, params: Params, cache: PagedCache):
-    """Run ``layer(x, pools, lp, base, index) -> (x, pools, counts)`` over the
-    layers, carrying each stacked pool as one flat pool ``[L*num_blocks, bs,
-    *row]`` (a bitcast, both ways). ``base`` is the first block of layer
-    ``index`` in it: a layer addresses its block ``b`` at ``base + b`` and
-    updates the carry in place; no layer's pool is taken out of the stack or
-    put back, and only ``base`` knows how layers are laid out. Leading layers
-    (``params["lead"]``, their own parameter tree, stacked) run one by one
-    before the scan over ``params["layers"]``, at bases 0, num_blocks, ..; the
-    scanned ones follow. → (x, cache', summed counts or None)."""
-    shapes = {name: pool.shape for name, pool in cache.items()}
-    L, nb = next(iter(shapes.values()))[:2]
-    pools = tuple(pool.reshape((L * nb,) + pool.shape[2:]) for pool in cache.values())
+def slot_pools(cfg) -> Tuple[str, ...]:
+    """The model's pools that hold one row a decode slot (recurrent state)."""
+    return tuple(name for name, pool in paged_model(cfg).pools.items() if pool.unit == "slots")
+
+
+def _scan_layers(layer, x, params: Params, cache: PagedCache, names):
+    """Run ``layer(x, pools, lp, bases, index) -> (x, pools, counts)`` over the
+    layers (``pools`` in the order of ``names``, the model's declared one: a
+    dictionary that crossed ``jit`` comes back sorted), carrying each stacked
+    pool ``[L, units, ..]`` as one flat pool ``[L*units, ..]`` (a bitcast, both
+    ways; ``units`` is the pool's own: its blocks, or the slots). ``bases[k]``
+    is the first unit of the call's
+    first layer in pool ``k``: a layer addresses its unit ``u`` at ``base +
+    u`` and updates the carry in place; no layer's pool is taken out of the
+    stack or put back, and only the bases know how layers are laid out.
+    Leading layers (``params["lead"]``, their own parameter tree, stacked)
+    run one by one before the scan over ``params["layers"]``, each on one
+    layer of every pool; a scanned body follows on as many layers of each
+    pool as the pool has left per body (one, for a model of one kind of
+    layer; a period's count of that kind, for a model of several).
+    → (x, cache', summed counts or None)."""
+    shapes = {name: cache[name].shape for name in names}
+    pools = tuple(cache[name].reshape((-1,) + shapes[name][2:]) for name in names)
     lead = params.get("lead")
     n_lead = 0 if lead is None else jax.tree.leaves(lead)[0].shape[0]
+    n_bodies = jax.tree.leaves(params["layers"])[0].shape[0]
+    units = tuple(shape[1] for shape in shapes.values())
+    # Layers of each pool that one scanned body runs.
+    per_body = tuple((shape[0] - n_lead) // n_bodies for shape in shapes.values())
+    assert all(n_lead + n * n_bodies == shape[0] for n, shape in zip(per_body, shapes.values()))
     total = None
     for j in range(n_lead):
-        x, pools, counts = layer(x, pools, jax.tree.map(lambda a: a[j], lead), j * nb, j)
+        x, pools, counts = layer(
+            x, pools, jax.tree.map(lambda a: a[j], lead), tuple(j * u for u in units), j)
         total = _add_counts(total, counts)
 
     def body(carry, xs):
-        lp, base = xs
-        x, pools, counts = layer(*carry, lp, base, base // nb)
+        lp, j = xs
+        bases = tuple((n_lead + j * n) * u for n, u in zip(per_body, units))
+        x, pools, counts = layer(*carry, lp, bases, n_lead + j)
         return (x, pools), counts
 
-    bases = jnp.arange(n_lead, L, dtype=jnp.int32) * nb
-    (x, pools), counts = jax.lax.scan(body, (x, pools), (params["layers"], bases))
+    (x, pools), counts = jax.lax.scan(
+        body, (x, pools), (params["layers"], jnp.arange(n_bodies, dtype=jnp.int32)))
     total = _add_counts(total, None if counts is None else counts.sum(0))
     return x, {name: pool.reshape(shapes[name]) for name, pool in zip(shapes, pools)}, total
 
@@ -202,12 +256,12 @@ def _decode_step(params: Params, cfg, tokens, cache: PagedCache, tables, lens):
     """``paged_decode_step`` with the layers' summed counts (or None) third."""
     model = paged_model(cfg)
 
-    def layer(x, pools, lp, base, index):
-        return model.decode_layer(x, pools, lp, tables + base, lens, params, index)
+    def layer(x, pools, lp, bases, index):
+        return model.decode_layer(x, pools, lp, tables, lens, params, index, bases)
 
-    x = embed(params, tokens[:, None], cfg)
-    x, cache, counts = _scan_layers(layer, x, params, cache)
-    return unembed(params, x, cfg)[:, 0], cache, counts
+    x = model.embed(params, tokens[:, None], cfg)
+    x, cache, counts = _scan_layers(layer, x, params, cache, tuple(model.pools))
+    return model.unembed(params, x, cfg)[:, 0], cache, counts
 
 
 def paged_decode_step(
@@ -279,15 +333,18 @@ def paged_decode_loop(
     linearly in n_steps (~seconds for window 8)."""
     # A row whose table starts on the trash block holds no sequence (the
     # host points idle and still-prefilling slots there), yet every window
-    # advances it: restart it, so that it reads and writes one block
-    # however long it has idled.
-    lens = jnp.where(tables[:, 0] == TRASH_BLOCK, 0, lens)
+    # would advance it: hold it at 0 all through the window, so that it reads
+    # and writes one position of the trash block however long it has idled,
+    # and a layer that keeps state by SLOT knows to leave that slot's alone
+    # (a live row's ``lens`` is its prompt's length or more, never 0).
+    idle = tables[:, 0] == TRASH_BLOCK
+    lens = jnp.where(idle, 0, lens)
     seq, total = [], None
     for _ in range(n_steps):
         key, sub = jax.random.split(key)
         logits, cache, counts = _decode_step(params, cfg, tokens, cache, tables, lens)
         tokens = sample_tokens(logits, temps, sub)
-        lens = lens + 1
+        lens = jnp.where(idle, 0, lens + 1)
         seq.append(tokens)
         total = _add_counts(total, counts)
     return _with_counts(jnp.stack(seq), total), cache
@@ -312,11 +369,15 @@ def paged_prefill(
     model = paged_model(cfg)
     if model.prefill is not None:
         return model.prefill(params, tokens, cache, block_row, block_size)
+    if slot_pools(cfg):
+        # Its padding would enter the slot's state, and no slot is named here.
+        raise ValueError("a model that keeps state by slot prefills through paged_prefill_chunk, "
+                         "which is told each tile's slot and its real tokens")
     S = tokens.shape[1]
     logits, cache, _ = _prefill_chunk(
         params, cfg, tokens, cache, block_row[None, :], block_row, block_size,
         jnp.zeros((1,), jnp.int32), jnp.arange(S, dtype=jnp.int32),
-        jnp.full((1,), S, jnp.int32))
+        jnp.full((1,), S, jnp.int32), jnp.zeros((1,), jnp.int32))
     return logits, cache
 
 
@@ -375,19 +436,19 @@ def chunk_tile(width: int, block_size: int) -> int:
     return math.gcd(width, 4 * block_size)
 
 
-def _attend_chunk(q, ck, cv, qpos, cfg: TransformerConfig):
+def _attend_chunk(q, ck, cv, qpos, scale=None):
     """q: [n, C, H, HD] chunk queries by tile; ck/cv: [n, m, KV, HD] each
     tile's gathered block view (its slot's prefix + the chunk, post-
     scatter); qpos: [n, C] absolute positions — attend over cache
     positions <= qpos (causal, prefix inclusive). Same f32 einsum/softmax
     math as ``reference_paged_attention``. → [n, C, H*HD]."""
     n, C, H, HD = q.shape
-    KV = cfg.n_kv_heads
+    KV = ck.shape[2]
     G = H // KV
     qg = q.reshape(n, C, KV, G, HD)
     scores = jnp.einsum(
         "tckgd,tmkd->tckgm", qg.astype(jnp.float32), ck.astype(jnp.float32)
-    ) * (HD**-0.5)
+    ) * (HD**-0.5 if scale is None else scale)
     m = ck.shape[1]
     valid = jnp.arange(m)[None, None, :] <= qpos[:, :, None]  # [n, C, m]
     scores = jnp.where(valid[:, :, None, None, :], scores, -1e30)
@@ -396,19 +457,21 @@ def _attend_chunk(q, ck, cv, qpos, cfg: TransformerConfig):
     return og.reshape(n, C, H * HD).astype(q.dtype)
 
 
-def _dense_decode_layer(cfg: TransformerConfig, x, pools, lp: Params, tables, lens, _params, _index):
+def _dense_decode_layer(cfg: TransformerConfig, x, pools, lp: Params, tables, lens, _params,
+                        _index, bases):
     """The dense decoder's layer, one token a slot (``PagedModel.decode_layer``)."""
-    x, ck, cv = _paged_layer_step(x, lp, cfg, *pools, tables, lens)
+    x, ck, cv = _paged_layer_step(x, lp, cfg, *pools, tables + bases[0], lens)
     return x, (ck, cv), None
 
 
 def _dense_chunk_layer(cfg: TransformerConfig, x, pools, lp: Params, table_rows, rows_at, offs,
-                       qpos, _live, _params, _index):
+                       qpos, _live, _params, _index, bases, _slot_of):
     """The dense decoder's layer over a chunk call's token axis (``PagedModel
     .chunk_layer``): K/V of the tokens into their blocks, then every tile
     through its slot's gathered table. Padded queries are computed like real
     ones: a tile is a gather and two products whose cost a mask would not cut."""
     ck, cv = pools
+    table_rows, rows_at = table_rows + bases[0], rows_at + bases[0]
     n, C = qpos.shape
     W, bs = table_rows.shape[1], ck.shape[1]
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -418,13 +481,13 @@ def _dense_chunk_layer(cfg: TransformerConfig, x, pools, lp: Params, table_rows,
     cv = cv.at[rows_at, offs].set(v[0])
     ck_g = ck[table_rows].reshape(n, W * bs, KV, HD)
     cv_g = cv[table_rows].reshape(n, W * bs, KV, HD)
-    o = _attend_chunk(q.reshape(n, C, H, HD), ck_g, cv_g, qpos, cfg)
+    o = _attend_chunk(q.reshape(n, C, H, HD), ck_g, cv_g, qpos)
     x = x + (o.reshape(1, n * C, H * HD) @ lp["wo"].astype(o.dtype))
     return mlp_block(x, lp, cfg), (ck, cv), None
 
 
 def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, chunk_row,
-                   block_size: int, starts, last_idx, live):
+                   block_size: int, starts, last_idx, live, slot_of):
     """``paged_prefill_chunk`` with the layers' summed counts (or None) third."""
     b, T = tokens.shape
     n = table_rows.shape[0]
@@ -439,13 +502,13 @@ def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, c
     offs = jnp.tile(jnp.arange(block_size, dtype=jnp.int32), T // block_size)
     model = paged_model(cfg)
 
-    def layer(x, pools, lp, base, index):
+    def layer(x, pools, lp, bases, index):
         return model.chunk_layer(
-            x, pools, lp, table_rows + base, rows + base, offs, qpos, live, params, index)
+            x, pools, lp, table_rows, rows, offs, qpos, live, params, index, bases, slot_of)
 
-    x = embed(params, tokens, cfg)
-    x, cache, counts = _scan_layers(layer, x, params, cache)
-    return unembed(params, x[:, last_idx], cfg)[0], cache, counts
+    x = model.embed(params, tokens, cfg)
+    x, cache, counts = _scan_layers(layer, x, params, cache, tuple(model.pools))
+    return model.unembed(params, x[:, last_idx], cfg)[0], cache, counts
 
 
 def paged_prefill_chunk(
@@ -459,6 +522,7 @@ def paged_prefill_chunk(
     starts: jax.Array,  # [n] int32 — per tile, absolute position of its first token
     last_idx: jax.Array,  # [n] int32 — per segment, where on the token axis it ends
     live: Optional[jax.Array] = None,  # [n] int32 — per tile, its real tokens (None: all)
+    slot_of: Optional[jax.Array] = None,  # [n] int32 — per tile, its decode slot (None: none's)
 ) -> Tuple[jax.Array, PagedCache]:
     """Prefill several slots' suffixes in ONE call: the token axis holds
     them one after another, each padded to whole tiles, and a tile covers
@@ -479,18 +543,28 @@ def paged_prefill_chunk(
     prompt among them. A tile nobody uses points at the trash block and has
     ``live`` 0; a segment's last tile holds its remainder, and a model may
     leave the padding behind it uncomputed (``PagedModel.chunk_layer``).
+    ``slot_of`` matters to a model that keeps state by slot, and to no other:
+    a tile's state begins from nothing (``starts`` 0), from the tile before
+    it (the same slot's) or from the slot's stored rows, and the segment's
+    last tile leaves them as its last real token does. A slot number past
+    the last slot is nobody's: nothing is read or stored for it.
     Returns (logits [n, V] fp32, cache')."""
     n = table_rows.shape[0]
     if live is None:
         live = jnp.full((n,), tokens.shape[1] // n, jnp.int32)
+    if slot_of is None:
+        if slot_pools(cfg):
+            raise ValueError("this model keeps state by slot: say which slot each tile is of")
+        slot_of = jnp.zeros((n,), jnp.int32)
     logits, cache, _ = _prefill_chunk(
-        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live)
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live,
+        slot_of)
     return logits, cache
 
 
 def prefill_chunk_and_sample(
     params, cfg, tokens, cache, table_rows, chunk_row,
-    block_size: int, starts, last_idx, live, temps, key,
+    block_size: int, starts, last_idx, live, slot_of, temps, key,
 ):
     """Chunk prefill + on-device sampling of one token a segment, at
     ``last_idx`` and that segment's temperature. A segment's token is only
@@ -499,17 +573,16 @@ def prefill_chunk_and_sample(
     the extra samples cost no sync. → (tokens [n] int32, cache'); where the
     model's layers count, the call's counts follow the tokens (``_with_counts``)."""
     logits, cache, counts = _prefill_chunk(
-        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live
-    )
+        params, cfg, tokens, cache, table_rows, chunk_row, block_size, starts, last_idx, live,
+        slot_of)
     return _with_counts(sample_tokens(logits, temps, key), counts), cache
 
 
 @paged_model.register
 def _(cfg: TransformerConfig) -> PagedModel:
-    row = (cfg.n_kv_heads, cfg.head_dim)
+    kv = Pool(row=(cfg.n_kv_heads, cfg.head_dim), layers=cfg.n_layers)
     return PagedModel(
-        rows={"k": row, "v": row},
-        n_layers=cfg.n_layers,
+        pools={"k": kv, "v": kv},
         decode_layer=functools.partial(_dense_decode_layer, cfg),
         chunk_layer=functools.partial(_dense_chunk_layer, cfg),
         prefill=functools.partial(_dense_prefill, cfg),

@@ -2,7 +2,8 @@
 
 ``paged_attention(q, ck, cv, tables, lens)`` attends slot ``i``'s token to
 positions ``0 .. lens[i]`` (itself included) of the sequence whose blocks
-``tables[i]`` names in the flat pool ``ck``/``cv`` ``[P, bs, KV, HD]``.
+``tables[i]`` names in the flat pool ``ck``/``cv`` ``[P, bs, KV, HD]``; the
+softmax scale is ``HD**-0.5`` unless the model gives its own.
 
 - ``reference_paged_attention``: gather every slot's whole table into a
   padded ``[b, W*bs, KV, HD]`` view and run masked float32 einsums over it.
@@ -13,6 +14,13 @@ positions ``0 .. lens[i]`` (itself included) of the sequence whose blocks
   into an online softmax. Nothing padded is written to HBM. The pool is
   passed as it is laid out; the kernel's own view of it, ``[P, bs*KV, HD]``,
   is a bitcast (one block is ``bs`` tiles of ``[KV, HD]`` either way).
+- ``packed_paged_attention``: the same for heads narrower than a lane tile
+  (``HD`` 64). Such a pool is declared ``[P, bs, KV*HD]``, a token's heads
+  side by side on the lanes (as ``[.., KV, 64]`` a TPU would pad every head to
+  128 lanes, in memory too), and goes through the SAME kernel as a pool of
+  one wide head: each query head is laid into the lanes of its own kv head
+  with zeros elsewhere, so the one product scores it against its own keys
+  alone, and of the weighted sum of whole rows it keeps its own lanes.
 
 Which one runs is decided from what the code can see (``_tiles``), as
 ``ops/attention.py`` decides for the flash kernels.
@@ -43,17 +51,18 @@ _BLOCKS_PER_STEP = 16
 _ROWS_PER_STEP = 2048
 
 
-def reference_paged_attention(q, ck, cv, tables, lens):
+def reference_paged_attention(q, ck, cv, tables, lens, scale=None):
     """q: [b, H, HD]; ck/cv: [P, bs, KV, HD]; tables: [b, W] block ids into
     the pool; lens: [b] position of the token just written. → [b, H*HD] in
     ``q.dtype``. Scores, softmax and the weighted sum in float32."""
     b, H, HD = q.shape
+    scale = HD**-0.5 if scale is None else scale
     bs, KV = ck.shape[1:3]
     m = tables.shape[1] * bs
     qg = q.reshape(b, KV, H // KV, HD).astype(jnp.float32)
     ck_g = ck[tables].reshape(b, m, KV, HD).astype(jnp.float32)
     cv_g = cv[tables].reshape(b, m, KV, HD).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,bmkd->bkgm", qg, ck_g) * (HD**-0.5)
+    scores = jnp.einsum("bkgd,bmkd->bkgm", qg, ck_g) * scale
     valid = jnp.arange(m)[None, :] <= lens[:, None]  # [b, m]
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -67,7 +76,7 @@ def _paged_attend_kernel(
     k_hbm, v_hbm,  # the pools, left in HBM: [P, bs*KV, HD]
     o_ref,  # VMEM [b, H, HD]
     k_buf, v_buf, sems,  # [2, C*bs*KV, HD] x2, DMA semaphores [2, 2]
-    *, bs: int, kv_heads: int, chunk: int,
+    *, bs: int, kv_heads: int, chunk: int, scale: float,
 ):
     """One program for all slots. The work is the flat sequence of (slot,
     step) pairs, a step being ``chunk`` consecutive blocks of the slot's
@@ -80,7 +89,6 @@ def _paged_attend_kernel(
     b, H, HD = q_ref.shape
     rows = bs * kv_heads  # of one block
     group = H // kv_heads
-    scale = HD**-0.5
 
     def last_token(slot):
         # ``lens`` past the table's end reads the whole table, as the plain
@@ -163,14 +171,16 @@ def _paged_attend_kernel(
     jax.lax.fori_loop(0, b, slot_body, 0)
 
 
-def _paged_attend(q, ck, cv, tables, lens, *, interpret: bool = False):
+def _paged_attend(q, ck, cv, tables, lens, scale=None, *, interpret: bool = False):
+    """q: [b, H, HD]; ck/cv: [P, bs, KV, HD] → [b, H*HD]."""
     b, H, HD = q.shape
     P, bs, KV, _ = ck.shape
     rows = bs * KV
+    scale = HD**-0.5 if scale is None else scale
     chunk = max(1, min(_BLOCKS_PER_STEP, _ROWS_PER_STEP // rows, tables.shape[1]))
     buf = pltpu.VMEM((2, chunk * rows, HD), ck.dtype)
     out = pl.pallas_call(
-        functools.partial(_paged_attend_kernel, bs=bs, kv_heads=KV, chunk=chunk),
+        functools.partial(_paged_attend_kernel, bs=bs, kv_heads=KV, chunk=chunk, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -190,20 +200,43 @@ def _paged_attend(q, ck, cv, tables, lens, *, interpret: bool = False):
 
 
 def _tiles(ck) -> bool:
-    """Shapes the kernel's buffers and products tile on a TPU: lanes of
-    128, and a block whose (token, kv head) rows fill whole sublane tiles
-    of the cache's dtype (16 rows of bf16, 8 of float32)."""
+    """Shapes the kernel's buffers and products tile on a TPU: rows whose
+    width (``HD``; ``KV*HD`` of a packed pool, which comes here as one wide
+    head) is whole lane tiles of 128, and a block whose (token, kv head) rows
+    fill whole sublane tiles of the cache's dtype (16 rows of bf16, 8 of
+    float32)."""
     bs, KV, HD = ck.shape[1:]
     sublanes = 8 * 4 // jnp.dtype(ck.dtype).itemsize
     return HD % 128 == 0 and (bs * KV) % sublanes == 0
 
 
 @jax.named_scope("paged.attend")
-def paged_attention(q, ck, cv, tables, lens):
+def paged_attention(q, ck, cv, tables, lens, scale=None):
     """q: [b, H, HD] one token a slot; ck/cv: [P, bs, KV, HD] a flat pool;
     tables: [b, W] the slots' block ids in it; lens: [b] the position each
-    slot's token was just written at → [b, H*HD] in ``q.dtype``. The
-    kernel on a TPU where the shapes tile, else the plain form."""
+    slot's token was just written at; scale: of the scores (``HD**-0.5``
+    unless given) → [b, H*HD] in ``q.dtype``. The kernel on a TPU where the
+    shapes tile, else the plain form."""
     if _use_pallas() and _tiles(ck):
-        return _paged_attend(q, ck, cv, tables, lens)
-    return reference_paged_attention(q, ck, cv, tables, lens)
+        return _paged_attend(q, ck, cv, tables, lens, scale)
+    return reference_paged_attention(q, ck, cv, tables, lens, scale)
+
+
+@jax.named_scope("paged.attend")
+def packed_paged_attention(q, ck, cv, tables, lens, scale, *, interpret: bool = False):
+    """q: [b, H, HD]; ck/cv: [P, bs, KV*HD], a token's kv heads side by side
+    (module docstring) → [b, H*HD] in ``q.dtype``."""
+    b, H, HD = q.shape
+    P, bs, row = ck.shape
+    KV = row // HD
+    if not interpret and not (_use_pallas() and _tiles(ck[:, :, None])):
+        split = (P, bs, KV, HD)
+        return reference_paged_attention(q, ck.reshape(split), cv.reshape(split), tables, lens, scale)
+    # Head h's query in the lanes of kv head h // group, zeros in the others'.
+    own = jnp.arange(H)[:, None] // (H // KV) == jnp.arange(KV)[None, :]  # [H, KV]
+    wide = jnp.where(own[None, :, :, None], q[:, :, None, :], 0).reshape(b, H, row)
+    out = _paged_attend(wide, ck[:, :, None], cv[:, :, None], tables, lens, scale,
+                        interpret=interpret).reshape(b, H, KV, HD)
+    # Of the sum over whole rows, a head's own lanes: the other KV - 1 parts
+    # are sums of other heads' values under this head's weights.
+    return jnp.sum(jnp.where(own[None, :, :, None], out, 0), axis=2).reshape(b, H * HD)

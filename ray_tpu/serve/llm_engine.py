@@ -70,11 +70,13 @@ from ray_tpu.models.paged import (
     paged_decode_loop,
     prefill_and_sample,
     prefill_chunk_and_sample,
+    slot_pools,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util import tracing
 
 if TYPE_CHECKING:
+    from ray_tpu.models.hybrid_ssm import HybridSSMConfig
     from ray_tpu.models.latent_moe import LatentMoEConfig
 
 _req_ids = itertools.count()
@@ -104,7 +106,8 @@ _STARVED = _SUB_PHASES + ("emit", "record", "between")
 _SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
 # The counters a recorded step carries as what the iteration added to them.
 _STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks",
-                "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill")
+                "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill",
+                "state_slots_live")
 # The width under which a chunk call's time is the read of the weights and no
 # longer its tokens' arithmetic: two FLOPs and two bytes a parameter a token
 # put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e whatever the
@@ -449,7 +452,7 @@ class LLMEngine:
     def __init__(
         self,
         params,
-        cfg: Union[TransformerConfig, "LatentMoEConfig"],
+        cfg: Union[TransformerConfig, "LatentMoEConfig", "HybridSSMConfig"],
         pcfg: Optional[PagedConfig] = None,
         *,
         decode_window: int = 1,
@@ -513,6 +516,17 @@ class LLMEngine:
             prefill_chunk = -(-int(prefill_chunk) // p.block_size) * p.block_size
             prefill_chunk = min(prefill_chunk, p.max_seq_len)
         self.prefill_chunk = int(prefill_chunk or 0)
+        # Pools that hold one row a decode slot (a state-space layer's state):
+        # row ``i`` is slot ``i``'s, so there is nothing to allocate, but the
+        # row is not a function of a block of tokens and a step over it is not
+        # idempotent. See ``_start_prefill``, ``_chunk_call``, ``_free_slot``.
+        self._state_pools = slot_pools(cfg)
+        if self._state_pools and enable_prefix_cache:
+            raise ValueError(
+                f"enable_prefix_cache with a model that keeps state by slot (pools "
+                f"{', '.join(self._state_pools)}): the prefix cache shares blocks of tokens, and "
+                "a request that skipped a shared prefix would begin from a state that never saw "
+                "it. It needs snapshots of the state at block boundaries, which nothing keeps")
         self.prefix_cache = _PrefixCache() if enable_prefix_cache else None
         # The widths a prompt or a chunk call is padded to: block-multiple
         # powers of two, then the table's whole length. O(log max_seq_len)
@@ -527,6 +541,11 @@ class LLMEngine:
         # ``_build_programs`` reads it off the decode program's output.
         self._counted = False
         self.cache = init_paged_cache(cfg, p)
+        # For ``report_state``: the arrays themselves are donated call by call.
+        self._pool_facts = {
+            name: {"shape": list(pool.shape), "dtype": str(pool.dtype), "bytes": int(pool.nbytes),
+                   "unit": "slots" if name in self._state_pools else "blocks"}
+            for name, pool in self.cache.items()}
         (self._decode, self._prefill, self._prefill_chunk_fn,
          self.params) = self._build_programs(params)
         self.alloc = _BlockAllocator(p)
@@ -580,6 +599,8 @@ class LLMEngine:
                       "prefill_tile_queries": 0, "prefill_live_queries": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       "windows_behind_prefill": 0, "prefill_flushed_first": 0,
+                      "state_slots_live": 0, "state_slots_table": 0,
+                      "state_segments_carried": 0, "state_segments_fresh": 0,
                       **{name: 0 for name in _MOE_COUNTS},
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED},
                       "starved_us": 0, "unloaded_us": 0,
@@ -677,10 +698,10 @@ class LLMEngine:
             return tok, cache, cur.at[slot].set(tok, mode="drop")
 
         def _chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
-            starts, last_idx, slot_of, live = per_tile
+            starts, last_idx, slot_of, live, state_of = per_tile
             toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts,
-                last_idx, live, temps, key,
+                last_idx, live, state_of, temps, key,
             )
             # (Entries past the tiles', where there are any, are counts.)
             return toks, cache, cur.at[slot_of].set(toks[:slot_of.shape[0]], mode="drop")
@@ -716,13 +737,21 @@ class LLMEngine:
             params = jax.jit(params, out_shardings=params_fmt)()
         else:
             params = jax.device_put(params, params_fmt)
+        # The cache and ``cur`` reach these two from three makers: fresh and
+        # uncommitted, the decode program's output (uncommitted: it was lowered
+        # from shapes alone) and their own (committed, because ``params_fmt``
+        # names a device). Left unspecified, jit lowers AND compiles again for
+        # each kind it meets, the second time on the served path (PERF.md: a
+        # bucket compiled again on its first live use, PR 24; 14 s of the
+        # hybrid decoder's chunk program, PR 45). Named, every kind is one.
+        placed = jax.tree.leaves(params_fmt)[0].sharding
         prefill = jax.jit(
             _prefill, donate_argnums=(2,),
-            in_shardings=(params_fmt,) + (None,) * 7,
+            in_shardings=(params_fmt, None, placed) + (None,) * 4 + (placed,),
         )
         chunk = jax.jit(
             _chunk, donate_argnums=(2,),
-            in_shardings=(params_fmt,) + (None,) * 8,
+            in_shardings=(params_fmt, None, placed) + (None,) * 5 + (placed,),
         )
         return compiled, prefill, chunk, params
 
@@ -736,7 +765,8 @@ class LLMEngine:
         bs = self.pcfg.block_size
         self.key, sub = jax.random.split(self.key)
         n = 0
-        for S in self._widths:
+        # A model with state by slot has no whole-prompt program to warm.
+        for S in ([] if self._state_pools else self._widths):
             _tok, self.cache, self._dev["cur"] = self._prefill(
                 self.params, jax.numpy.asarray(np.zeros((1, S), np.int32)),
                 self.cache,
@@ -747,8 +777,8 @@ class LLMEngine:
             n += 1
         if self.prefill_chunk:
             chunk_sizes = [self.prefill_chunk]
-        elif self.prefix_cache is not None:
-            chunk_sizes = self._widths  # cache hits leave bucketed suffixes
+        elif self.prefix_cache is not None or self._state_pools:
+            chunk_sizes = self._widths  # cache hits, or whole prompts, in bucketed calls
         else:
             chunk_sizes = []
         for C in chunk_sizes:
@@ -879,7 +909,17 @@ class LLMEngine:
         an eos, a speculated window behind a stopped slot, the unharvested
         window of a slot preempted under ``overlap``) lies at or above it. A
         slot still in ``_prefilling`` publishes nothing: its prompt's blocks
-        are not all written yet."""
+        are not all written yet.
+
+        State kept by slot (``_state_pools``) is left as it is, whatever it
+        holds: the steps named above have advanced it past the transcript, a
+        preempted prefill left it halfway, and neither matters, because the
+        slot's next request begins at position 0 and a segment that does
+        starts from nothing (``_chunk_call``), while every window from now
+        until that request's prefill ends finds ``lens`` 0 here and leaves
+        the row alone. A preempted request is such a next request: its
+        ``full_prompt`` is prefilled from position 0 and its state rebuilt,
+        never restored."""
         pc = self.prefix_cache
         if pc is None:
             self.alloc.release(self.slot_blocks[i])
@@ -1094,9 +1134,13 @@ class LLMEngine:
         self.stats["prompt_tokens"] += plen - start
         if self.prefill_chunk and plen - start > self.prefill_chunk:
             self._prefilling[i] = _ChunkState(req, full, start, plen)
-        elif start == 0:
+        elif start == 0 and not self._state_pools:
             self._finish_prefill(i, req, self._run_full_prefill(i, req, full), ())
         else:
+            # A suffix behind a hit; or, for a model that keeps state by slot,
+            # a whole prompt: tiles of the chunk program, which is told each
+            # tile's slot and real tokens (a padded bucket would run its
+            # padding through the state).
             suffixes.append((i, req, full, start, plen))
 
     def _chunk_width(self, lens: Sequence[int]) -> Optional[int]:
@@ -1216,11 +1260,13 @@ class LLMEngine:
             # position of its last token, and whose ``cur`` its sampled token
             # is (no slot's, unless the segment ends its prompt); by tile
             # again: how many of its tokens are real (a segment's last tile
-            # holds its remainder, a tile no segment uses none). One array:
-            # every host argument is a transfer of its own.
-            per_tile = np.zeros((4, n), np.int32)
-            starts, last_idx, slot_of, live = per_tile
-            slot_of[:] = p.max_batch
+            # holds its remainder, a tile no segment uses none), and whose
+            # state-by-slot it reads and leaves (its segment's slot; nobody's
+            # for a tile no segment uses: a model without such state ignores
+            # the row). One array: every host argument is a transfer of its own.
+            per_tile = np.zeros((5, n), np.int32)
+            starts, last_idx, slot_of, live, state_of = per_tile
+            slot_of[:] = state_of[:] = p.max_batch
             temps = np.zeros(n, np.float32)
             at = 0  # the next free tile's first position on the axis
             for k, (i, req, full, start, end) in enumerate(segs):
@@ -1231,6 +1277,7 @@ class LLMEngine:
                 trows[t0:t0 + tiles, :len(blocks)] = blocks
                 starts[t0:t0 + tiles] = start + tile * np.arange(tiles)
                 live[t0:t0 + tiles] = np.minimum(tile, end - starts[t0:t0 + tiles])
+                state_of[t0:t0 + tiles] = i
                 under = blocks[start // bs:start // bs + tiles * tile // bs]
                 crow[at // bs:at // bs + len(under)] = under
                 last_idx[k] = at + end - start - 1
@@ -1242,6 +1289,12 @@ class LLMEngine:
             # them: their ratio is the share of a taken tile that is real.
             self.stats["prefill_tile_queries"] += at
             self.stats["prefill_live_queries"] += int(live.sum())
+            if self._state_pools:
+                # Segments that took up the slot's stored state (a later chunk
+                # of a long prompt), and those that began from nothing.
+                carried = sum(1 for seg in segs if seg[3] > 0)
+                self.stats["state_segments_carried"] += carried
+                self.stats["state_segments_fresh"] += len(segs) - carried
         sub = self._split_key("admit")
         with tracing.phase("engine.admit.launch", ph):
             self._at("admit_launch")
@@ -1433,6 +1486,10 @@ class LLMEngine:
                 (self.lens[occupied] // self.pcfg.block_size + 1).sum())
             self.stats["decode_blocks_table"] += (
                 self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
+            if self._state_pools:
+                # Rows of state the window's steps read and write, of those held.
+                self.stats["state_slots_live"] += len(entries)
+                self.stats["state_slots_table"] += self.pcfg.max_batch
         sub = self._split_key("dispatch")
         with tracing.phase("engine.dispatch.ship", ph):
             self._at("dispatch_ship")
@@ -1661,6 +1718,8 @@ class LLMEngine:
                     "cached_blocks": pc.resident_blocks if pc else 0,
                     "overlapped": overlapped,
                     "behind_prefill": moved["windows_behind_prefill"],
+                    # Rows of state by slot the window this step dispatched moves.
+                    "state_slots_live": moved["state_slots_live"],
                 }
                 self._maybe_flush_metrics()
         self._at("between")
@@ -1725,6 +1784,10 @@ class LLMEngine:
                 ("moe_pairs_here", m.engine_moe_pairs_here),
                 ("moe_experts_touched", m.engine_moe_experts_touched),
                 ("moe_layer_steps", m.engine_moe_layer_steps),
+                ("state_slots_live", m.engine_state_slots_live),
+                ("state_slots_table", m.engine_state_slots_table),
+                ("state_segments_carried", m.engine_state_segments_carried),
+                ("state_segments_fresh", m.engine_state_segments_fresh),
             ):
                 delta = s[key] - prev.get(key, 0)
                 if delta:
@@ -1831,6 +1894,19 @@ class LLMEngine:
                 / max(1, self.stats["moe_layer_steps"]),
                 "pairs_per_touched_expert": self.stats["moe_pairs_here"]
                 / max(1, self.stats["moe_experts_touched"]),
+            },
+            # What the cache holds: every pool the model declared, and what
+            # the state kept by slot has cost (rows a window's steps moved, of
+            # the rows held; chunk-call segments by where their state began).
+            pools=self._pool_facts,
+            state={
+                "pools": list(self._state_pools),
+                "slots_live": self.stats["state_slots_live"],
+                "slots_table": self.stats["state_slots_table"],
+                "slots_live_pct": 100.0 * self.stats["state_slots_live"]
+                / max(1, self.stats["state_slots_table"]),
+                "segments_carried": self.stats["state_segments_carried"],
+                "segments_fresh": self.stats["state_segments_fresh"],
             },
             overlap={
                 "enabled": self.overlap,

@@ -235,6 +235,29 @@ class _ServeMetrics:
             "serve_engine_moe_experts_touched_total / this)",
             dr,
         )
+        self.engine_state_slots_live = Counter(
+            "serve_engine_state_slots_live_total",
+            "Rows of state kept by slot (a state-space layer's) that dispatched decode "
+            "windows read and wrote: occupied slots, summed over windows",
+            dr,
+        )
+        self.engine_state_slots_table = Counter(
+            "serve_engine_state_slots_table_total",
+            "Rows of state kept by slot that the engine held, summed over dispatched "
+            "windows (max_batch a window; live share = ..._live_total / this)",
+            dr,
+        )
+        self.engine_state_segments_carried = Counter(
+            "serve_engine_state_segments_carried_total",
+            "Chunk-call segments that began from their slot's stored state (a later "
+            "chunk of a long prompt)",
+            dr,
+        )
+        self.engine_state_segments_fresh = Counter(
+            "serve_engine_state_segments_fresh_total",
+            "Chunk-call segments that began at position 0, from no state",
+            dr,
+        )
         self.engine_overlap_windows = Counter(
             "serve_engine_overlap_windows_total",
             "Decode windows dispatched before the previous window was read "

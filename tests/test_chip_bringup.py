@@ -329,15 +329,15 @@ def test_paged_programs_address_the_cache_in_place_on_v5e(v5e, program):
         assert n == {1: 1, 3: 3, 8: 2}[nb]
 
         def run(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
-            starts, last_idx, slot_of, live = per_tile
+            starts, last_idx, slot_of, live, state_of = per_tile
             toks, cache = prefill_chunk_and_sample(
                 params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx,
-                live, temps, key,
+                live, state_of, temps, key,
             )
             return toks, cache, cur.at[slot_of].set(toks, mode="drop")
 
         args = (sds((1, nb * bs), np.int32), cache, sds((n, w), np.int32), sds((nb,), np.int32),
-                sds((4, n), np.int32), sds((n,), np.float32), key, sds((b,), np.int32))
+                sds((5, n), np.int32), sds((n,), np.float32), key, sds((b,), np.int32))
     compiled = jax.jit(
         run, donate_argnums=(2,), in_shardings=(auto,) + (None,) * len(args),
     ).lower(params, *args).compile()
@@ -525,5 +525,177 @@ def test_latent_kernels_read_the_plain_forms_sums_on_the_chip():
         pytest.skip(f"needs a TPU, both forms run: {where}")
     env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     r = subprocess.run([sys.executable, "-c", _LATENT_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# The hybrid state-space decoder (models/hybrid_ssm.py): its two kernels
+# ---------------------------------------------------------------------------
+def test_ssm_state_update_compiles_for_v5e_at_the_published_widths(v5e):
+    """The state update at the widths it is served at (64 slots of a state of
+    128 columns x 64 heads of 64, float32, 36 layers in one flat pool): one
+    named Pallas call whose output IS its input (the pool is neither copied in
+    nor out: temporaries stay under a slot's state)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.ssm import ssm_update
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    b, n, hp = 64, 128, 4096
+    compiled = jax.jit(ssm_update, donate_argnums=(0,)).lower(
+        sds((36 * b, n, hp), jnp.float32), sds((), np.int32), sds((b,), np.int32),
+        sds((b, hp), jnp.float32), sds((b, hp), jnp.float32), sds((b, n), jnp.float32),
+        sds((b, n), jnp.float32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["ssm_state_update"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 36 * b * n * hp * 4
+    assert memory.temp_size_in_bytes < n * hp * 4
+
+
+def test_paged_attend_compiles_for_v5e_at_heads_of_64_side_by_side(v5e):
+    """``paged_attend`` on a pool whose rows are a token's 8 kv heads of 64 side
+    by side ([16, 8 x 64]: 512 lanes, where [8, 64] would be padded to 128 lanes
+    a head), 32 query heads, 64 slots, a table of 96, the model's own scale:
+    the kernel by its name, and no copy of either pool."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.paged_attention import packed_paged_attention
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = sds((4 * 6145, 16, 512), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, t, l: packed_paged_attention(q, k, v, t, l, 0.015625)).lower(
+        sds((64, 32, 64), jnp.bfloat16), pool, pool, sds((64, 96), np.int32),
+        sds((64,), np.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["paged_attend"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**22
+
+
+def test_hybrid_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
+    """One period of the published model (five state-space layers, an attention
+    layer, four more; every width published, the vocabulary cut for the
+    compile's sake) in the decode window and the chunk program as ``LLMEngine``
+    builds them: 2 x 10 state updates and 2 attention kernels in the window, and
+    temporaries far under ONE state-space layer's matrices (152 MB) or one
+    layer's pool of states (128 MB): no layer's weights are copied out of the
+    period's stack and no pool is gathered or copied."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import hybrid_ssm as hs
+    from ray_tpu.models.paged import (PagedConfig, chunk_tile, init_paged_cache,
+                                      paged_decode_loop, prefill_chunk_and_sample)
+
+    cfg = hs.HybridSSMConfig(vocab_size=2048, num_hidden_layers=10, layer_types=hs._PERIOD)
+    p = PagedConfig(block_size=16, num_blocks=513, max_batch=64, max_blocks_per_seq=96)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: hs.init_params(k, cfg), jax.random.PRNGKey(0)))
+    auto = jax.tree.map(lambda a: Format(Layout.AUTO, one), params)
+    cache = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_paged_cache(cfg, p)))
+    b, w, bs = p.max_batch, p.max_blocks_per_seq, p.block_size
+
+    def decode(params, tokens, cache, tables, lens, temps, key):
+        return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
+
+    compiled = jax.jit(decode, donate_argnums=(2,), in_shardings=(auto,) + (None,) * 6).lower(
+        params, sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
+        sds((b,), np.float32), sds((2,), np.uint32)).compile()
+    names = _kernel_names(compiled.as_text())
+    assert sorted(names) == ["paged_attend"] * 2 + ["ssm_state_update"] * 18
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    (params_fmt, *_), _ = compiled.input_formats
+    width = 1024
+    n = width // chunk_tile(width, bs)
+
+    def chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+        starts, last_idx, slot_of, live, state_of = per_tile
+        toks, cache = prefill_chunk_and_sample(
+            params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx, live,
+            state_of, temps, key)
+        return toks, cache, cur.at[slot_of].set(toks, mode="drop")
+
+    compiled = jax.jit(chunk, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 8).lower(
+        params, sds((1, width), np.int32), cache, sds((n, w), np.int32),
+        sds((width // bs,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
+        sds((2,), np.uint32), sds((b,), np.int32)).compile()
+    # The widest thing a 1,024-token call holds: four attention layers' scores.
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+_HYBRID_KERNELS_AGAINST_PLAIN_FORMS = """
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.ops import paged_attention as PA
+from ray_tpu.ops import ssm
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+ks = jax.random.split(jax.random.PRNGKey(1), 8)
+# the state update: 16 slots of the served state, the second of three layers, slots skipped
+b, n, hp = 16, 128, 4096
+pool = jax.random.normal(ks[0], (3 * b, n, hp), jnp.float32)
+decay = jax.random.uniform(ks[1], (b, hp), jnp.float32, 0.5, 1.0)
+dx, B, C = (jax.random.normal(k, s, jnp.float32) for k, s in zip(ks[2:5], ((b, hp), (b, n), (b, n))))
+assert ssm._tiles(pool)
+for lens in ([0, 3, 0, 0, 5, 1, 9, 0, 0, 0, 2, 2, 0, 7, 1, 0], [0] * 3 + [4] + [0] * 12, [1] * 16, [0] * 16):
+    lens_ = jnp.asarray(lens, jnp.int32)
+    want_pool, want_y = jax.jit(ssm.reference_ssm_update)(pool, jnp.int32(b), lens_, decay, dx, B, C)
+    got_pool, got_y = jax.jit(ssm._ssm_state_update)(pool, jnp.int32(b), lens_, decay, dx, B, C)
+    skipped = np.flatnonzero(np.asarray(lens) == 0)
+    got, want = np.asarray(got_pool), np.asarray(want_pool)
+    assert np.array_equal(got[b + skipped], np.asarray(pool)[b + skipped]), lens
+    assert np.array_equal(got[:b], np.asarray(pool)[:b]) and np.array_equal(got[2 * b:], np.asarray(pool)[2 * b:])
+    assert not np.asarray(got_y)[skipped].any()
+    assert np.abs(got - want).max() < 1e-5, (lens, np.abs(got - want).max())
+    apart = np.abs(np.asarray(got_y) - np.asarray(want_y)).max() / max(1e-9, np.abs(np.asarray(want_y)).max())
+    assert apart < 1e-5, (lens, apart)
+    print("ssm_state_update", sum(1 for x in lens if x), "live: apart", apart)
+
+# decode attention at heads of 64 side by side: 32 q heads, 8 kv heads, blocks of 16, a table of 96
+b, H, KV, HD, bs, W, P = 8, 32, 8, 64, 16, 96, 900
+ck, cv = (jax.random.normal(k, (P, bs, KV * HD), jnp.float32).astype(jnp.bfloat16) for k in ks[5:7])
+q = jax.random.normal(ks[7], (b, H, HD), jnp.float32).astype(jnp.bfloat16)
+lens = jnp.asarray([3000, 0, 15, 16, 100, 511, 1000, 1535], jnp.int32)  # slot 0 idle, its lens run on
+tables = np.zeros((b, W), np.int32)
+tables[1:] = np.asarray(jax.random.permutation(ks[0], jnp.arange(1, P)))[:7 * W].reshape(7, W)
+tables = jnp.asarray(tables)
+assert PA._tiles(ck[:, :, None])
+text = jax.jit(lambda *a: PA.packed_paged_attention(*a, 0.015625)).lower(q, ck, cv, tables, lens).as_text()
+assert "paged_attend" in text
+kernel = np.asarray(jax.jit(lambda *a: PA.packed_paged_attention(*a, 0.015625))(q, ck, cv, tables, lens), np.float32)
+split = (P, bs, KV, HD)
+plain = np.asarray(jax.jit(lambda *a: PA.reference_paged_attention(a[0], a[1].reshape(split), a[2].reshape(split), a[3], a[4], 0.015625))(
+    q, ck, cv, tables, lens), np.float32)
+assert np.isfinite(kernel).all()
+apart = np.abs(kernel - plain).max() / np.abs(plain).max()
+assert apart < 2**-6, apart  # both round their output to bfloat16; the kernel's weights too
+print("paged_attend at 64: apart", apart)
+"""
+
+
+def test_hybrid_kernels_read_the_plain_forms_numbers_on_the_chip():
+    """``ssm_state_update`` and ``paged_attend`` at ``head_dim`` 64 against their
+    plain forms on a chip, at the served widths, slots skipped and live. In a
+    process of its own: this one is held to the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    r = subprocess.run([sys.executable, "-c", _HYBRID_KERNELS_AGAINST_PLAIN_FORMS], cwd=REPO,
                        env=env, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]

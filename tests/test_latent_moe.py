@@ -286,7 +286,7 @@ def test_engine_tells_the_chunk_program_each_tiles_real_queries_and_counts_them(
         eng.step()
     # One call 128 wide: tiles of 32 for 5 and 32 + 8 tokens, and one to spare.
     (per_tile,) = rows
-    assert per_tile.shape == (4, 4)
+    assert per_tile.shape == (5, 4)  # the fifth: each tile's slot, for state kept by slot
     assert per_tile[0].tolist() == [16, 16, 48, 0] and per_tile[3].tolist() == [5, 32, 8, 0]
     s = eng.stats
     assert (s["prefill_chunks"], s["prefill_segments"]) == (1, 2)
@@ -296,7 +296,7 @@ def test_engine_tells_the_chunk_program_each_tiles_real_queries_and_counts_them(
     # A lone suffix of three tokens: a call one block wide, which is its tile.
     rows.clear()
     eng.generate_batch([doc + [7, 8, 9]], 2)
-    assert [r.tolist() for r in rows] == [[[16], [2], [0], [3]]]
+    assert [r.tolist() for r in rows] == [[[16], [2], [0], [3], [0]]]  # the fifth row: slot 0
     assert (s["prefill_tile_queries"], s["prefill_live_queries"]) == (96 + 8, 45 + 3)
     snap = eng.report_state()
     assert snap["prefill"] == {

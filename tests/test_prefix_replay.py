@@ -102,7 +102,7 @@ class _Programs:
         """The packed signature. A segment's tiles follow one another under
         one slot's table row; a tile nobody uses lies on the trash block and
         counts no real query."""
-        starts, _last_idx, slot_of, real = per_tile
+        starts, _last_idx, slot_of, real, _state_of = per_tile
         live = real > 0
         assert (live == (table_rows != TRASH_BLOCK).any(axis=1)).all()
         tile = toks.shape[1] // len(starts)
