@@ -35,11 +35,12 @@ slots, 3, conv_dim]``). A decode step advances both for every slot whose
 ``lens`` is above 0 and for no other; a chunk call reads them where a segment
 does not begin its prompt and stores them after the segment's last real token.
 
-The chunk program computes the recurrence a tile at a time: inside a tile the
-quadratic form (``y_i = sum_{j <= i} (C_i . B_j) (prod_{j < k <= i} a_k) D_j
-x_j``, float32), plus what the tile's incoming state gives; between tiles a
-scan hands a tile's outgoing state to the next tile of the same segment. A
-padded token has ``D = 0``: its decay is 1 and nothing of it enters.
+The chunk program computes the recurrence a tile at a time
+(``ops/ssm.ssm_chunk_scan``: on a TPU one Pallas kernel a layer, else the plain
+form): inside a tile the quadratic form (``y_i = sum_{j <= i} (C_i . B_j)
+(prod_{j < k <= i} a_k) D_j x_j``, float32), plus what the tile's incoming
+state gives; a tile's outgoing state goes to the next tile of the same
+segment. A padded token has ``D = 0``: its decay is 1 and nothing of it enters.
 """
 from __future__ import annotations
 
@@ -53,10 +54,9 @@ import jax.numpy as jnp
 from ray_tpu.models import paged
 from ray_tpu.models.transformer import Params, rms_norm
 from ray_tpu.ops.paged_attention import packed_paged_attention
-from ray_tpu.ops.ssm import ssm_update
+from ray_tpu.ops.ssm import ssm_chunk_scan, ssm_update
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
-_EXACT = jax.lax.Precision.HIGHEST  # products of float32 state and decays
 
 
 @dataclasses.dataclass(frozen=True)
@@ -296,7 +296,7 @@ def _mamba_chunk(cfg: HybridSSMConfig, x, ssm, conv, lp: Params, qpos, live, slo
     of C; tile t is of slot ``slot_of[t]`` (``n_slots``: nobody's), begins at
     position ``qpos[t, 0]`` and holds ``live[t]`` real tokens."""
     n, C = qpos.shape
-    h, p, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    h, p = cfg.mamba_n_heads, cfg.mamba_d_head
     fresh, cont, last = _segments(qpos, slot_of, n_slots)
     row = base + jnp.minimum(slot_of, n_slots - 1)  # a read that nothing uses, for nobody's tile
     store = jnp.where(last, base + slot_of, conv.shape[0])  # past the pool: dropped
@@ -313,43 +313,12 @@ def _mamba_chunk(cfg: HybridSSMConfig, x, ssm, conv, lp: Params, qpos, live, slo
             ext, (live[:, None] + jnp.arange(width)[None, :])[:, :, None], axis=1)
         conv = conv.at[store].set(kept, mode="drop")
         xs, B, Cm = _split_xbc(out, cfg)  # [n, C, inner], [n, C, N] x2, float32
-    with jax.named_scope("ssm.scan"):
-        real = jnp.arange(C)[None, :] < live[:, None]
-        dt = jnp.where(real[:, :, None], dt.reshape(n, C, h), 0.0)  # padding: no step, no decay
-        cum = jnp.cumsum(-dt * jnp.exp(lp["A_log"].astype(jnp.float32)), axis=1)  # [n, C, h] log decay
-        dx = dt[..., None] * xs.reshape(n, C, h, p)
-        # Inside the tile: token i hears token j <= i through (C_i . B_j) prod a.
-        heard = jnp.tril(jnp.ones((C, C), bool))[None, :, :, None]
-        decays = jnp.exp(jnp.where(heard, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))
-        scores = jnp.einsum("niN,njN->nij", Cm, B, precision=_EXACT)[..., None] * decays
-        y = jnp.einsum("nijh,njhp->nihp", scores, dx, precision=_EXACT)
-        # What the tile adds to the state by its end, and how far it decays what came in.
-        to_end = jnp.exp(cum[:, -1:, :] - cum)  # [n, C, h]
-        added = jnp.einsum("njN,njhp->nNhp", B, to_end[..., None] * dx, precision=_EXACT)
-        added = added.reshape(n, N, h * p)
-        whole = jnp.repeat(jnp.exp(cum[:, -1, :]), p, axis=-1)  # [n, h*p]
-
-        # Between tiles, with the pool as the carry: a tile takes its slot's
-        # stored rows where it lies (ONE row read: a gather of the tiles' rows
-        # has the compiler slice the whole pool) and leaves its outgoing state
-        # there, in place. A segment's later tiles overwrite its earlier ones',
-        # so what stays is the state after the last; nobody's tile puts back
-        # what it read.
-        def tile(carry, t):
-            ssm, before = carry
-            fresh_t, cont_t, row_t, mine_t, whole_t, added_t = t
-            stored = jax.lax.dynamic_index_in_dim(ssm, row_t, axis=0, keepdims=False)
-            came = jnp.where(fresh_t, 0.0, jnp.where(cont_t, before, stored))
-            left = whole_t[None, :] * came + added_t
-            ssm = jax.lax.dynamic_update_index_in_dim(
-                ssm, jnp.where(mine_t, left, stored), row_t, axis=0)
-            return (ssm, left), came
-
-        (ssm, _), came = jax.lax.scan(
-            tile, (ssm, jnp.zeros(ssm.shape[1:], ssm.dtype)),
-            (fresh, cont, row, slot_of < n_slots, whole, added))
-        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
-            "niN,nNhp->nihp", Cm, came.reshape(n, N, h, p), precision=_EXACT)
+    # Between tiles a segment's state goes from tile to tile; it comes from the
+    # slot's row of the pool where a segment neither begins its prompt nor
+    # follows its own tile, and its last tile leaves it there.
+    ssm, y = ssm_chunk_scan(
+        ssm, jnp.where(slot_of < n_slots, base + slot_of, ssm.shape[0]), fresh, cont, last, live,
+        dt.reshape(n, C, h), jnp.exp(lp["A_log"].astype(jnp.float32)), xs, B, Cm)
     out = _gate(y.reshape(n * C, h * p), xs.reshape(n * C, h * p), z, lp, cfg)
     x = x + cfg.residual_multiplier * out[None]
     return _mlp(x, lp, cfg), ssm, conv

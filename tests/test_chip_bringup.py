@@ -557,6 +557,35 @@ def test_ssm_state_update_compiles_for_v5e_at_the_published_widths(v5e):
     assert memory.temp_size_in_bytes < n * hp * 4
 
 
+def test_ssm_chunk_scan_compiles_for_v5e_at_the_published_widths(v5e):
+    """The chunk scan at the widths it is served at (a 1,024-token call: 16 tiles
+    of 64; 64 heads of 64, a state of 128 columns, 64 slots x 36 layers of
+    pool): one named Pallas call that reads the pool where it lies, and the
+    pool the program's input AND its output (rows put in place: neither copied
+    in nor out). Nothing of ``[tile, tile, heads]`` is among the temporaries:
+    they are the states the segments' ends hand out (a row a tile, 33.6 MB)
+    and the small per-tile operands the wrapper lays out."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.ssm import ssm_chunk_scan
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    n, T, h, p, N, slots = 16, 64, 64, 64, 128, 64
+    compiled = jax.jit(ssm_chunk_scan, donate_argnums=(0,)).lower(
+        sds((36 * slots, N, h * p), jnp.float32), sds((n,), np.int32), sds((n,), np.bool_),
+        sds((n,), np.bool_), sds((n,), np.bool_), sds((n,), np.int32), sds((n, T, h), jnp.float32),
+        sds((h,), jnp.float32), sds((n, T, h * p), jnp.float32), sds((n, T, N), jnp.float32),
+        sds((n, T, N), jnp.float32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["ssm_chunk_scan"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 36 * slots * N * h * p * 4
+    assert memory.temp_size_in_bytes < n * N * h * p * 4 + 4 * 2**20
+
+
 def test_paged_attend_compiles_for_v5e_at_heads_of_64_side_by_side(v5e):
     """``paged_attend`` on a pool whose rows are a token's 8 kv heads of 64 side
     by side ([16, 8 x 64]: 512 lanes, where [8, 64] would be padded to 128 lanes
@@ -633,7 +662,10 @@ def test_hybrid_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
         params, sds((1, width), np.int32), cache, sds((n, w), np.int32),
         sds((width // bs,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
         sds((2,), np.uint32), sds((b,), np.int32)).compile()
-    # The widest thing a 1,024-token call holds: four attention layers' scores.
+    # Nine chunk scans a period, each a kernel that holds a tile's decays and its
+    # segment's state in VMEM; the widest thing a 1,024-token call holds in HBM is
+    # the attention layer's gathered tables and scores.
+    assert _kernel_names(compiled.as_text()).count("ssm_chunk_scan") == 9
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
 
 
@@ -664,6 +696,33 @@ for lens in ([0, 3, 0, 0, 5, 1, 9, 0, 0, 0, 2, 2, 0, 7, 1, 0], [0] * 3 + [4] + [
     assert apart < 1e-5, (lens, apart)
     print("ssm_state_update", sum(1 for x in lens if x), "live: apart", apart)
 
+# the chunk scan at the served widths: 16 tiles of 64, 64 heads of 64, 8 slots, the second of three layers.
+# Slot 5 takes up its stored row over two tiles (the second partly padding), slot 2 begins from nothing
+# over three though its row holds something, a tile nobody uses, slot 0 a lone short tile, the rest nobody's.
+from ray_tpu.models.hybrid_ssm import _segments
+slots, N, h, p, T, n = 8, 128, 64, 64, 64, 16
+spec = [(5, 128, 64), (5, 192, 30), (2, 0, 64), (2, 64, 64), (2, 128, 17), (None, 0, 0), (0, 0, 9)] + [(None, 0, 0)] * 9
+pool = jax.random.normal(ks[0], (3 * slots, N, h * p), jnp.float32)
+slot_of = jnp.asarray([slots if s is None else s for s, _, _ in spec], jnp.int32)
+live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
+fresh, cont, last = _segments(jnp.asarray([a for _, a, _ in spec], jnp.int32)[:, None], slot_of, slots)
+row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots)
+dt = jax.random.uniform(ks[1], (n, T, h), jnp.float32, 0.001, 0.1)
+A = jax.random.uniform(ks[2], (h,), jnp.float32, 1.0, 16.0)
+xs, B, C = (jax.random.normal(k, s, jnp.float32) for k, s in zip(ks[3:6], ((n, T, h * p), (n, T, N), (n, T, N))))
+args = (pool, row, fresh, cont, last, live, dt, A, xs, B, C)
+assert ssm._scan_tiles(pool, dt, xs)
+assert "ssm_chunk_scan" in jax.jit(ssm.ssm_chunk_scan).lower(*args).as_text()
+want_pool, want_y = (np.asarray(a) for a in jax.jit(ssm.reference_ssm_chunk_scan)(*args))
+got_pool, got_y = (np.asarray(a) for a in jax.jit(ssm.ssm_chunk_scan)(*args))
+kept = [r for r in range(3 * slots) if r - slots not in (5, 2, 0)]
+assert np.array_equal(got_pool[kept], np.asarray(pool)[kept])
+assert not got_y[np.asarray(live) == 0].any() and np.isfinite(got_y).all()
+apart_pool = np.abs(got_pool - want_pool).max() / np.abs(want_pool).max()
+apart_y = np.abs(got_y - want_y).max() / np.abs(want_y).max()
+print("ssm_chunk_scan: apart pool", apart_pool, "largest difference", np.abs(got_pool - want_pool).max(), "y", apart_y)
+assert np.abs(got_pool - want_pool).max() < 1e-5 and apart_y < 1e-5, (apart_pool, apart_y)
+
 # decode attention at heads of 64 side by side: 32 q heads, 8 kv heads, blocks of 16, a table of 96
 b, H, KV, HD, bs, W, P = 8, 32, 8, 64, 16, 96, 900
 ck, cv = (jax.random.normal(k, (P, bs, KV * HD), jnp.float32).astype(jnp.bfloat16) for k in ks[5:7])
@@ -687,9 +746,10 @@ print("paged_attend at 64: apart", apart)
 
 
 def test_hybrid_kernels_read_the_plain_forms_numbers_on_the_chip():
-    """``ssm_state_update`` and ``paged_attend`` at ``head_dim`` 64 against their
-    plain forms on a chip, at the served widths, slots skipped and live. In a
-    process of its own: this one is held to the CPU (conftest)."""
+    """``ssm_state_update``, ``ssm_chunk_scan`` and ``paged_attend`` at ``head_dim``
+    64 against their plain forms on a chip, at the served widths: slots skipped
+    and live; segments carried and fresh, a tile partly padding, a nobody's tile.
+    In a process of its own: this one is held to the CPU (conftest)."""
     from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 
     seen, where = TPUAcceleratorManager.detect_chips()
@@ -698,4 +758,5 @@ def test_hybrid_kernels_read_the_plain_forms_numbers_on_the_chip():
     env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     r = subprocess.run([sys.executable, "-c", _HYBRID_KERNELS_AGAINST_PLAIN_FORMS], cwd=REPO,
                        env=env, capture_output=True, text=True, timeout=600)
+    print(r.stdout[-3000:])
     assert r.returncode == 0, r.stderr[-3000:]

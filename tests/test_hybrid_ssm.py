@@ -373,6 +373,67 @@ def test_ssm_state_update_kernel_reads_the_plain_forms_numbers(lens):
     assert np.array_equal(np.asarray(got_pool)[2 * b:], np.asarray(pool)[2 * b:])
 
 
+# A chunk call's tiles for ``ssm_chunk_scan``: a tile is (slot or None for nobody's,
+# its first position, its real tokens). Slots 0-3 of the second of three layers;
+# slot 1's row holds the state an earlier call left, every other row 0.5.
+SCANS = {
+    "a_full_tile": [(2, 0, 64)],
+    "a_tile_partly_padding": [(2, 0, 23)],
+    "nobodys_tile_between_two_segments": [(0, 0, 64), (0, 64, 9), (None, 0, 0), (3, 0, 40)],
+    "a_fresh_segment_of_three_tiles": [(2, 0, 64), (2, 64, 64), (2, 128, 17), (None, 0, 0)],
+    "a_stored_row_taken_up_behind_a_segment_from_nothing": [
+        (1, 128, 64), (1, 192, 30), (2, 0, 64), (2, 64, 5)],
+    "nobody_at_all": [(None, 0, 0), (None, 0, 0)],
+}
+
+
+@pytest.mark.parametrize("tiles", list(SCANS))
+def test_ssm_chunk_scan_kernel_reads_the_plain_forms_numbers(tiles):
+    """The chunk-scan kernel under the Pallas interpreter against the plain
+    form, in the second of three layers of a flat pool, tiles of 64 and heads
+    of 64: ``y`` and the WHOLE pool agree to rounding. A tile partly padding
+    leaves the state after its last real token (whatever stands behind it); a
+    nobody's tile has zeros for ``y`` and touches no row; a fresh segment begins
+    from nothing though its slot's row holds 0.5, a carried one from its row;
+    rows of slots no segment ends in, and the other layers', are bit for bit
+    what they were."""
+    from ray_tpu.ops import ssm
+
+    rng = np.random.default_rng(7)
+    slots, N, h, p, T = 4, 128, 4, 64, 64
+    spec = SCANS[tiles]
+    n = len(spec)
+    pool = np.full((3 * slots, N, h * p), 0.5, np.float32)
+    pool[slots + 1] = rng.normal(size=(N, h * p))
+    slot_of = np.asarray([slots if s is None else s for s, _, _ in spec], np.int32)
+    starts = np.asarray([a for _, a, _ in spec], np.int32)
+    live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
+    fresh, cont, last = hs._segments(jnp.asarray(starts)[:, None], jnp.asarray(slot_of), slots)
+    row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots).astype(jnp.int32)
+    dt = jnp.asarray(rng.uniform(0.001, 0.1, (n, T, h)), jnp.float32)
+    A = jnp.asarray(rng.uniform(1, 16, h), jnp.float32)
+    xs, B, C = (jnp.asarray(rng.normal(size=s), jnp.float32)
+                for s in ((n, T, h * p), (n, T, N), (n, T, N)))
+    args = (jnp.asarray(pool), row, fresh, cont, last, live, dt, A, xs, B, C)
+    assert ssm._scan_tiles(args[0], dt, xs)
+    want_pool, want_y = jax.jit(ssm.reference_ssm_chunk_scan)(*args)
+    got_pool, got_y = jax.jit(lambda *a: ssm._ssm_chunk_scan(*a, interpret=True))(*args)
+    assert np.allclose(got_y, want_y, rtol=1e-4, atol=1e-4)
+    assert np.allclose(got_pool, want_pool, rtol=1e-5, atol=1e-5)
+    got_pool, got_y = np.asarray(got_pool), np.asarray(got_y)
+    ended = {s for (s, _, _), e in zip(spec, np.asarray(last)) if e}
+    kept = [r for r in range(3 * slots) if r - slots not in ended]
+    assert np.array_equal(got_pool[kept], pool[kept])
+    assert all(np.abs(got_pool[slots + s] - pool[slots + s]).max() > 0 for s in ended)
+    assert not got_y[np.asarray(live) == 0].any()
+    if tiles == "a_tile_partly_padding":
+        # The state is the state after the 23rd token: other tokens behind it change nothing.
+        other = (args[0], row, fresh, cont, last, live, dt.at[:, 23:].set(0.05), A,
+                 xs.at[:, 23:].set(3.0), B.at[:, 23:].set(-1.0), C)
+        again, _ = jax.jit(lambda *a: ssm._ssm_chunk_scan(*a, interpret=True))(*other)
+        assert np.array_equal(np.asarray(again), got_pool)
+
+
 def test_packed_paged_attend_reads_the_plain_forms_numbers():
     """Heads of 64 side by side on the lanes, through the decode attention
     kernel (interpreter) as ONE wide head: the plain form's numbers, at the
