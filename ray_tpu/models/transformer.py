@@ -11,7 +11,9 @@ Design choices (vs. the reference, which delegates models to torch):
 - GQA attention through ray_tpu.ops.flash_attention (Pallas on TPU);
   when a sequence-parallel mesh axis is active the caller routes attention
   through ring attention instead (ray_tpu/parallel/ring.py).
-- ``jax.checkpoint`` per layer to trade FLOPs for HBM (remat).
+- ``jax.checkpoint`` per layer to trade FLOPs for HBM (remat): a layer's
+  XLA operations are recomputed on backward, the flash forward kernel's
+  output and logsumexp are kept (``checkpoint_layer``).
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import FLASH_RESIDUAL_NAMES, flash_attention
 
 Params = Dict[str, Any]
 
@@ -44,11 +46,14 @@ class TransformerConfig:
     experts_per_token: int = 2
     # Blockwise cross-entropy chunk (tokens); 0 = materialize full logits.
     logits_chunk: int = 0
-    # Remat policy: "full" recomputes the whole layer on backward;
-    # "dots" saves matmul outputs and recomputes only cheap elementwise
-    # ops (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) —
-    # far less recompute FLOPs for modestly more HBM; "attn" saves only
-    # the flash-attention outputs.
+    # What a rematerialised layer keeps (``checkpoint_layer``). "full":
+    # every XLA operation of the layer is recomputed on backward, and the
+    # flash forward kernel's two results are kept so the kernel runs once
+    # (one [b, s, heads x head_dim] in ``dtype`` and one [b, heads, s]
+    # float32 a layer, beside the layer input the scan already keeps).
+    # "dots": matmul outputs are kept as well
+    # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) — far
+    # less recompute FLOPs for more HBM.
     remat_policy: str = "full"
     # lax.scan unroll over the layer stack: >1 inlines several layer
     # bodies per scan step, widening XLA's fusion/scheduling scope
@@ -178,9 +183,6 @@ def attention_block(
             if attn_fn is None
             else attn_fn(qt, kt, vt)
         )
-        from jax.ad_checkpoint import checkpoint_name
-
-        o = checkpoint_name(o, "attn_out")  # remat_policy="attn" saves these
     else:
         # custom attention (ring/Ulysses SP) still takes equal head
         # counts — repeat kv heads for those paths
@@ -191,9 +193,6 @@ def attention_block(
             vr = jnp.repeat(v, rep, axis=2)
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, kr, vr))
         o = attn_fn(qt, kt, vt)
-        from jax.ad_checkpoint import checkpoint_name
-
-        o = checkpoint_name(o, "attn_out")  # remat_policy="attn" saves these
     o = o.transpose(0, 2, 1, 3).reshape(b, s, H * HD)
     out = x + o @ lp["wo"].astype(o.dtype)
     if return_kv:
@@ -248,6 +247,30 @@ def embed(params: Params, tokens, cfg: TransformerConfig):
     return params["embed"].astype(cfg.dtype)[tokens]
 
 
+def checkpoint_layer(layer_fn: Callable, cfg: TransformerConfig) -> Callable:
+    """``layer_fn`` as the body of a layer scan: rematerialised if
+    ``cfg.remat``. The ONE place that decides what such a layer keeps
+    (``decoder_stack``, the pipeline stage of parallel/train_step.py and
+    parallel/mpmd.py::make_stage_fn all come here). Under every policy the
+    flash forward kernel's output and logsumexp are kept by name
+    (ops/attention.py names them where the kernel returns): the backward
+    kernels read them, so the recomputation is XLA operations only and the
+    kernel runs once a layer a step. On a backend without the kernels
+    nothing carries the names and nothing extra is kept."""
+    if cfg.remat_policy not in ("full", "dots"):
+        raise ValueError(
+            f"remat_policy must be 'full' or 'dots', got {cfg.remat_policy!r}"
+        )
+    if not cfg.remat:
+        return layer_fn
+    policy = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUAL_NAMES)
+    if cfg.remat_policy == "dots":
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable, policy
+        )
+    return jax.checkpoint(layer_fn, prevent_cse=False, policy=policy)
+
+
 def decoder_stack(params: Params, h, cfg: TransformerConfig, positions, attn_fn=None):
     """Scan over stacked layers; optionally rematerialized."""
 
@@ -255,23 +278,9 @@ def decoder_stack(params: Params, h, cfg: TransformerConfig, positions, attn_fn=
         out = decoder_layer(carry, lp, cfg, positions, attn_fn)
         return out, None
 
-    if cfg.remat:
-        if cfg.remat_policy not in ("full", "dots", "attn"):
-            raise ValueError(
-                f"remat_policy must be 'full', 'dots' or 'attn', got {cfg.remat_policy!r}"
-            )
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        elif cfg.remat_policy == "attn":
-            # Save ONLY the flash-attention outputs ([b,s,d] per layer —
-            # ~50 MB/layer at the flagship config): the backward pass then
-            # skips recomputing the most expensive fwd op while activation
-            # memory stays near full-remat levels.
-            policy = jax.checkpoint_policies.save_only_these_names("attn_out")
-        else:
-            policy = None
-        layer_fn = jax.checkpoint(layer_fn, prevent_cse=False, policy=policy)
-    h, _ = jax.lax.scan(layer_fn, h, params["layers"], unroll=cfg.scan_unroll)
+    h, _ = jax.lax.scan(
+        checkpoint_layer(layer_fn, cfg), h, params["layers"], unroll=cfg.scan_unroll
+    )
     return h
 
 

@@ -34,6 +34,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -566,11 +567,23 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None)
     return _fwd(q, k, v, causal, scale)[0]
 
 
+# The names under which the forward kernel's two results are known to a
+# ``jax.checkpoint`` policy (models/transformer.py::checkpoint_layer keeps
+# them, so a rematerialised layer never runs the kernel a second time).
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
 def _fwd(q, k, v, causal, scale):
     s = scale if scale is not None else q.shape[-1] ** -0.5
     if _use_pallas():
         bq, bk = _default_blocks(q.shape[-2], k.shape[-2], q.shape[-1])
         out, lse = _flash_forward(q, k, v, causal, s, block_q=bq, block_k=bk, interpret=False)
+        # Named HERE, before they become both the primal result and the
+        # residuals: the variables the backward kernels read are then the
+        # named ones, and a policy that keeps the names keeps the kernel
+        # from running again. Outside a checkpoint a name is the identity.
+        out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
         return out, (q, k, v, out, lse)
     return reference_attention(q, k, v, causal=causal, scale=s), (q, k, v, None, None)
 

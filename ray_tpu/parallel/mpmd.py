@@ -62,9 +62,7 @@ def make_stage_fn(cfg: "tf.TransformerConfig", attn_fn=None) -> Callable:
         def layer_fn(carry, lp):
             return tf.decoder_layer(carry, lp, cfg, positions, attn_fn), None
 
-        if cfg.remat:
-            layer_fn = jax.checkpoint(layer_fn, prevent_cse=False)
-        x, _ = jax.lax.scan(layer_fn, x, stage_params)
+        x, _ = jax.lax.scan(tf.checkpoint_layer(layer_fn, cfg), x, stage_params)
         return x
 
     return stage_fn

@@ -78,16 +78,9 @@ def build_loss_fn(cfg: tf.TransformerConfig, plan: MeshPlan, mesh: Mesh, num_mic
             out = tf.decoder_layer(carry, lp, cfg, positions, stage_attn_fn)
             return out, None
 
-        if cfg.remat:
-            # honor the same remat_policy knob as tf.decoder_stack
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            elif cfg.remat_policy == "attn":
-                policy = jax.checkpoint_policies.save_only_these_names("attn_out")
-            else:
-                policy = None
-            layer_fn = jax.checkpoint(layer_fn, prevent_cse=False, policy=policy)
-        x, _ = jax.lax.scan(layer_fn, x, stage_params, unroll=cfg.scan_unroll)
+        x, _ = jax.lax.scan(
+            tf.checkpoint_layer(layer_fn, cfg), x, stage_params, unroll=cfg.scan_unroll
+        )
         return x
 
     def loss(params, batch):

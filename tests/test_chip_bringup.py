@@ -174,17 +174,8 @@ def test_flash_sequence_ceiling_is_a_value_error(v5e):
         )).lower(short)
 
 
-@pytest.mark.parametrize(
-    "plan_kw, microbatches",
-    [
-        (dict(fsdp=4), 1),
-        (dict(fsdp=2, sp=2), 1),
-        (dict(fsdp=2, sp=2, sp_mode="ulysses"), 1),
-        (dict(pp=2, tp=2), 2),
-    ],
-    ids=["fsdp4", "sp2-ring", "sp2-ulysses", "pp2xtp2"],
-)
-def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
+def _compiled_train_step(v5e, cfg, plan_kw, sequences, seq_len, microbatches=1, opt_kw=None):
+    """``make_train_step`` compiled for the described chips at abstract shapes."""
     from ray_tpu.models import transformer as tf
     from ray_tpu.parallel import MeshPlan, build_mesh
     from ray_tpu.parallel import mesh as mesh_lib
@@ -192,13 +183,9 @@ def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
         _opt_state_shardings, make_optimizer, make_train_step,
     )
 
-    cfg = tf.TransformerConfig(
-        vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
-        d_ff=128, max_seq_len=128, dtype=jnp.bfloat16, remat=True,
-    )
     plan = MeshPlan(**plan_kw)
-    mesh = build_mesh(plan, devices=v5e)
-    opt = make_optimizer(lr=1e-3, warmup=1)
+    mesh = build_mesh(plan, devices=v5e[: plan.num_devices])
+    opt = make_optimizer(**(opt_kw or dict(lr=1e-3, warmup=1)))
     p_shard = mesh_lib.param_shardings(mesh, cfg, plan)
     params = _abstract(
         jax.eval_shape(lambda k: tf.init_params(k, cfg), jax.random.PRNGKey(0)), p_shard
@@ -207,14 +194,68 @@ def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
         jax.eval_shape(opt.init, params), _opt_state_shardings(opt, params, p_shard, mesh)
     )
     batch = {"tokens": jax.ShapeDtypeStruct(
-        (4, 129), jnp.int32, sharding=mesh_lib.batch_sharding(mesh, plan))}
+        (sequences, seq_len + 1), jnp.int32, sharding=mesh_lib.batch_sharding(mesh, plan))}
     step = make_train_step(cfg, plan, mesh, opt, num_microbatches=microbatches)
-    text = step.lower(params, opt_state, batch).compile().as_text()
+    return step.lower(params, opt_state, batch).compile()
+
+
+@pytest.mark.parametrize(
+    "plan_kw, microbatches",
+    [
+        (dict(dp=1), 1),
+        (dict(fsdp=4), 1),
+        (dict(fsdp=2, sp=2), 1),
+        (dict(fsdp=2, sp=2, sp_mode="ulysses"), 1),
+        (dict(pp=2, tp=2), 2),
+    ],
+    ids=["one-device", "fsdp4", "sp2-ring", "sp2-ulysses", "pp2xtp2"],
+)
+def test_train_step_compiles_for_v5e(v5e, plan_kw, microbatches):
+    from ray_tpu.models import transformer as tf
+
+    cfg = tf.TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
+        d_ff=128, max_seq_len=128, dtype=jnp.bfloat16, remat=True,
+    )
+    text = _compiled_train_step(v5e, cfg, plan_kw, 4, 128, microbatches).as_text()
     # ring attention is einsums; every other plan must hold the Pallas kernel
     assert ("tpu_custom_call" in text) == (plan_kw.get("sp_mode", "ring") != "ring"
-                                           or plan.sp == 1)
+                                           or plan_kw.get("sp", 1) == 1)
     # under remat and shard_map alike the trace will call them by their own names
-    assert set(_kernel_names(text)) <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    kernels = _kernel_names(text)
+    assert set(kernels) <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    # a rematerialised layer keeps the forward kernel's results and never runs
+    # it again (transformer.checkpoint_layer): one forward call a backward call
+    # (remat_policy="attn", gone, kept a name the residuals did not carry: 2 to 1)
+    assert kernels.count("flash_fwd") == kernels.count("flash_bwd_dq") \
+        == kernels.count("flash_bwd_dkv")
+
+
+def test_one_chip_training_cell_keeps_its_margin_on_v5e(v5e):
+    """The step of ``train-dense-1chip`` at its own shapes (Mistral-7B's
+    widths, 2 layers, 3 x 4,096 tokens, the cell's optimizer): what the
+    compiler counts stays under the chip's 16.9 GB and within 0.3 GB of PR
+    47's reading, so the next change to the layer cannot spend the margin
+    unnoticed. ``temp_size_in_bytes`` counts a buffer carried through a
+    ``while`` twice (PERF.md section 6, PR 47); ``peak_memory_in_bytes`` is
+    the heap's peak with the arguments."""
+    from ray_tpu.models import transformer as tf
+
+    cfg = tf.TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=8, d_ff=14336,
+        rope_theta=1e6, max_seq_len=4096, dtype=jnp.bfloat16, remat=True, logits_chunk=512,
+    )
+    compiled = _compiled_train_step(
+        v5e, cfg, dict(dp=1), 3, 4096,
+        opt_kw=dict(lr=3e-4, weight_decay=0.1, warmup=10, grad_clip=1.0),
+    )
+    kernels = _kernel_names(compiled.as_text())
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    m = compiled.memory_analysis()
+    counted = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert counted < 16.9e9, counted
+    assert abs(counted - 16.494e9) < 0.3e9, counted
+    assert abs(m.peak_memory_in_bytes - 13.882e9) < 0.3e9, m.peak_memory_in_bytes
 
 
 def _engine_shapes(cfg, pcfg, device):

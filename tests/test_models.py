@@ -488,21 +488,71 @@ def test_chunked_nll_respects_mask():
     assert abs(l0 - l1) < 1e-6
 
 
-def test_remat_policy_dots_same_loss():
+@pytest.mark.parametrize("remat", [False, "full", "dots"])
+def test_remat_policy_same_loss_and_gradients(remat):
+    """Whatever a rematerialised layer keeps, the numbers are those of the
+    layer that keeps everything: the loss and every gradient leaf (float32 on
+    the CPU; to rounding, because XLA fuses a recomputed body its own way)."""
     import dataclasses
 
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import transformer as tf
-
-    cfg = tf.TransformerConfig.tiny(dtype=jnp.float32, remat=True)
+    plain = tf.TransformerConfig.tiny(dtype=jnp.float32, remat=False)
+    cfg = dataclasses.replace(plain, remat=bool(remat), remat_policy=remat or "full")
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, cfg.vocab_size)
-    batch = {"tokens": tokens}
-    l_full = float(tf.loss_fn(params, batch, cfg))
-    cfg_dots = dataclasses.replace(cfg, remat_policy="dots")
-    l_dots = float(jax.grad(lambda p: tf.loss_fn(p, batch, cfg_dots))(params)["final_norm"][0]), float(
-        tf.loss_fn(params, batch, cfg_dots)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, cfg.vocab_size)}
+    want_l, want_g = jax.value_and_grad(lambda p: tf.loss_fn(p, batch, plain))(params)
+    got_l, got_g = jax.value_and_grad(lambda p: tf.loss_fn(p, batch, cfg))(params)
+    assert abs(float(got_l) - float(want_l)) < 1e-6
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
+        ),
+        got_g, want_g,
     )
-    assert abs(l_dots[1] - l_full) < 1e-6
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_remat_policy_attn_is_gone(remat):
+    """``"attn"`` named the attention's output AFTER the custom VJP and so
+    kept a copy of it beside a kernel that still ran twice: the value is
+    refused, rematerialised or not, and the error names the two left."""
+    cfg = tf.TransformerConfig.tiny(dtype=jnp.float32, remat=remat, remat_policy="attn")
+    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    with pytest.raises(ValueError, match="'full' or 'dots'"):
+        tf.loss_fn(params, batch, cfg)
+
+
+def _count_in_jaxpr(jaxpr, name: str) -> int:
+    """Pallas calls named ``name`` anywhere in ``jaxpr``, sub-jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_in_jaxpr(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("plan_kw", [dict(dp=1), dict(fsdp=4)], ids=["one-device", "fsdp4"])
+def test_rematerialised_layer_runs_the_flash_forward_kernel_once(monkeypatch, plan_kw):
+    """The forward scan's body and the backward scan's body together hold ONE
+    ``flash_fwd``: the kernel's output and logsumexp are kept by name
+    (``checkpoint_layer``), so the recomputation is XLA operations only. A
+    policy that keeps a name the residuals do not carry (``"attn"``, gone)
+    read 2 here."""
+    from ray_tpu.parallel import MeshPlan, build_mesh
+    from ray_tpu.parallel.train_step import build_loss_fn
+
+    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")  # the TPU's dispatch, traced only
+    cfg = tf.TransformerConfig(
+        vocab_size=128, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1, d_ff=128,
+        max_seq_len=128, dtype=jnp.bfloat16, remat=True,
+    )
+    plan = MeshPlan(**plan_kw)
+    mesh = build_mesh(plan, devices=jax.devices()[: plan.num_devices])
+    loss = build_loss_fn(cfg, plan, mesh)
+    params = jax.eval_shape(lambda k: tf.init_params(k, cfg), jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 129), jnp.int32)}
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr
+    counts = {k: _count_in_jaxpr(jaxpr, k) for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert counts == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}, counts
