@@ -33,8 +33,8 @@ Iteration-level perf suite (opt-in unless it says otherwise, see ``__init__``):
 - **Host/device overlap** (``overlap``): window N+1 is dispatched from
   window N's device-resident outputs before N's tokens are read; the
   host consumes/schedules while the device keeps stepping. Decode
-  inputs live on device and only scheduler-dirtied arrays are re-shipped
-  (``_ship``).
+  inputs live on device: ``tables``/``lens``/``temps`` are re-shipped only
+  when the scheduler dirtied them (``_ship``), ``cur`` never leaves it.
 - **The window behind the prefill** (always): a prefill program puts the
   token it sampled into the device's ``cur``, so the step dispatches its
   decode window before the host has read that token and the dispatch's
@@ -103,7 +103,7 @@ _STEP_MS = tuple((f"{name}_ms", "engine." + name) for name in _PHASES) + tuple(
 # every phase: the turn of the loop, and what a step does between two phases.
 _STARVED = _SUB_PHASES + ("emit", "record", "between")
 # Why a window in flight was not overlapped: ``stats["spec_blocked_<reason>"]``.
-_SPEC_BLOCKED = ("idle", "admission", "dirty_cur", "finishing")
+_SPEC_BLOCKED = ("idle", "admission", "finishing")
 # The counters a recorded step carries as what the iteration added to them.
 _STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks",
                 "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill",
@@ -550,11 +550,15 @@ class LLMEngine:
          self.params) = self._build_programs(params)
         self.alloc = _BlockAllocator(p)
         self.key = jax.random.PRNGKey(seed)
-        # Slot state. Host-side numpy is the source of truth and the
-        # scheduler thread its one owner; the device is handed copies
-        # (``_hand_over``), kept in ``_dev`` and re-uploaded ONLY when the
-        # scheduler dirtied them — steady-state decode re-ships nothing
-        # (cur/lens ride the decode program's own outputs).
+        # Slot state. ``tables``, ``lens`` and ``temps`` are the scheduler
+        # thread's, on the host: the device is handed copies (``_hand_over``),
+        # kept in ``_dev`` and re-uploaded ONLY when the scheduler dirtied
+        # them (steady-state decode re-ships nothing: ``lens`` rides the
+        # decode program's own output). ``cur``, the token each slot feeds
+        # its next decode step, is the device's: made once, below, written
+        # only by programs (a prefill's first token, a window's last), and
+        # never on the host. An idle row's is whatever was left there; no
+        # live row's output depends on it (``paged_decode_loop``).
         self.slots: List[Optional[Request]] = [None] * p.max_batch
         self.slot_blocks: List[List[int]] = [[] for _ in range(p.max_batch)]
         # Bumped on every (re)assignment of a slot: an in-flight window's
@@ -566,11 +570,13 @@ class LLMEngine:
         self.tables = np.full((p.max_batch, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
         self.lens = np.zeros(p.max_batch, np.int32)
         self.temps = np.zeros(p.max_batch, np.float32)
-        self.cur = np.zeros(p.max_batch, np.int32)
         self._dev: Dict[str, Optional[jax.Array]] = {
-            "tables": None, "lens": None, "temps": None, "cur": None,
+            "tables": None, "lens": None, "temps": None,
+            # Fresh and uncommitted, as the cache is: the prefill programs
+            # name the placement of both (``_build_programs``).
+            "cur": jax.numpy.zeros(p.max_batch, np.int32),
         }
-        self._dirty = {"tables", "lens", "temps", "cur"}
+        self._dirty = {"tables", "lens", "temps"}
         # In-flight speculated window: ([(slot, rid, gen), ...], seq device
         # array, number of its program call). Harvested (ONE host sync) at
         # the top of the next step.
@@ -772,7 +778,7 @@ class LLMEngine:
                 self.cache,
                 jax.numpy.asarray(np.full(S // bs, TRASH_BLOCK, np.int32)),
                 np.asarray([1, self.pcfg.max_batch], np.int32),  # no slot's
-                np.float32(0.0), sub, self._device_cur(),
+                np.float32(0.0), sub, self._dev["cur"],
             )
             n += 1
         if self.prefill_chunk:
@@ -787,7 +793,7 @@ class LLMEngine:
         # Decode window: already compiled (AOT) — this is its first
         # execution, so a program that does not fit fails at build time.
         seq, self._dev["cur"], _lens, self.cache = self._decode(
-            self.params, self._device_cur(), self.cache,
+            self.params, self._dev["cur"], self.cache,
             self._hand_over(self.tables), self._hand_over(self.lens),
             self._hand_over(self.temps), sub,
         )
@@ -939,8 +945,7 @@ class LLMEngine:
         self.tables[i] = TRASH_BLOCK
         self.lens[i] = 0
         self.temps[i] = 0.0
-        self.cur[i] = 0
-        self._dirty.update(("tables", "lens", "temps", "cur"))
+        self._dirty.update(("tables", "lens", "temps"))
 
     def _alloc_blocks(self, n: int) -> Optional[List[int]]:
         """Allocate ``n`` blocks, evicting prefix-cache residents as needed
@@ -1031,9 +1036,9 @@ class LLMEngine:
         if the pool is exhausted. False if a slot is left without: there
         is nobody to preempt, or first tokens are still unread. A victim
         may be the slot one of them belongs to (the youngest goes first),
-        and giving a slot back dirties ``cur``, whose wholesale ship would
-        lose what the prefill programs put into the device's: the host
-        reads them first (``step``)."""
+        and a request preempted before the host has read its first token
+        loses it and pays its prefill again: the host reads them first
+        (``step``)."""
         bs = self.pcfg.block_size
         for i in range(len(self.slots)):
             while self.slots[i] is not None and i not in self._prefilling:
@@ -1219,7 +1224,7 @@ class LLMEngine:
                 self.params, jax.numpy.asarray(toks), self.cache,
                 jax.numpy.asarray(row),
                 np.asarray([plen, i], np.int32), np.float32(req.temperature), sub,
-                self._device_cur(),
+                self._dev["cur"],
             )
             self._launched()
         return tok
@@ -1303,7 +1308,7 @@ class LLMEngine:
             # mutates in place, need _hand_over's copy.
             out, self.cache, self._dev["cur"] = self._prefill_chunk_fn(
                 self.params, toks, self.cache, trows, crow, per_tile, temps, sub,
-                self._device_cur())
+                self._dev["cur"])
             self._launched()
         return out
 
@@ -1356,11 +1361,10 @@ class LLMEngine:
         """Read the first tokens the iteration's prefill programs sampled (ONE
         transfer: the host blocks until the last of them has run) and emit
         them. ``step`` calls this AFTER it has dispatched the decode window,
-        which took those tokens from the device's ``cur``: what the read
-        returns goes into the host's mirror and leaves it no dirtier, the
-        device holds it already. Only when that dispatch has to preempt,
-        which would dirty the host's ``cur`` between a prefill's launch and
-        the ship, does the flush come first (``_ensure_decode_blocks``)."""
+        which took those tokens from the device's ``cur``: the host reads
+        them only to emit them. Only when that dispatch has to preempt,
+        and the victim may own one of them, does the flush come first
+        (``_ensure_decode_blocks``)."""
         if not self._pending_first:
             return
         pend, self._pending_first = self._pending_first, []
@@ -1378,9 +1382,7 @@ class LLMEngine:
             for i, req, t, k in pend:
                 if self.slots[i] is not req:
                     continue  # preempted between prefill and flush
-                tok = int(vals[id(t)][k])
-                self.cur[i] = tok  # as the device's ``cur[i]`` is since the program ran
-                self._emit(i, tok)
+                self._emit(i, int(vals[id(t)][k]))
         self._at("between")
 
     def _count(self, counts):
@@ -1417,10 +1419,10 @@ class LLMEngine:
     def _ship(self) -> Dict[str, jax.Array]:
         """Device-resident decode inputs, re-uploading ONLY the mirrors the
         scheduler dirtied since the last dispatch, each as a copy
-        (``_hand_over``). A prefill dirties three of the four: its first
-        token reached ``cur`` on the device (``_device_cur``)."""
+        (``_hand_over``); ``cur`` is the device's own and is never among
+        them."""
         for name, host in (("tables", self.tables), ("lens", self.lens),
-                           ("temps", self.temps), ("cur", self.cur)):
+                           ("temps", self.temps)):
             if self._dev[name] is None or name in self._dirty:
                 self._dev[name] = self._hand_over(host)
                 self._dirty.discard(name)
@@ -1428,24 +1430,6 @@ class LLMEngine:
             else:
                 self.stats["h2d_skips"] += 1
         return self._dev
-
-    def _device_cur(self) -> jax.Array:
-        """The device's ``cur``, for a prefill program to put its first token
-        in and hand on (to the next prefill call of the iteration, then to
-        the decode window). With no window in flight the device's copy is
-        as good as the mirror on every occupied row: the harvest wrote the
-        mirror from that window's own output, a prefill's token reached the
-        device first, and the rows ``_free_slot`` zeroed are idle, whose
-        token no one reads. So the mirror stops being dirty here and is not
-        shipped. Behind a speculated window the device is AHEAD of the
-        mirror: its copy is the one to use, and a dirty mirror stays dirty
-        until the harvest has caught up (``_can_speculate``)."""
-        if self._dev["cur"] is None:  # the first program call of all
-            self._dev["cur"] = self._hand_over(self.cur)
-            self.stats["h2d_ships"] += 1
-        if self._inflight is None:
-            self._dirty.discard("cur")
-        return self._dev["cur"]
 
     def _decode_entries(self) -> List[tuple]:
         """(slot, rid, slot_gen) for every decodable slot — occupied and
@@ -1455,7 +1439,7 @@ class LLMEngine:
         return [(i, s.rid, self._slot_gen[i]) for i, s in enumerate(self.slots)
                 if s is not None and i not in self._prefilling]
 
-    def _dispatch_window(self, speculative: bool = False) -> bool:
+    def _dispatch_window(self) -> bool:
         """Dispatch ONE decode window over the decodable slots without
         reading it back: outputs (sampled tokens, advanced lens) stay on
         device and feed the next window directly. The host advances the
@@ -1463,20 +1447,19 @@ class LLMEngine:
         still-prefilling row stays at the 0 ``_free_slot`` left it at. The
         device's own ``lens`` output advances EVERY row; what an idle row
         holds there is ``paged_decode_loop``'s to define (it restarts a
-        row whose table starts on the trash block)."""
+        row whose table starts on the trash block).
+
+        Speculated (a window still in flight) or not, the call is the same,
+        and its ``_ensure_decode_blocks`` may preempt under that window:
+        the host's ``lens`` were advanced when it was dispatched, so a ship
+        of the three mirrors is current without its tokens, and ``cur`` is
+        that window's own output, which no ship replaces. False if nothing
+        is left to decode, or a slot is left without blocks."""
         ph = self._phase_ms
         with tracing.phase("engine.dispatch.blocks", ph):
             self._at("dispatch_blocks")
             entries = self._ensure_decode_blocks() and self._decode_entries()
             if not entries:
-                return False
-            if speculative and "cur" in self._dirty:
-                # The host ``cur`` mirror LAGS the in-flight window (its live
-                # rows are window N-1's tokens until the harvest), so a dirty
-                # cur — a preemption the _ensure above just performed — must
-                # not be shipped wholesale now: it would rewind every other
-                # slot by one window. Abort the speculation; the synchronous
-                # path re-dispatches after the harvest has re-synced the mirror.
                 return False
             self.stats["max_active"] = max(self.stats["max_active"], len(entries))
             # Blocks the occupied slots' tokens lie in as the window starts,
@@ -1510,10 +1493,10 @@ class LLMEngine:
         self._inflight = (entries, seq, self._launches)
         return True
 
-    def _dispatch(self, speculative: bool = False) -> bool:
+    def _dispatch(self) -> bool:
         """``_dispatch_window`` as the iteration's ``engine.dispatch`` phase."""
         with tracing.phase("engine.dispatch", self._phase_ms):
-            dispatched = self._dispatch_window(speculative)
+            dispatched = self._dispatch_window()
         self._at("between")
         return dispatched
 
@@ -1542,7 +1525,6 @@ class LLMEngine:
                 for k in range(self.window):
                     if self.slots[i] is not req:
                         break  # finished mid-window; rest is overshoot
-                    self.cur[i] = nxt[k, i]
                     self._emit(i, int(nxt[k, i]))
         self._at("between")
         return True
@@ -1552,20 +1534,16 @@ class LLMEngine:
         go ahead, else the reason not to (one of ``_SPEC_BLOCKED``):
         ``idle``, nothing decodable; ``admission``, a waiting request
         could use a free slot first (it should join N+1, not N+2);
-        ``dirty_cur``, a slot was given back since the last ship and the
-        host cur lags the in-flight window, so sync first (a prefill's
-        flush does not dirty it: its token is on the device before the
-        host reads it); ``finishing``, a slot's cap-finish inside N is
-        already certain (the speculated window would be pure waste). An
-        eos-stopped slot can still waste one window — capacity covers it
-        (the 2*window-1 overlap margin)."""
+        ``finishing``, a slot's cap-finish inside N is already certain
+        (the speculated window would be pure waste). An eos-stopped slot
+        can still waste one window — capacity covers it (the 2*window-1
+        overlap margin). A slot given back under N is no reason: N+1 takes
+        its tokens from N's own output on the device."""
         entries = self._decode_entries()
         if not entries:
             return "idle"
         if self.waiting and any(s is None for s in self.slots):
             return "admission"
-        if "cur" in self._dirty:
-            return "dirty_cur"
         if any(self.slots[i].remaining <= self.window for i, _, _ in entries):
             return "finishing"
         return None
@@ -1631,11 +1609,11 @@ class LLMEngine:
         generation check discards it, and its blocks are freed under that
         window as a speculated window's are (below). The one case in which
         the read comes first: the dispatch's own ``_ensure_decode_blocks``
-        has to preempt. Giving a slot back dirties the host's ``cur``, and
-        a wholesale ship of it would lose the device's first tokens (the
-        victim may be the very slot that owns one); so the dispatch gives
-        up, the flush comes first, and the preemption and the ship after
-        it (``prefill_flushed_first``).
+        has to preempt. The victim, the youngest slot, may be the very one
+        that owns an unread first token, which would be dropped and its
+        prefill paid again; so the dispatch gives up, the flush comes
+        first, and the preemption and the dispatch after it
+        (``prefill_flushed_first``).
 
         With ``overlap`` the device is double-buffered: window N+1 is
         dispatched from N's device-resident outputs BEFORE N's tokens are
@@ -1667,8 +1645,8 @@ class LLMEngine:
                 # Exactly one of spec_windows and the spec_blocked_* counts
                 # goes up for every window found in flight.
                 blocked = self._can_speculate()
-                if blocked is None and not self._dispatch(speculative=True):
-                    blocked = "dirty_cur"  # _ensure_decode_blocks preempted
+                if blocked is None and not self._dispatch():
+                    blocked = "idle"  # its preemptions left nothing decodable
                 if blocked is None:
                     self.stats["spec_windows"] += 1
                     overlapped = 1

@@ -273,13 +273,13 @@ class _ServeMetrics:
         self.engine_prefill_flushed_first = Counter(
             "serve_engine_prefill_flushed_first_total",
             "Decode windows that waited for the host to read the prefills' first "
-            "tokens: the dispatch had to preempt, which dirties the host's token mirror",
+            "tokens: the dispatch had to preempt, and the victim may own one of them",
             dr,
         )
         self.engine_overlap_blocked = Counter(
             "serve_engine_overlap_blocked_total",
             "Decode windows found in flight and NOT overlapped, by reason "
-            "(idle, admission, dirty_cur, finishing)",
+            "(idle, admission, finishing)",
             dr + ("reason",),
         )
         self.engine_device_starved = Counter(

@@ -8,8 +8,9 @@ the time the device has nothing queued is counted by where the scheduler
 thread was (``stats["starved_us_*"]``), apart from idleness for want of load;
 every window found in flight is either overlapped (``spec_windows``) or
 counted under the reason it was not (``spec_blocked_*``); the decode
-program's layer carries ``paged.*`` scopes. The scheduler owns the slot
-mirrors: the device is handed copies, and only a dispatched row advances.
+program's layer carries ``paged.*`` scopes. The scheduler owns three slot
+mirrors (``tables``, ``lens``, ``temps``): the device is handed copies, and
+only a dispatched row advances. ``cur`` is the device's alone.
 """
 import json
 import os
@@ -23,7 +24,6 @@ import numpy as np
 import pytest
 
 from ray_tpu.models.paged import PagedConfig
-from ray_tpu.models.transformer import TransformerConfig, init_params
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve.llm_engine import LLMEngine
 from ray_tpu.util import tracing
@@ -35,16 +35,30 @@ BUCKETS = ["starved_us_" + where for where in llm_engine._STARVED]
 BLOCKED = ["spec_blocked_" + why for why in llm_engine._SPEC_BLOCKED]
 
 
+def _tiny(which):
+    """One of the three bodies behind ``paged_model(cfg)``, at its tests' size."""
+    if which == "dense":
+        from ray_tpu.models import transformer as m
+        cfg = m.TransformerConfig.tiny(dtype=jnp.float32, remat=False)
+    elif which == "latent":
+        from ray_tpu.models import latent_moe as m
+        cfg = m.LatentMoEConfig.tiny()
+    else:
+        from ray_tpu.models import hybrid_ssm as m
+        cfg = m.HybridSSMConfig.tiny()
+    return cfg, m.init_params(jax.random.PRNGKey(7), cfg)
+
+
 @pytest.fixture(scope="module")
 def tiny_model():
-    cfg = TransformerConfig.tiny(dtype=jnp.float32, remat=False)
-    return cfg, init_params(jax.random.PRNGKey(7), cfg)
+    return _tiny("dense")
 
 
-def _engine(cfg, params, *, window=2, overlap=True, **paged):
+def _engine(cfg, params, *, window=2, overlap=True, prefill_chunk=0, seed=0, **paged):
     pcfg = PagedConfig(**{**dict(block_size=8, num_blocks=33, max_batch=4,
                                  max_blocks_per_seq=8), **paged})
-    return LLMEngine(params, cfg, pcfg, decode_window=window, overlap=overlap)
+    return LLMEngine(params, cfg, pcfg, decode_window=window, overlap=overlap,
+                     prefill_chunk=prefill_chunk, seed=seed)
 
 
 # ---------------------------------------------------------------------------
@@ -252,6 +266,7 @@ def test_a_slow_launch_is_counted_in_dispatch_launch_alone(tiny_model, overlap):
     cfg, params = tiny_model
     eng = _engine(cfg, params, overlap=overlap)
     eng.add_request([5, 9, 2, 11], 40)
+    eng.add_request([17, 1, 8], 40)
     eng.step()
     eng.step()
     decode = eng._decode
@@ -261,7 +276,9 @@ def test_a_slow_launch_is_counted_in_dispatch_launch_alone(tiny_model, overlap):
         return decode(*args)
 
     eng._decode = slow
-    eng._dirty.add("cur")  # with overlap: no speculation, so the harvest comes first
+    # with overlap: the first ends in the window in flight, so no speculation:
+    # the harvest comes first, and the other's next window after it
+    _force_finishing(eng)
     before = _starved(eng)
     eng.step()
     eng._decode = decode
@@ -360,8 +377,8 @@ def test_the_same_seed_gives_the_same_tokens_behind_the_prefill_as_flushed_first
         assert sb["preemptions"] > 0 and sb["prefill_flushed_first"] > 0
     else:
         assert sb["prefill_flushed_first"] == 0
-        # nothing is shipped for a first token: the flush dirties no mirror
-        assert sb["h2d_ships"] < sf["h2d_ships"]
+        # nothing is shipped for a first token in either order: ``cur`` is the device's
+        assert sb["h2d_ships"] == sf["h2d_ships"]
 
 
 @pytest.mark.parametrize("overlap", [False, True])
@@ -433,9 +450,10 @@ def test_a_window_behind_a_prefill_starves_nobody_in_dispatch(tiny_model, overla
     assert moved["starved_us_admit_launch"] > 0 and moved["unloaded_us"] == 0
     assert moved["starved_us"] == sum(moved[k] for k in BUCKETS)
     # tables, lens and temps: the first token is in the device's ``cur`` already
-    assert eng.stats["h2d_ships"] - ships == 3 and "cur" not in eng._dirty
-    if not overlap:  # harvested: the mirror and the device agree on the occupied rows
-        assert (np.asarray(eng._dev["cur"])[:2] == eng.cur[:2]).all() and eng.cur[1] != 0
+    assert eng.stats["h2d_ships"] - ships == 3 and not eng._dirty
+    if not overlap:  # harvested: the device's ``cur`` is the occupied rows' last token
+        last = [eng.slots[i].generated[-1] for i in (0, 1)]
+        assert list(np.asarray(eng._dev["cur"])[:2]) == last and len(eng.slots[1].generated) == 3
     while eng.active_count():
         eng.step()
 
@@ -496,8 +514,8 @@ def test_each_window_in_flight_is_overlapped_or_counted_once(tiny_model, paged, 
     assert s["spec_windows"] + sum(s[k] for k in BLOCKED) == found_in_flight
     assert s["spec_windows"] > 0 and s["spec_blocked_finishing"] > 0
     assert s["spec_blocked_admission"] > 0
-    if paged:  # the speculated dispatch that preempted was aborted, and counted
-        assert s["preemptions"] > 0 and s["spec_blocked_dirty_cur"] > 0
+    if paged:  # a preemption, under a window in flight or not, is no reason of its own
+        assert s["preemptions"] > 0
     snap = eng.report_state()
     assert snap["overlap"]["blocked"] == {
         why: s["spec_blocked_" + why] for why in llm_engine._SPEC_BLOCKED}
@@ -537,11 +555,11 @@ def _on_a_64_byte_boundary(a):
     return out
 
 
-@pytest.mark.parametrize("name", ["tables", "lens", "temps", "cur"])
+@pytest.mark.parametrize("name", ["tables", "lens", "temps"])
 def test_the_device_is_handed_a_copy_of_each_mirror(tiny_model, name):
     """The scheduler writes its mirrors in place right after a dispatch
-    (``lens`` advances, the harvest sets ``cur``, ``_free_slot`` resets a
-    row): what a program was handed must not move with them."""
+    (``lens`` advances, ``_free_slot`` resets a row): what a program was
+    handed must not move with them."""
     cfg, params = tiny_model
     eng = _engine(cfg, params)
     host = _on_a_64_byte_boundary(getattr(eng, name))
@@ -592,7 +610,6 @@ def _force_finishing(eng):
 @pytest.mark.parametrize("reason, force", [
     ("idle", _force_idle),
     ("admission", lambda eng: eng.add_request([9, 8, 7], 4)),
-    ("dirty_cur", lambda eng: eng._dirty.add("cur")),
     ("finishing", _force_finishing),
 ])
 def test_a_blocked_window_is_counted_under_its_reason(tiny_model, reason, force):
@@ -609,6 +626,89 @@ def test_a_blocked_window_is_counted_under_its_reason(tiny_model, reason, force)
     assert moved == {"spec_blocked_" + reason}
     assert eng.stats["spec_blocked_" + reason] == before["spec_blocked_" + reason] + 1
     assert eng.recorder.steps[-1]["overlapped"] == 0
+
+
+def _run(eng, reqs):
+    """Step ``eng`` until it is empty: per step, whether it found a window in
+    flight, what it added to ``preemptions`` and ``finished``, and its record."""
+    steps = []
+    while eng.active_count() or eng.waiting:
+        before = dict(eng.stats)
+        in_flight = eng._inflight is not None
+        eng.step()
+        assert eng._dirty <= {"tables", "lens", "temps"}
+        steps.append((in_flight, eng.stats["preemptions"] - before["preemptions"],
+                      eng.stats["finished"] - before["finished"], eng.recorder.steps[-1]))
+    return [r.generated for r in reqs], steps
+
+
+@pytest.mark.parametrize("how", ["preempted", "stopped"])
+def test_a_slot_given_back_under_a_window_in_flight_does_not_stop_the_next_speculation(
+        tiny_model, how):
+    """The next window takes its tokens from the in-flight window's own output
+    on the device, and the host's ``lens`` are current for what it ships, so a
+    preemption inside a speculated dispatch goes ahead, and so does the
+    speculation after an eos stop was harvested under a speculated window. The
+    tokens are those of the engine that reads every window before the next."""
+    cfg, params = tiny_model
+    if how == "preempted":  # four long answers want 16 of 12 blocks
+        paged, todo = dict(num_blocks=13, max_blocks_per_seq=4), [
+            ([i + 1, i + 2, i + 3, i + 4], 24, None) for i in range(6)]
+    else:  # the second stops where no cap tells the scheduler it will
+        plain = _engine(cfg, params, overlap=False).generate_batch([[17, 1, 8]], max_new_tokens=12)[0]
+        at = next(k for k in range(4, 12) if plain[k] not in plain[:k])
+        paged, todo = {}, [([5, 9, 2, 11], 40, None), ([17, 1, 8], 40, plain[at])]
+    outs = {}
+    for overlap in (False, True):
+        eng = _engine(cfg, params, overlap=overlap, **paged)
+        outs[overlap] = _run(eng, [eng.add_request(p, n, eos_id=eos) for p, n, eos in todo])
+    (plain, _), (served, steps) = outs[False], outs[True]
+    assert served == plain and all(served)
+    if how == "preempted":  # the speculated dispatch preempted, and was not given up
+        assert any(found and preempted and rec["overlapped"]
+                   for found, preempted, _finished, rec in steps)
+    else:
+        assert len(served[1]) == at + 1 < 40
+        gave_back = next(k for k, step in enumerate(steps) if step[2])
+        assert steps[gave_back][3]["overlapped"]  # freed under the window after its own
+        assert steps[gave_back + 1][0] and steps[gave_back + 1][3]["overlapped"]
+
+
+@pytest.mark.parametrize("which", ["dense", "latent", "hybrid"])
+def test_a_stale_token_on_an_idle_row_reaches_no_live_row(which):
+    """An idle row of the device's ``cur`` holds whatever was left there. No
+    live row's tokens depend on it, greedy or sampled: attention, state and
+    sampling are per row, and an expert layer has no capacity for an idle
+    row's pairs to use up."""
+    cfg, params = _tiny(which)
+    outs = []
+    for stale in (0, 201):
+        eng = _engine(cfg, params, prefill_chunk=16, seed=3)
+        reqs = [eng.add_request([5, 9, 2, 11, 3], 14, temperature=0.8),
+                eng.add_request(list(range(30, 50)), 3),  # two chunks; its row idles from its end on
+                eng.add_request([17, 1, 8], 12)]
+        while eng.active_count() or eng.waiting:
+            cur = np.array(eng._dev["cur"])
+            idle = [i for i, req in enumerate(eng.slots) if req is None or i in eng._prefilling]
+            cur[idle] = stale
+            eng._dev["cur"] = jax.device_put(cur, eng._dev["cur"].sharding)
+            eng.step()
+        outs.append([r.generated for r in reqs])
+        assert [len(o) for o in outs[-1]] == [14, 3, 12]
+    assert outs[0] == outs[1]
+
+
+def test_the_engine_keeps_no_host_copy_of_cur(tiny_model):
+    cfg, params = tiny_model
+    eng = _engine(cfg, params, num_blocks=13, max_blocks_per_seq=4)
+    assert not hasattr(eng, "cur") and eng._dirty == {"tables", "lens", "temps"}
+    assert isinstance(eng._dev["cur"], jax.Array)  # made with the engine, before any program
+    assert llm_engine._SPEC_BLOCKED == ("idle", "admission", "finishing")
+    served, steps = _run(eng, [eng.add_request([i + 1, i + 2, i + 3, i + 4], 24) for i in range(6)])
+    assert [len(o) for o in served] == [24] * 6 and eng.stats["preemptions"] > 0
+    s = eng.stats
+    assert s["spec_windows"] + sum(s[k] for k in BLOCKED) == sum(found for found, *_ in steps)
+    assert set(eng.report_state()["overlap"]["blocked"]) == {"idle", "admission", "finishing"}
 
 
 def test_blocked_reasons_reach_the_registry_counter(tiny_model):
