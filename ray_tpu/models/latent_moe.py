@@ -73,6 +73,9 @@ class LatentMoEConfig:
     num_experts_per_tok: int = 8
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
+    # Selection by groups (``route``): 1 and 1 is none, the largest of all.
+    n_group: int = 1
+    topk_group: int = 1
     rope_theta: float = 25.6e6
     rms_norm_eps: float = 1e-5
     # This chip's share of every expert layer: experts held_first ..
@@ -188,8 +191,11 @@ def project(h, lp: Params, cfg: LatentMoEConfig, positions):
     H, rope], cache rows [b, s, row_width] = ``[c | roped k_rope | 0]``)."""
     b, s, _ = h.shape
     H, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    c_q = _norm(h @ lp["w_dq"].astype(h.dtype), lp["q_norm"], cfg)
-    q = (c_q @ lp["w_uq"].astype(h.dtype)).reshape(b, s, H, nope + rope)
+    if "w_dq" in lp:
+        c_q = _norm(h @ lp["w_dq"].astype(h.dtype), lp["q_norm"], cfg)
+        q = (c_q @ lp["w_uq"].astype(h.dtype)).reshape(b, s, H, nope + rope)
+    else:  # no query latent (``models/kda_moe.py``): ONE matrix, the norm a head's
+        q = _norm((h @ lp["w_q"].astype(h.dtype)).reshape(b, s, H, nope + rope), lp["q_norm"], cfg)
     q_rope = _rope(q[..., nope:], positions, cfg.rope_theta)
     kv = h @ lp["w_dkv"].astype(h.dtype)
     c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg)
@@ -208,11 +214,14 @@ def absorb(q_nope, q_rope, lp: Params, cfg: LatentMoEConfig):
     return jnp.concatenate([qa, q_rope, pad], axis=-1)
 
 
-def attention_out(u, lp: Params, cfg: LatentMoEConfig):
+def attention_out(u, lp: Params, cfg: LatentMoEConfig, gate=None):
     """u: [.., H, rank] per-head weighted sums of latents → the attention
-    block's output [.., D]: through ``W_uv`` and ``W_o``."""
+    block's output [.., D]: through ``W_uv`` and ``W_o``, a head's output
+    times ``gate`` [.., H] between them where there is one."""
     _, w_uv = _up_kv(lp, cfg, u.dtype)
     o = jnp.einsum("...hc,chv->...hv", u, w_uv)
+    if gate is not None:
+        o = o * gate[..., None].astype(o.dtype)
     return o.reshape(o.shape[:-2] + (-1,)) @ lp["wo"].astype(u.dtype)
 
 
@@ -256,20 +265,46 @@ def route(y, lp: Params, cfg: LatentMoEConfig):
     float32): sigmoid scores in float32 (a product of the activation and the
     router in their own precision, accumulated in float32, is the float32
     product of those numbers), the k largest, normalised over the chosen,
-    times ``routed_scaling_factor``."""
+    times ``routed_scaling_factor``.
+
+    Where the layer has an ``expert_bias`` or the configuration groups
+    (``n_group`` above 1), the k are SELECTED on ``scores + expert_bias``, among
+    the experts of the ``topk_group`` groups whose two largest such numbers sum
+    highest; the gates are still the chosen experts' scores, without the bias.
+    A model with neither computes what it always did."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             y, lp["router"].astype(y.dtype), preferred_element_type=jnp.float32))
-        top, experts = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+        if "expert_bias" in lp or cfg.n_group > 1:
+            experts = select_experts(scores, lp.get("expert_bias"), cfg.n_group, cfg.topk_group,
+                                     cfg.num_experts_per_tok)
+            top = jnp.take_along_axis(scores, experts, axis=-1)
+        else:
+            top, experts = jax.lax.top_k(scores, cfg.num_experts_per_tok)
         gates = cfg.routed_scaling_factor * top / jnp.sum(top, axis=-1, keepdims=True)
         return experts.astype(jnp.int32), gates
+
+
+def select_experts(scores, bias, n_group: int, topk_group: int, k: int):
+    """scores: [T, E] float32 → the chosen experts [T, k]: the k largest of
+    ``scores + bias`` inside the ``topk_group`` groups (of ``n_group``, experts
+    side by side) whose two largest sum highest."""
+    chosen = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = chosen.shape
+        grouped = chosen.reshape(T, n_group, E // n_group)
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, n_group]
+        _, groups = jax.lax.top_k(best, topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        chosen = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(T, E)
+    return jax.lax.top_k(chosen, k)[1]
 
 
 def routed_experts(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
     """The held experts' part of the layer's result for y [T, D], and the
     counts (pairs computed here, held experts touched, 1) as int32 [3].
-    ``held`` is ``params["experts"]`` whole and ``layer`` this layer's number
-    among the expert layers (it may be traced).
+    ``held`` is a stack of layers' held experts, ``[layers, held, ...]``, and
+    ``layer`` this layer's number in it (it may be traced).
 
     Every token-expert pair gets a key: its expert's index among the held
     ones, or ``held`` if the expert lives elsewhere; a stable sort by key

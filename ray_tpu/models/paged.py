@@ -115,6 +115,7 @@ class Pool(NamedTuple):
     layers: int  # layers that keep such rows (leading ones included)
     unit: str = "blocks"  # or "slots"
     dtype: Optional[Any] = None  # None: the configuration's
+    lead: Optional[int] = None  # how many of them are leading layers (None: every leading layer)
 
     def units(self, pcfg: "PagedConfig") -> Tuple[int, ...]:
         return (pcfg.max_batch,) if self.unit == "slots" else (pcfg.num_blocks, pcfg.block_size)
@@ -182,9 +183,9 @@ def slot_pools(cfg) -> Tuple[str, ...]:
     return tuple(name for name, pool in paged_model(cfg).pools.items() if pool.unit == "slots")
 
 
-def _scan_layers(layer, x, params: Params, cache: PagedCache, names):
+def _scan_layers(layer, x, params: Params, cache: PagedCache, declared: Dict[str, Pool]):
     """Run ``layer(x, pools, lp, bases, index) -> (x, pools, counts)`` over the
-    layers (``pools`` in the order of ``names``, the model's declared one: a
+    layers (``pools`` in the order of ``declared``, the model's own: a
     dictionary that crossed ``jit`` comes back sorted), carrying each stacked
     pool ``[L, units, ..]`` as one flat pool ``[L*units, ..]`` (a bitcast, both
     ways; ``units`` is the pool's own: its blocks, or the slots). ``bases[k]``
@@ -194,19 +195,22 @@ def _scan_layers(layer, x, params: Params, cache: PagedCache, names):
     stack or put back, and only the bases know how layers are laid out.
     Leading layers (``params["lead"]``, their own parameter tree, stacked)
     run one by one before the scan over ``params["layers"]``, each on one
-    layer of every pool; a scanned body follows on as many layers of each
-    pool as the pool has left per body (one, for a model of one kind of
-    layer; a period's count of that kind, for a model of several).
+    layer of every pool (of every pool that has leading layers: ``Pool.lead``
+    0 is a pool whose kind of layer is no leading one, and the base such a
+    layer is handed for it is nobody's); a scanned body follows on as many
+    layers of each pool as the pool has left per body (one, for a model of
+    one kind of layer; a period's count of that kind, for a model of several).
     → (x, cache', summed counts or None)."""
-    shapes = {name: cache[name].shape for name in names}
-    pools = tuple(cache[name].reshape((-1,) + shapes[name][2:]) for name in names)
+    shapes = {name: cache[name].shape for name in declared}
+    pools = tuple(cache[name].reshape((-1,) + shapes[name][2:]) for name in declared)
     lead = params.get("lead")
     n_lead = 0 if lead is None else jax.tree.leaves(lead)[0].shape[0]
     n_bodies = jax.tree.leaves(params["layers"])[0].shape[0]
     units = tuple(shape[1] for shape in shapes.values())
-    # Layers of each pool that one scanned body runs.
-    per_body = tuple((shape[0] - n_lead) // n_bodies for shape in shapes.values())
-    assert all(n_lead + n * n_bodies == shape[0] for n, shape in zip(per_body, shapes.values()))
+    # Leading layers of each pool, and its layers that one scanned body runs.
+    leads = tuple(n_lead if pool.lead is None else pool.lead for pool in declared.values())
+    per_body = tuple((shape[0] - k) // n_bodies for k, shape in zip(leads, shapes.values()))
+    assert all(k + n * n_bodies == shape[0] for k, n, shape in zip(leads, per_body, shapes.values()))
     total = None
     for j in range(n_lead):
         x, pools, counts = layer(
@@ -215,7 +219,7 @@ def _scan_layers(layer, x, params: Params, cache: PagedCache, names):
 
     def body(carry, xs):
         lp, j = xs
-        bases = tuple((n_lead + j * n) * u for n, u in zip(per_body, units))
+        bases = tuple((k + j * n) * u for k, n, u in zip(leads, per_body, units))
         x, pools, counts = layer(*carry, lp, bases, n_lead + j)
         return (x, pools), counts
 
@@ -260,7 +264,7 @@ def _decode_step(params: Params, cfg, tokens, cache: PagedCache, tables, lens):
         return model.decode_layer(x, pools, lp, tables, lens, params, index, bases)
 
     x = model.embed(params, tokens[:, None], cfg)
-    x, cache, counts = _scan_layers(layer, x, params, cache, tuple(model.pools))
+    x, cache, counts = _scan_layers(layer, x, params, cache, model.pools)
     return model.unembed(params, x, cfg)[:, 0], cache, counts
 
 
@@ -507,7 +511,7 @@ def _prefill_chunk(params: Params, cfg, tokens, cache: PagedCache, table_rows, c
             x, pools, lp, table_rows, rows, offs, qpos, live, params, index, bases, slot_of)
 
     x = model.embed(params, tokens, cfg)
-    x, cache, counts = _scan_layers(layer, x, params, cache, tuple(model.pools))
+    x, cache, counts = _scan_layers(layer, x, params, cache, model.pools)
     return model.unembed(params, x[:, last_idx], cfg)[0], cache, counts
 
 

@@ -77,6 +77,7 @@ from ray_tpu.util import tracing
 
 if TYPE_CHECKING:
     from ray_tpu.models.hybrid_ssm import HybridSSMConfig
+    from ray_tpu.models.kda_moe import KDAMoEConfig
     from ray_tpu.models.latent_moe import LatentMoEConfig
 
 _req_ids = itertools.count()
@@ -452,7 +453,7 @@ class LLMEngine:
     def __init__(
         self,
         params,
-        cfg: Union[TransformerConfig, "LatentMoEConfig", "HybridSSMConfig"],
+        cfg: Union[TransformerConfig, "LatentMoEConfig", "HybridSSMConfig", "KDAMoEConfig"],
         pcfg: Optional[PagedConfig] = None,
         *,
         decode_window: int = 1,
@@ -516,10 +517,11 @@ class LLMEngine:
             prefill_chunk = -(-int(prefill_chunk) // p.block_size) * p.block_size
             prefill_chunk = min(prefill_chunk, p.max_seq_len)
         self.prefill_chunk = int(prefill_chunk or 0)
-        # Pools that hold one row a decode slot (a state-space layer's state):
-        # row ``i`` is slot ``i``'s, so there is nothing to allocate, but the
-        # row is not a function of a block of tokens and a step over it is not
-        # idempotent. See ``_start_prefill``, ``_chunk_call``, ``_free_slot``.
+        # Pools that hold one row a decode slot (a recurrent layer's state: a
+        # state-space layer's, a delta rule's): row ``i`` is slot ``i``'s, so
+        # there is nothing to allocate, but the row is not a function of a
+        # block of tokens and a step over it is not idempotent. See
+        # ``_start_prefill``, ``_chunk_call``, ``_free_slot``.
         self._state_pools = slot_pools(cfg)
         if self._state_pools and enable_prefix_cache:
             raise ValueError(

@@ -801,3 +801,167 @@ def test_hybrid_kernels_read_the_plain_forms_numbers_on_the_chip():
                        env=env, capture_output=True, text=True, timeout=600)
     print(r.stdout[-3000:])
     assert r.returncode == 0, r.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# The KDA / latent-attention expert decoder (models/kda_moe.py)
+# ---------------------------------------------------------------------------
+def test_kda_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
+    """The served cut of the published model (a leading KDA layer and one period
+    of six: every width published, 8 of the 512 experts held and the vocabulary
+    cut for the compile's sake) in the decode window and the chunk program as
+    ``LLMEngine`` builds them: 2 x 6 state updates and 2 latent attention kernels
+    in the window, one prefill kernel in the chunk program, and temporaries (45
+    MB at the served 64 slots: the kernels' turned and padded operands) under one
+    layer's pool of states (134 MB) and far under the period's stack of KDA
+    mixers (630 MB): no layer's weights are copied out of the stack and no pool
+    is gathered or copied."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import kda_moe as km
+    from ray_tpu.models.paged import (PagedConfig, chunk_tile, init_paged_cache,
+                                      paged_decode_loop, prefill_chunk_and_sample)
+
+    cfg = km.KDAMoEConfig(num_hidden_layers=7, first_k_dense_replace=1, vocab_size=2048, held_count=8)
+    p = PagedConfig(block_size=16, num_blocks=513, max_batch=64, max_blocks_per_seq=78)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: km.init_params(k, cfg), jax.random.PRNGKey(0)))
+    auto = jax.tree.map(lambda a: Format(Layout.AUTO, one), params)
+    cache = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_paged_cache(cfg, p)))
+    b, w, bs = p.max_batch, p.max_blocks_per_seq, p.block_size
+
+    def decode(params, tokens, cache, tables, lens, temps, key):
+        return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
+
+    compiled = jax.jit(decode, donate_argnums=(2,), in_shardings=(auto,) + (None,) * 6).lower(
+        params, sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
+        sds((b,), np.float32), sds((2,), np.uint32)).compile()
+    names = _kernel_names(compiled.as_text())
+    # (the experts' grouped products are kernels of the compiler's own)
+    assert (names.count("kda_state_update"), names.count("latent_attend")) == (12, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
+    (params_fmt, *_), _ = compiled.input_formats
+    width = 1024
+    n = width // chunk_tile(width, bs)
+
+    def chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+        starts, last_idx, slot_of, live, state_of = per_tile
+        toks, cache = prefill_chunk_and_sample(
+            params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx, live,
+            state_of, temps, key)
+        return toks, cache, cur.at[slot_of].set(toks[:n], mode="drop")
+
+    compiled = jax.jit(chunk, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 8).lower(
+        params, sds((1, width), np.int32), cache, sds((n, w), np.int32),
+        sds((width // bs,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
+        sds((2,), np.uint32), sds((b,), np.int32)).compile()
+    # The chunk scan is plain XLA (no kernel yet): the widest thing a 1,024-token
+    # call holds are a layer's sub-tile decays, 67 MB, and its projections.
+    assert _kernel_names(compiled.as_text()).count("latent_prefill_attend") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+_KDA_KERNEL_AGAINST_PLAIN_FORM = """
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.ops import kda
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+ks = jax.random.split(jax.random.PRNGKey(1), 8)
+# the state update at the served shapes (64 slots; and the 128 the pool was first sized for) of 32 heads of
+# 128 x 128, the second of three layers
+H, K = 32, 128
+unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+for b in (64, 128):
+    pool = jax.random.normal(ks[0], (3 * b, H, K, K), jnp.float32)
+    a = jnp.exp(-jnp.exp(jax.random.uniform(ks[1], (b, H, K), jnp.float32, np.log(1e-3), np.log(5.0))))
+    k, q = unit(jax.random.normal(ks[2], (b, H, K))), unit(jax.random.normal(ks[3], (b, H, K))) / K ** 0.5
+    v = jax.random.normal(ks[4], (b, H, K), jnp.float32)
+    beta = jax.random.uniform(ks[5], (b, H), jnp.float32)
+    assert kda._tiles(pool)
+    assert "kda_state_update" in jax.jit(kda.kda_update).lower(pool, jnp.int32(b), jnp.ones(b, jnp.int32), a, k, q, v, beta).as_text()
+    scattered = np.asarray(jax.random.bernoulli(ks[6], 0.6, (b,))).astype(np.int32) * 7
+    scattered[:3] = 0
+    for name, lens in (("all live", np.ones(b, np.int32)), ("idle rows scattered", scattered),
+                       ("one live", np.eye(b, dtype=np.int32)[b - 51] * 5), ("none live", np.zeros(b, np.int32))):
+        lens_ = jnp.asarray(lens, jnp.int32)
+        want_pool, want_o = jax.jit(kda.reference_kda_update)(pool, jnp.int32(b), lens_, a, k, q, v, beta)
+        got_pool, got_o = jax.jit(kda.kda_update)(pool, jnp.int32(b), lens_, a, k, q, v, beta)
+        skipped = np.flatnonzero(lens == 0)
+        got, want = np.asarray(got_pool), np.asarray(want_pool)
+        assert np.array_equal(got[b + skipped], np.asarray(pool)[b + skipped]), name
+        assert np.array_equal(got[:b], np.asarray(pool)[:b]) and np.array_equal(got[2 * b:], np.asarray(pool)[2 * b:])
+        assert not np.asarray(got_o)[skipped].any()
+        assert np.abs(got - want).max() < 1e-4, (name, np.abs(got - want).max())
+        apart = np.abs(np.asarray(got_o) - np.asarray(want_o)).max() / max(1e-9, np.abs(np.asarray(want_o)).max())
+        assert apart < 1e-5, (name, apart)
+        print("kda_state_update", b, "slots,", name, int((lens > 0).sum()), "live: state apart", np.abs(got - want).max(), "o apart", apart)
+
+# the chunk scan's plain form on the chip against the recurrence token by token, at the served widths:
+# 16 tiles of 64; slot 5 takes up its stored row over two tiles (the second partly padding), slot 2
+# begins from nothing over three, a tile nobody uses, slot 0 a lone short tile, the rest nobody's.
+from ray_tpu.models.hybrid_ssm import _segments
+slots, T, n = 8, 64, 16
+spec = [(5, 128, 64), (5, 192, 30), (2, 0, 64), (2, 64, 64), (2, 128, 17), (None, 0, 0), (0, 0, 9)] + [(None, 0, 0)] * 9
+pool = jax.random.normal(ks[0], (3 * slots, H, K, K), jnp.float32)
+slot_of = jnp.asarray([slots if s is None else s for s, _, _ in spec], jnp.int32)
+live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
+fresh, cont, last = _segments(jnp.asarray([s for _, s, _ in spec], jnp.int32)[:, None], slot_of, slots)
+row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots)
+g = -jnp.exp(jax.random.uniform(ks[1], (n, T, H, K), jnp.float32, np.log(1e-3), np.log(4.9)))
+qs, kk = unit(jax.random.normal(ks[2], (n, T, H, K))) / K ** 0.5, unit(jax.random.normal(ks[3], (n, T, H, K)))
+vs = jax.random.normal(ks[4], (n, T, H, K), jnp.float32)
+bs_ = jax.random.uniform(ks[5], (n, T, H), jnp.float32)
+got_pool, got_o = (np.asarray(x) for x in jax.jit(kda.kda_chunk_scan)(pool, row, fresh, cont, last, live, g, qs, kk, vs, bs_))
+
+def by_token(S, tile):  # one tile's real tokens through the recurrence, one at a time
+    def token(S, now):
+        g_t, q_t, k_t, v_t, b_t = now
+        S = jnp.exp(g_t)[..., None] * S
+        u = v_t - jnp.sum(S * k_t[..., None], axis=1)
+        S = S + b_t[:, None, None] * k_t[..., None] * u[:, None, :]
+        return S, jnp.sum(S * q_t[..., None], axis=1)
+    return jax.lax.scan(token, S, tile)
+
+step = jax.jit(by_token)
+want_pool = np.asarray(pool).copy()
+S = None
+for t, (s, start, ln) in enumerate(spec):
+    if s is None:
+        assert not got_o[t].any()
+        continue
+    S = jnp.zeros((H, K, K)) if start == 0 else (S if bool(cont[t]) else pool[slots + s])
+    S, o = step(S, tuple(x[t, :ln] for x in (g, qs, kk, vs, bs_)))
+    want_pool[slots + s] = np.asarray(S)
+    apart = np.abs(got_o[t, :ln] - np.asarray(o)).max() / np.abs(np.asarray(o)).max()
+    assert apart < 1e-4, (t, apart)
+    print("kda_chunk_scan tile", t, "o apart", apart)
+apart = np.abs(got_pool - want_pool).max()
+assert apart < 1e-4, apart
+print("kda_chunk_scan: state apart", apart)
+"""
+
+
+def test_kda_kernel_reads_the_plain_forms_numbers_on_the_chip():
+    """``kda_state_update`` against its plain form on a chip at the served shapes
+    (64 slots, and 128: all live; idle rows scattered; one; none), and the chunk scan's
+    plain form against the recurrence token by token (decays down to exp(-4.9) a
+    token: the sub-tiles' ranges). In a process of its own: this one is held to
+    the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    r = subprocess.run([sys.executable, "-c", _KDA_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=600)
+    print(r.stdout[-3000:])
+    assert r.returncode == 0, r.stderr[-3000:]

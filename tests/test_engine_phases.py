@@ -36,13 +36,16 @@ BLOCKED = ["spec_blocked_" + why for why in llm_engine._SPEC_BLOCKED]
 
 
 def _tiny(which):
-    """One of the three bodies behind ``paged_model(cfg)``, at its tests' size."""
+    """One of the four bodies behind ``paged_model(cfg)``, at its tests' size."""
     if which == "dense":
         from ray_tpu.models import transformer as m
         cfg = m.TransformerConfig.tiny(dtype=jnp.float32, remat=False)
     elif which == "latent":
         from ray_tpu.models import latent_moe as m
         cfg = m.LatentMoEConfig.tiny()
+    elif which == "kda":
+        from ray_tpu.models import kda_moe as m
+        cfg = m.KDAMoEConfig.tiny(head_dim=32)
     else:
         from ray_tpu.models import hybrid_ssm as m
         cfg = m.HybridSSMConfig.tiny()
@@ -674,7 +677,7 @@ def test_a_slot_given_back_under_a_window_in_flight_does_not_stop_the_next_specu
         assert steps[gave_back + 1][0] and steps[gave_back + 1][3]["overlapped"]
 
 
-@pytest.mark.parametrize("which", ["dense", "latent", "hybrid"])
+@pytest.mark.parametrize("which", ["dense", "latent", "hybrid", "kda"])
 def test_a_stale_token_on_an_idle_row_reaches_no_live_row(which):
     """An idle row of the device's ``cur`` holds whatever was left there. No
     live row's tokens depend on it, greedy or sampled: attention, state and

@@ -53,6 +53,22 @@ def model():
     return make()
 
 
+@pytest.fixture(scope="module", params=["hybrid", "kda"])
+def body(request):
+    """Each model that keeps state by slot, for the engine's rules about such
+    state: (its configuration, its parameters, ``reference(seq)`` -> the plain
+    reference's logits [t, vocab], the names of its pools by slot). Both have a
+    vocabulary of 256."""
+    if request.param == "hybrid":
+        dims, key, cfg, params = request.getfixturevalue("model")
+        return cfg, params, lambda seq: np.asarray(
+            R.stream_logits(key, jnp.asarray(seq)[None], dims, jnp.float32)[0]), ("ssm", "conv")
+    import test_kda_moe as K  # the KDA / latent-attention expert decoder, at its tests' size
+
+    made = K.make()
+    return made[2], made[3], lambda seq: K.reference_logits(made, seq), K.STATE
+
+
 @pytest.fixture(scope="module")
 def tokens():
     return np.random.default_rng(3).integers(0, CONF["vocab_size"], PROMPT + STEPS).astype(np.int32)
@@ -98,9 +114,11 @@ def chunk_call(params, cfg, cache, width: int, segs, pcfg=PCFG):
     return np.asarray(logits)[:len(segs)], cache
 
 
-def decode(params, cfg, cache, tokens, first: int, slot=SLOT, blocks=BLOCKS, round_state=None):
+def decode(params, cfg, cache, tokens, first: int, slot=SLOT, blocks=BLOCKS, round_state=None,
+           state="ssm"):
     """Decode steps for ``tokens[first:]`` of the sequence in ``slot``, one
-    token a slot through the cache; → (logits a step, cache)."""
+    token a slot through the cache; → (logits a step, cache). ``round_state``:
+    the pool ``state`` is rounded to that dtype after every step."""
     tables = np.full((PCFG.max_batch, PCFG.max_blocks_per_seq), TRASH_BLOCK, np.int32)
     tables[slot, :len(blocks)] = blocks
     step = jax.jit(lambda tok, c, lens: paged.paged_decode_step(
@@ -111,7 +129,7 @@ def decode(params, cfg, cache, tokens, first: int, slot=SLOT, blocks=BLOCKS, rou
         tok[slot], lens[slot] = tokens[at], at
         logits, cache = step(jnp.asarray(tok), cache, jnp.asarray(lens))
         if round_state is not None:
-            cache = {**cache, "ssm": cache["ssm"].astype(round_state).astype(jnp.float32)}
+            cache = {**cache, state: cache[state].astype(round_state).astype(jnp.float32)}
         out.append(np.asarray(logits[slot]))
     return np.stack(out), cache
 
@@ -184,19 +202,19 @@ def test_bfloat16_fails_the_tolerance(tokens, ref_logits, model, what):
     assert apart(steps, ref[PROMPT:]) > 4 * TOL
 
 
-def test_a_decode_window_leaves_idle_and_prefilling_slots_alone(model, tokens):
+def test_a_decode_window_leaves_idle_and_prefilling_slots_alone(body, tokens):
     """Three decode steps in one program with slot 2 live, slot 1 idle (its rows
     hold what a finished request left) and slot 3 halfway through a chunked
     prefill (its table on the trash block, as the engine keeps it until the
     prefill ends; its device ``lens`` whatever an earlier window left): the
     state and the convolution's inputs of slots 0, 1 and 3 are bit for bit
     what they were, in every layer; slot 2's moved."""
-    _dims, _key, cfg, params = model
+    cfg, params, _reference, pools = body
     cache = paged.init_paged_cache(cfg, PCFG)
-    cache = {**cache, **{name: cache[name].at[:, 1].set(0.25) for name in ("ssm", "conv")}}
+    cache = {**cache, **{name: cache[name].at[:, 1].set(0.25) for name in pools}}
     _, cache = chunk_call(params, cfg, cache, 64, [(SLOT, BLOCKS, tokens, 0, PROMPT)])
     _, cache = chunk_call(params, cfg, cache, 32, [(3, [9, 10, 11, 12, 13], tokens, 0, 32)])
-    before = {name: np.asarray(cache[name]) for name in ("ssm", "conv")}
+    before = {name: np.asarray(cache[name]) for name in pools}
     assert all(np.abs(before[name][:, 3]).max() > 0 for name in before)
     tables = np.full((4, 8), TRASH_BLOCK, np.int32)
     tables[SLOT] = BLOCKS
@@ -238,14 +256,14 @@ def test_padding_behind_live_leaves_the_state_unchanged(model, tokens):
     assert np.allclose(np.asarray(cache["conv"][:, SLOT]), left[0]["conv"], rtol=1e-4, atol=1e-5)
 
 
-def _assert_served_is_the_references_greedy_continuation(key, dims, prompts, reqs):
+def _assert_served_is_the_references_greedy_continuation(reference, prompts, reqs):
     """Every served token is the reference's own largest logit at its position,
     the reference being fed prompt + served tokens; a near-tie may go either
     way, so what is asserted is that the served token is within 1e-4 of the
     spread of the largest."""
     for prompt, req in zip(prompts, reqs):
         seq = np.asarray(prompt + req.generated, np.int32)
-        ref = np.asarray(R.stream_logits(key, jnp.asarray(seq)[None], dims, jnp.float32)[0])
+        ref = reference(seq)
         at = np.arange(len(prompt) - 1, len(seq) - 1)
         chosen = ref[at, np.asarray(req.generated)]
         deficit = (ref[at].max(-1) - chosen) / ref[at].std(-1)
@@ -273,7 +291,7 @@ def _spy(eng, monkeypatch):
     return windows, chunks
 
 
-def test_engine_serves_the_references_tokens_and_runs_no_position_twice(model, monkeypatch):
+def test_engine_serves_the_references_tokens_and_runs_no_position_twice(body, monkeypatch):
     """``LLMEngine`` end to end, four slots for seven requests, overlap on, a
     fixed prefill chunk, answers that end inside a window, a pool so small
     that requests are preempted and resumed. Served tokens are the reference's
@@ -283,7 +301,7 @@ def test_engine_serves_the_references_tokens_and_runs_no_position_twice(model, m
     before them ended (a window more for each), so no live slot's state is
     advanced over a position twice; a preempted request comes back under a
     new assignment and from position 0."""
-    dims, key, cfg, params = model
+    cfg, params, reference, _pools = body
     p = PagedConfig(block_size=BS, num_blocks=20, max_batch=4, max_blocks_per_seq=16)
     eng = LLMEngine(params, cfg, p, decode_window=3, overlap=True, prefill_chunk=32, seed=1)
     windows, chunks = _spy(eng, monkeypatch)
@@ -299,7 +317,7 @@ def test_engine_serves_the_references_tokens_and_runs_no_position_twice(model, m
     s = eng.stats
     assert s["preemptions"] > 0 and s["spec_windows"] > 0 and s["windows_behind_prefill"] > 0
     assert s["state_segments_carried"] > 0 and s["state_segments_fresh"] >= len(prompts)
-    _assert_served_is_the_references_greedy_continuation(key, dims, prompts, reqs)
+    _assert_served_is_the_references_greedy_continuation(reference, prompts, reqs)
     # Chunk calls: each assignment's segments tile [0, end) with no gap or overlap.
     by_assignment = {}
     for call in chunks:
@@ -319,9 +337,9 @@ def test_engine_serves_the_references_tokens_and_runs_no_position_twice(model, m
             at[(slot, gen)] = first + 3
 
 
-def test_a_prefix_cache_with_state_by_slot_is_refused(model):
-    _dims, _key, cfg, params = model
-    with pytest.raises(ValueError, match="state by slot.*ssm, conv"):
+def test_a_prefix_cache_with_state_by_slot_is_refused(body):
+    cfg, params, _reference, pools = body
+    with pytest.raises(ValueError, match=f"state by slot.*{', '.join(pools)}"):
         LLMEngine(params, cfg, PCFG, enable_prefix_cache=True)
     with pytest.raises(ValueError, match="state by slot"):
         paged.paged_prefill(params, cfg, jnp.zeros((1, 8), jnp.int32),
@@ -556,4 +574,6 @@ def test_a_burst_is_admitted_at_once_in_as_many_chunk_calls_as_it_needs(model):
     assert first["chunks"] == 4 and first["segments"] == 8 and first["state_slots_live"] == 8
     assert eng.stats["prefill_chunks"] == 4 and eng.stats["state_segments_fresh"] == 8
     assert eng.stats["state_segments_carried"] == 0
-    _assert_served_is_the_references_greedy_continuation(key, dims, prompts, reqs)
+    _assert_served_is_the_references_greedy_continuation(
+        lambda seq: np.asarray(R.stream_logits(key, jnp.asarray(seq)[None], dims, jnp.float32)[0]),
+        prompts, reqs)
