@@ -39,6 +39,7 @@ _UNBOUNDED_METHODS = frozenset(
         "object_ensure_local",
         "object_broadcast",
         "stream_next",
+        "stream_take",
         "pg_wait_ready",
         "wait_actor_ready",
         "drain_node",
@@ -186,6 +187,9 @@ class CoreWorker:
         self.node_id = node_id
         self._put_counter = itertools.count()
         self._task_counter = itertools.count()
+        # What ships this process's streamed items (``worker_main.
+        # _StreamShipper``, with its counts): made by the first stream.
+        self.stream_shipper = None
         self._lock = threading.Lock()
         self._handler = handler or _NullHandler()
         self._listen_addr = listen_addr

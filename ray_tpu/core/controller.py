@@ -1612,10 +1612,7 @@ class Controller:
             # End-of-stream only on terminal states — a retried streaming
             # task must not signal a premature end to its consumers.
             rec.stream_done = True
-            for fut in rec.stream_waiters:
-                if not fut.done():
-                    fut.set_result(True)
-            rec.stream_waiters.clear()
+            self._wake_stream(rec)
         self._schedule_pump()
         return True
 
@@ -1658,10 +1655,7 @@ class Controller:
                 self._wake(orec)
                 rec.stream_count += 1
                 rec.stream_done = True
-                for fut in rec.stream_waiters:
-                    if not fut.done():
-                        fut.set_result(True)
-                rec.stream_waiters.clear()
+                self._wake_stream(rec)
             return
         for oid in spec.return_ids():
             orec = self._object(oid)
@@ -4397,16 +4391,58 @@ class Controller:
     # =================================================================
     # Streaming generators
     # =================================================================
-    async def rpc_stream_item(self, peer, task_id: TaskID, index: int):
-        rec = self.tasks.get(task_id)
-        if rec is None:
-            return False
-        rec.stream_count = max(rec.stream_count, index + 1)
+    @staticmethod
+    def _wake_stream(rec: TaskRecord):
+        """Wake whoever waits for the stream's next item or its end."""
         for fut in rec.stream_waiters:
             if not fut.done():
                 fut.set_result(True)
         rec.stream_waiters.clear()
+
+    async def rpc_stream_items(self, peer, node_id: NodeID, runs: list):
+        """One shipment of a worker's streaming generators (``worker_main.
+        _StreamShipper``): ``runs`` is ``[task_id, first, callsite, entries]``
+        a stream, ``entries`` its consecutive items from index ``first`` on,
+        each ``(kind, payload, is_error, contained)``: ``inline`` with the
+        item's bytes, or ``shm`` with the size of what the worker wrote to
+        ``node_id``'s store. Every item is filed under its own id, so a ref an
+        item resolves as it always did."""
+        for task_id, first, callsite, entries in runs:
+            for k, (kind, payload, is_error, contained) in enumerate(entries):
+                oid = ObjectID.for_task_return(task_id, first + k)
+                if kind == "inline":
+                    await self.rpc_object_put_inline(
+                        peer, oid, payload, is_error, contained, callsite)
+                else:
+                    await self.rpc_object_put_shm(
+                        peer, oid, payload, node_id, is_error, contained, callsite)
+            rec = self.tasks.get(task_id)
+            if rec is None:
+                continue
+            rec.stream_count = max(rec.stream_count, first + len(entries))
+            self._wake_stream(rec)
         return True
+
+    async def rpc_stream_take(self, peer, task_id: TaskID, index: int):
+        """Block until item ``index`` exists, then hand out every item from
+        it on that has arrived, BY VALUE, in this one reply: ``(bytes,
+        is_error)`` each, or None for one the consumer has to get by its ref
+        (not inline, or it holds refs of its own, which the object pins).
+        None at end-of-stream. An item handed out by value that no ref was
+        ever taken to is freed here: nobody else will ask for it."""
+        if await self.rpc_stream_next(peer, task_id, index) is None:
+            return None
+        out = []
+        for i in range(index, self.tasks[task_id].stream_count):
+            oid = ObjectID.for_task_return(task_id, i)
+            orec = self.objects.get(oid)
+            if orec is None or orec.state != "READY" or orec.inline is None or orec.children:
+                out.append(None)
+                continue
+            out.append((orec.inline, orec.is_error))
+            if not orec.ever_held and not orec.holders and not orec.waiters:
+                await self._free_object(oid)
+        return out
 
     async def rpc_stream_next(self, peer, task_id: TaskID, index: int):
         """Block until item `index` exists; "item" when available, None at

@@ -94,6 +94,11 @@ class ObjectRefGenerator:
 
     next() blocks until the producer has yielded the next item (or the
     stream ends → StopIteration). Works from the driver or inside tasks.
+
+    A consumer that wants the VALUES and no refs calls ``take()`` instead
+    (serve's ``DeploymentStreamingResponse`` does): every item that has
+    arrived, in one controller call. The producer ships the same way for
+    both; which call the consumer makes is the whole difference.
     """
 
     def __init__(self, task_id):
@@ -118,6 +123,34 @@ class ObjectRefGenerator:
         ref = ObjectRef(ObjectID.for_task_return(self.task_id, self._index))
         self._index += 1
         return ref
+
+    def take(self) -> list:
+        """By value: block until the producer has yielded the next item,
+        then return every item from there on that has arrived, as ``(value,
+        is_error)`` pairs, in ONE controller call (inline items ride the
+        reply; one that does not is fetched by its ref). StopIteration at
+        the end of the stream. An item taken by value that no ref was ever
+        taken to is freed: take a stream by value or by reference."""
+        from ray_tpu.core.api import _require_worker
+        from ray_tpu.utils.serialization import deserialize
+
+        worker = _require_worker()
+        items = worker._call("stream_take", self.task_id, self._index, timeout=self.timeout)
+        if items is None:
+            raise StopIteration
+        out = []
+        for item in items:
+            if item is None:
+                ref = ObjectRef(ObjectID.for_task_return(self.task_id, self._index))
+                try:
+                    out.append((worker.get(ref, timeout=self.timeout), False))
+                except Exception as e:  # noqa: BLE001 — the item IS the error
+                    out.append((e, True))
+            else:
+                data, is_error = item
+                out.append((deserialize(data), is_error))
+            self._index += 1
+        return out
 
     def __reduce__(self):
         return (_rebuild_generator, (self.task_id, self._index))

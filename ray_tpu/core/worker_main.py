@@ -316,6 +316,7 @@ class TaskExecutor:
         self._actor_gate = threading.Lock()
         self.actor_instance: Any = None
         self.cancelled: set = set()
+        self._shipper_gate = threading.Lock()
         self.current_task_info: Optional[dict] = None  # read by rpc_current_task
         self._func_cache: Dict[bytes, Any] = {}
         self._reply_handoff = None  # created lazily (needs the loop)
@@ -746,13 +747,14 @@ class TaskExecutor:
 
     def _report_stream(self, spec: TaskSpec, result):
         """Stream generator items as they are produced: each yield becomes
-        its own object, published immediately (reference: streaming
-        generator execution, _raylet.pyx:1077)."""
-        from ray_tpu.utils.ids import ObjectID
-
+        its own object (reference: streaming generator execution,
+        _raylet.pyx:1077). This thread runs the generator and never waits
+        for a shipment; what it has yielded leaves by ``_StreamShipper``."""
         from ray_tpu.core.client import _serialize_capturing
         from ray_tpu.core.memory_census import task_site as _task_site
 
+        shipper = self._stream_shipper()
+        site = _task_site(spec.name)
         index = 0
         error = None
         try:
@@ -766,13 +768,8 @@ class TaskExecutor:
                     except Exception:  # noqa: BLE001 — user close errors
                         logger.exception("stream close failed for %s", spec.name)
                     break
-                oid = ObjectID.for_task_return(spec.task_id, index)
                 data, contained = _serialize_capturing(item)
-                self.core.put_serialized(
-                    oid, data, contained=contained,
-                    callsite=_task_site(spec.name),
-                )
-                self.core._call("stream_item", spec.task_id, index)
+                shipper.add(spec.task_id, index, data, False, contained, site)
                 index += 1
         except Exception as e:  # noqa: BLE001 — mid-stream error → final item
             tb = traceback.format_exc()
@@ -780,13 +777,104 @@ class TaskExecutor:
             from ray_tpu.core import log_plane
 
             log_plane.record_task_error(spec.name, spec.task_id.hex(), e, tb)
-            oid = ObjectID.for_task_return(spec.task_id, index)
-            self.core.put_serialized(oid, serialize(err_item), is_error=True)
-            self.core._call("stream_item", spec.task_id, index)
+            shipper.add(spec.task_id, index, serialize(err_item), True, None, "")
+        # Every item reaches the controller before the task's end does.
+        shipper.flush(spec.task_id)
         try:
             self.core._call("task_done", spec.task_id, [], error)
         except rpc.ConnectionLost:
             os._exit(1)
+
+    def _stream_shipper(self) -> "_StreamShipper":
+        with self._shipper_gate:
+            if self.core.stream_shipper is None:
+                self.core.stream_shipper = _StreamShipper(self.core)
+            return self.core.stream_shipper
+
+
+class _StreamShipper:
+    """Group commit of what this process's streaming generators have yielded.
+
+    ``add`` (a generator's thread) files one serialized item and returns at
+    once. ONE thread ships: whatever has accumulated, of every stream, leaves
+    as one ``stream_items`` call to the controller, each stream's consecutive
+    items as one run; while that call is on its way the next run gathers. An
+    idle stream's lone item leaves at once: nothing waits for company, there is
+    no timer and no size. An item over the inline limit is written to this
+    node's store by the thread that yielded it, and only its size rides the
+    call. Counts: ``stream_items_total`` and ``stream_shipments_total`` (their
+    ratio is items a shipment) in this process's metric registry, and the same
+    two as plain numbers here."""
+
+    def __init__(self, core: CoreWorker):
+        from ray_tpu.util.metrics import Counter
+
+        self.core = core
+        self._cv = threading.Condition()
+        self._pending: list = []  # (task_id, index, entry, callsite), in yield order
+        self._unshipped: Dict[TaskID, int] = {}
+        self.items = self.shipments = 0
+        self._items = Counter(
+            "stream_items_total", "Items streaming generators yielded in this process")
+        self._shipments = Counter(
+            "stream_shipments_total",
+            "Controller calls that carried them (items a shipment = stream_items_total / this)")
+        threading.Thread(target=self._ship, daemon=True, name="stream-shipper").start()
+
+    def add(self, task_id: TaskID, index: int, data: bytes, is_error: bool,
+            contained: Optional[list], callsite: str):
+        from ray_tpu.utils.ids import ObjectID
+
+        if contained:
+            self.core.promote_refs(contained)
+        if len(data) <= self.core.inline_limit:
+            entry = ("inline", data, is_error, contained or [])
+        else:
+            self.core.plasma.put_bytes(ObjectID.for_task_return(task_id, index), data)
+            entry = ("shm", len(data), is_error, contained or [])
+        with self._cv:
+            self._pending.append((task_id, index, entry, callsite))
+            self._unshipped[task_id] = self._unshipped.get(task_id, 0) + 1
+            self._cv.notify_all()
+
+    def flush(self, task_id: TaskID):
+        """Return when every item of ``task_id`` added so far has shipped."""
+        with self._cv:
+            while self._unshipped.get(task_id):
+                # bounded by the shipment's own call (the control timeout): ``_ship``
+                # counts a run down whether its call returned or raised
+                self._cv.wait()  # ray-tpu: lint-ignore[RTL008]
+
+    def _ship(self):
+        while True:
+            with self._cv:
+                while not self._pending:
+                    self._cv.wait()  # ray-tpu: lint-ignore[RTL008] — the shipper idles until a generator yields
+                pending, self._pending = self._pending, []
+            runs: Dict[TaskID, list] = {}
+            for task_id, index, entry, callsite in pending:
+                run = runs.get(task_id)
+                if run is None:
+                    run = runs[task_id] = [task_id, index, callsite, []]
+                run[3].append(entry)
+            try:
+                self.core._call("stream_items", self.core.node_id, list(runs.values()))
+            except rpc.ConnectionLost:
+                os._exit(1)
+            except Exception:  # noqa: BLE001 — the consumer times out; keep shipping
+                logger.exception("stream shipment of %d items failed", len(pending))
+            self.items += len(pending)
+            self.shipments += 1
+            self._items.inc(len(pending))
+            self._shipments.inc(1)
+            with self._cv:
+                for task_id, _first, _site, entries in runs.values():
+                    left = self._unshipped[task_id] - len(entries)
+                    if left:
+                        self._unshipped[task_id] = left
+                    else:
+                        del self._unshipped[task_id]
+                self._cv.notify_all()
 
 
 class _DepError(Exception):

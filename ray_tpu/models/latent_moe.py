@@ -25,21 +25,28 @@ throughout; names are the published configuration's):
 
 **The expert layer is told which experts it holds** (``held_first``,
 ``held_count``: this chip's share of a layer that several chips divide). It
-routes over all of them, sorts the token-expert pairs that land here by
-expert, multiplies them as groups (``jax.lax.ragged_dot``: no capacity, no
-dropped token) and adds the shared expert for every token. What the absent
-experts would have added is left out; a token none of whose experts is here
-gets the shared expert only. It also counts: pairs computed here, held experts
-with at least one pair.
+routes over all of them and multiplies the token-expert pairs that land here,
+every one (no capacity, no dropped token), in one of two forms that the call's
+STATIC token count chooses (``routed_experts``, ``ops/moe.py``): a call under
+the chip's ridge (a decode step's few tokens) sends all its tokens through
+each TOUCHED expert in one Pallas kernel, ``moe_decode_experts``, weighted by
+their gates (0 for a token that did not choose the expert), reading the
+touched experts' weights once, where they lie; a call over it (a chunk call's
+1,024) sorts the pairs by expert and multiplies them as groups
+(``jax.lax.ragged_dot``). Then the shared expert for every token. What the
+absent experts would have added is left out; a token none of whose experts is
+here gets the shared expert only. It also counts: pairs computed here, held
+experts with at least one pair, and which form ran.
 
 Parameters: ``embed``, ``final_norm``, ``lm_head``; ``lead`` and ``layers``
 (stacked by layer: attention, norms, and in ``layers`` the router and the
 shared expert); and ``experts``, the held routed experts of ALL expert layers,
-``[expert layers, held, ...]``, which no scan slices: the grouped product is a
-kernel, a kernel's operand has to exist in memory, and a layer's slice of the
-stack would be copied there at every step (three copies of 0.5 GB a layer at
-the published widths). The kernel is handed the whole stack as ``layers x
-held`` groups of which only the layer's own hold rows.
+``[expert layers, held, ...]``, which no scan slices: both forms are kernels, a
+kernel's operand has to exist in memory, and a layer's slice of the stack
+would be copied there at every step (three copies of 0.5 GB a layer at the
+published widths). The grouped product is handed the whole stack as ``layers x
+held`` groups of which only the layer's own hold rows; the decode kernel takes
+the layer's number as a prefetched scalar of its blocks' index.
 """
 from __future__ import annotations
 
@@ -52,6 +59,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import paged
 from ray_tpu.models.transformer import Params, _rope, rms_norm
+from ray_tpu.ops import moe
 from ray_tpu.ops.latent_attention import latent_attention, latent_chunk_attention
 
 
@@ -302,45 +310,64 @@ def select_experts(scores, bias, n_group: int, topk_group: int, k: int):
 
 def routed_experts(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
     """The held experts' part of the layer's result for y [T, D], and the
-    counts (pairs computed here, held experts touched, 1) as int32 [3].
-    ``held`` is a stack of layers' held experts, ``[layers, held, ...]``, and
-    ``layer`` this layer's number in it (it may be traced).
+    counts (pairs computed here, held experts touched, 1, 1 if the kernel
+    multiplied them) as int32 [4]. ``held`` is a stack of layers' held
+    experts, ``[layers, held, ...]``, and ``layer`` this layer's number in it
+    (it may be traced).
 
     Every token-expert pair gets a key: its expert's index among the held
-    ones, or ``held`` if the expert lives elsewhere; a stable sort by key
-    puts the pairs of this chip first, grouped by expert, and ``ragged_dot``
-    multiplies the groups (rows past the last group are not computed; a
-    group of no rows reads no weights: the other layers' experts)."""
+    ones, or ``held`` if the expert lives elsewhere. Two forms, chosen by the
+    call's static token count (``ops/moe.fused``: under the chip's ridge, on a
+    TPU): a few tokens go through every touched expert whole, weighted by
+    their gates (``moe_decode_experts``, one kernel); many are sorted by key,
+    a stable sort that puts the pairs of this chip first, grouped by expert,
+    and ``ragged_dot`` multiplies the groups (rows past the last group are not
+    computed; a group of no rows reads no weights: the other layers' experts)."""
     T, D = y.shape
     k, E = cfg.num_experts_per_tok, cfg.held
     experts, gates = route(y, lp, cfg)
     with jax.named_scope("moe.experts"):
         local = experts - cfg.held_first
         here = (local >= 0) & (local < E)
-        key = jnp.where(here, local, E).reshape(T * k)
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0).astype(jnp.int32)
+        key = jnp.where(here, local, E)  # [T, k]
+        chose = key[..., None] == jnp.arange(E)  # [T, k, E]
+        sizes = jnp.sum(chose, axis=(0, 1)).astype(jnp.int32)
         pairs = jnp.sum(sizes)
-        x = y[order // k]  # [T*k, D]: the token of each sorted pair
-        n_layers = held["e_gate"].shape[0]
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
-
-        def dot(a, w):  # w: [layers, E, in, out], as layers x E groups (a bitcast)
-            return jax.lax.ragged_dot(a, w.reshape((-1,) + w.shape[2:]).astype(a.dtype), groups)
-
-        out = dot(jax.nn.silu(dot(x, held["e_gate"])) * dot(x, held["e_up"]), held["e_down"])
-        out = jnp.where((jnp.arange(T * k) < pairs)[:, None], out, 0)
-        # Back to (token, choice) order; a pair computed elsewhere weighs 0.
-        out = out[jnp.argsort(order)].reshape(T, k, D)
-        weight = jnp.where(here, gates, 0.0)  # float32, as the sum over the choices
-        m = jnp.sum(out.astype(jnp.float32) * weight[..., None], axis=1).astype(y.dtype)
-    counts = jnp.stack([pairs, jnp.sum(sizes > 0).astype(jnp.int32), jnp.int32(1)])
+        use_kernel = moe.fused(T, held)
+        if use_kernel:
+            w = jnp.sum(jnp.where(chose, gates[..., None], 0.0), axis=1)  # [T, E] float32
+            m = moe.moe_decode_experts(y, w, sizes, held, layer)
+        else:
+            m = _sorted_experts(y, key, jnp.where(here, gates, 0.0), sizes, held, layer)
+    counts = jnp.stack([pairs, jnp.sum(sizes > 0).astype(jnp.int32), jnp.int32(1),
+                        jnp.int32(use_kernel)])
     return m, counts
 
 
+def _sorted_experts(y, key, weight, sizes, held: Params, layer):
+    """``routed_experts``' grouped form. key: [T, k] a pair's held expert (or
+    ``held``: elsewhere); weight: [T, k] float32, 0 for a pair elsewhere;
+    sizes: [held] pairs an expert."""
+    (T, D), k = y.shape, key.shape[1]
+    E = sizes.shape[0]
+    order = jnp.argsort(key.reshape(T * k), stable=True)
+    x = y[order // k]  # [T*k, D]: the token of each sorted pair
+    n_layers = held["e_gate"].shape[0]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
+
+    def dot(a, w):  # w: [layers, E, in, out], as layers x E groups (a bitcast)
+        return jax.lax.ragged_dot(a, w.reshape((-1,) + w.shape[2:]).astype(a.dtype), groups)
+
+    out = dot(jax.nn.silu(dot(x, held["e_gate"])) * dot(x, held["e_up"]), held["e_down"])
+    out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None], out, 0)
+    # Back to (token, choice) order; a pair computed elsewhere weighs 0.
+    out = out[jnp.argsort(order)].reshape(T, k, D)
+    return jnp.sum(out.astype(jnp.float32) * weight[..., None], axis=1).astype(y.dtype)
+
+
 def expert_layer(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
-    """y: [T, D] → (shared expert + this chip's routed part, counts [3])."""
+    """y: [T, D] → (shared expert + this chip's routed part, counts [4])."""
     m, counts = routed_experts(y, lp, cfg, held, layer)
     with jax.named_scope("moe.shared"):
         m = m + _swiglu(y, lp["shared_gate"], lp["shared_up"], lp["shared_down"])

@@ -9,6 +9,7 @@ the less loaded (p2c), the same algorithm the reference router runs.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import random
 import threading
@@ -49,6 +50,11 @@ class DeploymentStreamingResponse:
     handle.py DeploymentResponseGenerator). Each ``next()`` blocks until
     the replica yields the next item (bounded by ``item_timeout_s``).
 
+    Items travel by value, a run at a time: whatever the replica had
+    shipped when this side asked comes in one controller call
+    (``ObjectRefGenerator.take``) and is handed out one ``next()`` at a
+    time; ``in_hand()`` gives a writer the rest of the run without a wait.
+
     The router's in-flight count is released on exhaustion, on ANY
     error, on close(), and as a last resort on GC — an abandoned stream
     (client disconnect, the normal LLM cancel path) must not leave a
@@ -61,7 +67,7 @@ class DeploymentStreamingResponse:
         self._on_done = on_done
         self._finished = False
         self._exhausted = False
-        self._timeout = item_timeout_s
+        self._run: collections.deque = collections.deque()  # (value, is_error) in hand
 
     def _finish(self):
         if not self._finished:
@@ -93,22 +99,30 @@ class DeploymentStreamingResponse:
         return self
 
     def __next__(self):
-        import ray_tpu
+        if not self._run:
+            try:
+                self._run.extend(self._gen.take())
+            except StopIteration:
+                self._exhausted = True
+                self._finish()
+                raise
+            except BaseException:
+                self._finish()
+                raise
+        value, is_error = self._run.popleft()
+        if is_error:
+            self._finish()
+            raise value
+        return value
 
-        try:
-            ref = next(self._gen)
-        except StopIteration:
-            self._exhausted = True
-            self._finish()
-            raise
-        except BaseException:
-            self._finish()
-            raise
-        try:
-            return ray_tpu.get(ref, timeout=self._timeout)
-        except BaseException:
-            self._finish()
-            raise
+    def in_hand(self) -> list:
+        """The items of the run in hand that ``next()`` has not given out
+        yet, without a wait (none: the next ``next()`` asks the controller).
+        An error among them stays in hand, for the ``next()`` after."""
+        out = []
+        while self._run and not self._run[0][1]:
+            out.append(self._run.popleft()[0])
+        return out
 
 
 class _Router:

@@ -122,16 +122,19 @@ _WEIGHTS_WIDTH = 256
 # Counts an expert model's programs return behind their tokens
 # (``paged._with_counts``), in this order: token-expert pairs computed by the
 # experts held here; held experts with at least one pair, summed over expert
-# layers and steps (or calls); expert layers x steps (or calls) counted. They
+# layers and steps (or calls); expert layers x steps (or calls) counted; of
+# those, the ones whose products the kernel ``moe_decode_experts`` made (a call
+# under the chip's ridge: ``ops/moe.fused``). They
 # arrive on the transfers that bring the tokens: a chunk call whose output the
 # host never reads (no segment of it ends a prompt) is not counted.
-_MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps")
+_MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_fused_layer_steps")
 
 
 @dataclasses.dataclass
 class Request:
-    """One generation request; ``out`` streams generated token ids and a
-    final ``None`` sentinel."""
+    """One generation request; ``out`` streams generated token ids, a run
+    of them an entry (what one program call gave the request: a window's
+    tokens, or a prefill's first token alone), and a final ``None`` sentinel."""
 
     prompt: List[int]
     max_new_tokens: int
@@ -165,14 +168,15 @@ class Request:
         return self.prompt + self.generated
 
     def tokens(self, timeout: Optional[float] = None):
-        """Iterate generated tokens until the sentinel (blocking)."""
+        """Iterate generated tokens until the sentinel (blocking: one wait
+        a run, ``timeout`` each)."""
         while True:
-            tok = self.out.get(timeout=timeout)
-            if tok is None:
+            run = self.out.get(timeout=timeout)
+            if run is None:
                 if self.error:
                     raise RuntimeError(self.error)
                 return
-            yield tok
+            yield from run
 
 
 class FlightRecorder:
@@ -597,7 +601,7 @@ class LLMEngine:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Stats for tests/bench.
-        self.stats = {"steps": 0, "tokens": 0, "max_active": 0, "preemptions": 0,
+        self.stats = {"steps": 0, "tokens": 0, "emit_batches": 0, "max_active": 0, "preemptions": 0,
                       "prefills": 0, "admitted": 0, "prompt_tokens": 0,
                       "finished": 0, "prefill_chunks": 0, "spec_windows": 0,
                       "h2d_ships": 0, "h2d_skips": 0, "prefix_hit_tokens": 0,
@@ -1384,7 +1388,7 @@ class LLMEngine:
             for i, req, t, k in pend:
                 if self.slots[i] is not req:
                     continue  # preempted between prefill and flush
-                self._emit(i, int(vals[id(t)][k]))
+                self._emit(i, [int(vals[id(t)][k])])
         self._at("between")
 
     def _count(self, counts):
@@ -1392,17 +1396,23 @@ class LLMEngine:
         for name, n in zip(_MOE_COUNTS, counts):
             self.stats[name] += int(n)
 
-    def _emit(self, i: int, tok: int):
-        """Record + stream one generated token; retire the slot when done.
-        Per-token cost stays allocation-light: one None check for the
-        TTFT mark — histograms/gauges flush at step cadence, not here."""
+    def _emit(self, i: int, toks: List[int]):
+        """Record + stream the tokens one program call gave slot ``i``, as
+        ONE queue entry (one wake-up of the request's reader): those up to
+        the request's end (its eos, or its last token: the rest of a window
+        is overshoot); retire the slot when done. Histograms/gauges flush at
+        step cadence, not here."""
         req = self.slots[i]
         if req.first_token_ts is None:
             req.first_token_ts = time.time()
-        req.generated.append(tok)
-        req.out.put(tok)
-        self.stats["tokens"] += 1
-        if (req.eos_id is not None and tok == req.eos_id) or req.remaining <= 0:
+        toks = toks[:req.remaining]
+        if req.eos_id is not None and req.eos_id in toks:
+            toks = toks[:toks.index(req.eos_id) + 1]
+        req.generated.extend(toks)
+        req.out.put(toks)
+        self.stats["tokens"] += len(toks)
+        self.stats["emit_batches"] += 1
+        if req.remaining <= 0 or (req.eos_id is not None and toks[-1] == req.eos_id):
             self._finish(i)
 
     @staticmethod
@@ -1524,10 +1534,7 @@ class LLMEngine:
                 req = self.slots[i]
                 if req is None or req.rid != rid or self._slot_gen[i] != gen:
                     continue  # finished / preempted / slot reused in flight
-                for k in range(self.window):
-                    if self.slots[i] is not req:
-                        break  # finished mid-window; rest is overshoot
-                    self._emit(i, int(nxt[k, i]))
+                self._emit(i, nxt[:self.window, i].tolist())
         self._at("between")
         return True
 
@@ -1764,6 +1771,8 @@ class LLMEngine:
                 ("moe_pairs_here", m.engine_moe_pairs_here),
                 ("moe_experts_touched", m.engine_moe_experts_touched),
                 ("moe_layer_steps", m.engine_moe_layer_steps),
+                ("moe_fused_layer_steps", m.engine_moe_fused_layer_steps),
+                ("emit_batches", m.engine_emit_batches),
                 ("state_slots_live", m.engine_state_slots_live),
                 ("state_slots_table", m.engine_state_slots_table),
                 ("state_segments_carried", m.engine_state_segments_carried),
@@ -1870,6 +1879,7 @@ class LLMEngine:
                 "pairs_here": self.stats["moe_pairs_here"],
                 "experts_touched": self.stats["moe_experts_touched"],
                 "layer_steps": self.stats["moe_layer_steps"],
+                "fused_layer_steps": self.stats["moe_fused_layer_steps"],
                 "experts_touched_per_layer_step": self.stats["moe_experts_touched"]
                 / max(1, self.stats["moe_layer_steps"]),
                 "pairs_per_touched_expert": self.stats["moe_pairs_here"]

@@ -235,6 +235,18 @@ class _ServeMetrics:
             "serve_engine_moe_experts_touched_total / this)",
             dr,
         )
+        self.engine_moe_fused_layer_steps = Counter(
+            "serve_engine_moe_fused_layer_steps_total",
+            "Of serve_engine_moe_layer_steps_total, those whose held experts one "
+            "kernel multiplied (moe_decode_experts: a call under the chip's ridge)",
+            dr,
+        )
+        self.engine_emit_batches = Counter(
+            "serve_engine_emit_batches_total",
+            "Queue entries the engine handed its requests (a window's tokens of a "
+            "request are one; tokens an entry = serve_engine_tokens_total / this)",
+            dr,
+        )
         self.engine_state_slots_live = Counter(
             "serve_engine_state_slots_live_total",
             "Rows of state kept by slot (a state-space layer's) that dispatched decode "

@@ -113,8 +113,10 @@ class ProxyActor:
                 return None
 
             def _send_stream(self, items, mode: str):
-                """Chunked streaming: one frame per yielded item, flushed
-                as produced (the LLM token-streaming path). NDJSON frames
+                """Chunked streaming: one frame (and one HTTP chunk) per
+                yielded item, flushed as produced (the LLM token-streaming
+                path); the items of a run that arrived together leave in
+                one write. NDJSON frames
                 are JSON lines; SSE frames are ``data: <json>\\n\\n`` with
                 errors as ``event: error`` (reference: serve's SSE
                 responses consumed by EventSource clients)."""
@@ -129,9 +131,10 @@ class ProxyActor:
                 self.send_header("Transfer-Encoding", "chunked")
                 self.end_headers()
 
-                def chunk(data: bytes) -> bool:
+                def chunk(*frames: bytes) -> bool:
                     try:
-                        self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
+                        self.wfile.write(b"".join(
+                            f"{len(data):x}\r\n".encode() + data + b"\r\n" for data in frames))
                         self.wfile.flush()
                         return True
                     except OSError:
@@ -151,9 +154,10 @@ class ProxyActor:
                     return json.dumps(item, default=str).encode() + b"\n"
 
                 alive = True
+                in_hand = getattr(items, "in_hand", list)  # what came with the item
                 try:
                     for item in items:
-                        alive = chunk(frame(item=item))
+                        alive = chunk(frame(item=item), *(frame(item=x) for x in in_hand()))
                         if not alive:
                             break
                 except Exception as e:  # noqa: BLE001 — replica error → error frame

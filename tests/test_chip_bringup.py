@@ -845,8 +845,11 @@ def test_kda_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
         params, sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
         sds((b,), np.float32), sds((2,), np.uint32)).compile()
     names = _kernel_names(compiled.as_text())
-    # (the experts' grouped products are kernels of the compiler's own)
-    assert (names.count("kda_state_update"), names.count("latent_attend")) == (12, 2)
+    # The window's 64 tokens are under the ridge: each of the period's six expert
+    # layers is ONE ``moe_decode_experts`` a step, and no grouped product is left.
+    assert (names.count("kda_state_update"), names.count("latent_attend"),
+            names.count("moe_decode_experts")) == (12, 2, 12)
+    assert "ragged-dot" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
     (params_fmt, *_), _ = compiled.input_formats
     width = 1024
@@ -865,8 +868,114 @@ def test_kda_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
         sds((2,), np.uint32), sds((b,), np.int32)).compile()
     # The chunk scan is plain XLA (no kernel yet): the widest thing a 1,024-token
     # call holds are a layer's sub-tile decays, 67 MB, and its projections.
+    # (a call of 1,024 tokens is over the ridge: its experts are the grouped products,
+    # kernels of the compiler's own, reading the same stacks in the same layout)
     assert _kernel_names(compiled.as_text()).count("latent_prefill_attend") == 1
+    assert "moe_decode_experts" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+# ---------------------------------------------------------------------------
+# The expert layers' decode kernel (ops/moe.py), both expert models' widths
+# ---------------------------------------------------------------------------
+_MOE_WIDTHS = {  # layers in the stack, held experts, hidden, expert width
+    "ling-3.0-flash": (6, 128, 2560, 768),
+    "pangu-ultra-moe": (2, 16, 7680, 2048),
+}
+
+
+@pytest.mark.parametrize("widths", list(_MOE_WIDTHS))
+@pytest.mark.parametrize("T", [32, 64, 128, 240])
+def test_moe_decode_experts_compiles_for_v5e_at_the_published_widths(v5e, widths, T):
+    """``moe_decode_experts`` at the two served models' published widths, at the
+    decode programs' token counts and at the ridge: one kernel, the stacks read
+    where they lie (temporaries far under ONE expert's matrix: no copy, no
+    relayout of ``[layers, held, in, out]``)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import moe
+
+    L, E, D, F = _MOE_WIDTHS[widths]
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    held = {"e_gate": sds((L, E, D, F), jnp.bfloat16), "e_up": sds((L, E, D, F), jnp.bfloat16),
+            "e_down": sds((L, E, F, D), jnp.bfloat16)}
+    assert moe.fused(T, held) and not moe.fused(moe.RIDGE_TOKENS + 1, held)
+    compiled = jax.jit(moe.moe_decode_experts).lower(
+        sds((T, D), jnp.bfloat16), sds((T, E), jnp.float32), sds((E,), jnp.int32), held,
+        sds((), jnp.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["moe_decode_experts"]
+    assert compiled.memory_analysis().temp_size_in_bytes < D * F  # half a matrix of bfloat16
+
+
+_MOE_KERNEL_AGAINST_GROUPED_FORM = """
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.models import latent_moe as lm
+from ray_tpu.ops import moe
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+WIDTHS = {
+    "ling-3.0-flash": (2, lm.LatentMoEConfig(hidden_size=2560, moe_intermediate_size=768, n_routed_experts=512,
+                                             num_experts_per_tok=8, n_group=8, topk_group=4, held_first=128, held_count=128)),
+    "pangu-ultra-moe": (2, lm.LatentMoEConfig(hidden_size=7680, moe_intermediate_size=2048, n_routed_experts=256,
+                                              num_experts_per_tok=8, held_first=16, held_count=16)),
+}
+for name, (L, cfg) in WIDTHS.items():
+    D, F, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.held
+    ks = jax.random.split(jax.random.PRNGKey(51), 5)
+    normal = lambda k, shape, fan: (jax.random.normal(k, shape, jnp.bfloat16) * fan ** -0.5).astype(jnp.bfloat16)
+    held = jax.jit(lambda: {"e_gate": normal(ks[0], (L, E, D, F), D), "e_up": normal(ks[1], (L, E, D, F), D),
+                            "e_down": normal(ks[2], (L, E, F, D), F)})()
+    lp = {"router": normal(ks[3], (D, cfg.n_routed_experts), D)}
+
+    def run(kernel):
+        def f(y, lp, held):
+            was, moe.fused = moe.fused, (lambda T, held: kernel)
+            try:
+                return lm.routed_experts(y, lp, cfg, held, 1)
+            finally:
+                moe.fused = was
+        return jax.jit(f)
+
+    for T in (32, 64, 128):
+        assert moe.fused(T, held)
+        y = jax.random.normal(jax.random.fold_in(ks[4], T), (T, D), jnp.bfloat16)
+        assert "moe_decode_experts" in jax.jit(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1)).lower(y, lp, held).as_text()
+        want, counts = run(False)(y, lp, held)
+        got, counts_kernel = run(True)(y, lp, held)
+        assert np.array_equal(np.asarray(counts)[:3], np.asarray(counts_kernel)[:3]), (counts, counts_kernel)
+        assert 0 < int(counts[1]) <= E and int(counts[0]) < T * 8  # some pairs live elsewhere
+        want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+        apart = np.abs(got - want).max() / np.abs(want).max()
+        assert np.isfinite(got).all() and apart < 2**-5, (name, T, apart)  # both round to bfloat16, the grouped form each product
+        none = np.asarray(lm.route(y, lp, cfg)[0])
+        none = ~((none >= cfg.held_first) & (none < cfg.held_first + E)).any(-1)
+        assert not got[none].any()  # a token with no expert here gets exactly nothing
+        print(name, "T", T, "pairs", int(counts[0]), "touched", int(counts[1]), "of", E, "apart", apart)
+    del held
+"""
+
+
+def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_the_chip():
+    """``routed_experts`` through ``moe_decode_experts`` against its sorted
+    ``ragged_dot`` form on a chip, at both served models' published widths (the
+    second layer of a stack of two, a share that does not begin at expert 0,
+    routing by the model's own router), T = 32, 64, 128: the counts equal, the
+    numbers within bfloat16's rounding. In a process of its own: this one is
+    held to the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "RAY_TPU_FORCE_PALLAS")}
+    r = subprocess.run([sys.executable, "-c", _MOE_KERNEL_AGAINST_GROUPED_FORM], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=900)
+    print(r.stdout[-3000:])
+    assert r.returncode == 0, r.stderr[-3000:]
 
 
 _KDA_KERNEL_AGAINST_PLAIN_FORM = """

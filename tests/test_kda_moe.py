@@ -15,12 +15,15 @@ that over an order of room; a state rounded to bfloat16 between tokens reads
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import test_hybrid_ssm as T  # the chunk call as the engine lays it out, the tilings
+import test_latent_moe as ML  # the expert kernel against the grouped form, the routings
 from chipbench import reference_kda_moe as R
 from chipbench import weights_kda_moe as W
 from ray_tpu.models import kda_moe as km
@@ -349,6 +352,29 @@ def test_a_places_stack_gives_a_layer_its_own_periods_experts(model):
     assert np.abs(np.asarray(in_stack)).max() > 0.1
     assert np.allclose(in_stack, alone, atol=1e-5) and np.array_equal(counts, counts_alone)
     assert not np.allclose(in_stack, other, atol=1e-2)
+
+
+@pytest.mark.parametrize("name", ML.ROUTINGS)
+@pytest.mark.parametrize("T", [1, 8, 64, 128])
+def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_a_places_stack(model, monkeypatch, T, name):
+    """Held experts 4-11 of 16, two a token: the period's third place, its
+    second period (the kernel is handed the place's stack and the period's number)."""
+    _dims, _key, cfg, params, _bias = model
+    ML.kernel_against_grouped(monkeypatch, cfg, params["experts"][2], 1, T, name)
+
+
+@pytest.mark.parametrize("T,kernel", [(64, True), (240, True), (241, False)])
+def test_the_token_count_alone_chooses_the_form_for_this_model_too(monkeypatch, T, kernel):
+    """The same rule as ``test_latent_moe``'s, from this model's configuration
+    object: nothing of the model is asked, only the call's static token count."""
+    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+    cfg = km.KDAMoEConfig.tiny(hidden_size=128, moe_intermediate_size=128, held_first=4, held_count=8)
+    held = {name: jax.ShapeDtypeStruct(shape, jnp.bfloat16) for name, shape in km.expert_shapes(cfg).items()}
+    lp = {"router": jax.ShapeDtypeStruct((128, cfg.num_experts), jnp.bfloat16),
+          "expert_bias": jax.ShapeDtypeStruct((cfg.num_experts,), jnp.float32)}
+    text = str(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 0))(
+        jax.ShapeDtypeStruct((T, 128), jnp.bfloat16), lp, held))
+    assert ("moe_decode_experts" in text, len(re.findall(r"= ragged_dot_general\[", text))) == ((True, 0) if kernel else (False, 3))
 
 
 def test_the_fitted_bias_levels_this_chips_share_on_tokens_it_never_saw():

@@ -11,6 +11,8 @@ bfloat16 reads ~3e-2 and fails it (``test_bfloat16_fails_the_tolerance``).
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -205,7 +207,93 @@ def test_the_counters_add_up_over_a_known_batch(model):
     experts = np.asarray(lm.route(y, lp, cfg)[0])
     here = (experts >= 2) & (experts < 6)
     _, counts = lm.routed_experts(y, lp, cfg, params["experts"], 1)
-    assert [int(c) for c in counts] == [int(here.sum()), len(set(experts[here].tolist())), 1]
+    assert [int(c) for c in counts] == [int(here.sum()), len(set(experts[here].tolist())), 1, 0]
+
+
+# --- the decode kernel of ops/moe.py under the interpreter --------------------
+def routing(name: str, T: int, cfg, seed: int = 0):
+    """(experts [T, k], gates [T, k]) of a named pattern over the share the
+    configuration holds (``held_first`` .. ``held_first + held - 1`` of all):
+    ``elsewhere``: drawn over ALL experts, so some pairs live on other chips;
+    ``untouched``: every token on the first two held experts, the other held ones idle;
+    ``one_expert``: every token on ONE held expert and one elsewhere;
+    ``none_here``: no pair lands on a held expert."""
+    rng = np.random.default_rng(seed)
+    k, first, held = cfg.num_experts_per_tok, cfg.held_first, cfg.held
+    total = first + held + 2  # at least two experts live elsewhere, behind the held ones
+    if name == "elsewhere":
+        experts = np.stack([rng.permutation(total)[:k] for _ in range(T)])
+        experts[0] = np.r_[first, total - 1 - np.arange(k - 1)]  # the first token: one here, the rest not
+    elif name == "untouched":
+        experts = np.tile(np.arange(first, first + k), (T, 1))
+    elif name == "one_expert":
+        experts = np.tile(np.r_[first + 1, total - 1 - np.arange(k - 1)], (T, 1))
+    else:
+        experts = np.tile(total - 1 - np.arange(k), (T, 1))
+    return jnp.asarray(experts, jnp.int32), jnp.asarray(rng.uniform(0.1, 1.5, (T, k)), jnp.float32)
+
+
+def kernel_against_grouped(monkeypatch, cfg, held, layer, T, name):
+    """``routed_experts`` through ``moe_decode_experts`` (the Pallas interpreter)
+    against its sorted ``ragged_dot`` form on the same routing: in float32 the
+    same numbers but for the order of the sums, in bfloat16 within its
+    rounding (the grouped form rounds each product, the kernel only what goes
+    into the down projection); the three counts equal, the fourth says which."""
+    import functools
+
+    from ray_tpu.ops import moe
+
+    chosen = routing(name, T, cfg)
+    monkeypatch.setattr(lm, "route", lambda y, lp, cfg: chosen)
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)):
+        y = jax.random.normal(jax.random.PRNGKey(T), (T, held["e_gate"].shape[2]), jnp.float32).astype(dtype)
+        weights = jax.tree.map(lambda a: a.astype(dtype), held)
+        with monkeypatch.context() as m:
+            m.setattr(moe, "fused", lambda T, held: False)
+            want, counts = lm.routed_experts(y, {}, cfg, weights, layer)
+        with monkeypatch.context() as m:
+            m.setattr(moe, "fused", lambda T, held: T <= moe.RIDGE_TOKENS)
+            m.setattr(moe, "moe_decode_experts", functools.partial(moe.moe_decode_experts, interpret=True))
+            got, counts_kernel = lm.routed_experts(y, {}, cfg, weights, layer)
+        assert got.dtype == want.dtype == dtype and got.shape == (T, y.shape[1])
+        want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+        assert np.abs(got - want).max() <= tol * max(1.0, np.abs(want).max()), (dtype, np.abs(got - want).max())
+        assert np.array_equal(counts[:3], counts_kernel[:3]) and (int(counts[3]), int(counts_kernel[3])) == (0, 1)
+        if name == "none_here":
+            assert not got.any() and int(counts[0]) == 0
+        else:
+            assert np.abs(want).max() > 0.05
+    return counts
+
+
+ROUTINGS = ("elsewhere", "untouched", "one_expert", "none_here")
+
+
+@pytest.mark.parametrize("name", ROUTINGS)
+@pytest.mark.parametrize("T", [1, 8, 64, 128])
+def test_moe_decode_kernel_reads_the_grouped_forms_numbers(model, monkeypatch, T, name):
+    """Held experts 2-5 of 8, two a token, the second expert layer of the stack."""
+    _dims, _key, cfg, params = model
+    counts = kernel_against_grouped(monkeypatch, cfg, params["experts"], 1, T, name)
+    assert int(counts[1]) == {"untouched": 2, "one_expert": 1, "none_here": 0}.get(name, int(counts[1]))
+
+
+@pytest.mark.parametrize("T,kernel", [(1, True), (64, True), (240, True), (241, False), (1024, False)])
+def test_the_token_count_alone_chooses_the_form(monkeypatch, T, kernel):
+    """On a TPU (forced: the lowering is never run) ``routed_experts`` of a call
+    of up to ``RIDGE_TOKENS`` tokens is the kernel and no ``ragged_dot``; of one
+    token more, three ``ragged_dot`` and no kernel. Nothing else is asked."""
+    from ray_tpu.ops import moe
+
+    assert moe.RIDGE_TOKENS == 240
+    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+    cfg = lm.LatentMoEConfig.tiny(hidden_size=128, moe_intermediate_size=128, held_first=2, held_count=4)
+    held = {name: jax.ShapeDtypeStruct(shape, jnp.bfloat16) for name, shape in lm.expert_shapes(cfg).items()}
+    lp = {"router": jax.ShapeDtypeStruct((128, cfg.n_routed_experts), jnp.bfloat16)}
+    text = str(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1))(
+        jax.ShapeDtypeStruct((T, 128), jnp.bfloat16), lp, held))
+    assert ("pallas_call" in text, len(re.findall(r"= ragged_dot_general\[", text))) == ((True, 0) if kernel else (False, 3))
+    assert ("moe_decode_experts" in text) == kernel
 
 
 def _deficits(ref, served):

@@ -286,3 +286,65 @@ def test_overlap_preemption_under_pressure(tiny_model):
     outs = eng.generate_batch(prompts, max_new_tokens=24)
     assert outs == calm
     assert eng.stats["preemptions"] > 0
+
+
+@pytest.mark.parametrize("new_tokens,eos_at", [(13, None), (9, None), (1, None), (13, 6)],
+                         ids=["ends-on-a-window", "ends-mid-window", "first-token-only", "eos-mid-window"])
+def test_a_windows_tokens_are_one_queue_entry_and_tokens_yields_each_once(tiny_model, new_tokens, eos_at):
+    """A request is handed what a program call gave it as ONE entry of ``out``
+    (the prefill's first token alone, then a window's four together, the last
+    entry cut at the request's end), and ``Request.tokens()`` still yields token
+    by token, every token once, whether the request ends on a window's last
+    token, in the middle of one, with its first token, or on an eos mid-window."""
+    cfg, params = tiny_model
+    prompt = [5, 9, 2]
+    [base] = _engine(cfg, params).generate_batch([prompt], max_new_tokens=13)
+    eos = None
+    if eos_at is not None:
+        eos_at = next((k for k in range(eos_at, 13) if base.index(base[k]) == k), None)
+        if eos_at is None:
+            pytest.skip("greedy output degenerated to pure repetition")
+        eos = base[eos_at]
+    want = base[:new_tokens] if eos is None else base[:eos_at + 1]
+    eng = LLMEngine(
+        params, cfg,
+        PagedConfig(block_size=8, num_blocks=33, max_batch=2, max_blocks_per_seq=8),
+        decode_window=4,
+    )
+    req = eng.add_request(prompt, new_tokens, eos_id=eos)
+    while eng.active_count() or eng.waiting:
+        eng.step()
+    entries = list(req.out.queue)
+    assert entries[-1] is None and all(isinstance(e, list) and e for e in entries[:-1])
+    # the first token alone, then whole windows, the last one cut at the end
+    assert [len(e) for e in entries[:-1]] == [1] + [min(4, len(want) - 1 - k) for k in range(0, len(want) - 1, 4)]
+    assert list(req.tokens(timeout=5)) == want == req.generated
+    assert eng.stats["tokens"] == len(want) and eng.stats["emit_batches"] == len(entries) - 1
+
+
+def test_a_reader_is_woken_once_a_window_not_once_a_token(tiny_model):
+    """Two requests in one batch, each read by a thread as a streaming driver
+    reads them: every token arrives once and in order, in a quarter of the
+    queue entries (windows of four)."""
+    cfg, params = tiny_model
+    eng = LLMEngine(
+        params, cfg,
+        PagedConfig(block_size=8, num_blocks=33, max_batch=2, max_blocks_per_seq=8),
+        decode_window=4,
+    )
+    prompts = [[5, 9, 2], [17, 1, 8, 4]]
+    base = _engine(cfg, params).generate_batch(prompts, max_new_tokens=17)
+    eng.start()
+    try:
+        reqs = [eng.add_request(p, 17) for p in prompts]
+        got = [[] for _ in reqs]
+        threads = [threading.Thread(target=lambda r=r, g=g: g.extend(r.tokens(timeout=60)))
+                   for r, g in zip(reqs, got)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        eng.stop()
+    assert got == base
+    assert eng.stats["tokens"] == 34 and eng.stats["emit_batches"] == 2 * (1 + 4)

@@ -235,6 +235,101 @@ def test_streaming_deployment_handle(serve_cluster):
         serve.delete("tok")
 
 
+@pytest.fixture
+def bursts(serve_cluster):
+    """A deployment that streams bursts, and says what its replica's process
+    has shipped (``core/worker_main._StreamShipper``) and how many of its
+    generators were closed."""
+    from ray_tpu import serve
+
+    @serve.deployment(name="bursts", max_ongoing_requests=8)
+    class Bursts:
+        def __init__(self):
+            self.closed = self.yielded = 0
+
+        def __call__(self, spec):
+            try:
+                for i in range(spec["n"]):
+                    if i == spec.get("fail_at"):
+                        raise ValueError(f"broke at {i}")
+                    if i >= spec.get("slow_from", spec["n"]):
+                        time.sleep(spec["gap_s"])
+                    self.yielded += 1
+                    yield {"i": i}
+            finally:
+                self.closed += 1
+
+        def facts(self, _=None):
+            from ray_tpu.core.api import _require_worker
+
+            shipper = _require_worker().stream_shipper
+            return {"closed": self.closed, "yielded": self.yielded,
+                    "items": shipper.items if shipper else 0,
+                    "shipments": shipper.shipments if shipper else 0}
+
+    handle = serve.run(Bursts.bind())
+    try:
+        yield handle
+    finally:
+        serve.delete("bursts")
+
+
+@pytest.mark.parametrize("n", [40, 400])
+def test_a_burst_reaches_the_handle_in_order_in_far_fewer_shipments(bursts, n):
+    """What the generator has yielded while a shipment was on its way leaves as
+    ONE shipment: N items arrive in order, every one once, in far fewer than N."""
+    before = bursts.facts.remote().result(timeout=60)
+    assert list(bursts.stream({"n": n})) == [{"i": i} for i in range(n)]
+    after = bursts.facts.remote().result(timeout=60)
+    assert after["items"] - before["items"] == n
+    assert 1 <= after["shipments"] - before["shipments"] <= n // 4, (before, after)
+    assert after["closed"] - before["closed"] == 1
+
+
+def test_a_lone_item_reaches_the_handle_without_waiting(bursts):
+    """An idle stream ships one item at once: time to first token does not
+    wait for company (the second item comes three seconds later)."""
+    bursts.facts.remote().result(timeout=60)
+    stream = bursts.stream({"n": 2, "slow_from": 1, "gap_s": 3.0})
+    t0 = time.monotonic()
+    assert next(stream) == {"i": 0}
+    assert time.monotonic() - t0 < 2.0
+    assert stream.in_hand() == []  # nothing else had been yielded
+    assert list(stream) == [{"i": 1}]
+
+
+def test_an_error_mid_burst_reaches_the_consumer_after_exactly_the_items_before_it(bursts):
+    stream = bursts.stream({"n": 30, "fail_at": 7})
+    got = []
+    with pytest.raises(Exception, match="broke at 7"):
+        for item in stream:
+            got.append(item)
+    assert got == [{"i": i} for i in range(7)]
+    with pytest.raises(StopIteration):
+        next(stream)
+    assert all(v == 0 for v in bursts._router._inflight.values())
+
+
+def test_closing_a_stream_mid_burst_cancels_the_producer_and_releases_the_router(bursts):
+    """The consumer reads three items of a burst of five hundred (the rest come
+    one every 20 ms) and closes: the replica's generator is closed long before
+    its end, and the router's in-flight count is back to zero."""
+    before = bursts.facts.remote().result(timeout=60)
+    stream = bursts.stream({"n": 500, "slow_from": 20, "gap_s": 0.02})
+    assert [next(stream) for _ in range(3)] == [{"i": 0}, {"i": 1}, {"i": 2}]
+    assert sum(bursts._router._inflight.values()) == 1
+    stream.close()
+    assert all(v == 0 for v in bursts._router._inflight.values())
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        after = bursts.facts.remote().result(timeout=60)
+        if after["closed"] - before["closed"] == 1:
+            break
+        time.sleep(0.1)
+    assert after["closed"] - before["closed"] == 1, "the producer ran on"
+    assert after["yielded"] - before["yielded"] < 400
+
+
 def test_stream_of_non_generator_is_single_item(serve_cluster):
     """Plain methods through stream(): one item, even for list returns
     (containers are a single response, not element-wise streams)."""
