@@ -26,14 +26,17 @@ throughout; names are the published configuration's):
 **The expert layer is told which experts it holds** (``held_first``,
 ``held_count``: this chip's share of a layer that several chips divide). It
 routes over all of them and multiplies the token-expert pairs that land here,
-every one (no capacity, no dropped token), in one of two forms that the call's
-STATIC token count chooses (``routed_experts``, ``ops/moe.py``): a call under
-the chip's ridge (a decode step's few tokens) sends all its tokens through
-each TOUCHED expert in one Pallas kernel, ``moe_decode_experts``, weighted by
-their gates (0 for a token that did not choose the expert), reading the
-touched experts' weights once, where they lie; a call over it (a chunk call's
-1,024) sorts the pairs by expert and multiplies them as groups
-(``jax.lax.ragged_dot``). Then the shared expert for every token. What the
+every one (no capacity, no dropped token), in one of two loop nests that the
+call's STATIC token count chooses (``routed_experts``, ``ops/moe.py``): a call
+under the chip's ridge (a decode step's few tokens) sends all its tokens
+through each TOUCHED expert in one Pallas kernel, ``moe_decode_experts``,
+weighted by their gates (0 for a token that did not choose the expert),
+reading the touched experts' weights once, where they lie; a call over it (a
+chunk call's 1,024) sorts the pairs by expert and multiplies them as groups,
+in one Pallas kernel too, ``moe_grouped_experts``, which reads each touched
+expert's weights once for the tile or two of sorted rows its group lies in
+(on a CPU, and at widths that are not lane tiles, three
+``jax.lax.ragged_dot``). Then the shared expert for every token. What the
 absent experts would have added is left out; a token none of whose experts is
 here gets the shared expert only. It also counts: pairs computed here, held
 experts with at least one pair, and which form ran.
@@ -44,9 +47,9 @@ shared expert); and ``experts``, the held routed experts of ALL expert layers,
 ``[expert layers, held, ...]``, which no scan slices: both forms are kernels, a
 kernel's operand has to exist in memory, and a layer's slice of the stack
 would be copied there at every step (three copies of 0.5 GB a layer at the
-published widths). The grouped product is handed the whole stack as ``layers x
-held`` groups of which only the layer's own hold rows; the decode kernel takes
-the layer's number as a prefetched scalar of its blocks' index.
+published widths). ``ragged_dot`` is handed the whole stack as ``layers x
+held`` groups of which only the layer's own hold rows; the two kernels take
+the layer's number as a prefetched scalar of their blocks' index.
 """
 from __future__ import annotations
 
@@ -310,19 +313,21 @@ def select_experts(scores, bias, n_group: int, topk_group: int, k: int):
 
 def routed_experts(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
     """The held experts' part of the layer's result for y [T, D], and the
-    counts (pairs computed here, held experts touched, 1, 1 if the kernel
-    multiplied them) as int32 [4]. ``held`` is a stack of layers' held
-    experts, ``[layers, held, ...]``, and ``layer`` this layer's number in it
-    (it may be traced).
+    counts (pairs computed here, held experts touched, 1, 1 if the decode
+    kernel multiplied them, 1 if the grouped kernel did) as int32 [5]. ``held``
+    is a stack of layers' held experts, ``[layers, held, ...]``, and ``layer``
+    this layer's number in it (it may be traced).
 
     Every token-expert pair gets a key: its expert's index among the held
-    ones, or ``held`` if the expert lives elsewhere. Two forms, chosen by the
-    call's static token count (``ops/moe.fused``: under the chip's ridge, on a
-    TPU): a few tokens go through every touched expert whole, weighted by
+    ones, or ``held`` if the expert lives elsewhere. Two loop nests, chosen by
+    the call's static token count (``ops/moe.fused``: under the chip's ridge,
+    on a TPU): a few tokens go through every touched expert whole, weighted by
     their gates (``moe_decode_experts``, one kernel); many are sorted by key,
     a stable sort that puts the pairs of this chip first, grouped by expert,
-    and ``ragged_dot`` multiplies the groups (rows past the last group are not
-    computed; a group of no rows reads no weights: the other layers' experts)."""
+    and the groups are multiplied (``_sorted_experts``: one kernel,
+    ``moe_grouped_experts``, where ``ops/moe.grouped`` says so, else
+    ``ragged_dot``; rows past the last group are not computed; a group of no
+    rows reads no weights: the other layers' experts)."""
     T, D = y.shape
     k, E = cfg.num_experts_per_tok, cfg.held
     experts, gates = route(y, lp, cfg)
@@ -333,37 +338,46 @@ def routed_experts(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
         chose = key[..., None] == jnp.arange(E)  # [T, k, E]
         sizes = jnp.sum(chose, axis=(0, 1)).astype(jnp.int32)
         pairs = jnp.sum(sizes)
-        use_kernel = moe.fused(T, held)
-        if use_kernel:
+        fused = moe.fused(T, held)
+        grouped = not fused and moe.grouped(T, held)
+        if fused:
             w = jnp.sum(jnp.where(chose, gates[..., None], 0.0), axis=1)  # [T, E] float32
             m = moe.moe_decode_experts(y, w, sizes, held, layer)
         else:
-            m = _sorted_experts(y, key, jnp.where(here, gates, 0.0), sizes, held, layer)
+            m = _sorted_experts(y, key, jnp.where(here, gates, 0.0), sizes, held, layer,
+                                moe.moe_grouped_experts if grouped else _ragged_products)
     counts = jnp.stack([pairs, jnp.sum(sizes > 0).astype(jnp.int32), jnp.int32(1),
-                        jnp.int32(use_kernel)])
+                        jnp.int32(fused), jnp.int32(grouped)])
     return m, counts
 
 
-def _sorted_experts(y, key, weight, sizes, held: Params, layer):
-    """``routed_experts``' grouped form. key: [T, k] a pair's held expert (or
-    ``held``: elsewhere); weight: [T, k] float32, 0 for a pair elsewhere;
-    sizes: [held] pairs an expert."""
-    (T, D), k = y.shape, key.shape[1]
-    E = sizes.shape[0]
-    order = jnp.argsort(key.reshape(T * k), stable=True)
-    x = y[order // k]  # [T*k, D]: the token of each sorted pair
-    n_layers = held["e_gate"].shape[0]
+def _ragged_products(x, sizes, held: Params, layer):
+    """``moe_grouped_experts``' numbers by three ``ragged_dot``: the form of a
+    CPU and of widths that are not lane tiles."""
+    n_layers, E = held["e_gate"].shape[:2]
     groups = jax.lax.dynamic_update_slice(
         jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
 
     def dot(a, w):  # w: [layers, E, in, out], as layers x E groups (a bitcast)
         return jax.lax.ragged_dot(a, w.reshape((-1,) + w.shape[2:]).astype(a.dtype), groups)
 
-    out = dot(jax.nn.silu(dot(x, held["e_gate"])) * dot(x, held["e_up"]), held["e_down"])
-    out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None], out, 0)
-    # Back to (token, choice) order; a pair computed elsewhere weighs 0.
+    return dot(jax.nn.silu(dot(x, held["e_gate"])) * dot(x, held["e_up"]), held["e_down"])
+
+
+def _sorted_experts(y, key, weight, sizes, held: Params, layer, products):
+    """``routed_experts``' sorted form. key: [T, k] a pair's held expert (or
+    ``held``: elsewhere); weight: [T, k] float32, 0 for a pair elsewhere;
+    sizes: [held] pairs an expert; products: (the sorted pairs' tokens [T*k,
+    D], sizes, held, layer) -> each row through its group's expert; rows past
+    the last group are nobody's (not computed, maybe never written)."""
+    (T, D), k = y.shape, key.shape[1]
+    order = jnp.argsort(key.reshape(T * k), stable=True)
+    out = products(y[order // k], sizes, held, layer)
+    # Back to (token, choice) order; a pair computed elsewhere weighs 0 and
+    # its row, one past the last group, is not read: no pass of its own zeroes them.
     out = out[jnp.argsort(order)].reshape(T, k, D)
-    return jnp.sum(out.astype(jnp.float32) * weight[..., None], axis=1).astype(y.dtype)
+    weight = weight[..., None]
+    return jnp.sum(jnp.where(weight != 0, out.astype(jnp.float32) * weight, 0), axis=1).astype(y.dtype)
 
 
 def expert_layer(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):

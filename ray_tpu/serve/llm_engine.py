@@ -127,7 +127,8 @@ _WEIGHTS_WIDTH = 256
 # under the chip's ridge: ``ops/moe.fused``). They
 # arrive on the transfers that bring the tokens: a chunk call whose output the
 # host never reads (no segment of it ends a prompt) is not counted.
-_MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_fused_layer_steps")
+_MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_fused_layer_steps",
+               "moe_grouped_layer_steps")
 
 
 @dataclasses.dataclass
@@ -1772,6 +1773,7 @@ class LLMEngine:
                 ("moe_experts_touched", m.engine_moe_experts_touched),
                 ("moe_layer_steps", m.engine_moe_layer_steps),
                 ("moe_fused_layer_steps", m.engine_moe_fused_layer_steps),
+                ("moe_grouped_layer_steps", m.engine_moe_grouped_layer_steps),
                 ("emit_batches", m.engine_emit_batches),
                 ("state_slots_live", m.engine_state_slots_live),
                 ("state_slots_table", m.engine_state_slots_table),
@@ -1880,6 +1882,7 @@ class LLMEngine:
                 "experts_touched": self.stats["moe_experts_touched"],
                 "layer_steps": self.stats["moe_layer_steps"],
                 "fused_layer_steps": self.stats["moe_fused_layer_steps"],
+                "grouped_layer_steps": self.stats["moe_grouped_layer_steps"],
                 "experts_touched_per_layer_step": self.stats["moe_experts_touched"]
                 / max(1, self.stats["moe_layer_steps"]),
                 "pairs_per_touched_expert": self.stats["moe_pairs_here"]

@@ -241,6 +241,12 @@ class _ServeMetrics:
             "kernel multiplied (moe_decode_experts: a call under the chip's ridge)",
             dr,
         )
+        self.engine_moe_grouped_layer_steps = Counter(
+            "serve_engine_moe_grouped_layer_steps_total",
+            "Of serve_engine_moe_layer_steps_total, those whose sorted pairs one "
+            "kernel multiplied (moe_grouped_experts: a call over the chip's ridge)",
+            dr,
+        )
         self.engine_emit_batches = Counter(
             "serve_engine_emit_batches_total",
             "Queue entries the engine handed its requests (a window's tokens of a "

@@ -868,10 +868,12 @@ def test_kda_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
         sds((2,), np.uint32), sds((b,), np.int32)).compile()
     # The chunk scan is plain XLA (no kernel yet): the widest thing a 1,024-token
     # call holds are a layer's sub-tile decays, 67 MB, and its projections.
-    # (a call of 1,024 tokens is over the ridge: its experts are the grouped products,
-    # kernels of the compiler's own, reading the same stacks in the same layout)
-    assert _kernel_names(compiled.as_text()).count("latent_prefill_attend") == 1
-    assert "moe_decode_experts" not in compiled.as_text()
+    # A call of 1,024 tokens is over the ridge: each of the period's six expert
+    # layers is ONE ``moe_grouped_experts`` over the sorted pairs, reading the
+    # same stacks in the same layout, and no grouped product of the compiler's is left.
+    names = _kernel_names(compiled.as_text())
+    assert (names.count("latent_prefill_attend"), names.count("moe_grouped_experts")) == (1, 6)
+    assert "moe_decode_experts" not in compiled.as_text() and "ragged-dot" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
 
 
@@ -911,6 +913,32 @@ def test_moe_decode_experts_compiles_for_v5e_at_the_published_widths(v5e, widths
     assert compiled.memory_analysis().temp_size_in_bytes < D * F  # half a matrix of bfloat16
 
 
+@pytest.mark.parametrize("widths", list(_MOE_WIDTHS))
+@pytest.mark.parametrize("T", [512, 1024])
+def test_moe_grouped_experts_compiles_for_v5e_at_the_published_widths(v5e, widths, T):
+    """``moe_grouped_experts`` at the two served models' published widths, over
+    the eight sorted pairs a token of a chunk call: one kernel, the stacks read
+    where they lie (temporaries far under ONE expert's matrix, let alone a
+    layer's experts: no copy, no relayout of ``[layers, held, in, out]``)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import moe
+
+    L, E, D, F = _MOE_WIDTHS[widths]
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    held = {"e_gate": sds((L, E, D, F), jnp.bfloat16), "e_up": sds((L, E, D, F), jnp.bfloat16),
+            "e_down": sds((L, E, F, D), jnp.bfloat16)}
+    assert moe.grouped(T, held) and not moe.fused(T, held) and not moe.grouped(moe.RIDGE_TOKENS, held)
+    compiled = jax.jit(moe.moe_grouped_experts).lower(
+        sds((T * 8, D), jnp.bfloat16), sds((E,), jnp.int32), held, sds((), jnp.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == ["moe_grouped_experts"]
+    assert compiled.memory_analysis().temp_size_in_bytes < D * F  # half a matrix of bfloat16
+
+
 _MOE_KERNEL_AGAINST_GROUPED_FORM = """
 import jax, jax.numpy as jnp, numpy as np
 from ray_tpu.models import latent_moe as lm
@@ -931,40 +959,44 @@ for name, (L, cfg) in WIDTHS.items():
                             "e_down": normal(ks[2], (L, E, F, D), F)})()
     lp = {"router": normal(ks[3], (D, cfg.n_routed_experts), D)}
 
-    def run(kernel):
+    def run(form):
         def f(y, lp, held):
-            was, moe.fused = moe.fused, (lambda T, held: kernel)
+            was = moe.fused, moe.grouped
+            moe.fused, moe.grouped = (lambda T, held: form == "decode"), (lambda T, held: form == "grouped")
             try:
                 return lm.routed_experts(y, lp, cfg, held, 1)
             finally:
-                moe.fused = was
+                moe.fused, moe.grouped = was
         return jax.jit(f)
 
-    for T in (32, 64, 128):
-        assert moe.fused(T, held)
+    for T in (32, 64, 128, 1024):
+        form = "decode" if T <= moe.RIDGE_TOKENS else "grouped"
+        assert moe.fused(T, held) == (form == "decode") and moe.grouped(T, held) == (form == "grouped")
         y = jax.random.normal(jax.random.fold_in(ks[4], T), (T, D), jnp.bfloat16)
-        assert "moe_decode_experts" in jax.jit(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1)).lower(y, lp, held).as_text()
-        want, counts = run(False)(y, lp, held)
-        got, counts_kernel = run(True)(y, lp, held)
+        text = jax.jit(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1)).lower(y, lp, held).as_text()
+        assert f"moe_{form}_experts" in text and "ragged_dot" not in text
+        want, counts = run("ragged")(y, lp, held)
+        got, counts_kernel = run(form)(y, lp, held)
         assert np.array_equal(np.asarray(counts)[:3], np.asarray(counts_kernel)[:3]), (counts, counts_kernel)
+        assert np.asarray(counts_kernel)[3:].tolist() == [int(form == "decode"), int(form == "grouped")]
         assert 0 < int(counts[1]) <= E and int(counts[0]) < T * 8  # some pairs live elsewhere
         want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
         apart = np.abs(got - want).max() / np.abs(want).max()
-        assert np.isfinite(got).all() and apart < 2**-5, (name, T, apart)  # both round to bfloat16, the grouped form each product
+        assert np.isfinite(got).all() and apart < 2**-5, (name, T, apart)  # both round to bfloat16, the ragged_dot form each product
         none = np.asarray(lm.route(y, lp, cfg)[0])
         none = ~((none >= cfg.held_first) & (none < cfg.held_first + E)).any(-1)
         assert not got[none].any()  # a token with no expert here gets exactly nothing
-        print(name, "T", T, "pairs", int(counts[0]), "touched", int(counts[1]), "of", E, "apart", apart)
+        print(name, "T", T, form, "pairs", int(counts[0]), "touched", int(counts[1]), "of", E, "apart", apart)
     del held
 """
 
 
 def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_the_chip():
-    """``routed_experts`` through ``moe_decode_experts`` against its sorted
-    ``ragged_dot`` form on a chip, at both served models' published widths (the
-    second layer of a stack of two, a share that does not begin at expert 0,
-    routing by the model's own router), T = 32, 64, 128: the counts equal, the
-    numbers within bfloat16's rounding. In a process of its own: this one is
+    """``routed_experts`` through its kernels against its sorted ``ragged_dot``
+    form on a chip, at both served models' published widths (the second layer
+    of a stack of two, a share that does not begin at expert 0, routing by the
+    model's own router): ``moe_decode_experts`` at T = 32, 64, 128 and the
+    sorted pairs through ``moe_grouped_experts`` at T = 1,024: the counts equal, the numbers within bfloat16's rounding. In a process of its own: this one is
     held to the CPU (conftest)."""
     from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 

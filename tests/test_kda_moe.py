@@ -355,26 +355,30 @@ def test_a_places_stack_gives_a_layer_its_own_periods_experts(model):
 
 
 @pytest.mark.parametrize("name", ML.ROUTINGS)
-@pytest.mark.parametrize("T", [1, 8, 64, 128])
-def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_a_places_stack(model, monkeypatch, T, name):
+@pytest.mark.parametrize("T,form", ML.KERNEL_CASES)
+def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_a_places_stack(model, monkeypatch, T, form, name):
     """Held experts 4-11 of 16, two a token: the period's third place, its
-    second period (the kernel is handed the place's stack and the period's number)."""
+    second period (a kernel is handed the place's stack and the period's number;
+    the grouped kernel's is traced, as the period's scan hands it over)."""
     _dims, _key, cfg, params, _bias = model
-    ML.kernel_against_grouped(monkeypatch, cfg, params["experts"][2], 1, T, name)
+    ML.kernel_against_grouped(monkeypatch, cfg, params["experts"][2], 1, T, name, form, traced=form == "grouped")
 
 
-@pytest.mark.parametrize("T,kernel", [(64, True), (240, True), (241, False)])
-def test_the_token_count_alone_chooses_the_form_for_this_model_too(monkeypatch, T, kernel):
+@pytest.mark.parametrize("T,width,tpu,form", [
+    (64, 128, True, "decode"), (240, 128, True, "decode"), (241, 128, True, "grouped"),
+    (1024, 128, True, "grouped"), (1024, 64, True, "ragged"), (1024, 128, False, "ragged")])
+def test_the_token_count_alone_chooses_the_form_for_this_model_too(monkeypatch, T, width, tpu, form):
     """The same rule as ``test_latent_moe``'s, from this model's configuration
-    object: nothing of the model is asked, only the call's static token count."""
-    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
-    cfg = km.KDAMoEConfig.tiny(hidden_size=128, moe_intermediate_size=128, held_first=4, held_count=8)
+    object: nothing of the model is asked, only the call's static token count
+    (and where it runs, and whether the widths tile)."""
+    if tpu:
+        monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+    cfg = km.KDAMoEConfig.tiny(hidden_size=width, moe_intermediate_size=width, held_first=4, held_count=8)
     held = {name: jax.ShapeDtypeStruct(shape, jnp.bfloat16) for name, shape in km.expert_shapes(cfg).items()}
-    lp = {"router": jax.ShapeDtypeStruct((128, cfg.num_experts), jnp.bfloat16),
+    lp = {"router": jax.ShapeDtypeStruct((width, cfg.num_experts), jnp.bfloat16),
           "expert_bias": jax.ShapeDtypeStruct((cfg.num_experts,), jnp.float32)}
-    text = str(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 0))(
-        jax.ShapeDtypeStruct((T, 128), jnp.bfloat16), lp, held))
-    assert ("moe_decode_experts" in text, len(re.findall(r"= ragged_dot_general\[", text))) == ((True, 0) if kernel else (False, 3))
+    assert ML.form_of(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 0))(
+        jax.ShapeDtypeStruct((T, width), jnp.bfloat16), lp, held)) == form
 
 
 def test_the_fitted_bias_levels_this_chips_share_on_tokens_it_never_saw():

@@ -207,7 +207,7 @@ def test_the_counters_add_up_over_a_known_batch(model):
     experts = np.asarray(lm.route(y, lp, cfg)[0])
     here = (experts >= 2) & (experts < 6)
     _, counts = lm.routed_experts(y, lp, cfg, params["experts"], 1)
-    assert [int(c) for c in counts] == [int(here.sum()), len(set(experts[here].tolist())), 1, 0]
+    assert [int(c) for c in counts] == [int(here.sum()), len(set(experts[here].tolist())), 1, 0, 0]
 
 
 # --- the decode kernel of ops/moe.py under the interpreter --------------------
@@ -233,32 +233,60 @@ def routing(name: str, T: int, cfg, seed: int = 0):
     return jnp.asarray(experts, jnp.int32), jnp.asarray(rng.uniform(0.1, 1.5, (T, k)), jnp.float32)
 
 
-def kernel_against_grouped(monkeypatch, cfg, held, layer, T, name):
-    """``routed_experts`` through ``moe_decode_experts`` (the Pallas interpreter)
-    against its sorted ``ragged_dot`` form on the same routing: in float32 the
-    same numbers but for the order of the sums, in bfloat16 within its
-    rounding (the grouped form rounds each product, the kernel only what goes
-    into the down projection); the three counts equal, the fourth says which."""
+ROW_TILE = 16  # of the grouped kernel under the interpreter: a tile of bfloat16's sublanes
+
+
+def kernel_against_grouped(monkeypatch, cfg, held, layer, T, name, form="decode", traced=False):
+    """``routed_experts`` through a kernel of ``ops/moe.py`` (the Pallas
+    interpreter) against its sorted ``ragged_dot`` form on the same routing:
+    ``moe_decode_experts`` (``form`` "decode") or the sorted pairs through
+    ``moe_grouped_experts`` in row tiles of 16 ("grouped"; ``traced``: under a
+    jit that is handed the layer's number). In float32 the same numbers but for
+    the order of the sums, in bfloat16 within its rounding (the ``ragged_dot``
+    form rounds each product, a kernel only what goes into the down
+    projection); the three counts equal, the last two say which form ran. What
+    the grouped kernel returns past its last group is 0 to the end of the row tile."""
     import functools
 
     from ray_tpu.ops import moe
 
     chosen = routing(name, T, cfg)
     monkeypatch.setattr(lm, "route", lambda y, lp, cfg: chosen)
+    kernel = functools.partial(moe.moe_grouped_experts, row_tile=ROW_TILE, interpret=True)
     for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)):
         y = jax.random.normal(jax.random.PRNGKey(T), (T, held["e_gate"].shape[2]), jnp.float32).astype(dtype)
         weights = jax.tree.map(lambda a: a.astype(dtype), held)
         with monkeypatch.context() as m:
             m.setattr(moe, "fused", lambda T, held: False)
             want, counts = lm.routed_experts(y, {}, cfg, weights, layer)
+        returned = []
+
+        def grouped_products(x, sizes, held, layer):
+            out = kernel(x, sizes, held, layer)
+            returned.append((out, sizes))
+            return out
+
         with monkeypatch.context() as m:
-            m.setattr(moe, "fused", lambda T, held: T <= moe.RIDGE_TOKENS)
+            m.setattr(moe, "fused", lambda T, held: form == "decode" and T <= moe.RIDGE_TOKENS)
+            m.setattr(moe, "grouped", lambda T, held: form == "grouped")
             m.setattr(moe, "moe_decode_experts", functools.partial(moe.moe_decode_experts, interpret=True))
-            got, counts_kernel = lm.routed_experts(y, {}, cfg, weights, layer)
+            m.setattr(moe, "moe_grouped_experts", grouped_products)
+            if traced:
+                got, counts_kernel = jax.jit(lambda y, layer: lm.routed_experts(y, {}, cfg, weights, layer))(
+                    y, jnp.int32(layer))
+            else:
+                got, counts_kernel = lm.routed_experts(y, {}, cfg, weights, layer)
         assert got.dtype == want.dtype == dtype and got.shape == (T, y.shape[1])
         want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
         assert np.abs(got - want).max() <= tol * max(1.0, np.abs(want).max()), (dtype, np.abs(got - want).max())
-        assert np.array_equal(counts[:3], counts_kernel[:3]) and (int(counts[3]), int(counts_kernel[3])) == (0, 1)
+        assert np.array_equal(counts[:3], counts_kernel[:3])
+        assert ([int(c) for c in counts[3:]], [int(c) for c in counts_kernel[3:]]) == (
+            [0, 0], [1, 0] if form == "decode" else [0, 1])
+        assert len(returned) == (form == "grouped")
+        for out, sizes in () if traced else returned:
+            assert out.shape == (T * cfg.num_experts_per_tok, y.shape[1]) and int(jnp.sum(sizes)) == int(counts[0])
+            pairs = int(counts[0])  # 0 from the last pair to the end of its row tile
+            assert not np.asarray(out[pairs:-(-pairs // ROW_TILE) * ROW_TILE], np.float32).any()
         if name == "none_here":
             assert not got.any() and int(counts[0]) == 0
         else:
@@ -269,31 +297,57 @@ def kernel_against_grouped(monkeypatch, cfg, held, layer, T, name):
 ROUTINGS = ("elsewhere", "untouched", "one_expert", "none_here")
 
 
+# The grouped kernel's cases, in row tiles of 16 over two pairs a token: 24
+# tokens are three tiles that "elsewhere"'s uneven groups cross and end inside
+# (rows past the last group); 64 on "untouched" are two groups of four whole
+# tiles and two of no rows; 300 on "one_expert" one group of 300 rows in 19
+# tiles and as many rows elsewhere; "none_here" no group at all.
+KERNEL_CASES = [(1, "decode"), (8, "decode"), (64, "decode"), (128, "decode"),
+                (24, "grouped"), (64, "grouped"), (300, "grouped")]
+
+
 @pytest.mark.parametrize("name", ROUTINGS)
-@pytest.mark.parametrize("T", [1, 8, 64, 128])
-def test_moe_decode_kernel_reads_the_grouped_forms_numbers(model, monkeypatch, T, name):
+@pytest.mark.parametrize("T,form", KERNEL_CASES)
+def test_moe_decode_kernel_reads_the_grouped_forms_numbers(model, monkeypatch, T, form, name):
     """Held experts 2-5 of 8, two a token, the second expert layer of the stack."""
     _dims, _key, cfg, params = model
-    counts = kernel_against_grouped(monkeypatch, cfg, params["experts"], 1, T, name)
+    counts = kernel_against_grouped(monkeypatch, cfg, params["experts"], 1, T, name, form)
     assert int(counts[1]) == {"untouched": 2, "one_expert": 1, "none_here": 0}.get(name, int(counts[1]))
 
 
-@pytest.mark.parametrize("T,kernel", [(1, True), (64, True), (240, True), (241, False), (1024, False)])
-def test_the_token_count_alone_chooses_the_form(monkeypatch, T, kernel):
-    """On a TPU (forced: the lowering is never run) ``routed_experts`` of a call
-    of up to ``RIDGE_TOKENS`` tokens is the kernel and no ``ragged_dot``; of one
-    token more, three ``ragged_dot`` and no kernel. Nothing else is asked."""
+# (tokens, hidden = expert width, on a TPU) -> the form
+FORM_CASES = [(1, 128, True, "decode"), (64, 128, True, "decode"), (240, 128, True, "decode"),
+              (241, 128, True, "grouped"), (1024, 128, True, "grouped"),
+              (1024, 64, True, "ragged"), (64, 64, True, "ragged"), (1024, 128, False, "ragged"),
+              (64, 128, False, "ragged")]
+
+
+def form_of(routed) -> str:
+    """Which form ``routed_experts`` traced to, from the jaxpr of ``routed``."""
+    text = str(routed)
+    kernels = [name for name in ("moe_decode_experts", "moe_grouped_experts") if name in text]
+    dots = len(re.findall(r"= ragged_dot_general\[", text))
+    assert ("pallas_call" in text, dots) == ((True, 0) if kernels else (False, 3)) and len(kernels) <= 1
+    return kernels[0].split("_")[1] if kernels else "ragged"
+
+
+@pytest.mark.parametrize("T,width,tpu,form", FORM_CASES)
+def test_the_token_count_alone_chooses_the_form(monkeypatch, T, width, tpu, form):
+    """On a TPU (forced: the lowering is never run) at widths that are whole
+    lane tiles, ``routed_experts`` of a call of up to ``RIDGE_TOKENS`` tokens is
+    the decode kernel, of one token more the grouped kernel over the sorted
+    pairs, and neither has a ``ragged_dot``; on a CPU, and at widths that do not
+    tile, three ``ragged_dot`` and no kernel. Nothing else is asked."""
     from ray_tpu.ops import moe
 
     assert moe.RIDGE_TOKENS == 240
-    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
-    cfg = lm.LatentMoEConfig.tiny(hidden_size=128, moe_intermediate_size=128, held_first=2, held_count=4)
+    if tpu:
+        monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+    cfg = lm.LatentMoEConfig.tiny(hidden_size=width, moe_intermediate_size=width, held_first=2, held_count=4)
     held = {name: jax.ShapeDtypeStruct(shape, jnp.bfloat16) for name, shape in lm.expert_shapes(cfg).items()}
-    lp = {"router": jax.ShapeDtypeStruct((128, cfg.n_routed_experts), jnp.bfloat16)}
-    text = str(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1))(
-        jax.ShapeDtypeStruct((T, 128), jnp.bfloat16), lp, held))
-    assert ("pallas_call" in text, len(re.findall(r"= ragged_dot_general\[", text))) == ((True, 0) if kernel else (False, 3))
-    assert ("moe_decode_experts" in text) == kernel
+    lp = {"router": jax.ShapeDtypeStruct((width, cfg.n_routed_experts), jnp.bfloat16)}
+    assert form_of(jax.make_jaxpr(lambda y, lp, held: lm.routed_experts(y, lp, cfg, held, 1))(
+        jax.ShapeDtypeStruct((T, width), jnp.bfloat16), lp, held)) == form
 
 
 def _deficits(ref, served):
@@ -340,6 +394,9 @@ def test_engine_serves_the_references_logits_with_cache_chunks_preemption_and_re
     assert s["moe_layer_steps"] % expert_layers == 0
     assert 0 < s["moe_experts_touched"] <= dims.held * s["moe_layer_steps"]
     assert s["moe_experts_touched"] <= s["moe_pairs_here"] <= 4 * 2 * 16 * s["moe_layer_steps"]
+    # On a CPU every counted layer ran ``ragged_dot``: neither kernel's count moved.
+    assert (s["moe_fused_layer_steps"], s["moe_grouped_layer_steps"]) == (0, 0)
+    assert eng.report_state()["moe"]["grouped_layer_steps"] == 0
     _assert_every_served_token_is_the_references_choice(key, dims, prompts, reqs)
 
 
