@@ -16,7 +16,9 @@ re-designed for XLA's static-shape world:
   latent-attention decoder (``models/latent_moe.py``) ONE pool whose row
   is the token's latent and rotary key; the hybrid state-space decoder
   (``models/hybrid_ssm.py``) ``k`` and ``v`` for its few attention layers
-  and two slot pools for its many state-space ones. Block 0 is a reserved
+  and two slot pools for its many state-space ones; the power retention
+  decoder (``models/power_retention.py``) ONE slot pool and no pool of
+  blocks at all. Block 0 is a reserved
   trash block that idle decode slots harmlessly write to, so the decode
   step never branches on slot liveness for a pool of blocks. A pool of
   slots has no trash row: a recurrent update is not idempotent, so an idle
@@ -131,7 +133,10 @@ class PagedModel(NamedTuple):
     # ``bases`` a tuple beside it: the first unit (block, or slot row) of the
     # first of this call's layers in that pool, the next layer's ``units``
     # further. A layer addresses block ``tables[i, j]`` at ``bases[k] +
-    # tables[i, j]`` and slot ``i``'s row at ``bases[k] + i``. ``lp`` is the
+    # tables[i, j]`` and slot ``i``'s row at ``bases[k] + i``. A model with no
+    # pool of blocks is handed ``tables`` (and a chunk call's ``table_rows`` and
+    # ``rows_at``) with NO column: there is no block to address, and the row a
+    # slot is all it reads. ``lp`` is the
     # body's own slice of ``lead`` or ``layers``; ``params`` the whole tree
     # and ``index`` the number of the call (leading layers, then scanned
     # bodies), for what a model keeps outside the scanned stack (weights a
@@ -181,6 +186,12 @@ def init_paged_cache(cfg, pcfg: PagedConfig) -> PagedCache:
 def slot_pools(cfg) -> Tuple[str, ...]:
     """The model's pools that hold one row a decode slot (recurrent state)."""
     return tuple(name for name, pool in paged_model(cfg).pools.items() if pool.unit == "slots")
+
+
+def block_pools(cfg) -> Tuple[str, ...]:
+    """The model's pools that hold blocks of tokens. None: every layer keeps its
+    past by slot, and the block tables have no column (``PagedModel``)."""
+    return tuple(name for name, pool in paged_model(cfg).pools.items() if pool.unit == "blocks")
 
 
 def _scan_layers(layer, x, params: Params, cache: PagedCache, declared: Dict[str, Pool]):
@@ -340,8 +351,10 @@ def paged_decode_loop(
     # would advance it: hold it at 0 all through the window, so that it reads
     # and writes one position of the trash block however long it has idled,
     # and a layer that keeps state by SLOT knows to leave that slot's alone
-    # (a live row's ``lens`` is its prompt's length or more, never 0).
-    idle = tables[:, 0] == TRASH_BLOCK
+    # (a live row's ``lens`` is its prompt's length or more, never 0). A
+    # model with no pool of blocks has a table of no column: there the host's
+    # 0 is all that says a row is idle, and whoever chains windows keeps it 0.
+    idle = tables[:, 0] == TRASH_BLOCK if tables.shape[1] else lens == 0
     lens = jnp.where(idle, 0, lens)
     seq, total = [], None
     for _ in range(n_steps):

@@ -200,8 +200,8 @@ def attention_block(
     return out
 
 
-def mlp_block(x, lp: Params, cfg: TransformerConfig):
-    h = rms_norm(x, lp["mlp_norm"])
+def mlp_block(x, lp: Params, cfg: TransformerConfig, eps: float = 1e-5):
+    h = rms_norm(x, lp["mlp_norm"], eps)
     if cfg.num_experts:
         return x + _moe_mlp(h, lp, cfg)
     gate = jax.nn.silu(h @ lp["w_gate"].astype(h.dtype))

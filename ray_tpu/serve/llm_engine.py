@@ -65,6 +65,7 @@ from jax.experimental.layout import Format, Layout
 from ray_tpu.models.paged import (
     TRASH_BLOCK,
     PagedConfig,
+    block_pools,
     chunk_tile,
     init_paged_cache,
     paged_decode_loop,
@@ -535,6 +536,12 @@ class LLMEngine:
                 "a request that skipped a shared prefix would begin from a state that never saw "
                 "it. It needs snapshots of the state at block boundaries, which nothing keeps")
         self.prefix_cache = _PrefixCache() if enable_prefix_cache else None
+        # A model none of whose pools holds blocks of tokens (every layer keeps
+        # its past by slot) is served with no block at all: none allocated,
+        # tabled, shipped, counted or preempted for. ``tables`` has no column,
+        # admission is by free slot, and ``max_seq_len`` alone bounds a request.
+        self._blocks = bool(block_pools(cfg))
+        self._table_width = p.max_blocks_per_seq if self._blocks else 0
         # The widths a prompt or a chunk call is padded to: block-multiple
         # powers of two, then the table's whole length. O(log max_seq_len)
         # compilations per program.
@@ -574,7 +581,7 @@ class LLMEngine:
         # would otherwise pass a bare request-identity check and receive
         # the stale speculated window's tokens twice).
         self._slot_gen = [0] * p.max_batch
-        self.tables = np.full((p.max_batch, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
+        self.tables = np.full((p.max_batch, self._table_width), TRASH_BLOCK, np.int32)
         self.lens = np.zeros(p.max_batch, np.int32)
         self.temps = np.zeros(p.max_batch, np.float32)
         self._dev: Dict[str, Optional[jax.Array]] = {
@@ -684,7 +691,7 @@ class LLMEngine:
         both programs copy-free. There is no plain-jit fallback: a
         failure here is the failure to report (at 7B the fallback's own
         program does not fit the chip and would bury it under an OOM)."""
-        cfg, p, window = self.cfg, self.pcfg, self.window
+        cfg, p, window, blocks = self.cfg, self.pcfg, self.window, self._blocks
         bs = p.block_size
 
         def _decode(params, tokens, cache, tables, lens, temps, key):
@@ -695,7 +702,10 @@ class LLMEngine:
             # lens) as DEVICE outputs: chained windows and speculative
             # dispatch re-upload nothing from the host. (Rows past the
             # window's, where there are any, are counts: ``_MOE_COUNTS``.)
-            return seq, seq[window - 1], lens + window, cache
+            # With no table to say which rows are idle, ``lens`` 0 says it, and
+            # the chain must leave it 0 (``paged_decode_loop``).
+            ahead = lens + window if blocks else jax.numpy.where(lens > 0, lens + window, 0)
+            return seq, seq[window - 1], ahead, cache
 
         # A prefill program also puts what it sampled into the device's
         # ``cur``, at the slot it sampled it for, so the decode window that
@@ -720,7 +730,7 @@ class LLMEngine:
             return toks, cache, cur.at[slot_of].set(toks[:slot_of.shape[0]], mode="drop")
 
         sds = jax.ShapeDtypeStruct
-        b, W = p.max_batch, p.max_blocks_per_seq
+        b, W = p.max_batch, self._table_width
         if callable(params):
             params_s = jax.eval_shape(params)
         else:
@@ -831,7 +841,7 @@ class LLMEngine:
         # own blocks.
         overshoot = self.window * (2 if self.overlap else 1) - 1
         total = len(req.prompt) + max_new_tokens + overshoot
-        worst_blocks = -(-total // self.pcfg.block_size)
+        worst_blocks = -(-total // self.pcfg.block_size) if self._blocks else 0
         if total > self.pcfg.max_seq_len or worst_blocks > self.pcfg.usable_blocks:
             req.error = (
                 f"prompt({len(req.prompt)}) + max_new_tokens({max_new_tokens}) "
@@ -1046,6 +1056,8 @@ class LLMEngine:
         and a request preempted before the host has read its first token
         loses it and pays its prefill again: the host reads them first
         (``step``)."""
+        if not self._blocks:
+            return True  # no pool of blocks: nothing to own
         bs = self.pcfg.block_size
         for i in range(len(self.slots)):
             while self.slots[i] is not None and i not in self._prefilling:
@@ -1105,7 +1117,7 @@ class LLMEngine:
             self._want(req)  # it arrived after the look at the queue's tail
         full = req.full_prompt
         plen = len(full)
-        real_blocks = -(-plen // bs)  # ceil
+        real_blocks = -(-plen // bs) if self._blocks else 0  # ceil
         hits: List[int] = []
         if self.prefix_cache is not None:
             # Pin hits BEFORE allocating — the allocation may evict
@@ -1266,8 +1278,8 @@ class LLMEngine:
             tile = chunk_tile(width, bs)
             n = width // tile
             toks = np.zeros((1, width), np.int32)
-            trows = np.full((n, p.max_blocks_per_seq), TRASH_BLOCK, np.int32)
-            crow = np.full(width // bs, TRASH_BLOCK, np.int32)
+            trows = np.full((n, self._table_width), TRASH_BLOCK, np.int32)
+            crow = np.full(width // bs if self._blocks else 0, TRASH_BLOCK, np.int32)
             # By tile: its first absolute position; by segment k: the axis
             # position of its last token, and whose ``cur`` its sampled token
             # is (no slot's, unless the segment ends its prompt); by tile
@@ -1436,7 +1448,9 @@ class LLMEngine:
         them."""
         for name, host in (("tables", self.tables), ("lens", self.lens),
                            ("temps", self.temps)):
-            if self._dev[name] is None or name in self._dirty:
+            # (A table with no column is handed over once, empty: nothing of
+            # it can be out of date.)
+            if self._dev[name] is None or (name in self._dirty and host.size):
                 self._dev[name] = self._hand_over(host)
                 self._dirty.discard(name)
                 self.stats["h2d_ships"] += 1
@@ -1478,10 +1492,11 @@ class LLMEngine:
             # Blocks the occupied slots' tokens lie in as the window starts,
             # against the table the plain form of decode attention gathers whole.
             occupied = [i for i, _rid, _gen in entries]
-            self.stats["decode_blocks_live"] += int(
-                (self.lens[occupied] // self.pcfg.block_size + 1).sum())
-            self.stats["decode_blocks_table"] += (
-                self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
+            if self._blocks:
+                self.stats["decode_blocks_live"] += int(
+                    (self.lens[occupied] // self.pcfg.block_size + 1).sum())
+                self.stats["decode_blocks_table"] += (
+                    self.pcfg.max_batch * self.pcfg.max_blocks_per_seq)
             if self._state_pools:
                 # Rows of state the window's steps read and write, of those held.
                 self.stats["state_slots_live"] += len(entries)

@@ -1106,3 +1106,166 @@ def test_kda_kernel_reads_the_plain_forms_numbers_on_the_chip():
                        env=env, capture_output=True, text=True, timeout=600)
     print(r.stdout[-3000:])
     assert r.returncode == 0, r.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# The power retention decoder (models/power_retention.py)
+# ---------------------------------------------------------------------------
+def test_power_programs_read_weights_and_the_pool_where_they_lie_on_v5e(v5e):
+    """The served cut of the published model (eight layers at every published
+    width, 16 slots; the vocabulary cut for the compile's sake) in the decode
+    window and the chunk program as ``LLMEngine`` builds them for a model with
+    no pool of blocks (a table, and a row of blocks, with NO column): one state
+    update kernel a step in the window, and temporaries far under the pool's own
+    4.63 GB in both (the window's: the kernel's rows and partial sums; the chunk
+    program's: a tile's ``phi`` of 128 x 40 queries, 170 MB, and the state that
+    goes from tile to tile): no pool is gathered or copied (a gather of the
+    tiles' rows copied it whole: 3.6 GB, and the program did not fit)."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import power_retention as pr
+    from ray_tpu.models.paged import (PagedConfig, chunk_tile, init_paged_cache,
+                                      paged_decode_loop, prefill_chunk_and_sample)
+
+    cfg = pr.PowerRetentionConfig(num_hidden_layers=8, vocab_size=2048)
+    p = PagedConfig(block_size=32, num_blocks=1, max_batch=16, max_blocks_per_seq=1024)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: pr.init_params(k, cfg), jax.random.PRNGKey(0)))
+    auto = jax.tree.map(lambda a: Format(Layout.AUTO, one), params)
+    cache = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_paged_cache(cfg, p)))
+    assert cache["power"].shape == (8, 16, 8, 136, 8320)
+    b, bs = p.max_batch, p.block_size
+
+    def decode(params, tokens, cache, tables, lens, temps, key):
+        return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
+
+    compiled = jax.jit(decode, donate_argnums=(2,), in_shardings=(auto,) + (None,) * 6).lower(
+        params, sds((b,), np.int32), cache, sds((b, 0), np.int32), sds((b,), np.int32),
+        sds((b,), np.float32), sds((2,), np.uint32)).compile()
+    assert _kernel_names(compiled.as_text()).count("power_state_update") == 2  # one a step: the layers are scanned
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
+    (params_fmt, *_), _ = compiled.input_formats
+    width = 1024
+    n = width // chunk_tile(width, bs)
+    assert n == 8
+
+    def chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+        starts, last_idx, slot_of, live, state_of = per_tile
+        toks, cache = prefill_chunk_and_sample(
+            params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx, live,
+            state_of, temps, key)
+        return toks, cache, cur.at[slot_of].set(toks[:n], mode="drop")
+
+    compiled = jax.jit(chunk, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 8).lower(
+        params, sds((1, width), np.int32), cache, sds((n, 0), np.int32),
+        sds((0,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
+        sds((2,), np.uint32), sds((b,), np.int32)).compile()
+    assert "power_state_update" not in compiled.as_text()  # the chunk scan is plain XLA: no kernel yet
+    assert compiled.memory_analysis().temp_size_in_bytes < 768 * 2**20
+
+
+_POWER_KERNEL_AGAINST_PLAIN_FORM = """
+import jax, jax.numpy as jnp, numpy as np
+from ray_tpu.ops import power_retention as ops
+
+assert jax.default_backend() == "tpu", jax.default_backend()
+ks = jax.random.split(jax.random.PRNGKey(1), 8)
+# the state update at the served shape (16 slots of 8 states of 136 x 8,320, five queries a state), the
+# second of three layers; the pool what a past of a few tokens leaves, so that a read is of the values' size
+b, H, G, d = 16, 8, 5, 128
+past_k, past_v = jax.random.normal(ks[0], (3 * b, H, 6, d)), jax.random.normal(ks[1], (3 * b, H, 6, d))
+pool = jax.jit(lambda k, v: jnp.einsum("rhtv,rhtp->rhvp", ops.with_one(v), ops.expand(k), precision="highest"))(past_k, past_v)
+g = 1 - jnp.exp(jax.random.uniform(ks[2], (b, H), jnp.float32, np.log(5e-4), np.log(0.1)))
+k, v = jax.random.normal(ks[3], (b, H, d)), jax.random.normal(ks[4], (b, H, d))
+q = jax.random.normal(ks[5], (b, H, G, d))
+assert ops._tiles(pool, q)
+assert "power_state_update" in jax.jit(ops.power_update).lower(pool, jnp.int32(b), jnp.ones(b, jnp.int32), g, k, q, v).as_text()
+scattered = np.asarray(jax.random.bernoulli(ks[6], 0.6, (b,))).astype(np.int32) * 7
+scattered[:3] = 0
+plain, kernel = jax.jit(ops.reference_power_update), jax.jit(ops.power_update)
+for name, lens in (("all live", np.ones(b, np.int32)), ("idle rows scattered", scattered),
+                   ("one live", np.eye(b, dtype=np.int32)[b - 5] * 5), ("none live", np.zeros(b, np.int32))):
+    lens_ = jnp.asarray(lens, jnp.int32)
+    want_pool, want_y = plain(pool, jnp.int32(b), lens_, g, k, q, v)
+    got_pool, got_y = kernel(pool, jnp.int32(b), lens_, g, k, q, v)
+    skipped = np.flatnonzero(lens == 0)
+    got, want = np.asarray(got_pool), np.asarray(want_pool)
+    assert np.array_equal(got[b + skipped], np.asarray(pool)[b + skipped]), name
+    assert np.array_equal(got[:b], np.asarray(pool)[:b]) and np.array_equal(got[2 * b:], np.asarray(pool)[2 * b:])
+    assert not np.asarray(got_y)[skipped].any()
+    apart_s = np.abs(got - want).max() / np.abs(want).max()
+    assert apart_s < 1e-6, (name, apart_s)
+    apart = np.abs(np.asarray(got_y) - np.asarray(want_y)).max() / max(1e-9, np.abs(np.asarray(want_y)).max())
+    assert apart < 1e-4, (name, apart)
+    print("power_state_update", name, int((lens > 0).sum()), "live: state apart", apart_s, "y apart", apart)
+
+# the chunk scan's plain form on the chip against the recurrence token by token, at the served widths:
+# 8 tiles of 128; slot 5 takes up its stored row over two tiles (the second partly padding), slot 2
+# begins from nothing over three, a tile nobody uses, slot 0 a lone short tile, one more nobody's.
+from ray_tpu.models.hybrid_ssm import _segments
+slots, C, n = 8, 128, 8
+spec = [(5, 256, 128), (5, 384, 30), (2, 0, 128), (2, 128, 128), (2, 256, 17), (None, 0, 0), (0, 0, 9), (None, 0, 0)]
+pool = pool[:3 * slots]
+slot_of = jnp.asarray([slots if s is None else s for s, _, _ in spec], jnp.int32)
+live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
+fresh, cont, last = _segments(jnp.asarray([s for _, s, _ in spec], jnp.int32)[:, None], slot_of, slots)
+row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots)
+log_g = jnp.log1p(-jnp.exp(jax.random.uniform(ks[2], (n, C, H), jnp.float32, np.log(5e-4), np.log(0.1))))
+kk, vs = jax.random.normal(ks[3], (n, C, H, d)), jax.random.normal(ks[4], (n, C, H, d))
+qs = jax.random.normal(ks[5], (n, C, H, G, d))
+got_pool, got_y = (np.asarray(x) for x in jax.jit(ops.power_chunk_scan)(pool, row, fresh, cont, last, live, log_g, qs, kk, vs))
+
+def by_token(S, tile):  # one tile's real tokens through the plain update, one at a time
+    def token(S, now):
+        lg, q_t, k_t, v_t = now
+        S, y = ops.reference_power_update(S[None], 0, jnp.ones(1, jnp.int32), jnp.exp(lg)[None], k_t[None], q_t[None], v_t[None])
+        return S[0], y[0]
+    return jax.lax.scan(token, S, tile)
+
+step = jax.jit(by_token)
+want_pool = np.asarray(pool).copy()
+S = None
+for t, (s, start, ln) in enumerate(spec):
+    if s is None:
+        assert not got_y[t].any()
+        continue
+    S = jnp.zeros(pool.shape[1:]) if start == 0 else (S if bool(cont[t]) else pool[slots + s])
+    S, y = step(S, tuple(x[t, :ln] for x in (log_g, qs, kk, vs)))
+    want_pool[slots + s] = np.asarray(S)
+    apart = np.abs(got_y[t, :ln] - np.asarray(y)).max() / np.abs(np.asarray(y)).max()
+    assert apart < 5e-4, (t, apart)  # a fresh segment's first reads are of one or two squares: 5e-5 on a CPU
+    print("power_chunk_scan tile", t, "y apart", apart)
+# On a CPU the two are 4e-7 apart; on the chip 5e-5: the update by token multiplies by exp(log g) once a
+# token where the tile takes ONE exp of the summed logs, and the chip's exp is a few 1e-7 off on the same
+# side every time, which 128-273 tokens compound (the kernel's state is the plain update's bit for bit).
+apart = np.abs(got_pool - want_pool).max() / np.abs(want_pool).max()
+assert apart < 3e-4, apart
+print("power_chunk_scan: state apart", apart)
+"""
+
+
+def test_power_kernel_reads_the_plain_forms_numbers_on_the_chip():
+    """``power_state_update`` against its plain form on a chip at the served shape
+    (16 slots of 8 states of 136 x 8,320, five queries a state: all live; idle rows
+    scattered; one; none), and the chunk scan's plain form at the served tile of 128
+    against the plain update token by token (a carried segment, a fresh one over
+    three tiles, nobody's tiles between). In a process of its own: this one is held
+    to the CPU (conftest)."""
+    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+    seen, where = TPUAcceleratorManager.detect_chips()
+    if not seen:
+        pytest.skip(f"needs a TPU, both forms run: {where}")
+    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    r = subprocess.run([sys.executable, "-c", _POWER_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=900)
+    print(r.stdout[-4000:])
+    assert r.returncode == 0, r.stderr[-3000:]
