@@ -1,6 +1,7 @@
 """Power retention of degree 2 ("Scaling Context Requires Rethinking Attention",
 arXiv:2507.04239) over the slots' stored states: one token a slot
-(``power_update``), and a chunk call's tiles (``power_chunk_scan``, below).
+(``power_update``), and a chunk call's tiles (``power_chunk_scan``, below: the
+plain form and its kernel; no option selects, the platform and the shapes do).
 
 A key/value head weighs a past token ``j`` for a query at ``t`` by ``(q_t . k_j)
 ** 2 / d`` times the decays since, and that square is a plain inner product of
@@ -233,12 +234,46 @@ def power_update(pool, base, lens, g, k, q, v):
 #     A = ((Q K^T) ** 2 / d) * exp(G_i - G_j) * [j <= i]
 #     read = A V' + exp(G_i) phi(Q) S_0^T          S_C = exp(G_C) S_0 + V'^T diag(exp(G_C - G_j)) phi(K)
 #
-# Every exponent is <= 0, so nothing is split. ``phi`` of a tile's queries and
-# keys is made inside the tile's step (of a whole call's it would be 1.6 GB);
-# only ``S_0`` goes from tile to tile, in a ``lax.scan`` with the pool as its
-# carry (``ops/ssm.py``: a tile reads its row where it lies and writes it back;
-# a gather of the tiles' rows before the scan copied the WHOLE pool on a v5e).
-# Everything float32 at ``highest``.
+# Every exponent is <= 0, so nothing is split. Everything float32 at ``highest``.
+#
+# - ``reference_power_chunk_scan``: the plain form. CPU, and the oracle. ``phi``
+#   of a tile's queries and keys is made inside the tile's step (of a whole
+#   call's it would be 1.6 GB; of a tile's 5,120 queries it is 170 MB, through
+#   HBM); only ``S_0`` goes from tile to tile, in a ``lax.scan`` with the pool
+#   as its carry (``ops/ssm.py``: a tile reads its row where it lies and writes
+#   it back; a gather of the tiles' rows before the scan copied the WHOLE pool
+#   on a v5e).
+# - ``_power_chunk_scan``: the Pallas kernel, named ``power_chunk_scan``. The
+#   grid is (head, tile), a head's tiles in order: the head's running state
+#   ``[VALUES, P]`` (4.5 MB) stays in a VMEM scratch from tile to tile, zeroed
+#   where a segment begins its prompt, copied from ``pool[row, head]`` (the pool
+#   stays in HBM) where it takes up a stored state. ``phi`` never leaves VMEM:
+#   a step walks the state's ``d / 2 + 1`` lane tiles, and for lane tile ``r``
+#   makes ``q roll(q, -r)`` of the tile's ``G * C`` queries and ``c_r k roll(k,
+#   -r)`` of its keys from the operands it holds, reads ``S_r`` (``c_r S_r`` times
+#   the queries' row of ``phi``, TURNED: the reads are ``[VALUES, G * C]``, the
+#   normaliser's row eight more rows of the streamed operand and ``exp(G_i)`` a
+#   row over the sublanes) and then advances it. The quadratic form inside the
+#   tile is two more products a step. A tile with no real token fetches nothing
+#   (its blocks are its live neighbour's), computes nothing and writes zeros.
+#   A segment's last tile hands its state out (``ends``), and a loop of as many
+#   steps as segments ended puts those rows into the pool in place: the kernel
+#   does not write the pool (``ops/ssm.py``: a custom call whose output is the
+#   aliased pool is a second pool to XLA's rematerialisation pass).
+#
+# The two forms differ where a call holds TWO segments of one row: the plain
+# form's second segment takes up what the first left (the pool is its carry),
+# the kernel's reads the INPUT pool, which no segment of this call has written
+# yet. A call holds at most one segment a row: ``hybrid_ssm._segments`` joins a
+# slot's neighbouring tiles into one, and the engine packs a slot's chunk once a
+# call.
+
+
+def _steps(live, log_g, k):
+    """→ (k with padding's taken out; ``G`` [n, C, H], the running sum of log g
+    since the tile began with padding's taken as 0: no key, no decay), in both forms."""
+    real = jnp.arange(log_g.shape[1])[None, :] < live[:, None]  # [n, C]
+    return jnp.where(real[..., None, None], k, 0.0), jnp.cumsum(jnp.where(real[..., None], log_g, 0.0), axis=1)
 
 
 @jax.jit
@@ -250,10 +285,7 @@ def reference_power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, 
     del last  # a segment's later tiles overwrite its earlier ones' rows
     n, C, H, G, d = q.shape
     R = pool.shape[0]
-    real = jnp.arange(C)[None, :] < live[:, None]  # [n, C]
-    log_g = jnp.where(real[..., None], log_g, 0.0)
-    k = jnp.where(real[..., None, None], k, 0.0)
-    run = jnp.cumsum(log_g, axis=1)  # [n, C, H]
+    k, run = _steps(live, log_g, k)
     lower = jnp.tril(jnp.ones((C, C), bool))
 
     def tile(carry, t):
@@ -281,8 +313,149 @@ def reference_power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, 
     return pool, jnp.where((live > 0)[:, None, None, None, None], y, 0.0)
 
 
+def _scan_kernel(meta_ref,  # scalar prefetch [6, n]: live, zero, load, row, store, (the inputs' block)
+                 q_ref,  # [1, 1, G * C, d]: the tile's queries of this state, query head by query head
+                 k_ref,  # [1, 1, C, d]: its keys (0 on padding)
+                 vt_ref,  # [1, 1, VALUES, C]: its values with their one, turned
+                 heard_ref,  # [1, 1, C, C]: exp(G_i - G_j) / d at [j, i] where j <= i, else 0
+                 rows_ref,  # [1, 1, 8, G * C]: row 0 exp(G_i) a query, row 1 exp(G_C - G_j) a key, row 2 exp(G_C)
+                 pool_ref,  # [R, H, VALUES, P] in HBM
+                 y_ref,  # [1, 1, VALUES, G * C]: the queries' reads, turned
+                 ends_ref,  # [n, H, VALUES, P] in HBM: tile t's outgoing state, where it ends its segment
+                 s_ref,  # scratch [VALUES, P]: the head's running state
+                 sem, *, group: int):
+    h, t = pl.program_id(0), pl.program_id(1)
+    live, zero, load, row, store = (meta_ref[j, t] for j in range(5))
+    GC, d = q_ref.shape[2:]
+    C = k_ref.shape[2]
+    P = s_ref.shape[1]
+
+    @pl.when(zero == 1)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(load == 1)
+    def _():
+        copy = pltpu.make_async_copy(pool_ref.at[row, h], s_ref, sem)
+        copy.start()
+        copy.wait()  # ray-tpu: lint-ignore[RTL008]
+
+    @pl.when(live == 0)
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(live > 0)
+    def _():
+        def dot(a, b, turned=False):  # a b, or a b^T
+            return jax.lax.dot_general(a, b, (((1,), (1 if turned else 0,)), ((), ())),
+                                       precision=_EXACT, preferred_element_type=jnp.float32)
+
+        q, k, vt = q_ref[0, 0], k_ref[0, 0], vt_ref[0, 0]
+        to_end = vt * rows_ref[0, 0, 1:2, :C]  # V'^T diag(exp(G_C - G_j))
+        whole = rows_ref[0, 0, 2:3, :d]  # exp(G_C), over a lane tile
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+        # Row r of phi is c_r a roll(a, -r), a lane tile of the state: made here of the operands
+        # in hand, read by the queries (S_0, before the update) and then advanced. A loop of groups
+        # of lane tiles, a group unrolled (``ops/ssm.py``: all unrolled are seconds of set-up).
+        def lane_tiles(i, _):
+            read = None
+            for j in range(group):
+                r = i * group + j
+                c = jnp.where((r == 0) | (r == d // 2), 1.0, 2.0 ** 0.5) * d ** -0.5
+                at = pl.ds(pl.multiple_of(r * d, d), d)
+                turn = jnp.where(r == 0, 0, d - r)
+                s = s_ref[:, at]
+                part = dot(s * c, q * pltpu.roll(q, turn, 1), turned=True)  # [VALUES, G * C]
+                read = part if read is None else read + part
+                s_ref[:, at] = whole * s + dot(to_end, k * pltpu.roll(k, turn, 1) * c)
+            y_ref[0, 0] += read
+
+        jax.lax.fori_loop(0, P // d // group, lane_tiles, None)
+        # Inside the tile: query i hears key j <= i through (q_i . k_j) ** 2 / d and the decays between.
+        heard = jnp.concatenate([heard_ref[0, 0]] * (GC // C), axis=1)
+        inside = dot(vt, jnp.square(dot(k, q, turned=True)) * heard)
+        y_ref[0, 0] = inside + rows_ref[0, 0, 0:1, :] * y_ref[0, 0]
+
+    @pl.when(store == 1)
+    def _():
+        copy = pltpu.make_async_copy(s_ref, ends_ref.at[t, h], sem)
+        copy.start()
+        copy.wait()  # ray-tpu: lint-ignore[RTL008]
+
+
+_SCAN_GROUP = 5  # lane tiles the chunk scan's kernel unrolls (of d / 2 + 1 = 65)
+
+
+# Under a jit of its own, as the update's kernel is.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, v, *, interpret: bool = False):
+    n, C, H, G, d = q.shape
+    R, _, V, P = pool.shape
+    assert (P // d) % _SCAN_GROUP == 0, (P, d, _SCAN_GROUP)
+    mine = row < R
+    run = mine & (live > 0)
+    idx = jnp.arange(n, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(run, idx, -1))  # the nearest tile at or before that runs
+    src = jnp.where(before >= 0, before, jnp.argmax(run).astype(jnp.int32))
+    meta = jnp.stack([jnp.where(run, live, 0), mine & fresh, mine & ~fresh & ~cont,
+                      jnp.minimum(row, R - 1), last & mine, src]).astype(jnp.int32)
+    k, since = _steps(live, log_g, k)
+    since = since.transpose(0, 2, 1)  # [n, H, C]
+    at_or_after = jnp.triu(jnp.ones((C, C), bool))  # [j, i]: j <= i
+    heard = jnp.exp(jnp.where(at_or_after, since[..., None, :] - since[..., :, None], -jnp.inf)) * d ** -1.0
+    end = since[..., -1:]
+    rows = jnp.exp(jnp.stack([since, end - since, jnp.broadcast_to(end, since.shape)], axis=2))  # [n, H, 3, C]
+    rows = jnp.pad(jnp.tile(rows, (1, 1, 1, G)), ((0, 0), (0, 0), (0, _SUBLANES - 3), (0, 0)))
+
+    def tile(*block, of=lambda t, meta: meta[5, t]):
+        return pl.BlockSpec((1, 1) + block, lambda h, t, meta: (of(t, meta), h, 0, 0))
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    y, ends = pl.pallas_call(
+        functools.partial(_scan_kernel, group=_SCAN_GROUP),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H, n),
+            in_specs=[tile(G * C, d), tile(C, d), tile(V, C), tile(C, C), tile(_SUBLANES, G * C), hbm],
+            out_specs=[tile(V, G * C, of=lambda t, meta: t), hbm],
+            scratch_shapes=[pltpu.VMEM((V, P), jnp.float32), pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((n, H, V, G * C), jnp.float32),
+                   jax.ShapeDtypeStruct((n, H, V, P), pool.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="power_chunk_scan",
+    )(meta, q.transpose(0, 2, 3, 1, 4).reshape(n, H, G * C, d), k.transpose(0, 2, 1, 3),
+      with_one(v).transpose(0, 2, 3, 1), heard, rows, pool)
+    # The segments that ended, in place: one row each.
+    ended = jnp.nonzero(meta[4], size=n, fill_value=0)[0]
+
+    def put(j, pool):
+        t = ended[j]
+        state = jax.lax.dynamic_index_in_dim(ends, t, axis=0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(pool, state, meta[3, t], axis=0)
+
+    pool = jax.lax.fori_loop(0, jnp.sum(meta[4]), put, pool)
+    read = y.reshape(n, H, V, G, C).transpose(0, 4, 1, 3, 2)  # [n, C, H, G, VALUES]
+    return pool, jnp.where((live > 0)[:, None, None, None, None], normalise(read, d), 0.0)
+
+
+def _scan_tiles(pool, q) -> bool:
+    """The chunk scan's kernel: a row of ``phi`` is the head's whole lane tile
+    (so a roll along the lanes turns it), the VALUES whole sublane tiles, and a
+    tile's tokens a lane tile too (they are the lanes of the decays and of the
+    values turned)."""
+    n, C, H, G, d = q.shape
+    return (pool.dtype == jnp.float32 and q.dtype == jnp.float32 and d == _LANES and C == _LANES
+            and pool.shape[-2] % _SUBLANES == 0 and pool.shape[-1] == phi_width(d))
+
+
 @jax.named_scope("power.scan")
 def power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, v):
     """The recurrence of one layer over a chunk call's tiles (the comment
-    above). The plain form everywhere: it has no kernel yet."""
+    above): the kernel on a TPU where the shapes tile, else the plain form."""
+    if _use_pallas() and _scan_tiles(pool, q):
+        return _power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, v)
     return reference_power_chunk_scan(pool, row, fresh, cont, last, live, log_g, q, k, v)

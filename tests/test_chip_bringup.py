@@ -114,6 +114,13 @@ def _kernel_names(compiled_text: str) -> list:
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def _phi_wide_shapes(compiled_text: str) -> set:
+    """The last two sizes of every float32 array of the program that is as wide
+    as ``phi`` of a head of 128: the pool's rows and the ended segments' states are
+    ``136,8320``; ``phi`` of a tile's 5,120 queries would be ``5,8320``."""
+    return {",".join(shape.split(",")[-2:]) for shape in re.findall(r"f32\[([0-9,]+,8320)\]", compiled_text)}
+
+
 def test_flash_fwd_and_bwd_compile_for_v5e(v5e):
     from jax.sharding import SingleDeviceSharding
 
@@ -1118,9 +1125,10 @@ def test_power_programs_read_weights_and_the_pool_where_they_lie_on_v5e(v5e):
     no pool of blocks (a table, and a row of blocks, with NO column): one state
     update kernel a step in the window, and temporaries far under the pool's own
     4.63 GB in both (the window's: the kernel's rows and partial sums; the chunk
-    program's: a tile's ``phi`` of 128 x 40 queries, 170 MB, and the state that
-    goes from tile to tile): no pool is gathered or copied (a gather of the
-    tiles' rows copied it whole: 3.6 GB, and the program did not fit)."""
+    program's: the ended segments' states, ``ends``, and a call's activations;
+    no ``phi`` since the chunk scan is a kernel too): no pool is gathered or
+    copied (a gather of the tiles' rows copied it whole: 3.6 GB, and the program
+    did not fit)."""
     from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
@@ -1152,6 +1160,7 @@ def test_power_programs_read_weights_and_the_pool_where_they_lie_on_v5e(v5e):
         sds((b,), np.float32), sds((2,), np.uint32)).compile()
     assert _kernel_names(compiled.as_text()).count("power_state_update") == 2  # one a step: the layers are scanned
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
+    window_arguments = compiled.memory_analysis().argument_size_in_bytes
     (params_fmt, *_), _ = compiled.input_formats
     width = 1024
     n = width // chunk_tile(width, bs)
@@ -1168,8 +1177,49 @@ def test_power_programs_read_weights_and_the_pool_where_they_lie_on_v5e(v5e):
         params, sds((1, width), np.int32), cache, sds((n, 0), np.int32),
         sds((0,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
         sds((2,), np.uint32), sds((b,), np.int32)).compile()
-    assert "power_state_update" not in compiled.as_text()  # the chunk scan is plain XLA: no kernel yet
-    assert compiled.memory_analysis().temp_size_in_bytes < 768 * 2**20
+    # The chunk scan is its kernel, once a layer (the layers are scanned), and the program holds no
+    # ``phi`` of a tile's queries (170 MB, twice, in the plain form): its temporaries are the ended
+    # segments' states (``ends``, 8 x 36 MB) and a call's activations: 312,595,968 B (PR 55; 0.479 GB
+    # with the plain form). The arguments are the weights and ONE pool, as in the window.
+    assert _kernel_names(compiled.as_text()) == ["power_chunk_scan"]
+    assert _phi_wide_shapes(compiled.as_text()) <= {"136,8320"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
+    assert compiled.memory_analysis().argument_size_in_bytes - window_arguments < 2**20
+
+
+def test_power_chunk_scan_kernel_compiles_for_v5e_at_the_published_widths(v5e):
+    """``power_chunk_scan`` alone at the served shapes (8 tiles of 128 tokens, 8
+    states of 136 x 8,320, five queries a state, a flat pool of 8 layers x 16
+    slots): the TPU's compiler takes the kernel (a head's state of 4.5 MB in
+    VMEM, the lane rolls by a traced count, the products with a turned operand
+    at ``highest``), the pool is donated and not copied, no array of the program
+    is ``phi`` of a tile's queries (the plain form's ``[128, 8, 5, 8320]``, 170
+    MB), and the temporaries are ``ends`` and the kernel's small operands:
+    290,314,752 B where the plain form's are 420,763,136 (PR 55)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import power_retention as ops
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    n, C, H, G, d, R = 8, 128, 8, 5, 128, 8 * 16
+    pool = sds((R, H, ops.values_rows(d), ops.phi_width(d)), jnp.float32)
+    q = sds((n, C, H, G, d), jnp.float32)
+    assert ops._scan_tiles(pool, q)
+    assert not ops._scan_tiles(sds((R, H, 24, ops.phi_width(16)), jnp.float32), sds((n, 8, H, G, 16), jnp.float32))
+    operands = (pool, sds((n,), jnp.int32), *(sds((n,), bool),) * 3, sds((n,), jnp.int32),
+                sds((n, C, H), jnp.float32), q, sds((n, C, H, d), jnp.float32), sds((n, C, H, d), jnp.float32))
+    compiled = jax.jit(ops.power_chunk_scan, donate_argnums=(0,)).lower(*operands).compile()
+    assert _kernel_names(compiled.as_text()) == ["power_chunk_scan"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4.63e9  # the pool goes out where it came in
+    assert memory.temp_size_in_bytes < 320 * 2**20
+    assert _phi_wide_shapes(compiled.as_text()) == {"136,8320"}
+    plain = jax.jit(ops.reference_power_chunk_scan, donate_argnums=(0,)).lower(*operands).compile()
+    assert "5,8320" in _phi_wide_shapes(plain.as_text())  # the check sees what the kernel took away
 
 
 _POWER_KERNEL_AGAINST_PLAIN_FORM = """
@@ -1207,9 +1257,9 @@ for name, lens in (("all live", np.ones(b, np.int32)), ("idle rows scattered", s
     assert apart < 1e-4, (name, apart)
     print("power_state_update", name, int((lens > 0).sum()), "live: state apart", apart_s, "y apart", apart)
 
-# the chunk scan's plain form on the chip against the recurrence token by token, at the served widths:
-# 8 tiles of 128; slot 5 takes up its stored row over two tiles (the second partly padding), slot 2
-# begins from nothing over three, a tile nobody uses, slot 0 a lone short tile, one more nobody's.
+# the chunk scan's KERNEL on the chip against its plain form and against the recurrence token by token, at
+# the served widths: 8 tiles of 128; slot 5 takes up its stored row over two tiles (the second partly
+# padding), slot 2 begins from nothing over three, a tile nobody uses, slot 0 a lone short tile, one more nobody's.
 from ray_tpu.models.hybrid_ssm import _segments
 slots, C, n = 8, 128, 8
 spec = [(5, 256, 128), (5, 384, 30), (2, 0, 128), (2, 128, 128), (2, 256, 17), (None, 0, 0), (0, 0, 9), (None, 0, 0)]
@@ -1221,7 +1271,19 @@ row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots)
 log_g = jnp.log1p(-jnp.exp(jax.random.uniform(ks[2], (n, C, H), jnp.float32, np.log(5e-4), np.log(0.1))))
 kk, vs = jax.random.normal(ks[3], (n, C, H, d)), jax.random.normal(ks[4], (n, C, H, d))
 qs = jax.random.normal(ks[5], (n, C, H, G, d))
-got_pool, got_y = (np.asarray(x) for x in jax.jit(ops.power_chunk_scan)(pool, row, fresh, cont, last, live, log_g, qs, kk, vs))
+scan = (pool, row, fresh, cont, last, live, log_g, qs, kk, vs)
+assert ops._scan_tiles(pool, qs)
+assert "tpu_custom_call" in jax.jit(ops.power_chunk_scan).lower(*scan).as_text()  # the kernel, not the plain form
+got_pool, got_y = (np.asarray(x) for x in jax.jit(ops.power_chunk_scan)(*scan))
+plain_pool, plain_y = (np.asarray(x) for x in ops.reference_power_chunk_scan(*scan))
+assert np.allclose(got_pool, plain_pool, rtol=1e-5, atol=1e-5)
+apart = np.abs(got_y - plain_y).max() / np.abs(plain_y).max()
+assert apart < 1e-4, apart
+ended = {s for (s, _, _), e in zip(spec, np.asarray(last)) if e}
+kept = [r for r in range(3 * slots) if r - slots not in ended]
+assert np.array_equal(got_pool[kept], np.asarray(pool)[kept])  # a tile with no real token touches no row
+print("power_chunk_scan kernel against the plain form: state apart",
+      np.abs(got_pool - plain_pool).max() / np.abs(plain_pool).max(), "y apart", apart)
 
 def by_token(S, tile):  # one tile's real tokens through the plain update, one at a time
     def token(S, now):
@@ -1232,7 +1294,7 @@ def by_token(S, tile):  # one tile's real tokens through the plain update, one a
 
 step = jax.jit(by_token)
 want_pool = np.asarray(pool).copy()
-S = None
+S = kind = None
 for t, (s, start, ln) in enumerate(spec):
     if s is None:
         assert not got_y[t].any()
@@ -1242,23 +1304,26 @@ for t, (s, start, ln) in enumerate(spec):
     want_pool[slots + s] = np.asarray(S)
     apart = np.abs(got_y[t, :ln] - np.asarray(y)).max() / np.abs(np.asarray(y)).max()
     assert apart < 5e-4, (t, apart)  # a fresh segment's first reads are of one or two squares: 5e-5 on a CPU
-    print("power_chunk_scan tile", t, "y apart", apart)
+    kind = kind if bool(cont[t]) else ("fresh" if start == 0 else "carried")  # its segment's beginning
+    print("power_chunk_scan tile", t, kind, "y apart from token by token: kernel", apart,
+          "plain form", np.abs(plain_y[t, :ln] - np.asarray(y)).max() / np.abs(np.asarray(y)).max())
 # On a CPU the two are 4e-7 apart; on the chip 5e-5: the update by token multiplies by exp(log g) once a
 # token where the tile takes ONE exp of the summed logs, and the chip's exp is a few 1e-7 off on the same
 # side every time, which 128-273 tokens compound (the kernel's state is the plain update's bit for bit).
 apart = np.abs(got_pool - want_pool).max() / np.abs(want_pool).max()
 assert apart < 3e-4, apart
-print("power_chunk_scan: state apart", apart)
+print("power_chunk_scan: state apart from token by token: kernel", apart,
+      "plain form", np.abs(plain_pool - want_pool).max() / np.abs(want_pool).max())
 """
 
 
 def test_power_kernel_reads_the_plain_forms_numbers_on_the_chip():
     """``power_state_update`` against its plain form on a chip at the served shape
     (16 slots of 8 states of 136 x 8,320, five queries a state: all live; idle rows
-    scattered; one; none), and the chunk scan's plain form at the served tile of 128
-    against the plain update token by token (a carried segment, a fresh one over
-    three tiles, nobody's tiles between). In a process of its own: this one is held
-    to the CPU (conftest)."""
+    scattered; one; none), and the chunk scan's kernel at the served tile of 128
+    against its plain form and against the plain update token by token (a carried
+    segment, a fresh one over three tiles, nobody's tiles between). In a process of
+    its own: this one is held to the CPU (conftest)."""
     from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 
     seen, where = TPUAcceleratorManager.detect_chips()

@@ -281,6 +281,21 @@ SCANS = {
 }
 
 
+def _scan_operands(rng, spec, slots, H, G, d, C):
+    """``power_chunk_scan``'s arguments behind the pool for the tiles of
+    ``spec``, slots of the second of three layers: decays from 0.9995 down to
+    0.5 a token, normal queries, keys and values."""
+    n = len(spec)
+    slot_of = jnp.asarray([slots if s is None else s for s, _, _ in spec], jnp.int32)
+    live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
+    fresh, cont, last = _segments(jnp.asarray([a for _, a, _ in spec], jnp.int32)[:, None], slot_of, slots)
+    row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots).astype(jnp.int32)
+    log_g = jnp.asarray(np.log(1 - np.exp(rng.uniform(np.log(5e-4), np.log(0.5), (n, C, H)))), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(n, C, H, d)), jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(n, C, H, G, d)), jnp.float32)
+    return row, fresh, cont, last, live, log_g, q, k, v
+
+
 @pytest.mark.parametrize("tiles", list(SCANS))
 def test_power_chunk_scan_is_the_recurrence_token_by_token(tiles):
     """The tiled form (tiles of 8) against the recurrence a token at a time in
@@ -290,22 +305,19 @@ def test_power_chunk_scan_is_the_recurrence_token_by_token(tiles):
     whatever stands behind it; a tile with ``live`` 0 has zeros for its reads
     and touches no row; a fresh segment begins from nothing though its slot's
     row holds 0.5, a carried one from its row; rows no segment ends in, and the
-    other layers', are bit for bit what they were."""
+    other layers', are bit for bit what they were.
+
+    This is the test that holds ``ops._steps``, the masking of padding and the
+    running sum of ``log g`` that the plain form and the kernel's wrapper share:
+    the kernel's own test compares the two forms, which would both be wrong
+    together. It is no repeat of that one."""
     rng = np.random.default_rng(7)
     slots, H, G, d, C = 4, 2, 5, 16, 8
     spec = SCANS[tiles]
-    n = len(spec)
     pool = np.full((3 * slots, H, ops.values_rows(d), ops.phi_width(d)), 0.5, np.float32)
     pool[slots + 1] = np.asarray(_real_state(rng, (H,), d))
-    slot_of = np.asarray([slots if s is None else s for s, _, _ in spec], np.int32)
-    starts = np.asarray([a for _, a, _ in spec], np.int32)
-    live = jnp.asarray([ln for _, _, ln in spec], jnp.int32)
-    fresh, cont, last = _segments(jnp.asarray(starts)[:, None], jnp.asarray(slot_of), slots)
-    row = jnp.where(slot_of < slots, slots + slot_of, 3 * slots).astype(jnp.int32)
-    log_g = jnp.asarray(np.log(1 - np.exp(rng.uniform(np.log(5e-4), np.log(0.5), (n, C, H)))), jnp.float32)
-    k, v = (jnp.asarray(rng.normal(size=(n, C, H, d)), jnp.float32) for _ in range(2))
-    q = jnp.asarray(rng.normal(size=(n, C, H, G, d)), jnp.float32)
-    args = (jnp.asarray(pool), row, fresh, cont, last, live, log_g, q, k, v)
+    args = (jnp.asarray(pool),) + _scan_operands(rng, spec, slots, H, G, d, C)
+    _, row, fresh, cont, last, live, log_g, q, k, v = args
     got_pool, got_y = (np.asarray(x) for x in jax.jit(ops.power_chunk_scan)(*args))
     want_pool, S = pool.astype(np.float64), None
     f64 = lambda x: np.asarray(x, np.float64)  # noqa: E731
@@ -331,6 +343,52 @@ def test_power_chunk_scan_is_the_recurrence_token_by_token(tiles):
         other = (args[0], row, fresh, cont, last, live, log_g.at[:, ln:].set(-3.0), q,
                  k.at[:, ln:].set(0.3), v.at[:, ln:].set(3.0))
         again, _ = jax.jit(ops.power_chunk_scan)(*other)
+        assert np.array_equal(np.asarray(again), got_pool)
+
+
+# The same for the kernel, at its own shapes: tiles of 128 tokens of heads of 128.
+KERNEL_SCANS = {
+    "a_fresh_segment_of_several_tiles": [(2, 0, 128), (2, 128, 128), (2, 256, 128)],
+    "a_carried_segment": [(1, 256, 128), (1, 384, 128)],
+    "two_packed_segments_one_carried_and_one_fresh": [(1, 256, 128), (1, 384, 30), (2, 0, 128), (2, 128, 17)],
+    "a_segment_that_ends_mid_tile": [(3, 0, 128), (3, 128, 41)],
+    "tiles_with_live_0_between_two_segments": [(0, 0, 77), (None, 0, 0), (None, 0, 0), (1, 128, 9)],
+    "nobody_at_all": [(None, 0, 0), (None, 0, 0)],
+}
+
+
+@pytest.mark.parametrize("tiles", list(KERNEL_SCANS))
+def test_power_chunk_scan_kernel_reads_the_plain_forms_numbers(tiles):
+    """The chunk scan's kernel under the Pallas interpreter against the plain
+    form, in the second of three layers of a flat pool, two states of five
+    queries at heads of 128 and tiles of 128: the reads and the WHOLE pool agree
+    to rounding (a carried segment begins from its row, a fresh one from nothing
+    though its row holds a state); a tile with ``live`` 0 has zeros for its
+    reads and, its row being past the pool, loads and stores nothing; rows no
+    segment ends in, and the other layers', are bit for bit what they were; what
+    stands behind a segment's last real token changes nothing."""
+    rng = np.random.default_rng(11)
+    slots, H, G, d, C = 4, 2, 5, 128, 128
+    spec = KERNEL_SCANS[tiles]
+    pool = _real_state(rng, (3 * slots, H), d)
+    args = (pool,) + _scan_operands(rng, spec, slots, H, G, d, C)
+    _, row, fresh, cont, last, live, log_g, q, k, v = args
+    assert ops._scan_tiles(pool, q)
+    want_pool, want_y = ops.reference_power_chunk_scan(*args)
+    got_pool, got_y = (np.asarray(x) for x in ops._power_chunk_scan(*args, interpret=True))
+    assert np.allclose(got_pool, want_pool, rtol=1e-5, atol=1e-5)
+    assert np.allclose(got_y, want_y, rtol=1e-4, atol=1e-4)
+    assert not got_y[np.asarray(live) == 0].any()
+    ended = {s for (s, _, _), e in zip(spec, np.asarray(last)) if e}
+    kept = [r for r in range(3 * slots) if r - slots not in ended]
+    assert np.array_equal(got_pool[kept], np.asarray(pool)[kept])
+    if ended:
+        assert not np.array_equal(got_pool[slots + min(ended)], np.asarray(pool)[slots + min(ended)])
+    if tiles == "a_segment_that_ends_mid_tile":
+        ln = spec[-1][2]
+        other = (pool, row, fresh, cont, last, live, log_g.at[-1, ln:].set(-3.0), q,
+                 k.at[-1, ln:].set(0.3), v.at[-1, ln:].set(3.0))
+        again, _ = ops._power_chunk_scan(*other, interpret=True)
         assert np.array_equal(np.asarray(again), got_pool)
 
 
