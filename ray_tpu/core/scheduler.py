@@ -436,7 +436,12 @@ class ClusterResourceScheduler:
             for _p, nid in sorted((e.pos[n], n) for n in e.fits):
                 if self.state.nodes[nid].utilization() < threshold:
                     return ScheduleResult(nid)
-            best = min(e.fits, key=lambda n: self.state.nodes[n].utilization())
+            # ``fits`` is a set of random ids: a tie goes to the earlier
+            # node, as in the scan path below and the native scheduler.
+            best = min(
+                e.fits,
+                key=lambda n: (self.state.nodes[n].utilization(), e.pos[n]),
+            )
             return ScheduleResult(best)
         self._full_scans += 1
         feasible = self._feasible_nodes(demand, exclude)

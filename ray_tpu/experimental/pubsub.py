@@ -16,7 +16,12 @@ import queue
 import threading
 from typing import Any, Dict, List, Optional
 
-_lock = threading.Lock()
+# Reentrant: ``subscribe`` and ``close`` call the controller UNDER it, and
+# the call that meets a lost connection reconnects on its own thread, which
+# re-issues the process's subscriptions (``_resubscribe``) under it again.
+# A plain lock there waited on itself for ever, and every later
+# ``subscribe`` / ``close`` of the process behind it.
+_lock = threading.RLock()
 _subscribers: Dict[str, List["Subscriber"]] = {}
 
 

@@ -9,7 +9,10 @@ JAX tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
 exercised without TPU hardware (the driver separately dry-runs the multichip
 path; see ``__graft_entry__.py``).
 """
+import contextlib
+import faulthandler
 import os
+import signal
 
 # Force CPU with 8 virtual devices: tier-1 must not depend on a chip (a
 # TPU host exports JAX_PLATFORMS=tpu,cpu). The env writes are a hard
@@ -57,6 +60,70 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+# The one limit a test. The suite's only other clock is the driver's, which
+# kills the whole run without a word (PR 55's was cut at 1,473 s of 1,470 by
+# one wait nobody could name). Sized from the slowest case under six workers
+# (90-114 s, 240 s with a second suite run beside; CHANGES.md, PR 56) and so
+# that a whole run with two waits in it still ends inside the driver's
+# 1,470 s. No inner bound in tests/ (a `timeout=` of `get`, `subprocess.run`,
+# `urlopen`) may stand at or above it.
+TEST_LIMIT_S = 300.0
+# How long after the limit a worker whose main thread sits in a call that no
+# signal interrupts (so the handler never runs) dumps its stacks and exits.
+# xdist reports the case as failed ("node down", "worker ... crashed while
+# running ...") and, under `--dist loadfile`, hands the file's unfinished
+# cases, THAT ONE INCLUDED, to another worker: a wait that comes now and then
+# passes there; one that comes every time ends a worker each time.
+_EXIT_AFTER_S = 60.0
+
+
+@contextlib.contextmanager
+def time_limit(nodeid, limit_s, exit_s):
+    """Hold the body to ``limit_s`` seconds; end the process at ``exit_s``.
+
+    At ``limit_s`` SIGALRM's handler writes every thread's stack to stderr
+    and raises pytest's ``Failed`` (a BaseException: no ``except Exception``
+    of the code under test swallows it) in the main thread, wherever it
+    stands. At ``exit_s`` the interpreter's own watchdog thread writes the
+    stacks and calls ``_exit(1)``. Both go to the stderr of the moment the
+    body starts (a dup: capture may point fd 2 elsewhere during the body).
+    Main thread only; does not nest (the process has one ITIMER_REAL and one
+    ``dump_traceback_later``).
+    """
+    err = os.dup(2)
+
+    def reached(signum, frame):
+        faulthandler.dump_traceback(file=err, all_threads=True)
+        pytest.fail(
+            f"{nodeid} reached the limit of {limit_s:g} s a test "
+            "(tests/conftest.py TEST_LIMIT_S; every thread's stack is on stderr)"
+        )
+
+    old = signal.signal(signal.SIGALRM, reached)
+    faulthandler.dump_traceback_later(exit_s, exit=True, file=err)
+    signal.setitimer(signal.ITIMER_REAL, limit_s)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, old)
+        os.close(err)
+
+
+@pytest.hookimpl(wrapper=True)
+def _limited(item):
+    # Each phase is armed on its own: a raise BETWEEN two phases would reach
+    # pytest as an internal error, and a teardown that follows a test which
+    # reached the limit needs time of its own to end the test's cluster
+    # (`end_cluster` is bounded: GONE_BOUND_S, core/cluster_utils.py).
+    with time_limit(item.nodeid, TEST_LIMIT_S, TEST_LIMIT_S + _EXIT_AFTER_S):
+        return (yield)
+
+
+pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = _limited
+
 
 # NOTE on numerics: this CPU backend's default matmul runs at reduced
 # precision (bf16-class, ~1e-3 relative error). Tests that compare two ways

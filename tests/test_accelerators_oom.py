@@ -66,8 +66,8 @@ def test_actor_gets_visible_chips(ray_start_regular):
             return os.environ.get("TPU_VISIBLE_CHIPS")
 
     a, b = TpuActor.remote(), TpuActor.remote()
-    ca = ray_tpu.get(a.chips.remote())
-    cb = ray_tpu.get(b.chips.remote())
+    ca = ray_tpu.get(a.chips.remote(), timeout=120)
+    cb = ray_tpu.get(b.chips.remote(), timeout=120)
     assert ca and cb
     assert set(ca.split(",")).isdisjoint(set(cb.split(",")))
     assert len(ca.split(",")) == 2
@@ -75,7 +75,7 @@ def test_actor_gets_visible_chips(ray_start_regular):
     ray_tpu.kill(a)
     time.sleep(0.5)
     c = TpuActor.remote()
-    cc = ray_tpu.get(c.chips.remote())
+    cc = ray_tpu.get(c.chips.remote(), timeout=120)
     assert len(cc.split(",")) == 2
 
 

@@ -150,16 +150,24 @@ def test_autoscaler_v2_scales_up_and_down():
             i.status == InstanceStatus.RAY_RUNNING for i in im.instances()
         ) or any(i.status == InstanceStatus.TERMINATED for i in im.instances(None))
 
+        # The provider loses a node one `reconcile` (interval_s) before its
+        # instance moves RAY_STOPPING -> TERMINATED: wait for both.
+        def histories():
+            return {i.instance_id: (i.status, i.history) for i in im.instances(None)}
+
         deadline = time.monotonic() + 120  # generous: shared box under load
         while time.monotonic() < deadline:
-            if not cluster.provider.non_terminated_nodes():
+            if not cluster.provider.non_terminated_nodes() and all(
+                i.status == InstanceStatus.TERMINATED for i in im.instances(None)
+            ):
                 break
             time.sleep(0.5)
-        assert not cluster.provider.non_terminated_nodes(), "idle nodes never reaped"
+        assert not cluster.provider.non_terminated_nodes(), (
+            "idle nodes never reaped", histories())
         # every instance ends terminal, with a coherent history
         for inst in im.instances(None):
-            assert inst.status == InstanceStatus.TERMINATED
-            assert inst.history[0].startswith("QUEUED->")
+            assert inst.status == InstanceStatus.TERMINATED, histories()
+            assert inst.history[0].startswith("QUEUED->"), histories()
     finally:
         ray_tpu.shutdown()
         cluster.shutdown()

@@ -17,7 +17,7 @@ def test_worker_chaos_tasks_complete(ray_start_regular):
     """Retriable tasks complete correctly while workers are being
     SIGKILLed underneath them."""
     killer = WorkerKillerActor.remote(kill_interval_s=0.4, max_kills=4, seed=0)
-    ray_tpu.get(killer.run.remote())
+    ray_tpu.get(killer.run.remote(), timeout=120)
 
     @ray_tpu.remote(max_retries=10)
     def chunk(i):
@@ -27,7 +27,7 @@ def test_worker_chaos_tasks_complete(ray_start_regular):
     refs = [chunk.remote(i) for i in range(40)]
     results = ray_tpu.get(refs, timeout=180)
     assert results == [i * i for i in range(40)]
-    killed = ray_tpu.get(killer.stop_run.remote())
+    killed = ray_tpu.get(killer.stop_run.remote(), timeout=120)
     assert killed, "chaos killer never killed anything"
 
 
@@ -43,7 +43,7 @@ def test_worker_chaos_actor_restarts(ray_start_regular):
 
     svc = Service.remote()
     assert ray_tpu.get(svc.work.remote(0), timeout=30) == 1
-    ray_tpu.get(killer.run.remote())
+    ray_tpu.get(killer.run.remote(), timeout=120)
     ok = 0
     for i in range(30):
         try:
@@ -51,7 +51,7 @@ def test_worker_chaos_actor_restarts(ray_start_regular):
             ok += 1
         except ray_tpu.exceptions.ActorDiedError:
             pytest.fail("actor permanently died despite max_restarts")
-    killed = ray_tpu.get(killer.stop_run.remote())
+    killed = ray_tpu.get(killer.stop_run.remote(), timeout=120)
     assert ok == 30
 
 
@@ -63,7 +63,7 @@ def test_node_chaos_retriable_workload(ray_start_cluster):
     ray = cluster.connect()
 
     killer = NodeKillerActor.remote(kill_interval_s=0.5, max_kills=1, seed=2)
-    ray_tpu.get(killer.run.remote())
+    ray_tpu.get(killer.run.remote(), timeout=120)
 
     @ray_tpu.remote(max_retries=10, resources={"slot": 1})
     def shard(i):
@@ -75,9 +75,9 @@ def test_node_chaos_retriable_workload(ray_start_cluster):
     # cluster can drain the workload before the first kill interval).
     deadline = time.time() + 30
     while time.time() < deadline:
-        if ray_tpu.get(killer.get_total_killed.remote()):
+        if ray_tpu.get(killer.get_total_killed.remote(), timeout=120):
             break
         time.sleep(0.2)
     assert ray_tpu.get(refs, timeout=180) == list(range(24))
-    killed = ray_tpu.get(killer.stop_run.remote())
+    killed = ray_tpu.get(killer.stop_run.remote(), timeout=120)
     assert any(k.startswith("node:") for k in killed), killed

@@ -451,21 +451,6 @@ print("apart", apart)
 """
 
 
-def test_paged_attend_kernel_reads_the_plain_forms_logits_on_the_chip():
-    """One decode step on a chip, kernel against the plain gather-and-einsum
-    form, at the serve cell's rows ([16, 8, 128], 4 q heads a kv head): the
-    same next token in every slot and logits a bf16 rounding apart. In a
-    process of its own: this one is held to the CPU (conftest)."""
-    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-    seen, where = TPUAcceleratorManager.detect_chips()
-    if not seen:
-        pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    r = subprocess.run([sys.executable, "-c", _KERNEL_AGAINST_PLAIN_FORM], cwd=REPO, env=env,
-                       capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, r.stderr[-3000:]
-
 
 # ---------------------------------------------------------------------------
 # Latent attention (models/latent_moe.py): the decode kernel
@@ -561,20 +546,6 @@ for live in ([C, C, C, C], [0, 120, 1, 250], [0, 0, 0, 0]):
     print("prefill live", live, "apart", apart)
 """
 
-
-def test_latent_kernels_read_the_plain_forms_sums_on_the_chip():
-    """The decode and the prefill kernel against their plain forms on a chip, at
-    the served widths, contexts from one token to the table's end. In a
-    process of its own: this one is held to the CPU (conftest)."""
-    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-    seen, where = TPUAcceleratorManager.detect_chips()
-    if not seen:
-        pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    r = subprocess.run([sys.executable, "-c", _LATENT_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
-                       env=env, capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, r.stderr[-3000:]
 
 
 # ---------------------------------------------------------------------------
@@ -793,22 +764,6 @@ print("paged_attend at 64: apart", apart)
 """
 
 
-def test_hybrid_kernels_read_the_plain_forms_numbers_on_the_chip():
-    """``ssm_state_update``, ``ssm_chunk_scan`` and ``paged_attend`` at ``head_dim``
-    64 against their plain forms on a chip, at the served widths: slots skipped
-    and live; segments carried and fresh, a tile partly padding, a nobody's tile.
-    In a process of its own: this one is held to the CPU (conftest)."""
-    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-    seen, where = TPUAcceleratorManager.detect_chips()
-    if not seen:
-        pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    r = subprocess.run([sys.executable, "-c", _HYBRID_KERNELS_AGAINST_PLAIN_FORMS], cwd=REPO,
-                       env=env, capture_output=True, text=True, timeout=600)
-    print(r.stdout[-3000:])
-    assert r.returncode == 0, r.stderr[-3000:]
-
 
 # ---------------------------------------------------------------------------
 # The KDA / latent-attention expert decoder (models/kda_moe.py)
@@ -998,24 +953,6 @@ for name, (L, cfg) in WIDTHS.items():
 """
 
 
-def test_moe_decode_kernel_reads_the_grouped_forms_numbers_on_the_chip():
-    """``routed_experts`` through its kernels against its sorted ``ragged_dot``
-    form on a chip, at both served models' published widths (the second layer
-    of a stack of two, a share that does not begin at expert 0, routing by the
-    model's own router): ``moe_decode_experts`` at T = 32, 64, 128 and the
-    sorted pairs through ``moe_grouped_experts`` at T = 1,024: the counts equal, the numbers within bfloat16's rounding. In a process of its own: this one is
-    held to the CPU (conftest)."""
-    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-    seen, where = TPUAcceleratorManager.detect_chips()
-    if not seen:
-        pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "RAY_TPU_FORCE_PALLAS")}
-    r = subprocess.run([sys.executable, "-c", _MOE_KERNEL_AGAINST_GROUPED_FORM], cwd=REPO,
-                       env=env, capture_output=True, text=True, timeout=900)
-    print(r.stdout[-3000:])
-    assert r.returncode == 0, r.stderr[-3000:]
-
 
 _KDA_KERNEL_AGAINST_PLAIN_FORM = """
 import jax, jax.numpy as jnp, numpy as np
@@ -1096,23 +1033,6 @@ assert apart < 1e-4, apart
 print("kda_chunk_scan: state apart", apart)
 """
 
-
-def test_kda_kernel_reads_the_plain_forms_numbers_on_the_chip():
-    """``kda_state_update`` against its plain form on a chip at the served shapes
-    (64 slots, and 128: all live; idle rows scattered; one; none), and the chunk scan's
-    plain form against the recurrence token by token (decays down to exp(-4.9) a
-    token: the sub-tiles' ranges). In a process of its own: this one is held to
-    the CPU (conftest)."""
-    from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-    seen, where = TPUAcceleratorManager.detect_chips()
-    if not seen:
-        pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    r = subprocess.run([sys.executable, "-c", _KDA_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
-                       env=env, capture_output=True, text=True, timeout=600)
-    print(r.stdout[-3000:])
-    assert r.returncode == 0, r.stderr[-3000:]
 
 
 # ---------------------------------------------------------------------------
@@ -1317,20 +1237,57 @@ print("power_chunk_scan: state apart from token by token: kernel", apart,
 """
 
 
-def test_power_kernel_reads_the_plain_forms_numbers_on_the_chip():
-    """``power_state_update`` against its plain form on a chip at the served shape
-    (16 slots of 8 states of 136 x 8,320, five queries a state: all live; idle rows
-    scattered; one; none), and the chunk scan's kernel at the served tile of 128
-    against its plain form and against the plain update token by token (a carried
-    segment, a fresh one over three tiles, nobody's tiles between). In a process of
-    its own: this one is held to the CPU (conftest)."""
+# ---------------------------------------------------------------------------
+# On a chip: every kernel against its plain form, at the served widths
+# ---------------------------------------------------------------------------
+# A script's bound: under conftest's TEST_LIMIT_S, and about twice the slowest's
+# 125 s on a v5e with the compile cache off (PERF.md section 7; PR 56).
+_ON_THE_CHIP_BOUND_S = 240
+_ON_THE_CHIP = {
+    # One decode step, `paged_attend` against the plain gather-and-einsum form at
+    # the serve cell's rows ([16, 8, 128], 4 q heads a kv head): the same next
+    # token in every slot and logits a bf16 rounding apart.
+    "paged": _KERNEL_AGAINST_PLAIN_FORM,
+    # `latent_attend` and `latent_prefill_attend`: contexts from one token to
+    # the table's end.
+    "latent": _LATENT_KERNEL_AGAINST_PLAIN_FORM,
+    # `ssm_state_update`, `ssm_chunk_scan` and `paged_attend` at `head_dim` 64:
+    # slots skipped and live; segments carried and fresh, a tile partly padding,
+    # a nobody's tile.
+    "hybrid": _HYBRID_KERNELS_AGAINST_PLAIN_FORMS,
+    # `routed_experts` through its kernels against its sorted `ragged_dot` form
+    # at both served models' published widths (the second layer of a stack of
+    # two, a share that does not begin at expert 0, routing by the model's own
+    # router): `moe_decode_experts` at T = 32, 64, 128 and the sorted pairs
+    # through `moe_grouped_experts` at T = 1,024: the counts equal, the numbers
+    # within bfloat16's rounding.
+    "moe": _MOE_KERNEL_AGAINST_GROUPED_FORM,
+    # `kda_state_update` at the served shapes (64 slots, and 128: all live; idle
+    # rows scattered; one; none), and the chunk scan's plain form against the
+    # recurrence token by token (decays down to exp(-4.9) a token: the
+    # sub-tiles' ranges).
+    "kda": _KDA_KERNEL_AGAINST_PLAIN_FORM,
+    # `power_state_update` at the served shape (16 slots of 8 states of 136 x
+    # 8,320, five queries a state: all live; idle rows scattered; one; none), and
+    # `power_chunk_scan` at the served tile of 128 against its plain form and
+    # against the plain update token by token (a carried segment, a fresh one
+    # over three tiles, nobody's tiles between).
+    "power": _POWER_KERNEL_AGAINST_PLAIN_FORM,
+}
+
+
+@pytest.mark.parametrize("script", list(_ON_THE_CHIP.values()), ids=list(_ON_THE_CHIP))
+def test_kernels_read_their_plain_forms_numbers_on_the_chip(script):
+    """In a process of its own, which meets what a user's does (the backend
+    chooses the forms): this one is held to the CPU (conftest)."""
     from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 
     seen, where = TPUAcceleratorManager.detect_chips()
     if not seen:
         pytest.skip(f"needs a TPU, both forms run: {where}")
-    env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    r = subprocess.run([sys.executable, "-c", _POWER_KERNEL_AGAINST_PLAIN_FORM], cwd=REPO,
-                       env=env, capture_output=True, text=True, timeout=900)
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "RAY_TPU_FORCE_PALLAS")}
+    r = subprocess.run([sys.executable, "-c", script], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=_ON_THE_CHIP_BOUND_S)
     print(r.stdout[-4000:])
     assert r.returncode == 0, r.stderr[-3000:]

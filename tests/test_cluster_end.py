@@ -135,7 +135,7 @@ def test_shutdown_returns_when_its_own_cluster_is_gone(monkeypatch, neighbour):
             def pid(self):
                 return os.getpid()
 
-        actor_pid = ray_tpu.get(A.remote().pid.remote())
+        actor_pid = ray_tpu.get(A.remote().pid.remote(), timeout=120)
         controller_pid = api._controller_proc.pid
         named = _named_by_the_controller(monkeypatch)
         t0 = time.monotonic()

@@ -29,7 +29,7 @@ def test_direct_path_used_and_results_owner_local(rt):
         return x + 1
 
     refs = [f.remote(i) for i in range(20)]
-    assert ray_tpu.get(refs) == list(range(1, 21))
+    assert ray_tpu.get(refs, timeout=120) == list(range(1, 21))
     # The direct path keeps normal tasks out of the controller's
     # TaskRecord table (they surface via event-derived rows instead).
     core = ray_tpu.core.api._global_worker
@@ -47,7 +47,7 @@ def test_lease_resources_released(rt):
 
     before = ray_tpu.available_resources()["CPU"]
     refs = [hold.remote() for _ in range(8)]
-    assert sum(ray_tpu.get(refs)) == 8
+    assert sum(ray_tpu.get(refs, timeout=120)) == 8
     # queue drained → leases released → resources return
     deadline = time.time() + 10
     while time.time() < deadline:
@@ -134,7 +134,7 @@ def test_pg_tasks_through_lease_path(rt):
     def inside():
         return "pg-ok"
 
-    assert ray_tpu.get([inside.remote() for _ in range(4)]) == ["pg-ok"] * 4
+    assert ray_tpu.get([inside.remote() for _ in range(4)], timeout=120) == ["pg-ok"] * 4
     remove_placement_group(pg)
 
 

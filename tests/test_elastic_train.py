@@ -141,6 +141,21 @@ def _run_elastic(cluster, tmp_path, *, name, steps, scaling, spare_nodes):
     return result, killed["node"]
 
 
+def _train_summary_with_recovery(mode):
+    """``summarize_train()`` once the controller has counted a recovery of
+    ``mode``, or the last one read 10 s from now. The driver's counters
+    reach the controller with its metric flusher, every
+    ``metrics_report_interval_ms`` (2 s); ``fit()`` does not wait for it."""
+    from ray_tpu.util import state as state_api
+
+    deadline = time.monotonic() + 10
+    while True:
+        summary = state_api.summarize_train()
+        if summary["recoveries"].get(mode, 0) >= 1 or time.monotonic() > deadline:
+            return summary
+        time.sleep(0.2)
+
+
 def test_gang_survives_host_death_rejoin(train_cluster, tmp_path):
     """SIGKILL one train worker's HOST mid-run with a spare node
     available: the gang repairs via replacement rejoin at the SAME world
@@ -184,9 +199,9 @@ def test_gang_survives_host_death_rejoin(train_cluster, tmp_path):
         and e["id"] == killed_node
         for e in events
     )
-    summary = state_api.summarize_train()
-    assert summary["recoveries"].get("rejoin", 0) >= 1
-    assert summary["worker_deaths"] >= 1
+    summary = _train_summary_with_recovery("rejoin")
+    assert summary["recoveries"].get("rejoin", 0) >= 1, summary
+    assert summary["worker_deaths"] >= 1, summary
 
 
 def test_gang_remesh_when_no_capacity(train_cluster, tmp_path):
@@ -206,9 +221,8 @@ def test_gang_remesh_when_no_capacity(train_cluster, tmp_path):
     assert result.metrics["ws"] == 1
     assert [r["mode"] for r in result.recoveries] == ["remesh"]
     assert result.recoveries[0]["world_size"] == 1
-    from ray_tpu.util import state as state_api
-
-    assert state_api.summarize_train()["recoveries"].get("remesh", 0) >= 1
+    summary = _train_summary_with_recovery("remesh")
+    assert summary["recoveries"].get("remesh", 0) >= 1, summary
 
 
 def test_worker_kill_detected_fast(ray_start_regular, tmp_path):

@@ -34,7 +34,7 @@ def test_envelope_smoke_submitted_dwell_within_budget():
 
         t0 = time.perf_counter()
         refs = [noop.remote() for _ in range(n)]
-        out = ray_tpu.get(refs, timeout=600)
+        out = ray_tpu.get(refs, timeout=240)
         drain_s = time.perf_counter() - t0
         assert out == [0] * n
 

@@ -84,8 +84,8 @@ def test_actor_restart(ray_start_regular):
             return os.getpid()
 
     p = Phoenix.remote()
-    ray_tpu.get(p.set.remote(42))
-    pid = ray_tpu.get(p.pid.remote())
+    ray_tpu.get(p.set.remote(42), timeout=120)
+    pid = ray_tpu.get(p.pid.remote(), timeout=120)
     _kill_worker_by_pid(pid)
     time.sleep(0.5)
     # Restarted: alive but state reset (reference restart semantics).
@@ -98,7 +98,7 @@ def test_actor_restart(ray_start_regular):
             if time.time() > deadline:
                 raise
             time.sleep(0.2)
-    new_pid = ray_tpu.get(p.pid.remote())
+    new_pid = ray_tpu.get(p.pid.remote(), timeout=120)
     assert new_pid != pid
     # Second kill exhausts max_restarts.
     _kill_worker_by_pid(new_pid)
@@ -118,7 +118,7 @@ def test_actor_task_failure_without_restart(ray_start_regular):
             return "ok"
 
     m = Mortal.remote()
-    pid = ray_tpu.get(m.pid.remote())
+    pid = ray_tpu.get(m.pid.remote(), timeout=120)
     _kill_worker_by_pid(pid)
     with pytest.raises(ActorDiedError):
         for _ in range(100):

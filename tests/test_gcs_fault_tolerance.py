@@ -219,6 +219,50 @@ def test_controller_restart_mid_training(tmp_path):
         cluster.shutdown()
 
 
+def test_closing_a_subscriber_rides_a_controller_restart(tmp_path):
+    """A ``Subscriber.close()`` whose ``unsubscribe`` is the call that meets
+    the lost connection reconnects ON ITS THREAD, and the reconnect re-issues
+    the process's subscriptions under the module lock that ``close`` holds.
+    It comes back, the lock is free for the next ``subscribe``, and what
+    stayed subscribed is known to the new controller. (Until PR 56 it waited
+    on itself for ever, with every later ``subscribe`` of the process, so
+    every later ``fit()``, behind it: ``fit()``'s own close after
+    ``test_controller_restart_mid_training``'s restart was such a call.)"""
+    import threading
+
+    from ray_tpu.experimental import pubsub
+
+    session = str(tmp_path / "session")
+    config = {"controller_reconnect_window_s": 30.0}
+    proc, port = _start_controller(session, config=config)
+    try:
+        ray_tpu.init(address=f"127.0.0.1:{port}")
+        kept = pubsub.subscribe("kept")
+        closed = pubsub.subscribe("closed")
+        proc.send_signal(signal.SIGKILL)
+        proc.wait(timeout=10)
+        os.remove(os.path.join(session, "controller_port"))
+
+        came_back = threading.Event()
+
+        def close_then_subscribe():
+            closed.close()  # meets ConnectionLost, dials until the restart below
+            pubsub.subscribe("after").close()
+            came_back.set()
+
+        threading.Thread(target=close_then_subscribe, daemon=True).start()
+        proc, port2 = _start_controller(session, port=port, config=config)
+        assert port2 == port
+        assert came_back.wait(60), "close() across the restart never came back"
+        assert pubsub.publish("kept", "still here") == 1
+        assert kept.get(timeout=10) == "still here"
+        assert pubsub.publish("closed", "nobody") == 0
+        kept.close()
+    finally:
+        proc.send_signal(signal.SIGKILL)
+        ray_tpu.shutdown()
+
+
 def test_controller_restart_recovers_state(tmp_path):
     """Kill -9 the controller; a restart on the same session dir restores
     KV entries, the PG table, and re-creates the named detached actor."""

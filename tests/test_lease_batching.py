@@ -449,7 +449,7 @@ def test_batched_path_correct_and_observable():
         def sq(x):
             return x * x
 
-        assert ray_tpu.get([sq.remote(i) for i in range(300)]) == [
+        assert ray_tpu.get([sq.remote(i) for i in range(300)], timeout=120) == [
             i * i for i in range(300)
         ]
         core = ray_tpu.core.api._require_worker()
@@ -484,7 +484,7 @@ def test_legacy_knob_restores_per_task_path():
         def sq(x):
             return x * x
 
-        assert ray_tpu.get([sq.remote(i) for i in range(60)]) == [
+        assert ray_tpu.get([sq.remote(i) for i in range(60)], timeout=120) == [
             i * i for i in range(60)
         ]
         core = ray_tpu.core.api._require_worker()
@@ -537,7 +537,7 @@ def test_chaos_dying_workers_batched_push_no_task_loss():
         killer = WorkerKillerActor.remote(
             kill_interval_s=0.3, max_kills=3, seed=17
         )
-        ray_tpu.get(killer.run.remote())
+        ray_tpu.get(killer.run.remote(), timeout=120)
 
         @ray_tpu.remote(max_retries=10)
         def chunk(i):
@@ -546,7 +546,7 @@ def test_chaos_dying_workers_batched_push_no_task_loss():
 
         refs = [chunk.remote(i) for i in range(48)]
         assert ray_tpu.get(refs, timeout=180) == [i * i for i in range(48)]
-        killed = ray_tpu.get(killer.stop_run.remote())
+        killed = ray_tpu.get(killer.stop_run.remote(), timeout=120)
         assert killed, "chaos killer never killed anything"
     finally:
         ray_tpu.shutdown()
@@ -566,7 +566,7 @@ def test_agent_mirror_tracks_controller_view():
         def warm():
             return 1
 
-        assert sum(ray_tpu.get([warm.remote() for _ in range(8)])) == 8
+        assert sum(ray_tpu.get([warm.remote() for _ in range(8)], timeout=120)) == 8
         core = ray_tpu.core.api._require_worker()
         deadline = time.time() + 20
         ok = False

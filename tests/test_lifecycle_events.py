@@ -49,7 +49,7 @@ def test_direct_task_chain_and_lease_latency():
         def f(x):
             return x
 
-        assert ray_tpu.get([f.remote(i) for i in range(3)]) == [0, 1, 2]
+        assert ray_tpu.get([f.remote(i) for i in range(3)], timeout=120) == [0, 1, 2]
 
         def finished_ids():
             evs = state_api.list_lifecycle_events(limit=100000)
@@ -103,7 +103,7 @@ def test_controller_path_retry_chain(tmp_path):
                 raise RuntimeError("first attempt fails")
             return "ok"
 
-        assert ray_tpu.get(flaky.remote(marker)) == "ok"
+        assert ray_tpu.get(flaky.remote(marker), timeout=120) == "ok"
         evs = state_api.list_lifecycle_events(limit=100000)
         ids = {
             e["id"]
@@ -142,7 +142,7 @@ def test_ring_never_exceeds_configured_size():
             return x
 
         # >= 4 transitions per task: 40 tasks overflow a 50-event ring.
-        assert len(ray_tpu.get([f.remote(i) for i in range(40)])) == 40
+        assert len(ray_tpu.get([f.remote(i) for i in range(40)], timeout=120)) == 40
         evs = state_api.list_lifecycle_events(limit=100000)
         assert len(evs) <= 50
         snap = state_api.summarize_lifecycle()
@@ -249,7 +249,7 @@ def test_pg_and_actor_chains():
                 return 1
 
         a = A.remote()
-        assert ray_tpu.get(a.ping.remote()) == 1
+        assert ray_tpu.get(a.ping.remote(), timeout=120) == 1
         ray_tpu.kill(a)
         aid = a._actor_id.hex()
         assert _wait_until(
@@ -276,7 +276,7 @@ def test_lifecycle_metric_tags_bounded():
         def f():
             return 1
 
-        ray_tpu.get([f.remote() for _ in range(3)])
+        ray_tpu.get([f.remote() for _ in range(3)], timeout=120)
         assert _wait_until(
             lambda: "task_state_transitions_total" in state_api.metrics_snapshot(),
             timeout=15,
@@ -302,7 +302,7 @@ def test_summarize_tasks_capped_with_totals():
         def f(x):
             return x
 
-        ray_tpu.get([f.remote(i) for i in range(5)])
+        ray_tpu.get([f.remote(i) for i in range(5)], timeout=120)
         s = state_api.summarize_tasks()
         assert s["f"]["FINISHED"] == 5
         t = s["_totals"]
@@ -332,7 +332,7 @@ def test_timeline_merges_lifecycle_and_spans(tmp_path, monkeypatch):
             return 1
 
         with tracing.start_span("user-span"):
-            assert ray_tpu.get(traced.remote()) == 1
+            assert ray_tpu.get(traced.remote(), timeout=120) == 1
         assert _wait_until(
             lambda: any(
                 e.get("kind") == "task" and e["state"] == "FINISHED"

@@ -743,7 +743,7 @@ def test_llm_deployment_two_clients_share_one_decode_batch(serve_cluster):
                          "Content-Type": "application/json"},
                 method="POST",
             )
-            with urllib.request.urlopen(req, timeout=300) as resp:
+            with urllib.request.urlopen(req, timeout=240) as resp:
                 results[name] = [
                     json.loads(l)["tok"]
                     for l in resp.read().decode().splitlines() if l

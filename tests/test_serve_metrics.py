@@ -83,7 +83,7 @@ def _stream_tokens(port, prompt):
                  "Content-Type": "application/json"},
         method="POST",
     )
-    with urllib.request.urlopen(req, timeout=300) as resp:
+    with urllib.request.urlopen(req, timeout=240) as resp:
         return [json.loads(l)["tok"] for l in resp.read().decode().splitlines() if l]
 
 
