@@ -47,6 +47,7 @@ while one engine drives the chip.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
 import itertools
 import logging
@@ -108,17 +109,17 @@ _STARVED = _SUB_PHASES + ("emit", "record", "between")
 _SPEC_BLOCKED = ("idle", "admission", "finishing")
 # The counters a recorded step carries as what the iteration added to them.
 _STEP_COUNTS = ("tokens", "prefills", "preemptions", "admitted", "prefill_chunks",
-                "prefill_segments", "prefix_hit_tokens", "windows_behind_prefill",
-                "state_slots_live")
+                "prefill_segments", "prefill_width_tokens", "prefix_hit_tokens",
+                "windows_behind_prefill", "state_slots_live")
 # The width under which a chunk call's time is the read of the weights and no
 # longer its tokens' arithmetic: two FLOPs and two bytes a parameter a token
 # put it at peak FLOP/s over peak bytes/s, 240 tokens on a v5e whatever the
 # model, as long as a token multiplies every weight the call reads (an expert
-# layer's token multiplies a few of them: its ridge lies higher, and a fixed
-# ``prefill_chunk`` leaves this packing no width to choose). Measured on the
-# dense decoder of Mistral-7B's widths, whose chunk program read 16.2 / 17.2 /
-# 20.35 ms at 64 / 128 / 256 tokens and 0.086 ms a token above (PERF.md
-# section 5). ``_run_suffixes`` packs by it.
+# layer's token multiplies a few of them: its ridge lies higher). Measured on
+# the dense decoder of Mistral-7B's widths, whose chunk program read 16.2 /
+# 17.2 / 20.35 ms at 64 / 128 / 256 tokens and 0.086 ms a token above (PERF.md
+# section 5). ``_run_suffixes`` packs by it, and a fixed ``prefill_chunk``'s
+# ladder of widths ends above it (``_chunk_ladder``).
 _WEIGHTS_WIDTH = 256
 # Counts an expert model's programs return behind their tokens
 # (``paged._with_counts``), in this order: token-expert pairs computed by the
@@ -130,6 +131,18 @@ _WEIGHTS_WIDTH = 256
 # host never reads (no segment of it ends a prompt) is not counted.
 _MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_fused_layer_steps",
                "moe_grouped_layer_steps")
+
+
+def _chunk_ladder(chunk: int, block_size: int) -> List[int]:
+    """The widths an engine with a fixed ``prefill_chunk`` calls its chunk
+    program at, narrowest first: the chunk, then its halves for as long as a
+    half is whole tiles of four blocks and no narrower than ``_WEIGHTS_WIDTH``
+    (under it a call is the read of the weights whatever its width, so a
+    narrower program would be compiled for nothing)."""
+    widths = [chunk]
+    while widths[0] % (8 * block_size) == 0 and widths[0] // 2 >= _WEIGHTS_WIDTH:
+        widths.insert(0, widths[0] // 2)
+    return widths
 
 
 @dataclasses.dataclass
@@ -501,7 +514,14 @@ class LLMEngine:
         into fixed-size chunks interleaved with decode windows, so one
         long admission no longer freezes every active stream (bounds
         TPOT). Rounded up to a block multiple; None/0 = single-shot
-        prefill (existing behavior).
+        prefill (existing behavior). A fixed chunk is a short LADDER of
+        compiled widths (``_chunk_ladder``: the chunk and its halves down
+        to ``_WEIGHTS_WIDTH``; 1,024, 512, 256), each an executable
+        compiled from shapes while this constructor makes the weights,
+        all at once on threads, and run once with no segment before it
+        returns: the served path compiles nothing. A chunk call goes at
+        the narrowest width whose tiles hold what it carries, a long
+        prompt's last chunk included (``_chunk_width``).
 
         ``overlap``: double-buffer decode — dispatch window N+1 from
         window N's device-resident outputs BEFORE reading N's tokens, so
@@ -550,6 +570,9 @@ class LLMEngine:
             self._widths.append(self._widths[-1] * 2)
         if self._widths[-1] < p.max_seq_len:
             self._widths.append(p.max_seq_len)
+        # A fixed chunk's widths, each an executable from build on; without
+        # one, chunk calls go at ``_widths``, compiled as they are met.
+        self._ladder = _chunk_ladder(self.prefill_chunk, p.block_size) if self.prefill_chunk else []
         _leave_persistent_compile_cache()
         # Whether the programs return counts behind their tokens (``_MOE_COUNTS``):
         # ``_build_programs`` reads it off the decode program's output.
@@ -617,6 +640,7 @@ class LLMEngine:
                       "prefix_evictions_wanted": 0, "prefix_evictions_spared": 0,
                       "prefix_published_blocks": 0, "prefill_segments": 0,
                       "prefill_tile_queries": 0, "prefill_live_queries": 0,
+                      "prefill_width_tokens": 0,
                       "decode_blocks_live": 0, "decode_blocks_table": 0,
                       "windows_behind_prefill": 0, "prefill_flushed_first": 0,
                       "state_slots_live": 0, "state_slots_table": 0,
@@ -642,6 +666,10 @@ class LLMEngine:
         self._where = "between"
         self._starved: Dict[str, int] = {}
         self._idle = True  # the last step found no work
+        for width in self._ladder:
+            # No segment: every tile on the trash block. A width that does not
+            # fit the device fails here and not in a served window.
+            jax.block_until_ready(self._chunk_call(width, []))
         if warmup_buckets:
             t0 = time.perf_counter()
             self.stats["warmup_compiles"] = self._warmup()
@@ -690,7 +718,10 @@ class LLMEngine:
         compiled to ACCEPT that same layout, so one params tree serves
         both programs copy-free. There is no plain-jit fallback: a
         failure here is the failure to report (at 7B the fallback's own
-        program does not fit the chip and would bury it under an OOM)."""
+        program does not fit the chip and would bury it under an OOM).
+        The chunk program is jitted too, and compiles at each width it
+        meets; with a fixed ``prefill_chunk`` it is instead the executables
+        of ``_ladder`` by width, compiled here."""
         cfg, p, window, blocks = self.cfg, self.pcfg, self.window, self._blocks
         bs = p.block_size
 
@@ -754,12 +785,6 @@ class LLMEngine:
         (params_fmt, *_), _kwargs_fmt = compiled.input_formats
         # Whether this model's programs carry counts behind their tokens.
         self._counted = compiled.out_info[0].shape[0] > window
-        if callable(params):
-            # Materialize weights directly in the program's layout —
-            # no second copy ever exists on device.
-            params = jax.jit(params, out_shardings=params_fmt)()
-        else:
-            params = jax.device_put(params, params_fmt)
         # The cache and ``cur`` reach these two from three makers: fresh and
         # uncommitted, the decode program's output (uncommitted: it was lowered
         # from shapes alone) and their own (committed, because ``params_fmt``
@@ -776,15 +801,48 @@ class LLMEngine:
             _chunk, donate_argnums=(2,),
             in_shardings=(params_fmt, None, placed) + (None,) * 5 + (placed,),
         )
+
+        def chunk_at(width):
+            """The chunk program ``width`` wide as an executable, from the
+            shapes ``_chunk_call`` builds."""
+            n = width // chunk_tile(width, bs)
+            return chunk.lower(
+                params_s, sds((1, width), np.int32), cache_s, sds((n, W), np.int32),
+                sds((width // bs if blocks else 0,), np.int32), sds((5, n), np.int32),
+                sds((n,), np.float32), sds((2,), np.uint32), sds((b,), np.int32),
+            ).compile()
+
+        # A fixed chunk's widths need nothing of each other or of the weights:
+        # each compiles on a thread of its own while this one makes the weights
+        # (a chunk program compiles for 15-25 s at 5 B parameters; one after
+        # another, or on the served path, each is that much set-up: PERF.md,
+        # PR 57). An engine without a fixed chunk starts none.
+        with concurrent.futures.ThreadPoolExecutor(len(self._ladder) or 1, "chunk-compile") as pool:
+            compiling = {width: pool.submit(chunk_at, width) for width in self._ladder}
+            if callable(params):
+                # Materialize weights directly in the program's layout —
+                # no second copy ever exists on device.
+                params = jax.jit(params, out_shardings=params_fmt)()
+            else:
+                params = jax.device_put(params, params_fmt)
+        if self._ladder:
+            chunk = {}
+            for width, done in compiling.items():
+                try:
+                    chunk[width] = done.result(timeout=0)  # the pool's exit waited for it
+                except Exception as e:
+                    raise RuntimeError(
+                        f"the chunk program {width} tokens wide did not compile: {e}") from e
         return compiled, prefill, chunk, params
 
     def _warmup(self) -> int:
         """Compile every program shape the serving path can hit: each
-        prefill bucket, the chunk program (fixed chunk width, or every
-        suffix bucket when the prefix cache may shorten prompts), and the
-        decode window. All warmup writes scatter into the trash block, so
-        live cache blocks are untouched. Returns the number of program
-        executions (== compilations on a cold process)."""
+        prefill bucket, the chunk program (every suffix bucket when the
+        prefix cache may shorten prompts; a fixed chunk's widths are
+        executables already), and the decode window. All warmup writes
+        scatter into the trash block, so live cache blocks are untouched.
+        Returns the number of program executions (== compilations on a
+        cold process)."""
         bs = self.pcfg.block_size
         self.key, sub = jax.random.split(self.key)
         n = 0
@@ -798,13 +856,10 @@ class LLMEngine:
                 np.float32(0.0), sub, self._dev["cur"],
             )
             n += 1
-        if self.prefill_chunk:
-            chunk_sizes = [self.prefill_chunk]
-        elif self.prefix_cache is not None or self._state_pools:
-            chunk_sizes = self._widths  # cache hits, or whole prompts, in bucketed calls
-        else:
-            chunk_sizes = []
-        for C in chunk_sizes:
+        # Cache hits, or whole prompts, in bucketed calls (a fixed chunk's widths
+        # were compiled and run at build, whatever ``warmup_buckets``).
+        bucketed = not self._ladder and (self.prefix_cache is not None or self._state_pools)
+        for C in self._widths if bucketed else []:
             self._chunk_call(C, [])  # no segment: every tile on the trash block
             n += 1
         # Decode window: already compiled (AOT) — this is its first
@@ -1169,11 +1224,12 @@ class LLMEngine:
 
     def _chunk_width(self, lens: Sequence[int]) -> Optional[int]:
         """The narrowest chunk call that holds segments of ``lens`` tokens,
-        each padded to that width's tiles: the configured chunk width when
-        set (one compiled shape serves every call), else one of
-        ``_widths``. None if they do not fit one call."""
+        each padded to that width's own tiles: a width of the fixed chunk's
+        ladder when one is set (1,024, 512 or 256: a call that carries one
+        short prompt does not run 1,024 places of matrices), else one of
+        ``_widths``. None if they do not fit the widest."""
         bs = self.pcfg.block_size
-        for width in ([self.prefill_chunk] if self.prefill_chunk else self._widths):
+        for width in self._ladder or self._widths:
             tile = chunk_tile(width, bs)
             if sum(-(-n // tile) * tile for n in lens) <= width:
                 return width
@@ -1218,7 +1274,8 @@ class LLMEngine:
         self._chunk_rr = i
         st = self._prefilling[i]
         start, st.pos = st.pos, min(st.pos + self.prefill_chunk, st.plen)
-        self._run_chunks(self.prefill_chunk, [(i, st.req, st.tokens, start, st.pos)])
+        # A prompt's last chunk may be short: the narrowest width that holds it.
+        self._run_chunks(self._chunk_width([st.pos - start]), [(i, st.req, st.tokens, start, st.pos)])
 
     def _run_full_prefill(self, i: int, req: Request, full: List[int]):
         """Whole-prompt full-attention prefill (bucketed); returns the
@@ -1256,6 +1313,9 @@ class LLMEngine:
         toks = self._chunk_call(width, segs)
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_segments"] += len(segs)
+        # Over ``prefill_chunks`` the mean call's width; ``prefill_tile_queries``
+        # over it the share of a call's places that segments took.
+        self.stats["prefill_width_tokens"] += width
         for k, (i, req, full, _start, end) in enumerate(segs):
             if end == len(full):
                 self._prefilling.pop(i, None)
@@ -1325,7 +1385,10 @@ class LLMEngine:
             # Built per call and never written again, so the program may read
             # them where they lie: only the slot mirrors, which the scheduler
             # mutates in place, need _hand_over's copy.
-            out, self.cache, self._dev["cur"] = self._prefill_chunk_fn(
+            program = self._prefill_chunk_fn
+            if self._ladder:
+                program = program[width]  # an executable: it cannot compile, a mismatch raises
+            out, self.cache, self._dev["cur"] = program(
                 self.params, toks, self.cache, trows, crow, per_tile, temps, sub,
                 self._dev["cur"])
             self._launched()
@@ -1717,6 +1780,7 @@ class LLMEngine:
                     "admitted": moved["admitted"],
                     "chunks": moved["prefill_chunks"],  # chunk-program calls, and the
                     "segments": moved["prefill_segments"],  # suffixes or chunks in them
+                    "chunk_width": moved["prefill_width_tokens"],  # the calls' widths, summed
                     "prefix_hit_tokens": moved["prefix_hit_tokens"],
                     "cached_blocks": pc.resident_blocks if pc else 0,
                     "overlapped": overlapped,
@@ -1879,12 +1943,14 @@ class LLMEngine:
                 # (an answer's, a preempted request's), not at a prefill's end.
                 "published_blocks": self.stats["prefix_published_blocks"],
             },
-            # Chunk-program calls: the segments in them, the queries of the
-            # tiles those took and the real ones among them (a segment is
-            # padded to whole tiles; a model may skip the padding).
+            # Chunk-program calls: the segments in them, the calls' widths
+            # summed, the queries of the tiles the segments took and the real
+            # ones among them (a segment is padded to whole tiles; a model may
+            # skip the padding).
             prefill={
                 "chunks": self.stats["prefill_chunks"],
                 "segments": self.stats["prefill_segments"],
+                "width_tokens": self.stats["prefill_width_tokens"],
                 "tile_queries": self.stats["prefill_tile_queries"],
                 "live_queries": self.stats["prefill_live_queries"],
                 "live_query_pct": 100.0 * self.stats["prefill_live_queries"]

@@ -16,6 +16,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import jax
@@ -26,7 +27,7 @@ import pytest
 from ray_tpu.models.paged import PagedConfig
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve.llm_engine import LLMEngine
-from ray_tpu.util import tracing
+from ray_tpu.util import compile_tracker, tracing
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASE_FIELDS = [f"{name}_ms" for name in llm_engine._PHASES]
@@ -807,3 +808,194 @@ def test_decode_program_carries_the_paged_scopes(tiny_model):
     for scope in ("paged.scatter", "paged.attend", "paged.mlp"):
         assert any("op_name=" in line and f"/{scope}/" in line
                    for line in text.splitlines()), scope
+
+
+# ---------------------------------------------------------------------------
+# A fixed ``prefill_chunk`` is a ladder of widths, each an executable from the
+# engine's build on, compiled side by side; a call goes at the narrowest.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("chunk, block_size, ladder", [
+    (1024, 16, [256, 512, 1024]),   # the hybrid and KDA cells: tiles of 64
+    (1024, 32, [256, 512, 1024]),   # the power cell: tiles of 128
+    (1024, 64, [256, 512, 1024]),   # the latent cell: 256 is one tile
+    (512, 16, [256, 512]),
+    (256, 16, [256]),               # a half would lie under _WEIGHTS_WIDTH
+    (768, 16, [384, 768]),
+    (1536, 64, [768, 1536]),        # 768 halves to no whole tile of four blocks
+    (32, 8, [32]),                  # the tests' own chunks: one width, as before
+    (1000, 8, [1000]),              # an odd count of tiles does not halve
+])
+def test_the_ladder_is_the_chunk_and_its_halves_down_to_the_weights_width(chunk, block_size, ladder):
+    assert llm_engine._chunk_ladder(chunk, block_size) == ladder
+    assert all(w % (4 * block_size) == 0 or w == chunk for w in ladder)
+
+
+class _Built:
+    """An engine with ``prefill_chunk=1024`` and what its build compiled where."""
+
+    def __init__(self, which, **kw):
+        compile_tracker.install()
+        self.cfg, self.params = _tiny(which)
+        self.aot_threads = []  # the thread of every ``Lowered.compile`` of the build
+        real = jax.stages.Lowered.compile
+
+        def compile_(lowered, *a, **k):
+            self.aot_threads.append(threading.get_ident())
+            return real(lowered, *a, **k)
+
+        before = self._compiled()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax.stages.Lowered, "compile", compile_)
+            self.eng = self.build(**kw)
+        self.compiled = {k: v - before.get(k, 0) for k, v in self._compiled().items()
+                         if v > before.get(k, 0)}
+        self.main_thread = threading.get_ident()
+
+    def build(self, **kw):
+        pcfg = PagedConfig(block_size=16, num_blocks=521, max_batch=4, max_blocks_per_seq=130)
+        kw = {**dict(decode_window=3, overlap=True, prefill_chunk=1024), **kw}
+        return LLMEngine(self.params, self.cfg, pcfg, **kw)
+
+    @staticmethod
+    def _compiled():
+        return {k: v["count"] for k, v in compile_tracker.snapshot(max_functions=10_000)["functions"].items()}
+
+
+@pytest.fixture(scope="module", params=["dense", "hybrid"])
+def laddered(request):
+    return _Built(request.param)
+
+
+def _widths_called(eng, monkeypatch):
+    """Record every chunk call's width and its segments' (slot, generation, start, end)."""
+    calls = []
+    chunk_call = eng._chunk_call
+
+    def spy(width, segs):
+        calls.append((width, [(i, eng._slot_gen[i], start, end) for i, _r, _f, start, end in segs]))
+        return chunk_call(width, segs)
+
+    monkeypatch.setattr(eng, "_chunk_call", spy)
+    return calls
+
+
+def _prompt(cfg, n, seed=0):
+    return np.random.default_rng(seed + n).integers(1, cfg.vocab_size, n).tolist()
+
+
+def test_a_fixed_chunk_is_built_as_the_ladders_executables_side_by_side(laddered):
+    """1,024, 512 and 256, each a compiled executable (not a jitted function
+    that would compile on its first call) when ``__init__`` returns, compiled
+    on threads of their own beside the main one, which compiled the decode
+    program and then made the weights."""
+    eng = laddered.eng
+    assert eng._ladder == [256, 512, 1024]
+    assert sorted(eng._prefill_chunk_fn) == eng._ladder
+    assert all(isinstance(x, jax.stages.Compiled) for x in eng._prefill_chunk_fn.values())
+    assert laddered.compiled.get("_chunk") == 3 and laddered.compiled.get("_decode") == 1
+    decode_thread, *chunk_threads = laddered.aot_threads
+    assert decode_thread == laddered.main_thread and len(chunk_threads) == 3
+    assert laddered.main_thread not in chunk_threads and len(set(chunk_threads)) > 1
+
+
+@pytest.mark.parametrize("lens, width", [
+    ([5], 256), ([200], 256), ([256], 256), ([257], 512), ([100, 100], 256), ([300, 200], 1024),
+    ([300, 100], 512), ([600], 1024), ([1024], 1024), ([1025], None), ([600, 500], None),
+])
+def test_a_call_goes_at_the_narrowest_width_whose_tiles_hold_it(laddered, lens, width):
+    """Segments are padded to tiles of 64 at every width of this ladder: the
+    narrowest that holds them, the widest when none narrower does, None past it."""
+    assert laddered.eng._chunk_width(lens) == width
+
+
+def test_one_width_when_a_half_would_lie_under_the_weights_width():
+    built = _Built("dense", prefill_chunk=256)
+    assert built.eng._ladder == [256] and list(built.eng._prefill_chunk_fn) == [256]
+    assert built.compiled.get("_chunk") == 1
+    assert [built.eng._chunk_width(lens) for lens in ([5], [256], [257])] == [256, 256, None]
+
+
+def test_the_served_path_compiles_nothing_at_any_width(laddered, monkeypatch):
+    """A first live call at EACH width of the ladder, a carried last chunk
+    among them, leaves the process's compile count where the build left it:
+    the build ran every executable once, and an executable cannot compile."""
+    eng, cfg = laddered.eng, laddered.cfg
+    calls = _widths_called(eng, monkeypatch)
+    before = compile_tracker.snapshot()["compiles"]
+    # (A dense model's prompts under the chunk are whole-prompt programs, compiled as met.)
+    lens, widths = (((5, 300, 600, 1024 + 40), [256, 512, 1024, 1024, 256]) if eng._state_pools
+                    else ((1024 + 40, 1024 + 300), [1024, 256, 1024, 512]))
+    for n in lens:
+        eng.generate_batch([_prompt(cfg, n)], 2)
+    assert [w for w, _segs in calls] == widths
+    assert compile_tracker.snapshot()["compiles"] == before
+
+
+@pytest.mark.parametrize("lens", [(5, 300, 600, 1100, 1500), (1024 + 300, 40, 1024 + 124)],
+                         ids=["short_and_carried", "last_chunks_of_a_half_and_a_quarter"])
+def test_the_ladder_serves_the_tokens_of_the_one_widest_width(laddered, monkeypatch, lens):
+    """The same requests through an engine whose ladder is forced to its one
+    widest width (every call 1,024 wide, as before) and through the ladder:
+    the same tokens, greedy, a long prompt's carried last chunk at a narrower
+    width included; every assignment's segments cover its prompt once, in order,
+    so no position runs twice; and the counter is the widths the calls had."""
+    cfg = laddered.cfg
+    prompts = [_prompt(cfg, n, seed=3) for n in lens]
+    asked = [7, 4, 9, 5, 6][:len(lens)]
+
+    def serve(eng):
+        reqs = [eng.add_request(pr, m) for pr, m in zip(prompts, asked)]
+        while eng.active_count() or eng.waiting:
+            eng.step()
+        return [r.generated for r in reqs]
+
+    with monkeypatch.context() as mp:
+        mp.setattr(llm_engine, "_chunk_ladder", lambda chunk, bs: [chunk])
+        widest = laddered.build()
+    assert widest._ladder == [1024]
+    wide_calls = _widths_called(widest, monkeypatch)
+    want = serve(widest)
+    assert {w for w, _ in wide_calls} == {1024}
+    eng = laddered.eng
+    calls = _widths_called(eng, monkeypatch)
+    before = dict(eng.stats)
+    eng.recorder.steps.clear()
+    assert serve(eng) == want and [len(g) for g in want] == asked
+    widths = [w for w, _ in calls]
+    assert min(widths) < 1024 and set(widths) <= set(eng._ladder)
+    at = {}
+    for _width, segs in calls:
+        for slot, gen, start, end in segs:
+            assert start == at.get((slot, gen), 0), (slot, gen, start)
+            at[(slot, gen)] = end
+    # A dense model's short prompts are whole-prompt programs: only the chunked ones are here.
+    chunked = sorted(n for n in lens if eng._state_pools or n > 1024)
+    assert sorted(at.values()) == chunked
+    moved = {k: eng.stats[k] - before[k] for k in
+             ("prefill_width_tokens", "prefill_tile_queries", "prefill_live_queries", "prefill_chunks")}
+    assert moved["prefill_width_tokens"] == sum(widths) and moved["prefill_chunks"] == len(widths)
+    assert moved["prefill_live_queries"] == sum(chunked)
+    assert moved["prefill_live_queries"] <= moved["prefill_tile_queries"] <= moved["prefill_width_tokens"]
+    assert moved["prefill_width_tokens"] < widest.stats["prefill_width_tokens"] == 1024 * len(wide_calls)
+    assert sum(step["chunk_width"] for step in eng.recorder.steps) == sum(widths)
+    assert eng.report_state()["prefill"]["width_tokens"] == eng.stats["prefill_width_tokens"]
+
+
+@pytest.mark.parametrize("which", ["dense", "hybrid"])
+def test_an_engine_without_a_fixed_chunk_builds_and_calls_what_it_did(which, monkeypatch):
+    """No ladder, no thread, no chunk program compiled at build: the chunk
+    program is the jitted function that compiles at each bucket it meets, and
+    the counter of widths counts those buckets."""
+    built = _Built(which, prefill_chunk=0, enable_prefix_cache=which == "dense")
+    eng = built.eng
+    assert eng._ladder == [] and "_chunk" not in built.compiled and built.compiled.get("_decode") == 1
+    assert built.aot_threads == [built.main_thread]  # the decode program alone, here
+    assert eng._prefill_chunk_fn._cache_size() == 0
+    calls = _widths_called(eng, monkeypatch)
+    doc = _prompt(built.cfg, 32)
+    eng.generate_batch([doc], 2)
+    eng.generate_batch([doc + _prompt(built.cfg, 5)], 2)  # dense: a suffix behind a hit of two blocks
+    widths = [w for w, _ in calls]
+    assert widths and set(widths) <= set(eng._widths)
+    assert eng._prefill_chunk_fn._cache_size() == len(set(widths))
+    assert eng.stats["prefill_width_tokens"] == sum(widths)

@@ -445,7 +445,7 @@ def test_engine_tells_the_chunk_program_each_tiles_real_queries_and_counts_them(
     assert (s["prefill_tile_queries"], s["prefill_live_queries"]) == (96 + 8, 45 + 3)
     snap = eng.report_state()
     assert snap["prefill"] == {
-        "chunks": 2, "segments": 3, "tile_queries": 104, "live_queries": 48,
+        "chunks": 2, "segments": 3, "width_tokens": 128 + 8, "tile_queries": 104, "live_queries": 48,
         "live_query_pct": pytest.approx(100 * 48 / 104)}
     assert snap["stats"]["prefill_live_queries"] == 48
     m = serve_metrics()
