@@ -1,0 +1,93 @@
+"""The four parts of a selecting full layer's attention alone on the chip (PR 59),
+at the served widths: one decode step's (32 slots, a table of 544 blocks of 64,
+64 index heads of 128, 2,048 of up to 34,816 cached tokens, 128 heads on rows of
+640) and one group of a chunk call's (64 queries of one slot).
+
+For each part microseconds a call, jitted alone, and what its bytes would take at
+the memory's peak: the index score, the selection (``jax.lax.top_k``, the form
+served), the gather of the chosen rows, the attention over them, and the four
+together; the sliding layers' window read beside them.
+
+    chiprun -- python3 benchmarks/sparse_parts_sweep.py --out chiprun_out/sparse_parts.json
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))  # run from a checkout
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import sparse_latent_attention as sparse
+
+HBM_BYTES_PER_S = 819e9  # chipbench/peaks.py: TPU v5e
+B, W, BS, HI, DI, H, R, RANK, TOPK = 32, 544, 64, 64, 128, 128, 640, 512, 2048
+BLOCKS = 2 * 6145  # the two full layers' pools, flat (as the issue sized them; the cell's hold 4,865)
+
+
+def timed(fn, *args, reps: int = 20) -> float:
+    """Microseconds a call, after one call that compiles."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e6
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--out", default="")
+    p.add_argument("--context", type=int, default=33300, help="cached tokens a slot")
+    args = p.parse_args(argv)
+    if jax.devices()[0].platform != "tpu":
+        print("sparse_parts_sweep: a measurement needs the chip", file=sys.stderr)
+        return 2
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 8)
+    rows = jax.random.normal(ks[0], (BLOCKS, BS, R), jnp.bfloat16)
+    keys = jax.random.normal(ks[1], (BLOCKS, BS, DI), jnp.bfloat16)
+    tables = jnp.asarray(np.random.default_rng(0).permutation(np.arange(1, 6145))[:B * 170]
+                         .reshape(B, 170).repeat(4, axis=1)[:, :W].astype(np.int32))
+    lens = jnp.full((B,), args.context, jnp.int32)
+    q = jax.random.normal(ks[2], (B, H, R), jnp.bfloat16)
+    qi = jax.random.normal(ks[3], (B, HI, DI), jnp.bfloat16)
+    w = jax.random.normal(ks[4], (B, HI), jnp.float32)
+    window_pool = jax.random.normal(ks[5], (6145, BS, 1152), jnp.bfloat16)
+    qw = jax.random.normal(ks[6], (B, 64, 1152), jnp.bfloat16)
+
+    score = jax.jit(lambda: sparse.index_scores(qi, w, keys, tables, lens))
+    scores = score()
+    select = jax.jit(lambda s: sparse.select(s, TOPK))
+    pos, valid = select(scores)
+    gather = jax.jit(lambda pos: sparse.gather_rows(rows, tables, pos))
+    got = gather(pos)
+    attend = jax.jit(lambda got, valid: sparse.attend_rows(q, got, valid, 192 ** -0.5, RANK))
+    whole = jax.jit(lambda: sparse.sparse_attention(q, qi, w, rows, keys, tables, tables, lens,
+                                                    192 ** -0.5, RANK, TOPK))
+    window = jax.jit(lambda: sparse.window_attention(qw, window_pool, tables, lens, 256 ** -0.5, 1024, 513))
+    group = jax.random.normal(ks[7], (64, W * BS), jnp.float32)
+    out = {
+        "context": args.context, "device": jax.devices()[0].device_kind,
+        "score_us": timed(score), "select_us": timed(select, scores), "gather_us": timed(gather, pos),
+        "attend_us": timed(attend, got, valid), "whole_us": timed(whole), "window_us": timed(window),
+        "chunk_group_select_us": timed(select, group),
+        "score_least_us": B * args.context * DI * 2 / HBM_BYTES_PER_S * 1e6,
+        "gather_least_us": B * TOPK * R * 2 / HBM_BYTES_PER_S * 1e6,
+    }
+    print("SPARSE_PARTS " + json.dumps(out), flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(out, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -131,6 +131,12 @@ _WEIGHTS_WIDTH = 256
 # host never reads (no segment of it ends a prompt) is not counted.
 _MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_fused_layer_steps",
                "moe_grouped_layer_steps")
+# Further counts of a model whose attention selects what it reads or slides a
+# window (``models/sparse_latent_moe.py``), behind the five above: cached tokens
+# a query could have read, summed over slots (or a chunk call's real queries),
+# selecting layers and steps; how many of them it selected; cache rows the
+# window layers' reads covered. A model returns the first so many of ``_COUNTS``.
+_COUNTS = _MOE_COUNTS + ("sparse_keys_live", "sparse_keys_selected", "window_rows_read")
 
 
 def _chunk_ladder(chunk: int, block_size: int) -> List[int]:
@@ -574,9 +580,9 @@ class LLMEngine:
         # one, chunk calls go at ``_widths``, compiled as they are met.
         self._ladder = _chunk_ladder(self.prefill_chunk, p.block_size) if self.prefill_chunk else []
         _leave_persistent_compile_cache()
-        # Whether the programs return counts behind their tokens (``_MOE_COUNTS``):
-        # ``_build_programs`` reads it off the decode program's output.
-        self._counted = False
+        # How many counts the programs return behind their tokens (the first so
+        # many of ``_COUNTS``): ``_build_programs`` reads it off the decode program's output.
+        self._counted = 0
         self.cache = init_paged_cache(cfg, p)
         # For ``report_state``: the arrays themselves are donated call by call.
         self._pool_facts = {
@@ -645,7 +651,7 @@ class LLMEngine:
                       "windows_behind_prefill": 0, "prefill_flushed_first": 0,
                       "state_slots_live": 0, "state_slots_table": 0,
                       "state_segments_carried": 0, "state_segments_fresh": 0,
-                      **{name: 0 for name in _MOE_COUNTS},
+                      **{name: 0 for name in _COUNTS},
                       **{f"spec_blocked_{why}": 0 for why in _SPEC_BLOCKED},
                       "starved_us": 0, "unloaded_us": 0,
                       **{f"starved_us_{where}": 0 for where in _STARVED}}
@@ -783,8 +789,8 @@ class LLMEngine:
         )
         compiled = dec.lower(*args_s).compile()
         (params_fmt, *_), _kwargs_fmt = compiled.input_formats
-        # Whether this model's programs carry counts behind their tokens.
-        self._counted = compiled.out_info[0].shape[0] > window
+        # The counts this model's programs carry behind their tokens.
+        self._counted = compiled.out_info[0].shape[0] - window
         # The cache and ``cur`` reach these two from three makers: fresh and
         # uncommitted, the decode program's output (uncommitted: it was lowered
         # from shapes alone) and their own (committed, because ``params_fmt``
@@ -1458,7 +1464,7 @@ class LLMEngine:
         if self._counted:
             for v in vals.values():
                 if v.ndim:  # a chunk call's; a whole-prompt program's is one token
-                    self._count(v[-len(_MOE_COUNTS):])
+                    self._count(v[-self._counted:])
         with tracing.phase("engine.emit", self._phase_ms):
             self._at("emit")
             for i, req, t, k in pend:
@@ -1469,7 +1475,7 @@ class LLMEngine:
 
     def _count(self, counts):
         """Add the counts one program call returned behind its tokens."""
-        for name, n in zip(_MOE_COUNTS, counts):
+        for name, n in zip(_COUNTS, counts):
             self.stats[name] += int(n)
 
     def _emit(self, i: int, toks: List[int]):

@@ -840,6 +840,82 @@ def test_kda_programs_read_weights_and_pools_where_they_lie_on_v5e(v5e):
 
 
 # ---------------------------------------------------------------------------
+# Selecting and sliding latent attention (models/sparse_latent_moe.py): no kernel
+# of its own, so what is held is that the plain forms copy no pool
+# ---------------------------------------------------------------------------
+def test_sparse_programs_read_pools_where_they_lie_on_v5e(v5e):
+    """The served cut of the published model (the leading full layer and one
+    period of full, sliding, sliding, sliding: every width published, 8 of the
+    256 experts held and the vocabulary cut for the compile's sake) at the
+    served cache (32 slots, 4,865 blocks of 64, a table of 544) in the decode
+    window and the widest chunk program as ``LLMEngine`` builds them: the expert
+    layers are the two kernels of ``ops/moe.py``, no operation COPIES a pool
+    (the index score gathers a slot's keys through its table, the sort's list
+    gathers 2,048 rows, a window read gathers 9 or 13 blocks), and the
+    temporaries stay under a gigabyte beside 3.1 GB of pools: the decode step's
+    largest are the index score's products ``[32, 64, 34816]`` in float32 (285
+    MB), the chunk call's a window tile's scores ``[4, 256, 64, 832]`` (218 MB)
+    and a group's gathered rows ``[64, 2048, 640]`` (168 MB)."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import sparse_latent_moe as sm
+    from ray_tpu.models.paged import (PagedConfig, chunk_tile, init_paged_cache,
+                                      paged_decode_loop, prefill_chunk_and_sample)
+
+    cfg = sm.SparseLatentMoEConfig(
+        num_hidden_layers=5, layer_types=(sm.FULL, sm.FULL) + (sm.SLIDING,) * 3, vocab_size=2048,
+        held_count=8)
+    p = PagedConfig(block_size=64, num_blocks=4865, max_batch=32, max_blocks_per_seq=544)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: sm.init_params(k, cfg), jax.random.PRNGKey(0)))
+    auto = jax.tree.map(lambda a: Format(Layout.AUTO, one), params)
+    cache = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_paged_cache(cfg, p)))
+    assert {k: v.shape[0] for k, v in cache.items()} == {"rows": 2, "index": 2, "window": 3}
+    b, w, bs = p.max_batch, p.max_blocks_per_seq, p.block_size
+    pool_copy = re.compile(r"bf16\[(?:9730|14595|2,4865|3,4865)[0-9,]*\]\S* copy\(")
+
+    def decode(params, tokens, cache, tables, lens, temps, key):
+        return paged_decode_loop(params, cfg, tokens, cache, tables, lens, temps, key, 2)
+
+    compiled = jax.jit(decode, donate_argnums=(2,), in_shardings=(auto,) + (None,) * 6).lower(
+        params, sds((b,), np.int32), cache, sds((b, w), np.int32), sds((b,), np.int32),
+        sds((b,), np.float32), sds((2,), np.uint32)).compile()
+    text = compiled.as_text()
+    # Two steps of four expert layers, 32 tokens each: under the ridge.
+    assert _kernel_names(text) == ["moe_decode_experts"] * 8 and "ragged-dot" not in text
+    assert not pool_copy.search(text)
+    assert compiled.out_info[0].shape == (2 + 8, b)  # the window's tokens, then the eight counts
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    (params_fmt, *_), _ = compiled.input_formats
+    width = 1024
+    n = width // chunk_tile(width, bs)
+
+    def chunk(params, tokens, cache, table_rows, chunk_row, per_tile, temps, key, cur):
+        starts, last_idx, slot_of, live, state_of = per_tile
+        toks, cache = prefill_chunk_and_sample(
+            params, cfg, tokens, cache, table_rows, chunk_row, bs, starts, last_idx, live,
+            state_of, temps, key)
+        return toks, cache, cur.at[slot_of].set(toks[:n], mode="drop")
+
+    compiled = jax.jit(chunk, donate_argnums=(2,), in_shardings=(params_fmt,) + (None,) * 8).lower(
+        params, sds((1, width), np.int32), cache, sds((n, w), np.int32),
+        sds((width // bs,), np.int32), sds((5, n), np.int32), sds((n,), np.float32),
+        sds((2,), np.uint32), sds((b,), np.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == ["moe_grouped_experts"] * 4 and "ragged-dot" not in text
+    assert not pool_copy.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+# ---------------------------------------------------------------------------
 # The expert layers' decode kernel (ops/moe.py), both expert models' widths
 # ---------------------------------------------------------------------------
 _MOE_WIDTHS = {  # layers in the stack, held experts, hidden, expert width
