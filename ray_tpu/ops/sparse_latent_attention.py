@@ -12,11 +12,15 @@ index queries ``qi`` [Hi, Di] and head weights ``w`` [Hi] the score of cached
 token ``s <= t`` is ``I(t, s) = sum_j w_j relu(qi_j . k_s)`` (float32); the
 query attends, in the absorbed form, to the ``k`` cached tokens of largest
 score and to no other (to all of ``0..t`` while there are at most ``k``).
-The selection is exact (``jax.lax.top_k``).
+The selection is exact, and what it returns is each chosen token's PLACE IN THE
+POOL of rows laid flat (``block * bs + offset``), not its position in the
+sequence: ``select`` is ONE sort whose payload is made from the slot's table by
+a broadcast before it, so no position is ever looked up in a table afterwards
+and the gather is one flat index.
 
 - Decode, one query a slot: ``index_scores`` (the slot's index keys through
-  its table, positions past ``lens`` at ``-inf``), ``select``, ``gather_rows``
-  (positions to (block, offset) through the table), ``attend_rows``.
+  its table, positions past ``lens`` at ``-inf``), ``select``, ``gather_rows``,
+  ``attend_rows``.
 - A chunk call, a tile of queries a slot: ``sparse_chunk_attention``. Each
   QUERY has its own list. A tile's scores are ``[queries, keys]`` in float32,
   made ``_KV_ROWS`` keys at a time up to the tile's last real query (the
@@ -65,21 +69,52 @@ def index_scores(qi, w, keys_pool, tables, lens):
     return jnp.where(jnp.arange(W * bs)[None, :] <= lens[:, None], s, -jnp.inf)
 
 
+def _descending_key(scores):
+    """float32 -> int32 whose ASCENDING order is the scores' descending one
+    (``-0.0`` with ``0.0``; ``-inf`` last of all numbers): a sort of integers
+    compares no float."""
+    bits = jax.lax.bitcast_convert_type(scores + 0.0, jnp.int32)
+    return ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
 @jax.named_scope("sparse.select")
-def select(scores, k: int):
-    """scores [.., m] float32 (``-inf``: no such token) -> (positions [.., k]
-    int32 of the k largest, valid [.., k]: whether the place holds a token)."""
-    top, pos = jax.lax.top_k(scores, min(k, scores.shape[-1]))
-    return pos.astype(jnp.int32), top > -jnp.inf
+def select(scores, tables, bs: int, blocks: int, k: int):
+    """scores [.., m] float32 over a sequence's positions (``-inf``: no such
+    token); tables [.., m // bs] (or [m // bs]: one table for every list) the
+    sequence's block ids in a pool of ``blocks`` blocks of ``bs`` -> (ids [.., k]
+    int32: the places ``block * bs + offset``, in the pool laid flat, of the k
+    largest scores, largest first and equal scores in the order of their
+    positions, exactly as ``jax.lax.top_k`` orders positions; valid [.., k]:
+    whether the place holds a token).
+
+    One sort, which carries the places. ``top_k`` is this sort with the position
+    as its second key; here the second key is ``position << bits | block`` while
+    that fits 31 bits (the same order, and the block comes out with it), else
+    the sort is stable with ``block * bs + offset`` for a payload (a third
+    operand on a TPU: slower, as exact)."""
+    m = scores.shape[-1]
+    k = min(k, m)
+    key = _descending_key(scores)
+    pos = jnp.arange(m, dtype=jnp.int32)
+    block = jnp.repeat(tables, bs, axis=-1)
+    bits = (blocks - 1).bit_length()
+    if (m - 1).bit_length() + bits <= 31:
+        key, packed = jax.lax.sort((key, jnp.broadcast_to(pos << bits | block, scores.shape)),
+                                   dimension=-1, num_keys=2, is_stable=False)
+        packed = packed[..., :k]
+        ids = (packed & ((1 << bits) - 1)) * bs + (packed >> bits) % bs
+    else:
+        key, ids = jax.lax.sort((key, jnp.broadcast_to(block * bs + pos % bs, scores.shape)),
+                                dimension=-1, num_keys=1, is_stable=True)
+        ids = ids[..., :k]
+    return ids, key[..., :k] < _descending_key(jnp.float32(-jnp.inf))
 
 
 @jax.named_scope("sparse.gather")
-def gather_rows(pool, tables, pos):
-    """pool [P, bs, R]; tables [b, W]; pos [b, k] positions in each slot's
-    sequence -> their cached rows [b, k, R]."""
-    bs = pool.shape[1]
-    blocks = jnp.take_along_axis(tables, pos // bs, axis=1)
-    return pool[blocks, pos % bs]
+def gather_rows(pool, ids):
+    """pool [P, bs, R]; ids [.., k] places in the pool laid flat (``select``)
+    -> their cached rows [.., k, R]."""
+    return pool.reshape(-1, pool.shape[-1])[ids]
 
 
 @jax.named_scope("sparse.attend")
@@ -98,8 +133,9 @@ def sparse_attention(q, qi, w, rows_pool, keys_pool, tables, key_tables, lens, s
     """Decode, the four parts in their order: q [b, H, R], qi [b, Hi, Di], w [b,
     Hi]; ``tables`` addresses ``rows_pool`` and ``key_tables`` the same blocks in
     ``keys_pool``; lens [b] -> [b, H, rank]."""
-    pos, valid = select(index_scores(qi, w, keys_pool, key_tables, lens), topk)
-    return attend_rows(q, gather_rows(rows_pool, tables, pos), valid, scale, rank)
+    ids, valid = select(index_scores(qi, w, keys_pool, key_tables, lens), tables,
+                        rows_pool.shape[1], rows_pool.shape[0], topk)
+    return attend_rows(q, gather_rows(rows_pool, ids), valid, scale, rank)
 
 
 def sparse_chunk_attention(q, qi, w, rows_pool, keys_pool, tables, key_tables, qpos, live,
@@ -138,9 +174,9 @@ def sparse_chunk_attention(q, qi, w, rows_pool, keys_pool, tables, key_tables, q
 
         def group(at):
             def attend():
-                at_pos, valid = select(jax.lax.dynamic_slice_in_dim(scores, at, g), topk)
-                with jax.named_scope("sparse.gather"):
-                    got = rows_pool[row[at_pos // bs], at_pos % bs]  # [g, k, R]
+                chosen, valid = select(jax.lax.dynamic_slice_in_dim(scores, at, g), row, bs,
+                                       rows_pool.shape[0], topk)
+                got = gather_rows(rows_pool, chosen)  # [g, k, R]
                 return attend_rows(jax.lax.dynamic_slice_in_dim(qt, at, g), got, valid, scale, rank)
 
             return jax.lax.cond(at < real, attend, lambda: jnp.zeros((g, H, rank), q.dtype))

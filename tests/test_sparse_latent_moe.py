@@ -231,12 +231,122 @@ def test_selection_is_latent_attention_while_the_context_is_under_index_topk(pro
 def test_select_takes_the_largest_scores_exactly_and_knows_which_places_are_empty():
     scores = jnp.asarray([[0.5, -jnp.inf, 2.0, 1.0, -3.0, -jnp.inf],
                           [1.0, 3.0, -jnp.inf, -jnp.inf, -jnp.inf, -jnp.inf]])
-    pos, valid = sparse.select(scores, 3)
+    pos, valid = sparse.select(scores, jnp.arange(6), 1, 6, 3)  # blocks of one: the places ARE positions
     assert np.array_equal(pos[0], [2, 3, 0]) and np.array_equal(valid, [[1, 1, 1], [1, 1, 0]])
     assert np.array_equal(pos[1][:2], [1, 0])
     # a list longer than the table: every position, once
-    pos, valid = sparse.select(scores, 99)
+    pos, valid = sparse.select(scores, jnp.arange(6), 1, 6, 99)
     assert pos.shape == (2, 6) and int(valid.sum()) == 6
+
+
+# The selection as PR 59 had it, in two steps: ``jax.lax.top_k`` gives POSITIONS in the
+# sequence, and each position is then looked up in the slot's table (a gather of its
+# own). The oracle of the one-step form, whose sort carries the places in the pool.
+
+
+def _top_k_positions(scores, k):
+    top, pos = jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    return pos.astype(jnp.int32), top > -jnp.inf
+
+
+def _through_the_table(tables, pos, bs):
+    """tables [b, W] (or [1, W]: one table for every list); pos [b, k] positions
+    -> their (block [b, k], offset [b, k]): a gather from the table."""
+    tables = jnp.broadcast_to(tables, pos.shape[:1] + tables.shape[1:])
+    return jnp.take_along_axis(tables, pos // bs, axis=1), pos % bs
+
+
+def _scores(rng, kind, b=4, m=48):
+    s = rng.normal(size=(b, m)).astype(np.float32)
+    if kind == "tied":  # few distinct values, zeros of both signs among them
+        s = np.round(s * 2) / 2
+        s[:, ::7] = -0.0
+    elif kind == "tails":  # a different count of real tokens a row; one row with none
+        for i, live in enumerate([0, 5, 17, 48][:b]):
+            s[i, live:] = -np.inf
+    return jnp.asarray(s)
+
+
+@pytest.mark.parametrize("blocks", [40, 2**27], ids=["packed", "stable"])
+@pytest.mark.parametrize("kind,k,pad", [
+    ("distinct", 12, 0), ("tied", 12, 0), ("tails", 12, 0), ("tails", 99, 0), ("tails", 12, 2)],
+    ids=["distinct", "tied", "inf_tails", "k_above_m", "padded_table"])
+def test_select_gives_top_k_looked_up_through_the_table(kind, k, pad, blocks):
+    """The places the sort carries are exactly the (block, offset) that the
+    positions of ``jax.lax.top_k`` give through the table: the same set in the
+    same order, ties and ``-inf`` places in the order of their positions, and
+    the same ``valid``; with a table padded by the trash block (0) as a chunk
+    call pads it, whose positions score ``-inf``. In both forms: the second key
+    that packs position and block (a pool of 40 blocks), and the stable sort
+    with the place as payload (a pool too large to pack beside 48 positions)."""
+    rng = np.random.default_rng(11)
+    bs, W = 8, 6 - pad
+    tables = jnp.asarray(rng.permutation(np.arange(1, 40))[:4 * W].reshape(4, W), jnp.int32)
+    tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    scores = _scores(rng, kind)
+    if pad:
+        scores = scores.at[:, W * bs:].set(-jnp.inf)
+    got, valid = jax.jit(lambda s, t: sparse.select(s, t, bs, blocks, k))(scores, tables)
+    pos, want_valid = _top_k_positions(scores, k)
+    block, offset = _through_the_table(tables, pos, bs)
+    want = block * bs + offset
+    assert got.dtype == jnp.int32 and got.shape == (4, min(k, 48))
+    assert np.array_equal(got, want) and np.array_equal(valid, want_valid)
+    if kind == "tails":
+        assert [int(v) for v in valid.sum(axis=1)] == [min(n, k) for n in (0, 5, 17, 48 - pad * bs)]
+    # one table for every list (a chunk call's tile)
+    one, _ = sparse.select(scores, tables[0], bs, blocks, k)
+    assert np.array_equal(one[0], want[0])
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "chunk_padded"])
+def test_attention_over_carried_ids_is_bit_identical_to_the_two_step_form(program, monkeypatch):
+    """At the rehearsal's sizes (4 slots, tables of 16 blocks of 8, 16 index
+    heads of 16, 16 of up to 128 cached tokens, 4 heads on rows of 128; tiles
+    of 32 queries): ``sparse_attention`` and ``sparse_chunk_attention`` give the
+    SAME BITS as the program's own scores under ``top_k`` and the lookup
+    through the table; ``chunk_padded`` scores 40 keys a step, so the table of
+    16 is padded to 20 by the trash block."""
+    rng = np.random.default_rng(12)
+    b, Wd, H, R, rank, Hi, Di, topk, C = 4, 16, 4, 128, 32, 16, 16, 16, 32
+    pool = jnp.asarray(rng.normal(size=(129, BS, R)), jnp.float32)
+    keys = jnp.asarray(rng.normal(size=(129, BS, Di)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, 129))[:b * Wd].reshape(b, Wd), jnp.int32)
+    key_tables = tables  # one pool a kind here: the model adds a layer's base to each
+    if program == "decode":
+        lens = jnp.asarray([83, 0, 9, 127], jnp.int32)  # above, idle, under ``topk``, the table's end
+        q = jnp.asarray(rng.normal(size=(b, H, R)), jnp.float32)
+        qi = jnp.asarray(rng.normal(size=(b, Hi, Di)), jnp.float32)
+        w = jnp.asarray(rng.normal(size=(b, Hi)), jnp.float32)
+        got = jax.jit(lambda: sparse.sparse_attention(
+            q, qi, w, pool, keys, tables, key_tables, lens, 0.2, rank, topk))()
+        pos, valid = _top_k_positions(sparse.index_scores(qi, w, keys, key_tables, lens), topk)
+        want = sparse.attend_rows(q, pool[_through_the_table(tables, pos, BS)], valid, 0.2, rank)
+        assert np.array_equal(got, want)
+        return
+    if program == "chunk_padded":
+        monkeypatch.setattr(sparse, "_KV_ROWS", 40)
+    qpos = jnp.asarray([[64], [0], [40], [96]], jnp.int32) + jnp.arange(C)[None, :]
+    live = jnp.asarray([32, 17, 0, 20], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, C, H, R)), jnp.float32)
+    qi = jnp.asarray(rng.normal(size=(b, C, Hi, Di)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(b, C, Hi)), jnp.float32)
+
+    def call(tile=slice(None)):
+        return sparse.sparse_chunk_attention(
+            q[tile], qi[tile], w[tile], pool, keys, tables[tile], key_tables[tile], qpos[tile],
+            live[tile], 0.2, rank, topk)
+
+    got = call()
+    # The oracle a tile at a time: the program's own scores, then the two steps
+    # through THAT tile's table, padded as the program pads it.
+    padded = jnp.pad(tables, ((0, 0), (0, 4 if program == "chunk_padded" else 0)))
+    monkeypatch.setattr(sparse, "select", lambda scores, _t, _bs, _blocks, k: _top_k_positions(scores, k))
+    for i in range(b):
+        monkeypatch.setattr(sparse, "gather_rows",
+                            lambda rows, pos, i=i: rows[_through_the_table(padded[i:i + 1], pos, BS)])
+        assert np.array_equal(got[i], call(slice(i, i + 1))[0]), i
+    assert np.asarray(got[0]).any() and not np.asarray(got[2]).any()
 
 
 @pytest.mark.parametrize("window,queries,bs,want", [
