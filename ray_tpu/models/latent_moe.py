@@ -207,10 +207,12 @@ def project(h, lp: Params, cfg: LatentMoEConfig, positions):
         q = (c_q @ lp["w_uq"].astype(h.dtype)).reshape(b, s, H, nope + rope)
     else:  # no query latent (``models/kda_moe.py``): ONE matrix, the norm a head's
         q = _norm((h @ lp["w_q"].astype(h.dtype)).reshape(b, s, H, nope + rope), lp["q_norm"], cfg)
-    q_rope = _rope(q[..., nope:], positions, cfg.rope_theta)
+    # The frequencies themselves where the configuration blends them (YaRN), else their base.
+    theta = getattr(cfg, "rope_frequencies", cfg.rope_theta)
+    q_rope = _rope(q[..., nope:], positions, theta)
     kv = h @ lp["w_dkv"].astype(h.dtype)
     c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg)
-    k_rope = _rope(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _rope(kv[..., None, cfg.kv_lora_rank:], positions, theta)[:, :, 0]
     pad = jnp.zeros((b, s, cfg.row_width - cfg.kv_lora_rank - rope), h.dtype)
     return q[..., :nope], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
 
@@ -388,19 +390,29 @@ def expert_layer(y, lp: Params, cfg: LatentMoEConfig, held: Params, layer):
     return m, counts
 
 
-def _finish(x, a, lp: Params, cfg: LatentMoEConfig, params: Params, index):
-    """Layer ``index`` after its attention output ``a`` [b, s, D]: sandwich
-    norms around the feed-forward, which is the expert layer where the
-    layer's parameters hold a router. → (x, counts or None)."""
-    x = x + _norm(a, lp["post_attn_norm"], cfg)
-    y = _norm(x, lp["mlp_norm"], cfg)
+def feed_forward(y, lp: Params, cfg: LatentMoEConfig, params: Params, index):
+    """Layer ``index``'s feed-forward of the normed ``y`` [b, s, D]: the expert
+    layer where the layer's parameters hold a router, else the dense SwiGLU.
+    → (m [b, s, D], counts or None)."""
     if "router" in lp:
         m, counts = expert_layer(y.reshape(-1, y.shape[-1]), lp, cfg, params["experts"],
                                  index - cfg.first_k_dense_replace)
-        m = m.reshape(y.shape)
-    else:
-        m, counts = _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
-    return x + _norm(m, lp["post_mlp_norm"], cfg), counts
+        return m.reshape(y.shape), counts
+    return _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+
+
+def _post(x, lp: Params, name: str, cfg):
+    """A sandwich norm on a sublayer's output, where the layer has one (a
+    model without them, ``models/hyper_latent_moe.py``, adds the output as it is)."""
+    return _norm(x, lp[name], cfg) if name in lp else x
+
+
+def _finish(x, a, lp: Params, cfg: LatentMoEConfig, params: Params, index):
+    """Layer ``index`` after its attention output ``a`` [b, s, D]: sandwich
+    norms around the feed-forward. → (x, counts or None)."""
+    x = x + _post(a, lp, "post_attn_norm", cfg)
+    m, counts = feed_forward(_norm(x, lp["mlp_norm"], cfg), lp, cfg, params, index)
+    return x + _post(m, lp, "post_mlp_norm", cfg), counts
 
 
 # ---------------------------------------------------------------------------
@@ -408,41 +420,54 @@ def _finish(x, a, lp: Params, cfg: LatentMoEConfig, params: Params, index):
 # ---------------------------------------------------------------------------
 
 
-def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, params, index, bases):
-    """One layer, one token a slot. x: [b, 1, D]; pools: (rows [P, bs, R],);
-    tables: [b, W] block ids into it, from ``bases[0]``; lens: [b] write positions."""
-    (pool,) = pools
-    tables = tables + bases[0]
+def decode_attention(h, pool, lp: Params, cfg: LatentMoEConfig, tables, lens):
+    """The attention sublayer, one token a slot. h: the normed hidden [b, 1,
+    D]; pool: rows [P, bs, R]; tables: [b, W] block ids into it; lens: [b]
+    write positions. → (its output [b, 1, D], the pool with the tokens' rows)."""
     bs = pool.shape[1]
-    q_nope, q_rope, rows = project(_norm(x, lp["attn_norm"], cfg), lp, cfg, lens[:, None])
+    q_nope, q_rope, rows = project(h, lp, cfg, lens[:, None])
     q = absorb(q_nope[:, 0], q_rope[:, 0], lp, cfg)
     with jax.named_scope("latent.scatter"):
         phys = jnp.take_along_axis(tables, (lens // bs)[:, None], axis=1)[:, 0]
         pool = pool.at[phys, lens % bs].set(rows[:, 0])
     # After the scatter, so the token just written attends to itself.
     u = latent_attention(q, pool, tables, lens, cfg.softmax_scale, cfg.kv_lora_rank)
-    x, counts = _finish(x, attention_out(u, lp, cfg)[:, None, :], lp, cfg, params, index)
-    return x, (pool,), counts
+    return attention_out(u, lp, cfg)[:, None, :], pool
 
 
-def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at, offs, qpos,
-                 live, params, index, bases, _slot_of):
-    """One layer over a chunk call's token axis. x: [1, T, D]; table_rows:
-    [n, W] each tile's slot's table; token j's row lands at (rows_at[j],
-    offs[j]); qpos: [n, C] absolute positions by tile; live: [n] a tile's
-    real tokens, the attention of the others is zeros."""
-    (pool,) = pools
-    table_rows, rows_at = table_rows + bases[0], rows_at + bases[0]
+def chunk_attention(h, pool, lp: Params, cfg: LatentMoEConfig, table_rows, rows_at, offs, qpos, live):
+    """The attention sublayer over a chunk call's token axis. h: the normed
+    hidden [1, T, D]; table_rows: [n, W] each tile's slot's table; token j's
+    row lands at (rows_at[j], offs[j]); qpos: [n, C] absolute positions by
+    tile; live: [n] a tile's real tokens, the attention of the others is
+    zeros. → (its output [1, T, D], the pool with the tokens' rows)."""
     n, C = qpos.shape
-    q_nope, q_rope, rows = project(
-        _norm(x, lp["attn_norm"], cfg), lp, cfg, qpos.reshape(1, n * C))
+    q_nope, q_rope, rows = project(h, lp, cfg, qpos.reshape(1, n * C))
     q = absorb(q_nope[0], q_rope[0], lp, cfg)  # [T, H, R]
     with jax.named_scope("latent.scatter"):
         pool = pool.at[rows_at, offs].set(rows[0])
     u = latent_chunk_attention(
         q.reshape((n, C) + q.shape[1:]), pool, table_rows, qpos, live,
         cfg.softmax_scale, cfg.kv_lora_rank)
-    a = attention_out(u.reshape((1, n * C) + u.shape[2:]), lp, cfg)
+    return attention_out(u.reshape((1, n * C) + u.shape[2:]), lp, cfg), pool
+
+
+def _decode_layer(cfg: LatentMoEConfig, x, pools, lp: Params, tables, lens, params, index, bases):
+    """One layer, one token a slot. x: [b, 1, D]; pools: (rows [P, bs, R],);
+    tables: [b, W] block ids into it, from ``bases[0]``; lens: [b] write positions."""
+    tables = tables + bases[0]
+    a, pool = decode_attention(_norm(x, lp["attn_norm"], cfg), pools[0], lp, cfg, tables, lens)
+    x, counts = _finish(x, a, lp, cfg, params, index)
+    return x, (pool,), counts
+
+
+def _chunk_layer(cfg: LatentMoEConfig, x, pools, lp: Params, table_rows, rows_at, offs, qpos,
+                 live, params, index, bases, _slot_of):
+    """One layer over a chunk call's token axis. x: [1, T, D]; the rest as
+    ``chunk_attention`` has it, the block ids from ``bases[0]``."""
+    table_rows, rows_at = table_rows + bases[0], rows_at + bases[0]
+    a, pool = chunk_attention(_norm(x, lp["attn_norm"], cfg), pools[0], lp, cfg,
+                              table_rows, rows_at, offs, qpos, live)
     x, counts = _finish(x, a, lp, cfg, params, index)
     return x, (pool,), counts
 

@@ -134,11 +134,18 @@ def rms_norm(x, scale, eps: float = 1e-5):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
-    """x: [b, s, h, hd]; rotate pairs (llama convention: split halves)."""
+def _rope(x, positions, theta):
+    """x: [b, s, h, hd]; rotate pairs (llama convention: split halves).
+    ``theta``: the base of the ``hd / 2`` frequencies ``theta ** (-i / half)``,
+    or the frequencies themselves, one a pair (a configuration that blends
+    them, as YaRN does: ``models/hyper_latent_moe.py``)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if isinstance(theta, (int, float)):
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(theta, jnp.float32)
+        assert freqs.shape == (half,), (freqs.shape, half)
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [b,s,half]
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)

@@ -135,8 +135,13 @@ _MOE_COUNTS = ("moe_pairs_here", "moe_experts_touched", "moe_layer_steps", "moe_
 # window (``models/sparse_latent_moe.py``), behind the five above: cached tokens
 # a query could have read, summed over slots (or a chunk call's real queries),
 # selecting layers and steps; how many of them it selected; cache rows the
-# window layers' reads covered. A model returns the first so many of ``_COUNTS``.
-_COUNTS = _MOE_COUNTS + ("sparse_keys_live", "sparse_keys_selected", "window_rows_read")
+# window layers' reads covered. Behind those, of a model with several residual
+# streams a token (``models/hyper_latent_moe.py``): token places (padding and
+# idle slots included) times the sublayers whose output was mixed into the
+# streams, and real tokens times the same. A model returns the first so many
+# of ``_COUNTS``.
+_COUNTS = _MOE_COUNTS + ("sparse_keys_live", "sparse_keys_selected", "window_rows_read",
+                         "hc_places_mixed", "hc_tokens_mixed")
 
 
 def _chunk_ladder(chunk: int, block_size: int) -> List[int]:

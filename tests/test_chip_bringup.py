@@ -916,6 +916,39 @@ def test_sparse_programs_read_pools_where_they_lie_on_v5e(v5e):
 
 
 # ---------------------------------------------------------------------------
+# Several residual streams a token (ops/hyper_connections.py): plain XLA, so what
+# is held is that the mixes compile for the chip and copy the streams no more
+# often than they must
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("places", [(32, 1), (1, 1024)], ids=["decode", "chunk"])
+def test_hyper_connections_compile_for_v5e_at_the_published_widths(v5e, places):
+    """One sublayer's maps and both mixes around a stand-in sublayer, at the served
+    model's widths (4 streams of 3,584, 20 Sinkhorn rounds) for a decode step's 32 places
+    and a chunk call's 1,024: the Sinkhorn rounds are ONE ``while`` of four steps (five
+    rounds unrolled a step), the product with ``phi`` is the program's only convolution,
+    and the temporaries stay under eight copies of the streams."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.hyper_latent_moe import HyperLatentMoEConfig, hc_shapes
+    from ray_tpu.ops import hyper_connections as hc
+
+    cfg = HyperLatentMoEConfig()
+    one = SingleDeviceSharding(v5e[0])
+    X = jax.ShapeDtypeStruct(places + (cfg.hc_mult, cfg.hidden_size), jnp.bfloat16, sharding=one)
+    hp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one) for name, shape in hc_shapes(cfg).items()}
+
+    def sublayer(X, hp):
+        pre, post, res = hc.maps(X, hp, cfg)
+        return hc.mix_out(res, post, X, jnp.tanh(hc.mix_in(pre, X)))
+
+    compiled = jax.jit(sublayer).lower(X, hp).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\) while\(", text)) == 1 and len(re.findall(r" convolution\(", text)) == 1
+    streams = places[0] * places[1] * cfg.hc_mult * cfg.hidden_size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * streams + 2**21
+
+
+# ---------------------------------------------------------------------------
 # The expert layers' decode kernel (ops/moe.py), both expert models' widths
 # ---------------------------------------------------------------------------
 _MOE_WIDTHS = {  # layers in the stack, held experts, hidden, expert width
